@@ -1,5 +1,5 @@
 """Overlap-forest vs post-hoc global sort: the network-levitated
-property's perf datum (VERDICT r3 weak #5 / task #7).
+property's perf datum.
 
 The reference's headline property is that merging overlaps fetching, so
 the post-last-fetch latency is small (reference MergeManager.cc:47-182).
@@ -37,7 +37,6 @@ sys.path.insert(0, REPO)
 
 from uda_tpu.utils import compile_cache  # noqa: E402
 
-compile_cache.apply_platform_env()
 compile_cache.enable()
 
 import numpy as np  # noqa: E402

@@ -1,12 +1,12 @@
 #!/usr/bin/env python
 """Fused staging-pipeline A/B: pipelined vs serial stage path.
 
-ISSUE 9's tentpole gate. The device engine hit 3.1 GB/s single-chip
-(BENCH_HW_r05.json) while serial staging fed it at 42-72 MB/s
-(STAGING_BENCH_r05.json) — the device was starved, not slow. The fix is
-the bounded stage pool + merge consumer in uda_tpu.merger.overlap
-(uda.tpu.stage.pipeline). This bench proves both halves of the claim on
-CPU, where correctness is provable without a pool window:
+ISSUE 9's tentpole gate. Serial staging fed the device engine at
+42-72 MB/s on the sandbox host (STAGING_BENCH_r05.json), far below any
+device sort rate on record (git history; not measured on this machine).
+The fix is the bounded stage pool + merge consumer in
+uda_tpu.merger.overlap (uda.tpu.stage.pipeline). This bench proves both
+halves of the claim on the CPU, where correctness is provable:
 
 - **correctness gate** (always, and all of ``--quick``): the pipelined
   staging path is BYTE-IDENTICAL to the serial path across
@@ -18,10 +18,6 @@ CPU, where correctness is provable without a pool window:
   regress (>= 0.95x) — plus ``merge.wait_ms`` p95 (how long the merge
   waited for each run to become mergeable) for both paths in the same
   run: the pipeline must DROP it.
-
-Hardware re-probe of the device-side levers (keys8f / lanes2 /
-cc-ladder / two-phase) is staged separately in scripts/tpu_return.py —
-pending pool recovery, not claimed here.
 
 Usage: python scripts/bench_pipeline.py [--segs 64] [--seg-mb 64]
        [--quick] [--out BENCH_PIPELINE.json]
@@ -43,8 +39,8 @@ sys.path.insert(0, REPO)
 
 
 def _force_cpu() -> None:
-    # staging is HOST work; the bench is valid on any backend. Force CPU
-    # so a wedged TPU pool can't hang the run.
+    # staging is HOST work; the bench is valid on any backend, so it
+    # holds no chip.
     import jax
 
     jax.config.update("jax_platforms", "cpu")

@@ -37,8 +37,8 @@ sys.path.insert(0, REPO)
 
 
 def _force_cpu_if_no_tpu() -> None:
-    # staging is HOST work; the bench is valid on any backend. Force CPU
-    # so a wedged TPU pool can't hang the run.
+    # staging is HOST work; the bench is valid on any backend, so it
+    # holds no chip.
     import jax
 
     jax.config.update("jax_platforms", "cpu")

@@ -181,8 +181,8 @@ def check_file(path: str, rel: str, known_classes: set[str],
 
 
 def check_callback_table(java_root: str, errors: list[str]) -> None:
-    """Callback-name resolution across the three bridge layers
-    (VERDICT.md ask #7): the up-call table must agree between
+    """Callback-name resolution across the three bridge layers: the
+    up-call table must agree between
 
     - ``bridge_shim.cc``'s ``uda_callbacks_t`` struct (the C ABI: one
       ``ctx`` plus N ordered function-pointer fields) and its

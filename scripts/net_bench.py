@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """CPU-only loopback benchmark of the network shuffle data plane.
 
-The net plane's perf trajectory without the (frequently unreachable)
-accelerator pool: a ShuffleServer over a synthetic MOF on 127.0.0.1,
+The net plane's perf trajectory without an accelerator: a
+ShuffleServer over a synthetic MOF on 127.0.0.1,
 measured three ways on the event-loop core (the ONLY core since the
 legacy threaded baseline was deleted — its last measured point is
 ``BENCH_NET_r06.json``: 944 vs 323 MB/s single-stream, 2.92x):

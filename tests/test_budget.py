@@ -33,7 +33,7 @@ KT = comparators.get_key_type("uda.tpu.RawBytes")
 # -- the device-bytes model --------------------------------------------------
 
 def test_device_bytes_model_shape():
-    # the VERDICT.md model: a 10 GB TeraSort partition's device working
+    # the admission model: a 10 GB TeraSort partition's device working
     # set exceeds a v5e's 16 GB HBM (the OOM scenario this PR closes)
     dev = device_bytes_estimate(10 << 30, key_width=16)
     assert dev > 16 << 30
@@ -44,6 +44,32 @@ def test_device_bytes_model_shape():
     assert device_bytes_estimate(1000, key_width=16, record_bytes=10) \
         >= 100 * 28
     assert device_bytes_estimate(0, 16) == 0
+
+
+def test_detect_hbm_on_an_accelerator_reports_or_raises(monkeypatch):
+    # off the CPU the budget is what the device reports, else its
+    # device_kind's table entry — never a guess
+    import jax
+
+    from uda_tpu.utils import budget
+
+    class Dev:
+        def __init__(self, kind, stats):
+            self.device_kind, self._stats = kind, stats
+
+        def memory_stats(self):
+            return self._stats
+
+    dev = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: dev)
+    dev[:] = [Dev("TPU v5 lite", {"bytes_limit": 1234 * MB})]
+    assert budget._detect_hbm_mb() == 1234
+    dev[:] = [Dev("TPU v5 lite", None)]
+    assert budget._detect_hbm_mb() == 16 * 1024
+    dev[:] = [Dev("TPU v9 mystery", {})]
+    with pytest.raises(UdaError, match="TPU v9 mystery"):
+        budget._detect_hbm_mb()
 
 
 def test_budget_defaults_resolve_lazily_and_from_config():

@@ -253,11 +253,7 @@ def test_lanes_engines_type_check_with_check_vma():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from uda_tpu.parallel import SHARD_MAP_NATIVE_VMA, shard_map
-
-    if not SHARD_MAP_NATIVE_VMA:
-        pytest.skip("vma checker needs a jax.shard_map with check_vma "
-                    "(legacy check_rep has no pallas_call rule)")
+    from uda_tpu.parallel import shard_map
 
     from uda_tpu.parallel import distributed as D
 

@@ -386,16 +386,18 @@ def test_device_trace_captures_profile(tmp_path):
     assert produced, "no profile artifacts written"
 
 
-def test_device_trace_survives_profiler_failure(tmp_path):
-    # a second concurrent trace normally raises inside start_trace; the
-    # hook must degrade to a no-op instead of failing the job
+def test_device_trace_raises_when_trace_cannot_start(tmp_path):
+    # a second concurrent trace raises inside start_trace; a trace that
+    # was asked for and cannot start must fail the run, not degrade to
+    # an untraced one that looks traced
     import jax
 
     jax.profiler.start_trace(str(tmp_path / "outer"))
     try:
         ran = []
-        with device_trace(str(tmp_path / "inner")):
-            ran.append(1)
-        assert ran == [1]
+        with pytest.raises(Exception):
+            with device_trace(str(tmp_path / "inner")):
+                ran.append(1)
+        assert ran == []
     finally:
         jax.profiler.stop_trace()

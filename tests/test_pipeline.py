@@ -275,6 +275,25 @@ def test_route_engine_steers_deployed_gather_engine(monkeypatch):
         sort_ops.resolve_sort_path("auto")
 
 
+def test_distributed_auto_never_routes_carrychunk_on_tpu(monkeypatch):
+    # carrychunk did not compile inside the fused step on four chips
+    # (chip run of 2026-09-26), so the step's "auto" lands on lanes
+    # whichever way carrychunk was reached; explicit stays explicit
+    from uda_tpu.parallel.distributed import _resolve_payload_path
+
+    assert _resolve_payload_path("auto", 25, 3, 1 << 24) == "carry"  # CPU
+    monkeypatch.setattr(sort_ops.jax, "default_backend", lambda: "tpu")
+    assert sort_ops.route_engine(1 << 24, "auto", lanes_ok=True) == \
+        "carrychunk"                    # the single-chip default stays
+    assert _resolve_payload_path("auto", 25, 3, 1 << 24) == "lanes"
+    assert _resolve_payload_path("carrychunk", 25, 3, 1 << 24) == \
+        "carrychunk"
+    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "keys8")
+    assert _resolve_payload_path("auto", 25, 3, 1 << 24) == "keys8"
+    # small-batch steering would hand the step carrychunk: lanes instead
+    assert _resolve_payload_path("auto", 25, 3, 1 << 16) == "lanes"
+
+
 def test_feed_racing_abort_releases_charge():
     # the narrow window: _charge() sees the abort flag unset, abort()
     # then completes fully (threads joined, queue reaped) before the

@@ -195,10 +195,9 @@ def test_empty_batch():
 
 
 def test_apply_perm_chunked_all_sweep_widths():
-    # every chunk width the hardware sweep times (scripts/
-    # sweep_carrychunk.py: cc=6/8/12/23) plus the degenerate and
-    # over-wide extremes must be a pure refactoring of the same
-    # permutation apply — byte-identical outputs per column
+    # every chunk width of the ladder (cc=6/8/12/23) plus the
+    # degenerate and over-wide extremes must be a pure refactoring of
+    # the same permutation apply — byte-identical outputs per column
     import jax
     import numpy as np
 

@@ -351,8 +351,7 @@ def test_perfwatch_ingests_all_historical_artifacts(tmp_path):
     entries = doc["entries"]
     assert len(entries) > 100
     workloads = {e["workload"] for e in entries}
-    assert {"pipeline", "net", "terasort_singlechip",
-            "regression_small"} <= workloads
+    assert {"pipeline", "net", "regression_small"} <= workloads
     # every entry normalized: required keys + sane directions
     for e in entries:
         assert e["direction"] in ("up", "down", "info")

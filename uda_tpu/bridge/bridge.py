@@ -38,6 +38,7 @@ from uda_tpu.bridge.protocol import Cmd, parse_cmd
 from uda_tpu.merger import LocalFetchClient, MergeManager
 from uda_tpu.merger.segment import InputClient
 from uda_tpu.mofserver import DataEngine, IndexRecord, IndexResolver
+from uda_tpu.utils import compile_cache
 from uda_tpu.utils.budget import MemoryBudget
 from uda_tpu.utils.config import Config
 from uda_tpu.utils.errors import FallbackSignal, ProtocolError, UdaError
@@ -149,6 +150,7 @@ class UdaBridge:
               callable_obj: Optional[UdaCallable] = None) -> None:
         """startNative: parse argv (the reference's getopt channel), wire
         the conf pull channel, pick the role (UdaBridge.cc:187-263)."""
+        compile_cache.enable()
         self.callable = callable_obj
         self.is_net_merger = is_net_merger
         self._argv = list(argv)

@@ -38,6 +38,7 @@ from uda_tpu.merger.emitter import FramedEmitter
 from uda_tpu.merger.recovery import RecoveryLedger
 from uda_tpu.merger.segment import InputClient, Segment
 from uda_tpu.ops import merge as merge_ops
+from uda_tpu.utils import compile_cache
 from uda_tpu.utils.budget import MemoryBudget, stage_inflight_cap
 from uda_tpu.utils.comparators import KeyType, get_key_type
 from uda_tpu.utils.config import Config
@@ -192,6 +193,7 @@ class MergeManager:
                  config: Optional[Config] = None,
                  progress: Optional[Callable[[int, int], None]] = None,
                  seed: int = 0):
+        compile_cache.enable()
         self.cfg = config or Config()
         self.client = client
         self.key_type = (get_key_type(key_type) if isinstance(key_type, str)

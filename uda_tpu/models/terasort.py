@@ -154,12 +154,14 @@ def single_chip_sort(words: jax.Array, path: str = "auto",
     Payload-movement strategy (see bench_step for the full trade-off):
     the lanes engines ("lanes"/"lanes2"/"keys8") run the Pallas
     bitonic pipeline with bounded compile; "carry" rides the 23 value
-    words through a ``lax.sort`` network (fast at runtime, pathological
-    compile on TPU remote-compile backends — the CPU default);
+    words through a ``lax.sort`` network (fast at runtime, but XLA's
+    variadic-sort compile time grows superlinearly in operand count —
+    the CPU default);
     "gather"/"gather2"/"carrychunk" apply a narrow-sort permutation
     (per-column gathers / one minor-dim gather / chunked carry sorts —
-    "carrychunk" is the TPU default via "auto": measured fly-off
-    champion, BENCH_HW_r05.json). "auto" resolves per the ambient
+    "carrychunk" is the TPU default via "auto": winner of the fly-off
+    of 2026-07-31 on a backend that no longer exists, git history; not
+    measured on this machine). "auto" resolves per the ambient
     backend — and the deployed UDA_TPU_SORT_PATH winner — at call time,
     with small batches steered off gather-bound engines
     (ops.sort.route_engine).
@@ -267,10 +269,8 @@ def bench_step(seed: jax.Array, n: int, k: int, path: str = "lanes",
     dispatch), so per-call host/RPC latency amortizes away and the
     result reflects device shuffle+merge throughput.
 
-    Nothing ever materializes an [n, 26] row matrix — on TPU, XLA
-    lane-pads the minor dimension to 128 words (5x HBM footprint and
-    bandwidth). Records are either 26 separate [n] columns (SoA) or the
-    [32, n] lanes layout.
+    Records are either 26 separate [n] columns (SoA) or the [32, n]
+    lanes layout; nothing materializes an [n, 26] row matrix.
 
     Four device strategies:
 
@@ -282,8 +282,8 @@ def bench_step(seed: jax.Array, n: int, k: int, path: str = "lanes",
       regardless of n and record width).
     - ``path="lanes2"``: the two-phase variant — each network runs on
       an 8-row keys view and the payload moves with one in-kernel lane
-      gather (sort_lanes two_phase=True). Faster where Mosaic lowers
-      the dynamic gather well; bench.py decides by a measured fly-off.
+      gather (sort_lanes two_phase=True). Mosaic does not lower that
+      gather (ops.sort.UNCOMPILED_ENGINES): interpret mode only.
     - ``path="keys8"``: the whole cascade runs on an 8-row keys-only
       array (4x less VPU and HBM work than the 32-row pipeline) and the
       payload moves ONCE via a global XLA lane gather (_keys8_parts) —
@@ -298,20 +298,18 @@ def bench_step(seed: jax.Array, n: int, k: int, path: str = "lanes",
       sorts. Payload moves through sort networks like "carry" but every
       sort stays far below the operand count where compile blows up.
     - ``path="carry"``: the payload rides the ``lax.sort`` network as
-      extra operands. Fast at runtime (~12 GB/s, CPU-backend
-      measurement) but XLA's
-      variadic-sort compile time grows superlinearly in operand count —
-      on remote-compile backends the 26-operand program can take hours
-      to compile ONCE (it persists in the compile cache afterwards).
+      extra operands, but XLA's variadic-sort compile time grows
+      superlinearly in operand count (the 26-operand program compiles
+      ONCE and persists in the compile cache afterwards).
     - ``path="gather"``: a 4-operand sort (3 key words + iota) computes
-      the permutation, then per-column gathers apply it. Compiles in
-      ~1 min cold; runtime is gather-bound: 0.30 GB/s measured on the
-      v5e chip at the full bench shape (BENCH_r02) — TPU random
-      per-element gathers are the slowest payload mover by far, which
-      is what motivated the lanes pipeline.
+      the permutation, then per-column gathers apply it. Runtime is
+      gather-bound — random per-element gathers were the slowest
+      payload mover by far on the chip (2026-07 runs, git history; not
+      measured on this machine), which is what motivated the lanes
+      pipeline.
 
-    bench.py probes which path is compilable within its time budget and
-    picks the fastest (see bench.py --probe).
+    bench.py times every candidate that compiles and reports the
+    fastest.
 
     Returns (total order violations, input checksum, output checksum):
     consuming the sorted output in-graph keeps XLA from eliminating any

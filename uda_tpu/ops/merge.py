@@ -399,8 +399,9 @@ def resolve_merge_mode(mode: str, num_batches: int) -> str:
     """Batch-count/backend-aware routing between the whole-shuffle
     re-sort ("resort") and the two-phase partial-sort + HBM merge tree
     ("two_phase"). "auto" takes two-phase on real accelerators (the
-    re-sort's final permutation gather is the small-batch bottleneck
-    the take-ramp exposed: 0.15 GB/s at 2^16 rows, BENCH_NOTES_r05) and
+    re-sort's final permutation gather was the small-batch bottleneck
+    in the take-ramp probe of 2026-07-31 on a backend that no longer
+    exists — git history; not measured on this machine) and
     keeps the re-sort on the XLA CPU backend, where one lexsort-shaped
     sort beats Python-orchestrated pairwise folds. Resolution is EAGER,
     never inside a jitted trace."""

@@ -8,11 +8,13 @@ walk) built for how a TPU actually wants to touch memory:
   layout"): row r holds word r of every record, record i lives in lane
   i. Rows 0..num_keys-1 are the big-endian key words; one row is the
   stability tie-break (global arrival index, written by the tile-sort
-  kernel); remaining rows are payload. Why: XLA lane-pads the minor
-  dimension of an ``[n, 26]`` row matrix to 128 words (5x HBM waste),
-  while ``[32, n]`` is perfectly tiled, every compare-exchange is a
-  lane-axis shift applied to all 32 rows at once, and every DMA window
-  is lane-aligned (the Mosaic rule that rejects ``[n, 26]`` slicing).
+  kernel); remaining rows are payload. Why: in ``[32, n]`` every
+  compare-exchange is a lane-axis shift applied to all 32 rows at
+  once, and every DMA window is lane-aligned (the Mosaic rule that
+  rejects ``[n, 26]`` slicing). (HBM footprint is NOT the reason: on
+  the v5e, libtpu 0.0.34, a ``uint32[n, 26]`` array is itself stored
+  long-dimension-minor at 128 B/record — columns padded 26 -> 32 —
+  and ``[n, 7]`` at 32 B/record; chip_smoke.py, 2026-09-26.)
 - **Tile sort** (`_tile_sort_kernel`): a full bitonic sorting network
   over T lanes in VMEM; static strides lower to lane rotates. Tiles are
   emitted ASCENDING or DESCENDING by tile-index parity — the classic
@@ -169,24 +171,11 @@ def _tile_sort_kernel(x_ref, o_ref, *, tile, num_keys, tb_row, alternate,
         o_ref[...] = net
 
 
-def _vma_of(x):
-    """The shard_map varying-manual-axes set of ``x`` on JAX versions
-    that type it (jax.typeof(...).vma); empty elsewhere — old releases
-    have no vma typing, so there is nothing to propagate."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return ()
-    return tuple(getattr(typeof(x), "vma", ()) or ())
-
-
 def _uint32_struct(shape, x):
-    """uint32 out_shape struct carrying ``x``'s vma so Pallas pipelines
-    work as-is inside distributed shard_map bodies (a plain struct on
-    JAX versions without vma typing)."""
-    vma = _vma_of(x)
-    if vma:
-        return jax.ShapeDtypeStruct(shape, jnp.uint32, vma=vma)
-    return jax.ShapeDtypeStruct(shape, jnp.uint32)
+    """uint32 out_shape struct carrying ``x``'s shard_map varying-manual-
+    axes set, so the Pallas pipelines work as-is inside distributed
+    shard_map bodies (the set is empty outside one)."""
+    return jax.ShapeDtypeStruct(shape, jnp.uint32, vma=jax.typeof(x).vma)
 
 
 @partial(jax.jit, static_argnames=("tile", "num_keys", "tb_row",
@@ -259,10 +248,8 @@ def _pass_splits(x, run_len, final, tile: int, num_keys: int, tb_row: int):
     # pcast the inits to x's vma (a no-op outside shard_map, where vma
     # is empty) — this is what lets the distributed sort run the lanes
     # engines with check_vma=True (see parallel/distributed._sort_step)
-    vma = _vma_of(x)
-    if vma:
-        lo = lax.pcast(lo, vma, to="varying")
-        hi = lax.pcast(hi, vma, to="varying")
+    vma = tuple(sorted(jax.typeof(x).vma))
+    lo, hi = lax.pcast((lo, hi), vma, to="varying")
 
     def body(_, carry):
         lo, hi = carry

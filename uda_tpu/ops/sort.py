@@ -39,7 +39,8 @@ from uda_tpu.ops.packing import PackedKeys
 __all__ = ["sort_permutation", "merge_runs", "sort_records_fixed",
            "concat_packed", "resolve_sort_path", "apply_perm_chunked",
            "route_engine", "LANES_ENGINES", "FLYOFF_ENGINES",
-           "BENCH_FLYOFF", "ALL_SORT_PATHS", "GATHER_BOUND_ENGINES",
+           "BENCH_FLYOFF", "ALL_SORT_PATHS", "UNCOMPILED_ENGINES",
+           "SELECTABLE_SORT_PATHS", "GATHER_BOUND_ENGINES",
            "CC_LADDER", "SMALL_BATCH_ROWS"]
 
 # The single source of truth for engine path names. LANES_ENGINES are
@@ -70,25 +71,31 @@ DEFAULT_CHUNK_COLS = int(os.environ.get("UDA_TPU_CHUNK_COLS", "6"))
 
 # The engine the "auto" policy deploys — how a fly-off/sweep winner
 # reaches every production call site at once (the engine analogue of
-# UDA_TPU_CHUNK_COLS; scripts/sweep_carrychunk.py + bench.py produce
-# the datum). Empty = the built-in per-backend defaults below. Read
-# ONCE at import, never inside a jitted trace. A deployed LANES engine
-# applies only to lanes-capable callers (lanes_ok=True); others keep
-# the built-in default rather than failing — the deploy var must never
-# break a pure-XLA code path.
+# UDA_TPU_CHUNK_COLS). Empty = the built-in per-backend defaults
+# below. Read ONCE at import, never inside a jitted trace. A deployed
+# LANES engine applies only to lanes-capable callers (lanes_ok=True);
+# others keep the built-in default rather than failing — the deploy
+# var must never break a pure-XLA code path.
 DEPLOYED_SORT_PATH = os.environ.get("UDA_TPU_SORT_PATH", "")
 
 LANES_ENGINES = ("lanes", "lanes2", "keys8", "keys8f")
-FLYOFF_ENGINES = ("lanes", "lanes2", "keys8", "gather2", "carrychunk")
+FLYOFF_ENGINES = ("lanes", "keys8", "gather2", "carrychunk")
 BENCH_FLYOFF = FLYOFF_ENGINES + ("keys8f",)
-ALL_SORT_PATHS = ("carry", "gather") + BENCH_FLYOFF
+# Engines whose kernels Mosaic refuses (chip_smoke.py Phase B records
+# the compiler's message): known by name, so interpret-mode tests can
+# still diff them, but in no fly-off and selectable by no policy — a
+# deployed or cached winner naming one is rejected like an unknown
+# engine. "lanes2": the in-kernel lane gather does not lower.
+UNCOMPILED_ENGINES = ("lanes2",)
+SELECTABLE_SORT_PATHS = ("carry", "gather") + BENCH_FLYOFF
+ALL_SORT_PATHS = SELECTABLE_SORT_PATHS + UNCOMPILED_ENGINES
 
 # Engines whose payload movement is one (or more) global HBM gathers.
-# The take-ramp probe (BENCH_NOTES_r05: 0.15 GB/s at 2^16 rows vs
-# 2.15 GB/s at 2^22) shows the gather is LATENCY-bound below
-# SMALL_BATCH_ROWS — fixed per-row random-access cost dominates before
-# the streaming rate amortizes it — so small batches route to a
-# gather-free engine (route_engine below).
+# A take-ramp probe of 2026-07-31, on a backend that no longer exists
+# (git history; not measured on this machine), found the gather
+# LATENCY-bound below SMALL_BATCH_ROWS — fixed per-row random-access
+# cost dominates before the streaming rate amortizes it — so small
+# batches route to a gather-free engine (route_engine below).
 GATHER_BOUND_ENGINES = ("gather", "gather2", "keys8", "keys8f")
 SMALL_BATCH_ROWS = 1 << 20
 
@@ -97,20 +104,19 @@ SMALL_BATCH_ROWS = 1 << 20
 # operand-words/record, cc=8 -> 3 (26), cc=12 -> 2 (25), cc=23 -> the
 # single-sort extreme (24 words/record — the ROADMAP "27->24" lever).
 # Larger cc strictly reduces sort-network traffic, bounded by XLA's
-# superlinear variadic-sort compile time; the ladder is what
-# scripts/sweep_carrychunk.py and the tpu_return re-probe measure, and
-# the sweep's winner deploys via UDA_TPU_CHUNK_COLS.
+# superlinear variadic-sort compile time; a sweep's winner deploys via
+# UDA_TPU_CHUNK_COLS.
 CC_LADDER = (8, 12, 23)
 
 
 def resolve_sort_path(path: str, lanes_ok: bool = False) -> str:
     """Resolve a payload-movement strategy name. "auto" picks
     operand-carry on CPU (compile is cheap there) and "carrychunk" on
-    TPU — the measured fly-off champion (BENCH_HW_r05.json: 3.04 GB/s
-    vs lanes 1.22 / keys8 1.30) with bounded compile (no sort exceeds
-    chunk_cols+1 operands; XLA's variadic-sort compile time grows
-    superlinearly in operand count, and on remote-compile backends a
-    wide carry sort can take hours) and no record-width limit.
+    TPU — the winner of the fly-off of 2026-07-31 on a backend that no
+    longer exists (git history; not measured on this machine), with
+    bounded compile (no sort exceeds chunk_cols+1 operands; XLA's
+    variadic-sort compile time grows superlinearly in operand count)
+    and no record-width limit.
     ``lanes_ok`` additionally admits the Pallas-pipeline engines
     (LANES_ENGINES) for callers that implement them; the pure-XLA
     strategies (carry/gather/gather2/carrychunk) are valid everywhere.
@@ -122,10 +128,10 @@ def resolve_sort_path(path: str, lanes_ok: bool = False) -> str:
                         if p not in LANES_ENGINES))
     if path == "auto":
         if DEPLOYED_SORT_PATH:
-            if DEPLOYED_SORT_PATH not in ALL_SORT_PATHS:
+            if DEPLOYED_SORT_PATH not in SELECTABLE_SORT_PATHS:
                 raise ValueError(
                     f"UDA_TPU_SORT_PATH={DEPLOYED_SORT_PATH!r} is not a "
-                    f"known sort path {ALL_SORT_PATHS}")
+                    f"selectable sort path {SELECTABLE_SORT_PATHS}")
             if DEPLOYED_SORT_PATH in valid:
                 return DEPLOYED_SORT_PATH
             # deployed lanes engine, lanes-incapable caller: keep the
@@ -136,7 +142,8 @@ def resolve_sort_path(path: str, lanes_ok: bool = False) -> str:
         elif backend == "tpu":
             path = "carrychunk"
         else:
-            path = "gather"
+            raise ValueError(f"no default sort path for backend "
+                             f"{backend!r} (cpu and tpu are supported)")
     if path not in valid:
         raise ValueError(f"unknown sort path {path!r}")
     return path
@@ -159,8 +166,8 @@ def _cached_engine(n_rows: int, lanes_ok: bool) -> "str | None":
     if rec is None:
         return None
     engine = (rec.get("winner") or {}).get("engine")
-    valid = (ALL_SORT_PATHS if lanes_ok
-             else tuple(p for p in ALL_SORT_PATHS
+    valid = (SELECTABLE_SORT_PATHS if lanes_ok
+             else tuple(p for p in SELECTABLE_SORT_PATHS
                         if p not in LANES_ENGINES))
     if engine not in valid:
         return None
@@ -208,11 +215,10 @@ def apply_perm_chunked(perm, cols, chunk_cols: int | None = None) -> list:
     "carrychunk" engine (terasort bench and the distributed step).
 
     ``chunk_cols=None`` resolves ``UDA_TPU_CHUNK_COLS`` so a
-    sweep-tuned value reaches every production call site at once
-    (scripts/sweep_carrychunk.py produces the datum). The env var is
-    read ONCE at import (module constant), never inside a jitted
-    trace — a trace-time read would bake into the jit cache without
-    being part of its key."""
+    sweep-tuned value reaches every production call site at once. The
+    env var is read ONCE at import (module constant), never inside a
+    jitted trace — a trace-time read would bake into the jit cache
+    without being part of its key."""
     if chunk_cols is None:
         chunk_cols = DEFAULT_CHUNK_COLS
     n = perm.shape[0]

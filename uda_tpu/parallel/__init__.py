@@ -2,46 +2,11 @@
 mesh collectives): mesh helpers, windowed all-to-all exchange, fused
 distributed sort step."""
 
+# first, so ``from uda_tpu.parallel import shard_map`` works from inside
+# the submodules below during package init
+from jax import shard_map
 
-def _resolve_shard_map():
-    """Version-tolerant shard_map import: newer JAX exports it as
-    ``jax.shard_map`` (sometimes as a module wrapping the function),
-    older releases only under ``jax.experimental.shard_map`` — and the
-    replication checker kwarg was renamed ``check_rep`` -> ``check_vma``
-    along the way, so on old signatures the shim translates it. Call
-    sites write the NEW spelling. Defined BEFORE the submodule imports
-    below so ``from uda_tpu.parallel import shard_map`` works from
-    inside them during package init."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    if not callable(sm):  # a jax.shard_map MODULE: take its function
-        sm = sm.shard_map
-    import inspect
-
-    if "check_vma" in inspect.signature(sm).parameters:
-        return sm, True
-    import functools
-
-    inner = sm
-
-    @functools.wraps(inner)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return inner(*args, **kwargs)
-
-    return shard_map, False
-
-
-# SHARD_MAP_NATIVE_VMA: True when the ambient JAX has the varying-
-# manual-axes checker (check_vma). On older releases the translated
-# check_rep checker has no pallas_call rule, so callers that wrap
-# Pallas kernels gate on this flag (parallel.distributed._vma_check_on).
-shard_map, SHARD_MAP_NATIVE_VMA = _resolve_shard_map()
-
-from uda_tpu.parallel.bytes_exchange import (ExchangeFetchClient,  # noqa: E402
+from uda_tpu.parallel.bytes_exchange import (ExchangeFetchClient,
                                              exchange_blobs)
 from uda_tpu.parallel.distributed import (DistributedSortResult,
                                           distributed_sort_step,

@@ -22,7 +22,6 @@ TeraSort.
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -57,22 +56,30 @@ def _lanes_interpret(payload_path: str, mesh: Mesh) -> bool:
 
 def _resolve_payload_path(path: str, wcols: int, num_keys: int,
                           n_rows: int = 0) -> str:
-    """route_engine with the lanes engines admitted. The built-in
-    "auto" defaults never resolve to a lanes engine (TPU auto =
-    carrychunk, the fly-off champion, which has no record-width limit
-    — see resolve_sort_path), so no width gate is needed here; an
-    EXPLICIT lanes-engine request (or a deployed UDA_TPU_SORT_PATH
-    winner) is passed through and fails loudly in
-    _sort_valid_rows_lanes if the record exceeds the 32-row layout.
-    ``n_rows`` is the GLOBAL row count — per-device shards are smaller,
-    so the small-batch steering (route_engine) is conservative: a
-    globally-small batch is certainly small per device.
+    """route_engine with the lanes engines admitted, and one repair of
+    its answer: on a TPU, "auto" never yields "carrychunk" here — the
+    built-in default, a cached winner and the small-batch steering all
+    land on "lanes" instead. Inside the fused shard_map program
+    carrychunk's variadic sorts were still in XLA's compiler after
+    1,280 s on a four-chip v5e host (chip run of 2026-09-26, 2^22
+    records per chip; the kill left the chip unresponsive), where the
+    lanes engine compiled and passed on both meshes in 44 s and 158 s
+    all told — a default has to start. An EXPLICIT "carrychunk" is
+    still honored. A record too wide for the 32-row lanes layout fails
+    loudly in _sort_valid_rows_lanes, which names the explicit
+    alternative. ``n_rows`` is the GLOBAL row count — per-device shards
+    are smaller, so the small-batch steering (route_engine) is
+    conservative: a globally-small batch is certainly small per device.
     ``wcols``/``num_keys`` stay in the signature for that error path's
-    callers and for any future auto policy that reconsiders lanes."""
-    del wcols, num_keys  # no auto path needs the width today
+    callers."""
+    del wcols, num_keys  # the lanes body checks the width itself
     from uda_tpu.ops.sort import route_engine
 
-    return route_engine(n_rows, path, lanes_ok=True)
+    engine = route_engine(n_rows, path, lanes_ok=True)
+    if (path == "auto" and engine == "carrychunk"
+            and jax.default_backend() == "tpu"):
+        return "lanes"
+    return engine
 
 
 def uniform_splitters(num_partitions: int) -> np.ndarray:
@@ -130,19 +137,9 @@ def _vma_check_on(payload_path: str, interpret: bool) -> bool:
     lanes engines under INTERPRET mode (the Pallas interpreter's grid
     machinery mis-types; scripts/repro_check_vma.py is the committed
     repro — the compiled path traces clean since the _pass_splits carry
-    pcast). UDA_TPU_FORCE_NO_CHECK_VMA=1 is the operational escape
-    hatch for a first-hardware-run surprise; using it should be
-    reported back into the repro script."""
+    pcast)."""
     from uda_tpu.ops.sort import LANES_ENGINES
-    from uda_tpu.parallel import SHARD_MAP_NATIVE_VMA
 
-    if os.environ.get("UDA_TPU_FORCE_NO_CHECK_VMA") == "1":
-        return False
-    if not SHARD_MAP_NATIVE_VMA:
-        # pre-vma JAX: the legacy check_rep checker has no pallas_call
-        # replication rule, so any lanes engine would fail to trace;
-        # the property is only checkable on native-vma releases
-        return payload_path not in LANES_ENGINES
     return not (payload_path in LANES_ENGINES and interpret)
 
 
@@ -161,10 +158,9 @@ def _sort_valid_rows(flat, valid, num_keys, payload_path, interpret=False):
     rows, stability via the pipeline's arrival tie-break, so equal-key
     order is IDENTICAL to the lax.sort paths below. "carry": all record
     columns ride the sort network (fast runtime, but XLA variadic-sort
-    compile time grows superlinearly in operand count — prohibitive on
-    TPU remote-compile backends). "gather": a narrow sort computes the
-    permutation and per-column gathers on [n] arrays apply it (bounded
-    compile, avoids the lane-padded [n, W] layout). "gather2": the same
+    compile time grows superlinearly in operand count — minutes on the
+    TPU). "gather": a narrow sort computes the permutation and
+    per-column gathers on [n] arrays apply it. "gather2": the same
     narrow-sort permutation applied with ONE minor-dim gather on the
     transposed [W, n] view instead — deliberately trading layouts; the
     faster of the two is backend-dependent and bench.py's fly-off
@@ -360,10 +356,11 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     entry and the auto overflow re-run inherits it.
     ``capacity``: per-(src, dst) records per round — the credit window.
     ``payload_path``: how the local sort moves value columns ("auto":
-    operand-carry on CPU meshes, chunked operand-carry ("carrychunk",
-    the measured fly-off champion — bounded compile, no record-width
-    limit) on TPU; the Pallas lanes engines and the gather paths stay
-    available explicitly — see _sort_valid_rows for the trade-offs).
+    operand-carry on CPU, the Pallas "lanes" pipeline on TPU — the
+    engine the four-chip run proved inside this program, see
+    _resolve_payload_path; the other lanes engines, "carrychunk" and
+    the gather paths stay available explicitly — see _sort_valid_rows
+    for the trade-offs).
     ``multiround``: skew completion policy. "auto" (default) runs the
     fused single-round program and, if any (src, dst) bucket overflowed
     the credit window, re-runs the shuffle through the windowed

@@ -217,6 +217,13 @@ def prepare_layout(words: jax.Array, dest: jax.Array, mesh: Mesh,
 
     words = put_rows(words, mesh, axis)
     dest = put_rows(dest, mesh, axis)
+    if int(words.shape[0]) == 0:
+        # an empty shuffle is its own bucketed layout with an all-zero
+        # count matrix; shard_map refuses zero-row operands (XLA folds
+        # the empty program's output sharding to replicated)
+        p = topo.num_devices
+        return ShuffleLayout(words, dest, dest, np.zeros((p, p), np.int32),
+                             mesh, axis, topo, hier, coded)
     sw, sd, pos, counts = _prep(words, dest)
     # count-matrix readback: allgather works on multi-process meshes
     # where the sharded array is not host-addressable

@@ -7,7 +7,7 @@ instead of dying (occupy_chunk, reference
 src/MOFServer/IndexInfo.cc:276-292). This engine's equivalent exposure
 is the device row matrix: the global sort holds ~27 uint32 words per
 record device-resident (~108 B/record at the TeraSort shape, ≈1.08x the
-shuffle bytes — VERDICT.md Missing #4), so a >10 GB per-chip partition
+shuffle bytes), so a >10 GB per-chip partition
 OOMs a 16 GB v5e with no graceful route, and on CPU the same rows are
 host RSS (the 9.3 GB xxlarge symptom).
 
@@ -82,7 +82,7 @@ def stage_inflight_cap(cfg, window: int, chunk_size: int,
         cap = min(cap, max(MB, budget.host_budget_bytes // 2))
     return cap
 
-# -- the device-bytes model (VERDICT.md Missing #4) -------------------------
+# -- the device-bytes model -------------------------------------------------
 #
 # Per record the engine holds one uint32 row of (key words, content
 # length, segment index, row index) = key_width/4 + ROW_OVERHEAD_WORDS
@@ -99,6 +99,13 @@ RECORD_BYTES_DEFAULT = 100    # TeraSort record (10 B key + 90 B value)
 # Transient working set: a pairwise merge holds both operands plus the
 # output simultaneously, and binary-counter runs pad to a power of two —
 # 2x the resident matrix bounds both.
+#
+# Against the device (chip_smoke.py Phase A, v5e, 2026-09-26, one run):
+# a staged uint32[n, 7] run costs 32 B/record in HBM (libtpu stores it
+# long-dimension-minor, 7 columns padded to 8 — not the 28 B modeled
+# and not a 128-word lane pad), and the overlapped merge of a 1.05 GB
+# partition peaked at 1.60 GB where this model says 2.27 GB: the model
+# is conservative by 1.4x, which is the side admission should err on.
 WORKING_SET_FACTOR = 2.0
 
 # Fraction of physical HBM the budget may claim by default (the rest is
@@ -106,9 +113,9 @@ WORKING_SET_FACTOR = 2.0
 HBM_RESERVE_FRACTION = 0.9
 
 # Known per-chip HBM sizes by TPU device-kind substring, FIRST MATCH
-# WINS (VERDICT.md ask #3 names v5e and v5p; the rest are the published
-# per-chip figures). Order matters: every v5e/lite spelling (libtpu
-# reports e.g. "TPU v5 lite") must match before "v5p", and a BARE "v5"
+# WINS (the published per-chip figures). Order matters: every v5e/lite
+# spelling (libtpu reports e.g. "TPU v5 lite") must match before "v5p",
+# and a BARE "v5"
 # resolves to the small end — over-budgeting a 16 GB chip as 95 GB
 # would silently re-open the exact OOM this layer exists to prevent.
 PLATFORM_HBM_MB = (
@@ -124,7 +131,6 @@ PLATFORM_HBM_MB = (
     ("v2", 8 * 1024),
     ("v5", 16 * 1024),          # bare v5: assume the small end
 )
-DEFAULT_HBM_MB = 16 * 1024      # unknown accelerator: assume the small end
 
 
 def _host_available_mb() -> int:
@@ -143,24 +149,30 @@ def _host_available_mb() -> int:
 
 
 def _detect_hbm_mb() -> int:
-    """Per-chip HBM of the ambient backend. On CPU backends the 'device'
-    rows live in host RSS, so the HBM budget IS the host budget (the
+    """Per-chip HBM of the ambient backend: what the device itself
+    reports (``memory_stats()["bytes_limit"]``), else the
+    PLATFORM_HBM_MB entry for its ``device_kind``. An accelerator that
+    offers neither is an error — guessing a size re-opens the exact OOM
+    this layer exists to prevent. On CPU backends the 'device' rows
+    live in host RSS, so the HBM budget IS the host budget (the
     xxlarge-rung reality). jax import stays lazy: admission must not
     drag a backend up in processes that never touch the device."""
-    try:
-        import jax
+    import jax
 
-        backend = jax.default_backend()
-        if backend == "cpu":
-            return _host_available_mb()
-        kind = str(jax.devices()[0].device_kind).lower()
-        for sub, mb in PLATFORM_HBM_MB:
-            if sub in kind:
-                return mb
-    except Exception as e:  # noqa: BLE001 - detection is best effort
-        log.warn(f"HBM budget autodetect failed ({e}); "
-                 f"assuming {DEFAULT_HBM_MB} MB")
-    return DEFAULT_HBM_MB
+    if jax.default_backend() == "cpu":
+        return _host_available_mb()
+    dev = jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return int(limit) // MB
+    kind = str(dev.device_kind).lower()
+    for sub, mb in PLATFORM_HBM_MB:
+        if sub in kind:
+            return mb
+    raise UdaError(
+        f"cannot size the HBM budget: device {dev.device_kind!r} reports "
+        f"no bytes_limit and matches no PLATFORM_HBM_MB entry (set "
+        f"uda.tpu.hbm.budget.mb)")
 
 
 def device_bytes_estimate(partition_bytes: int, key_width: int,

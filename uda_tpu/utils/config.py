@@ -142,8 +142,7 @@ _FLAG_LIST = [
          "(per-run partial sort + HBM-resident pairwise merge tree, "
          "ops.merge.merge_batches_two_phase), 'off' = whole-shuffle "
          "re-sort of the concatenation, 'auto' = two-phase on TPU "
-         "backends / re-sort on CPU (the small-batch take-ramp datum, "
-         "BENCH_NOTES_r05). Byte-identical either way"),
+         "backends / re-sort on CPU. Byte-identical either way"),
     # --- failure-domain knobs (failpoints + retrying fetch path) ---
     Flag("mapred.rdma.fetch.retry.backoff.ms", 0, int,
          "base exponential backoff between fetch retries in ms, doubling "

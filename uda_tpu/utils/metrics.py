@@ -1260,33 +1260,20 @@ def device_trace(log_dir: str | None = None) -> Iterator[None]:
     """Capture a device (Xprof) profile around a block, correlating the
     host-side spans above with on-device timelines — the SURVEY §7
     stage-8 'Xprof hooks'. Enabled by passing ``log_dir`` or setting
-    ``UDA_TPU_XPROF=<dir>``; a no-op otherwise (and when the ambient
-    backend does not support jax.profiler, e.g. relay backends — the
-    failure is logged, never raised: profiling must not take down the
-    job)."""
+    ``UDA_TPU_XPROF=<dir>``; a no-op otherwise. A trace that was asked
+    for and cannot start or stop raises: a measurement run must not
+    finish looking traced when it was not."""
     d = log_dir or os.environ.get("UDA_TPU_XPROF")
     if not d:
         yield
         return
     import jax
 
-    try:
-        jax.profiler.start_trace(d)
-    except Exception as e:  # noqa: BLE001 - profiling is best-effort
-        from uda_tpu.utils.logging import get_logger
-
-        get_logger().warn(f"device trace unavailable: {e}")
-        yield
-        return
+    jax.profiler.start_trace(d)
     try:
         yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:  # noqa: BLE001
-            from uda_tpu.utils.logging import get_logger
-
-            get_logger().warn(f"device trace stop failed: {e}")
+        jax.profiler.stop_trace()
 
 
 metrics = Metrics(ledger=_resledger)

@@ -44,8 +44,7 @@ Design constraints (the flightrec discipline):
   dict read and a per-tick counter flush taken OUTSIDE that lock;
 - **never fatal**: a sampling error (a frame dying mid-walk, a
   half-torn-down interpreter) is counted (``errors.swallowed``) and
-  the loop continues — profiling must not take down the job, the
-  device_trace contract.
+  the loop continues — host sampling must not take down the job.
 
 Span attribution needs the span layer recording (``UDA_TPU_STATS=1`` /
 ``metrics.enable_spans()``); with spans off, samples still aggregate
