@@ -41,6 +41,17 @@ from helpers import make_mof_tree, map_ids  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# the emit stage's timers beside "emit" (the consumer up-call alone)
+EMIT_TIMERS = ("emit_readback", "emit_gather", "emit_frame", "emit_deliver")
+ROUTES = pytest.mark.parametrize(
+    "streaming", (False, True), ids=("in_memory", "streaming"))
+
+
+def _emit_timers(streaming: bool) -> tuple:
+    """The emit timers that run on a route."""
+    return tuple(t for t in EMIT_TIMERS
+                 if not (streaming and t == "emit_frame"))
+
 
 def _burn(seconds: float, span: str | None = None) -> None:
     """A deterministically busy loop, optionally inside a span."""
@@ -229,13 +240,43 @@ def test_critpath_span_buckets_cover_known_names():
         assert name in critpath.SPAN_BUCKETS, name
     for bucket in critpath.SPAN_BUCKETS.values():
         assert bucket in critpath.BUCKET_PRIORITY
+    # the emit stage's timers are the emit bucket's, the consumer
+    # up-call included; serve keeps supplier-side names only
+    for name in EMIT_TIMERS + ("emit",):
+        assert critpath.SPAN_BUCKETS[name] == "emit", name
+    assert not [n for n, b in critpath.SPAN_BUCKETS.items()
+                if b == "serve" and n.startswith("emit")]
+    order = critpath.BUCKET_PRIORITY
+    assert order.index("decompress_pack") < order.index("emit") \
+        < order.index("serve")
+    assert "emit" not in critpath.TRIO_MAP  # the trio has no emit term
 
 
-def _run_quick_merge(tmp_path, cfg_extra=None):
+def test_critpath_charges_emit_spans_to_emit():
+    spans = [
+        _span("reduce_task", 0.0, 10.0, 1),
+        _span("merge", 0.0, 2.0, 2, parent=1),
+        _span("emit_readback", 2.0, 1.0, 3, parent=1),
+        _span("emit_gather", 3.0, 2.0, 4, parent=1),
+        _span("emit_frame", 5.0, 1.0, 5, parent=1),
+        _span("emit_deliver", 6.0, 2.0, 6, parent=1),
+        _span("wait_mem", 6.0, 0.5, 7, parent=6),   # slot acquire inside
+        _span("emit", 8.0, 1.0, 8, parent=1),
+        _span("net.serve", 8.5, 1.5, 9, parent=1),
+    ]
+    b = critpath.analyze(spans)["buckets"]
+    assert b["emit"]["critical_s"] == pytest.approx(7.0)
+    assert b["emit"]["busy_s"] == pytest.approx(7.0)
+    assert b["serve"]["critical_s"] == pytest.approx(1.0)  # [9, 10] only
+    assert b["wait"]["critical_s"] == pytest.approx(0.0)
+    assert b["other"]["critical_s"] == pytest.approx(0.0)
+
+
+def _run_quick_merge(tmp_path, cfg_extra=None, records_per_map=400):
     root = str(tmp_path / "mof")
     job = "timeacct"
     expected = make_mof_tree(root, job, num_maps=4, num_reducers=1,
-                             records_per_map=400, seed=3)
+                             records_per_map=records_per_map, seed=3)
     cfg = Config(dict({"mapred.rdma.buf.size": 8}, **(cfg_extra or {})))
     engine = DataEngine(DirIndexResolver(root), cfg)
     blocks = []
@@ -246,7 +287,7 @@ def _run_quick_merge(tmp_path, cfg_extra=None):
                lambda b: blocks.append(bytes(b)))
     finally:
         engine.stop()
-    assert len(expected[0]) == 1600
+    assert len(expected[0]) == 4 * records_per_map
     return b"".join(blocks)
 
 
@@ -275,13 +316,199 @@ def test_critpath_real_quick_merge_and_final_record(tmp_path):
     rep.stop(final=False)
 
 
+# a merge big enough (160,000 records in three slabs, 256 KB blocks)
+# that what no timer wraps — the merger's construction and thread
+# start (~0.5 ms), the streaming route's run-file removal (~0.7 ms),
+# the tracer's own microseconds between two spans — stays far under
+# the 5 % the coverage gate allows
+_COVER = {"records_per_map": 40000,
+          "cfg": {"mapred.rdma.buf.size": 256}}
+
+
+@ROUTES
+def test_spans_cover_the_quick_merge_wall(tmp_path, streaming):
+    """Coverage gate: with spans on, no more than 5% of the reduce
+    task's wall lies outside every span, and each emit stage shows."""
+    cfg = dict(_COVER["cfg"], **{"uda.tpu.online.streaming": streaming})
+    # first use (lazy imports, the native library) is set-up, not task
+    _run_quick_merge(tmp_path / "warm", cfg)
+    metrics.enable_spans()
+    assert _run_quick_merge(tmp_path, cfg, _COVER["records_per_map"])
+    spans = list(metrics.spans)
+    root = max((s for s in spans if s["name"] == "reduce_task"),
+               key=lambda s: s["ts"])
+    names = {s["name"] for s in spans if s["trace"] == root["trace"]}
+    # the streaming route's runs are framed when they are spooled, so
+    # it has no framing stage at emit time
+    assert set(_emit_timers(streaming)) | {"emit"} <= names
+    assert ("emit_frame" in names) != streaming
+    assert ("run_spool" in names) == streaming
+    # idle_s: the part of the root's wall with no span of its trace open
+    block = critpath.time_accounting_block()
+    assert block["wall_s"] == pytest.approx(root["dur"], abs=1e-5)
+    assert block["idle_s"] <= 0.05 * block["wall_s"], block
+    assert block["buckets"]["emit"]["critical_s"] > 0
+    # no yield of the piece generators sits inside a timer: every emit
+    # stage span is closed before the next one opens
+    stages = sorted((s["ts"], s["ts"] + s["dur"]) for s in spans
+                    if s["name"] in EMIT_TIMERS + ("emit",))
+    assert all(a[1] <= b[0] for a, b in zip(stages, stages[1:]))
+
+
+@ROUTES
+def test_stage_spans_hang_under_the_task_root(tmp_path, streaming):
+    """The span tree's shape: the staging threads adopt the reduce
+    task's root (``OverlappedMerger`` captures the current span when it
+    is built), so every pack, stage and merge-wait span, and every emit
+    stage, is a child of ``reduce_task`` — the links the watchdog's
+    dump and ``critpath``'s critical-path walk read."""
+    metrics.enable_spans()
+    assert _run_quick_merge(
+        tmp_path, {"uda.tpu.online.streaming": streaming})
+    spans = list(metrics.spans)
+    root, = (s for s in spans if s["name"] == "reduce_task")
+    assert root["parent"] is None
+    children = ("overlap_pack", "overlap_stage", "merge.wait", "merge",
+                "fetch", "emit") + _emit_timers(streaming)
+    for name in children:
+        mine = [s for s in spans if s["name"] == name]
+        assert mine, name
+        assert {s["parent"] for s in mine} == {root["id"]}, name
+        assert {s["trace"] for s in mine} == {root["trace"]}, name
+
+
+class _Reducer:
+    """Embedder double for one NetMerger task over local dirs."""
+
+    def __init__(self, conf=None):
+        self.conf = conf or {}
+        self.size = 0
+        self.failures = []
+
+    def data_from_uda(self, data, length):
+        self.size += length
+
+    def get_conf_data(self, name, default):
+        return self.conf.get(name, "")
+
+    def failure_in_uda(self, error):
+        self.failures.append(error)
+
+
+def _run_bridge_task(tmp_path, conf=None):
+    """One reduce task through a NetMerger ``UdaBridge``; returns its
+    wall (start -> merge thread joined), EXIT excluded, and the bridge
+    for the caller to EXIT."""
+    from uda_tpu.bridge import Cmd, UdaBridge, form_cmd
+    job = "timeacctb"
+    make_mof_tree(str(tmp_path), job, 4, 1, 2000, seed=5)
+    cb = _Reducer(conf)
+    bridge = UdaBridge()
+    t0 = time.perf_counter()
+    bridge.start(True, ["-s", "64"], cb)
+    bridge.do_command(form_cmd(
+        Cmd.INIT, [job, "0", "4", "uda.tpu.RawBytes",
+                   str(tmp_path).replace(":", "")]))
+    for mid in map_ids(job, 4):
+        bridge.do_command(form_cmd(Cmd.FETCH, ["localhost", job, mid, "0"]))
+    bridge.do_command(form_cmd(Cmd.FINAL, []))
+    bridge.reduce_exit()                  # joins the merge thread
+    wall = time.perf_counter() - t0
+    assert not cb.failures, cb.failures
+    assert cb.size > 8000 * 40
+    return wall, bridge
+
+
+@ROUTES
+def test_emit_and_open_counters_live_with_spans_off(tmp_path, streaming):
+    """The timers' counters are always live: with spans off a reduce
+    task advances every one its route runs, and — one thread at a time
+    runs them — they and the consumer's emit_time sum to no more than
+    the task's wall."""
+    from uda_tpu.bridge import Cmd, form_cmd
+    metrics.disable_spans()
+    wall, bridge = _run_bridge_task(
+        tmp_path, {"uda.tpu.online.streaming": "true"} if streaming else {})
+    snap = metrics.snapshot()
+    timers = [t + "_time"
+              for t in _emit_timers(streaming) + ("bridge_open",)]
+    for key in timers:
+        assert key in snap and snap[key] >= 0.0, key
+    for key in ("emit_gather_time", "emit_deliver_time", "bridge_open_time"):
+        assert snap[key] > 0.0, key
+    assert ("emit_frame_time" in snap) != streaming
+    assert (snap.get("spool.bytes", 0) > 0) == streaming
+    assert snap["emit.bytes"] == 8000 * 42 + 2
+    assert sum(snap[k] for k in timers) + snap["emit_time"] <= wall
+    assert metrics.spans == []
+    opened = snap["bridge_open_time"]
+    bridge.do_command(form_cmd(Cmd.EXIT, []))   # not a task-opening call
+    assert metrics.get("bridge_open_time") == opened
+
+
+def test_bridge_open_is_the_netmergers_alone(tmp_path):
+    """A MOFSupplier bridge's start and commands are not a reduce
+    task's opening: its role never touches bridge_open."""
+    from uda_tpu.bridge import Cmd, UdaBridge, form_cmd
+    supplier = UdaBridge()
+    supplier.start(False, [], _Reducer())
+    supplier.do_command(form_cmd(Cmd.INIT, []))
+    supplier.do_command(form_cmd(Cmd.EXIT, []))
+    assert "bridge_open_time" not in metrics.snapshot()
+    reducer = UdaBridge()
+    reducer.start(True, [], _Reducer())
+    assert metrics.get("bridge_open_time") > 0
+    reducer.do_command(form_cmd(Cmd.EXIT, []))
+
+
+@pytest.mark.parametrize("spans_on", (True, False),
+                         ids=("spans_on", "spans_off"))
+def test_spans_are_mirrored_into_the_profile(tmp_path, spans_on):
+    """Inside a profiler session every context-managed span is also a
+    host event of the same .xplane.pb, on the profiler's clock, as long
+    as the recorded span's; with spans off the profile holds none."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    metrics.record_spans = spans_on
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with metrics.timer("emit_gather"):
+            _burn(0.02)
+        pending = metrics.start_span("merge.wait")  # may end elsewhere:
+        pending.end()                               # never mirrored
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    events = [e for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name in ("emit_gather", "merge.wait")]
+    assert metrics.get("emit_gather_time") >= 0.02
+    if not spans_on:
+        assert events == [] and metrics.spans == []
+        return
+    event, = events
+    span, = (s for s in metrics.spans if s["name"] == "emit_gather")
+    assert event.name == "emit_gather"
+    assert abs(event.duration_ns / 1e9 - span["dur"]) \
+        <= max(0.2 * span["dur"], 1e-3)
+
+
 def test_buckets_from_counters_fallback():
     block = critpath.buckets_from_counters(
         {"fetch_time": 2.0, "merge_time": 3.0, "wait_mem_time": 0.5,
-         "overlap_pack_time": 1.0, "emit_time": 0.25})
+         "overlap_pack_time": 1.0, "emit_time": 0.25,
+         "emit_gather_time": 0.5, "supplier_read_time": 0.125})
     assert block["kind"] == "busy_seconds_from_counters"
     assert block["buckets"]["fetch"] == pytest.approx(2.0)
-    assert block["buckets"]["serve"] == pytest.approx(0.25)
+    assert block["buckets"]["emit"] == pytest.approx(0.75)
+    assert block["buckets"]["serve"] == pytest.approx(0.125)
     assert block["trio"]["total_merge_time"] == pytest.approx(4.0)
 
 

@@ -30,6 +30,7 @@ bind to.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 from typing import Optional, Protocol, Sequence
@@ -52,6 +53,12 @@ from uda_tpu.utils.stats import (StatsReporter, reporter_output_from_env,
 __all__ = ["UdaCallable", "UdaBridge"]
 
 log = get_logger()
+
+# The down-calls that open a reduce task. With start() they run on the
+# caller's thread before the merge thread opens the reduce_task root
+# span, so the span tree cannot see them: the bridge_open timer does.
+# EXIT is not one of them (it follows the end of the stream).
+_OPEN_CMDS = (Cmd.INIT, Cmd.FETCH, Cmd.FINAL)
 
 
 class UdaCallable(Protocol):
@@ -150,6 +157,12 @@ class UdaBridge:
               callable_obj: Optional[UdaCallable] = None) -> None:
         """startNative: parse argv (the reference's getopt channel), wire
         the conf pull channel, pick the role (UdaBridge.cc:187-263)."""
+        with (metrics.timer("bridge_open") if is_net_merger
+              else contextlib.nullcontext()):
+            self._start(is_net_merger, argv, callable_obj)
+
+    def _start(self, is_net_merger: bool, argv: Sequence[str],
+               callable_obj: Optional[UdaCallable]) -> None:
         compile_cache.enable()
         self.callable = callable_obj
         self.is_net_merger = is_net_merger
@@ -240,7 +253,9 @@ class UdaBridge:
             if header == Cmd.GET_STATS:  # role-independent, like
                 return json.dumps(self.get_stats())  # set_log_level
             if self.is_net_merger:
-                self._reduce_downcall(header, params)
+                with (metrics.timer("bridge_open") if header in _OPEN_CMDS
+                      else contextlib.nullcontext()):
+                    self._reduce_downcall(header, params)
             else:
                 self._mof_downcall(header, params)
         except Exception as e:  # noqa: BLE001 - ANY engine failure must

@@ -965,10 +965,13 @@ class OverlappedMerger:
                 from uda_tpu import native
 
                 for rows in stream_mod.iter_row_slabs(acc.rows, acc.valid):
-                    seg = rows[:, kw + 1].astype(np.int64)
-                    row = rows[:, kw + 2].astype(np.int64)
-                    sub = stream_mod.slab_batch(batches, seg, row)
-                    yield native.frame_batch(sub, write_eof=False)
+                    with metrics.timer("emit_gather"):
+                        seg = rows[:, kw + 1].astype(np.int64)
+                        row = rows[:, kw + 2].astype(np.int64)
+                        sub = stream_mod.slab_batch(batches, seg, row)
+                    with metrics.timer("emit_frame"):
+                        piece = native.frame_batch(sub, write_eof=False)
+                    yield piece
                 yield EOF_MARKER
 
             return emitter.emit_framed(pieces(), consumer)

@@ -394,10 +394,13 @@ class _RunCursor:
 def iter_row_slabs(rows, valid: int,
                    slab: int = SLAB_RECORDS) -> Iterator[np.ndarray]:
     """Yield the merged composite-key rows in bounded host slabs (the
-    rows may be device-resident; each slice transfers one slab)."""
+    rows may be device-resident; each slice transfers one slab, timed
+    as ``emit_readback``)."""
     for start in range(0, valid, slab):
         stop = min(start + slab, valid)
-        yield np.asarray(rows[start:stop])
+        with metrics.timer("emit_readback"):
+            host = np.asarray(rows[start:stop])
+        yield host
 
 
 def interleave_runs(slabs: Iterator[np.ndarray], store: RunStore,
@@ -422,42 +425,50 @@ def interleave_runs(slabs: Iterator[np.ndarray], store: RunStore,
             del open_lru[victim]
             cursors[victim].suspend()
 
+    def gather_slab(rows: np.ndarray) -> bytes:
+        """One slab's framed bytes, gathered from the runs' next
+        spans in merged order (the runs are framed already, so this
+        route has no ``emit_frame`` stage)."""
+        seg = rows[:, num_key_words + 1].astype(np.int64)
+        unique, ranks, counts = _group_ranks(seg)
+        spans: dict[int, np.ndarray] = {}
+        starts: dict[int, np.ndarray] = {}
+        lens: dict[int, np.ndarray] = {}
+        for s, c in zip(unique.tolist(), counts.tolist()):
+            cur = cursors.get(s)
+            if cur is None:
+                if s not in store.counts:
+                    raise MergeError(
+                        f"merged rows reference unstaged segment {s}")
+                cur = cursors[s] = _RunCursor(*store._paths(s))
+            span, ln = cur.next_span(c)
+            _touch(s, cur)
+            spans[s] = span
+            lens[s] = ln
+            starts[s] = np.cumsum(ln) - ln
+        # per-record framed length and source offset in its span
+        rec_len = np.empty(seg.shape[0], np.int64)
+        src_off = np.empty(seg.shape[0], np.int64)
+        for s in unique.tolist():
+            m = seg == s
+            rec_len[m] = lens[s][ranks[m]]
+            src_off[m] = starts[s][ranks[m]]
+        out = np.empty(int(rec_len.sum()), np.uint8)
+        dst_end = np.cumsum(rec_len)
+        dst_start = dst_end - rec_len
+        for s in unique.tolist():
+            m = seg == s
+            _gather_spans(spans[s], src_off[m], rec_len[m],
+                          out, dst_start[m])
+        return out.tobytes()
+
     try:
         for rows in slabs:
             if rows.shape[0] == 0:
                 continue
-            seg = rows[:, num_key_words + 1].astype(np.int64)
-            unique, ranks, counts = _group_ranks(seg)
-            spans: dict[int, np.ndarray] = {}
-            starts: dict[int, np.ndarray] = {}
-            lens: dict[int, np.ndarray] = {}
-            for s, c in zip(unique.tolist(), counts.tolist()):
-                cur = cursors.get(s)
-                if cur is None:
-                    if s not in store.counts:
-                        raise MergeError(
-                            f"merged rows reference unstaged segment {s}")
-                    cur = cursors[s] = _RunCursor(*store._paths(s))
-                span, ln = cur.next_span(c)
-                _touch(s, cur)
-                spans[s] = span
-                lens[s] = ln
-                starts[s] = np.cumsum(ln) - ln
-            # per-record framed length and source offset in its span
-            rec_len = np.empty(seg.shape[0], np.int64)
-            src_off = np.empty(seg.shape[0], np.int64)
-            for s in unique.tolist():
-                m = seg == s
-                rec_len[m] = lens[s][ranks[m]]
-                src_off[m] = starts[s][ranks[m]]
-            out = np.empty(int(rec_len.sum()), np.uint8)
-            dst_end = np.cumsum(rec_len)
-            dst_start = dst_end - rec_len
-            for s in unique.tolist():
-                m = seg == s
-                _gather_spans(spans[s], src_off[m], rec_len[m],
-                              out, dst_start[m])
-            yield out.tobytes()
+            with metrics.timer("emit_gather"):
+                piece = gather_slab(rows)
+            yield piece
     finally:
         for cur in cursors.values():
             cur.close()

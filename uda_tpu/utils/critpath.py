@@ -12,12 +12,12 @@ recorded span tree of a completed task:
 - **wall partition** ("critical share"): sweep the root span's
   timeline; at every instant the active spans map to stage *buckets*
   and exactly ONE bucket is charged, by a fixed gating-priority order
-  (``merge`` > ``device_put`` > ``decompress_pack`` > ``serve`` >
-  ``fetch`` > ``other`` > ``wait``) — nested spans naturally resolve
-  to the most specific stage, and instants where only waiting is
-  active charge ``wait``. Unclaimed instants are ``idle``. By
-  construction the buckets + idle sum EXACTLY to the root's wall time
-  (the 5%% acceptance gate holds with margin).
+  (``merge`` > ``device_put`` > ``decompress_pack`` > ``emit`` >
+  ``serve`` > ``fetch`` > ``other`` > ``wait``) — nested spans
+  naturally resolve to the most specific stage, and instants where
+  only waiting is active charge ``wait``. Unclaimed instants are
+  ``idle``. By construction the buckets + idle sum EXACTLY to the
+  root's wall time (the 5%% acceptance gate holds with margin).
 - **busy time**: per bucket, the plain sum of its spans' durations —
   can exceed the wall (that is the overlap working); ``overlap`` =
   busy / critical says how much parallel work each critical second of
@@ -73,18 +73,22 @@ SPAN_BUCKETS: Dict[str, str] = {
     "merge": "merge", "overlap_device_merge": "merge",
     "device_sort": "merge", "lpq_spill": "merge", "lpq_phase": "merge",
     "rpq_phase": "merge",
-    # serve: supplier-side reads + emission to the consumer
+    # emit: the reduce side's output path after the forest is merged —
+    # slab read-back, record gather, framing, block staging, and the
+    # consumer up-call (the reference's trio has no emit term)
+    "emit": "emit", "emit_readback": "emit", "emit_gather": "emit",
+    "emit_frame": "emit", "emit_deliver": "emit",
+    # serve: supplier-side reads
     "net.serve": "serve", "engine.pread": "serve",
-    "engine.read_batch": "serve",
-    "supplier_read": "serve", "emit": "serve",
+    "engine.read_batch": "serve", "supplier_read": "serve",
 }
 
 # who gets charged when several buckets are active at one instant:
 # earlier = the stage gating completion. "wait" is LAST on purpose — a
 # merge.wait overlapping a live fetch is caused by the fetch, so the
 # instant charges fetch; wait wins only when nothing else runs.
-BUCKET_PRIORITY = ("merge", "device_put", "decompress_pack", "serve",
-                   "fetch", "other", "wait")
+BUCKET_PRIORITY = ("merge", "device_put", "decompress_pack", "emit",
+                   "serve", "fetch", "other", "wait")
 
 # bucket -> the reference trio alias it reconciles onto (reducer.h:80-90)
 TRIO_MAP: Dict[str, str] = {
@@ -257,7 +261,10 @@ def buckets_from_counters(counters: Dict[str, float]) -> Dict:
              ("merge", ("merge_time", "overlap_device_merge_time",
                         "device_sort_time", "lpq_spill_time",
                         "lpq_phase_time", "rpq_phase_time")),
-             ("serve", ("supplier_read_time", "emit_time")))
+             ("emit", ("emit_time", "emit_readback_time",
+                       "emit_gather_time", "emit_frame_time",
+                       "emit_deliver_time")),
+             ("serve", ("supplier_read_time",)))
     out = {b: round(sum(counters.get(k, 0.0) for k in keys), 6)
            for b, keys in table}
     return {"kind": "busy_seconds_from_counters", "buckets": out,

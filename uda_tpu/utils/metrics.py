@@ -43,6 +43,7 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from collections import defaultdict
@@ -810,6 +811,22 @@ _current_span: contextvars.ContextVar[Optional["Span"]] = \
     contextvars.ContextVar("uda_tpu_current_span", default=None)
 
 
+def _open_annotation(name: str):
+    """Mirror a context-managed span into the JAX profiler's own trace:
+    an entered ``jax.profiler.TraceAnnotation`` (the caller exits it),
+    or None when this process has not imported jax. Inside a profiler
+    session the host stage then lands on its thread's line of the same
+    ``.xplane.pb`` as the device operations, on the profiler's clock;
+    outside one it costs a flag test. jax is looked up, never imported:
+    the supplier process must stay free of it."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:  # no jax here (or its import is still running)
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """One span of the trace tree. ``end()`` records it (idempotent);
     attributes may be added at end time (e.g. error status). A span is
@@ -1066,7 +1083,11 @@ class Metrics:
     def span(self, name: str, parent: Optional[Span] = None,
              **attrs) -> Iterator[Span]:
         """Context-managed span that also becomes the thread's current
-        span for the duration, so nested spans/timers parent under it."""
+        span for the duration, so nested spans/timers parent under it.
+        It opens and closes on one thread, so it is also mirrored into
+        the JAX profiler's trace (:func:`_open_annotation`);
+        ``start_span``/``Span.end`` pairs, which may close on another
+        thread, are not."""
         s = self.start_span(name, parent=parent, **attrs)
         if s is _NOOP_SPAN:
             yield s
@@ -1077,9 +1098,12 @@ class Metrics:
             tid = threading.get_ident()
             prev = _THREAD_SPANS.get(tid)
             _THREAD_SPANS[tid] = s
+        ann = _open_annotation(name)
         try:
             yield s
         finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
             if tid is not None:
                 if prev is not None:
                     _THREAD_SPANS[tid] = prev
@@ -1257,12 +1281,17 @@ def stats_enabled_from_env() -> bool:
 
 @contextlib.contextmanager
 def device_trace(log_dir: str | None = None) -> Iterator[None]:
-    """Capture a device (Xprof) profile around a block, correlating the
-    host-side spans above with on-device timelines — the SURVEY §7
-    stage-8 'Xprof hooks'. Enabled by passing ``log_dir`` or setting
-    ``UDA_TPU_XPROF=<dir>``; a no-op otherwise. A trace that was asked
-    for and cannot start or stop raises: a measurement run must not
-    finish looking traced when it was not."""
+    """Capture a device (Xprof) profile around a block — the SURVEY §7
+    stage-8 'Xprof hooks'. The ``.xplane.pb`` holds the device
+    operations and, while spans are enabled, every context-managed span
+    and timer of this process (``metrics.span``/``metrics.timer``) as a
+    host event on the line of the thread that ran it, on the profiler's
+    own clock; with spans off it holds the device side alone.
+    ``start_span``/``end`` pairs (``net.fetch``, ``fetch.segment``,
+    ``merge.wait``) are in the span export only. Enabled by passing
+    ``log_dir`` or setting ``UDA_TPU_XPROF=<dir>``; a no-op otherwise.
+    A trace that was asked for and cannot start or stop raises: a
+    measurement run must not finish looking traced when it was not."""
     d = log_dir or os.environ.get("UDA_TPU_XPROF")
     if not d:
         yield
