@@ -960,15 +960,15 @@ class OverlappedMerger:
             if not self._check_accounting(acc, total):
                 return emitter.emit_framed(iter([EOF_MARKER]), consumer)
             kw = int(acc.rows.shape[1]) - 3
+            table = stream_mod.segment_table(batches)
 
             def pieces():
                 from uda_tpu import native
 
                 for rows in stream_mod.iter_row_slabs(acc.rows, acc.valid):
                     with metrics.timer("emit_gather"):
-                        seg = rows[:, kw + 1].astype(np.int64)
-                        row = rows[:, kw + 2].astype(np.int64)
-                        sub = stream_mod.slab_batch(batches, seg, row)
+                        sub = stream_mod.slab_batch(
+                            batches, rows[:, kw + 1], rows[:, kw + 2], table)
                     with metrics.timer("emit_frame"):
                         piece = native.frame_batch(sub, write_eof=False)
                     yield piece

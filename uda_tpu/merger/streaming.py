@@ -25,10 +25,14 @@ reference's memory model around the device permutation:
 - **Slab gather** (:func:`slab_batch`): the in-memory twin used when
   streaming is off — gathers each output slab's bytes directly from the
   per-segment batches, so even the memory-resident path never
-  concatenates the whole shuffle a second time.
+  concatenates the whole shuffle a second time. With the native library
+  it is two C passes per slab over a per-task :func:`segment_table`
+  (O(records), whatever the segment count); the
+  numpy path (two masked passes per segment per slab) is the fallback
+  and the reference the native one is parity-tested against.
 
-Everything is vectorized numpy; the only per-record work is done by the
-native framer when runs are written.
+The rest is vectorized numpy; its only per-record work is done by the
+native framer when runs are written and by the native span gather.
 """
 
 from __future__ import annotations
@@ -48,7 +52,7 @@ from uda_tpu.utils.logging import get_logger
 from uda_tpu.utils.metrics import metrics
 
 __all__ = ["RunStore", "framed_lengths", "interleave_runs", "slab_batch",
-           "iter_row_slabs", "SLAB_RECORDS"]
+           "segment_table", "iter_row_slabs", "SLAB_RECORDS"]
 
 log = get_logger()
 
@@ -92,22 +96,28 @@ def _expand_spans(off: np.ndarray, length: np.ndarray) -> np.ndarray:
         total, dtype=np.int64)
 
 
-_gather_impl = None  # resolved build/availability, cached per process
+_native_built = None  # resolved build/availability, cached per process
+
+
+def _native_ready() -> bool:
+    """Whether this call may take a native gather: library availability
+    is resolved once per process; the ``uda.tpu.use.native`` kill switch
+    stays LIVE (re-read per call, like frame_batch)."""
+    global _native_built
+    if not native_enabled():
+        return False
+    if _native_built is None:
+        _native_built = bool(native.build() and native.available())
+    return _native_built
 
 
 def _gather_spans(src: np.ndarray, src_off: np.ndarray, lens: np.ndarray,
                   dst: np.ndarray, dst_off: np.ndarray) -> None:
     """dst[dst_off_i : +len_i] = src[src_off_i : +len_i] per record —
     native memcpy loop when built (8x less memory traffic than the
-    expand-index fallback, the streaming emit hot path). Library
-    availability is resolved once per process; the ``uda.tpu.use.native``
-    kill switch stays LIVE (re-read per call, like frame_batch)."""
-    global _gather_impl
-    if _gather_impl is None and native_enabled():
-        _gather_impl = (native.gather_spans_native
-                        if native.build() and native.available() else False)
-    if (_gather_impl and native_enabled()
-            and _gather_impl(src, src_off, lens, dst, dst_off)):
+    expand-index fallback, the streaming emit hot path)."""
+    if (_native_ready()
+            and native.gather_spans_native(src, src_off, lens, dst, dst_off)):
         return
     dst[_expand_spans(dst_off, lens)] = src[_expand_spans(src_off, lens)]
 
@@ -481,11 +491,37 @@ def interleave_runs(slabs: Iterator[np.ndarray], store: RunStore,
     yield EOF_MARKER
 
 
+def segment_table(batches: Sequence[RecordBatch]
+                  ) -> Optional["native.SegmentTable"]:
+    """The per-task table :func:`slab_batch`'s native path looks records
+    up through (O(segments) to build, nothing concatenated), or None
+    when the native library is off or unavailable — every slab then
+    takes the numpy path."""
+    return native.SegmentTable(batches) if _native_ready() else None
+
+
 def slab_batch(batches: Sequence[RecordBatch], seg: np.ndarray,
-               row: np.ndarray) -> RecordBatch:
+               row: np.ndarray,
+               table: Optional["native.SegmentTable"] = None) -> RecordBatch:
     """Gather one output slab's records from per-segment batches into a
-    compact RecordBatch (its own small data buffer) — the in-memory
-    emission path's bounded gather, replacing whole-shuffle concat."""
+    compact RecordBatch (its own small data buffer: all keys, then all
+    values) — the in-memory emission path's bounded gather, replacing
+    whole-shuffle concat. ``seg`` / ``row`` name each record's batch and
+    its row in it; the uint32 slab's columns may be passed as they are.
+
+    With ``table`` (:func:`segment_table` over the same ``batches``) the
+    slab is gathered by the native routine in O(records) and counted in
+    ``emit.gather.native_slabs``; an index or span out of range raises
+    MergeError. Without it, or with ``uda.tpu.use.native`` switched off
+    since, the numpy path below runs: two masked passes per segment
+    present in the slab — the plain reference, same bytes."""
+    if table is not None and native_enabled():
+        sub = native.gather_slab_native(table, seg, row)
+        if sub is not None:
+            metrics.add("emit.gather.native_slabs")
+            return sub
+    seg = np.asarray(seg).astype(np.int64, copy=False)
+    row = np.asarray(row).astype(np.int64, copy=False)
     m = seg.shape[0]
     k_len = np.empty(m, np.int64)
     v_len = np.empty(m, np.int64)

@@ -19,14 +19,14 @@ from typing import Optional
 
 import numpy as np
 
-from uda_tpu.utils.errors import StorageError
+from uda_tpu.utils.errors import MergeError, StorageError
 from uda_tpu.utils.ifile import RecordBatch
 from uda_tpu.utils.logging import get_logger
 
 __all__ = ["available", "build", "crack_native", "crack_partial_native",
            "decode_vlongs_native", "write_records_native", "frame_batch",
            "iter_framed_chunks", "ReadPool", "kway_supported",
-           "kway_merge_paths"]
+           "kway_merge_paths", "SegmentTable", "gather_slab_native"]
 
 log = get_logger()
 
@@ -112,6 +112,18 @@ def _bind(lib):
     lib.uda_gather_spans.restype = None
     lib.uda_gather_spans.argtypes = [u8p, i64p, i64p, ctypes.c_int64,
                                      u8p, i64p]
+    # the table and the (seg, row) slab columns go as raw addresses:
+    # SegmentTable / gather_slab_native own their layout checks
+    addr = ctypes.c_void_p
+    lib.uda_slab_lengths.restype = ctypes.c_int64
+    slab = [addr, addr, ctypes.c_int64, addr, ctypes.c_int64,
+            ctypes.c_int64]  # table, seg + stride, row + stride, n
+    lib.uda_slab_lengths.restype = ctypes.c_int64
+    lib.uda_slab_lengths.argtypes = slab + [ctypes.c_int64, i64p, i64p,
+                                            i64p]
+    lib.uda_slab_copy.restype = None
+    lib.uda_slab_copy.argtypes = slab + [i64p, i64p, ctypes.c_int64, u8p,
+                                         i64p, i64p]
     return lib
 
 
@@ -389,6 +401,92 @@ def gather_spans_native(src: np.ndarray, src_off: np.ndarray,
         _i64ptr(np.ascontiguousarray(lens, np.int64)), n,
         _u8ptr(dst), _i64ptr(np.ascontiguousarray(dst_off, np.int64)))
     return True
+
+
+class SegmentTable:
+    """One task's per-segment lookup table for :func:`gather_slab_native`:
+    row ``s`` of a contiguous ``int64[segments, 7]`` array holds batch
+    ``s``'s ``data``, ``key_off``, ``key_len``, ``val_off`` and
+    ``val_len`` base addresses, its record count and its data size (the
+    C side's ``UdaSegment``). Built once per task in O(segments):
+    nothing of the shuffle is concatenated or copied (a column that is
+    not already contiguous int64 is, once, and the copy kept). The
+    arrays the addresses point into are kept alive for the table's
+    life."""
+
+    __slots__ = ("segments", "_rows", "_keep")
+
+    def __init__(self, batches):
+        self.segments = len(batches)
+        self._rows = np.empty((self.segments, 7), np.int64)
+        self._keep = []
+        for s, b in enumerate(batches):
+            data = np.ascontiguousarray(b.data, np.uint8)
+            cols = [np.ascontiguousarray(c, np.int64)
+                    for c in (b.key_off, b.key_len, b.val_off, b.val_len)]
+            n = cols[0].shape[0]
+            if data.ndim != 1 or any(c.shape != (n,) for c in cols):
+                raise ValueError(f"segment {s}: ragged record columns")
+            self._keep.append((data, cols))
+            self._rows[s] = (data.ctypes.data, *(c.ctypes.data for c in cols),
+                             n, data.size)
+
+
+_SLAB_ERRORS = {1: "segment index out of range",
+                2: "row index out of range",
+                3: "record span outside its segment's data"}
+
+
+def _u32_column(col: np.ndarray, what: str) -> np.ndarray:
+    """A slab's segment or row column as the C loop reads it: 1-D
+    uint32 at any stride (the row-major slab's column is taken in
+    place). Other integer dtypes are converted, checked first — a
+    negative or oversized index must not wrap into range."""
+    col = np.asarray(col)
+    if col.ndim != 1 or col.dtype.kind not in "iu":
+        raise ValueError(f"slab {what} column must be a 1-D integer array")
+    if col.dtype == np.uint32:
+        return col
+    if col.size and (int(col.min()) < 0 or int(col.max()) > 0xFFFFFFFF):
+        raise MergeError(f"slab gather: {what} index out of range")
+    return col.astype(np.uint32)
+
+
+def gather_slab_native(table: SegmentTable, seg: np.ndarray,
+                       row: np.ndarray) -> Optional[RecordBatch]:
+    """One output slab's records, looked up through ``table`` by
+    ``(seg[i], row[i])`` and written as a compact RecordBatch — its own
+    buffer with all keys then all values — in two C passes over the
+    slab, whatever the segment count (the native twin of
+    ``merger/streaming.py:slab_batch``'s numpy path, byte-identical).
+    The C loop checks every record; an index or span out of range
+    raises MergeError. Returns None when the library isn't available."""
+    lib = _load()
+    if lib is None:
+        return None
+    seg = _u32_column(seg, "segment")
+    row = _u32_column(row, "row")
+    n = seg.shape[0]
+    if row.shape[0] != n:
+        raise ValueError(f"slab columns disagree: {n} segment indices, "
+                         f"{row.shape[0]} rows")
+    slab = (table._rows.ctypes.data, seg.ctypes.data, seg.strides[0],
+            row.ctypes.data, row.strides[0], n)
+    k_len = np.empty(n, np.int64)
+    v_len = np.empty(n, np.int64)
+    out = (ctypes.c_int64 * 3)()  # key total, value total, bad record
+    rc = lib.uda_slab_lengths(*slab, table.segments, _i64ptr(k_len),
+                              _i64ptr(v_len), out)
+    if rc:
+        raise MergeError(f"slab gather: {_SLAB_ERRORS.get(rc, rc)} at slab "
+                         f"record {out[2]} (segment {int(seg[out[2]])}, "
+                         f"row {int(row[out[2]])})")
+    buf = np.empty(out[0] + out[1], np.uint8)
+    k_off = np.empty(n, np.int64)
+    v_off = np.empty(n, np.int64)
+    lib.uda_slab_copy(*slab, _i64ptr(k_len), _i64ptr(v_len), out[0],
+                      _u8ptr(buf), _i64ptr(k_off), _i64ptr(v_off))
+    return RecordBatch(buf, k_off, k_len, v_off, v_len)
 
 
 def merge_rows_native(a: np.ndarray, b: np.ndarray) -> Optional[np.ndarray]:

@@ -121,3 +121,97 @@ extern "C" void uda_gather_spans(const uint8_t* src, const int64_t* src_off,
   for (int64_t i = 0; i < n; ++i)
     std::memcpy(dst + dst_off[i], src + src_off[i], (size_t)lens[i]);
 }
+
+// Slab gather over a per-task segment table: the in-memory emit path's
+// byte movement (uda_tpu/merger/streaming.py:slab_batch). The merged
+// device rows name every output record as (segment, row); one table
+// entry per segment holds that segment's data and column base
+// addresses, so a slab is gathered in O(records), whatever the segment
+// count. Two passes because the compact buffer (all keys, then all
+// values) is sized from the lengths: pass 1 validates every record and
+// writes its lengths, Python allocates, pass 2 copies.
+struct UdaSegment {       // one row of the int64[segments, 7] table
+  const uint8_t* data;
+  const int64_t* key_off;
+  const int64_t* key_len;
+  const int64_t* val_off;
+  const int64_t* val_len;
+  int64_t records;
+  int64_t data_size;
+};
+static_assert(sizeof(UdaSegment) == 7 * sizeof(int64_t),
+              "UdaSegment must match the int64[segments, 7] table");
+
+// (segment, row) of slab record i: uint32 columns addressed by byte
+// stride, so the columns of the row-major uint32 slab are read in place
+static inline uint32_t slab_u32(const uint8_t* col, int64_t stride,
+                                int64_t i) {
+  uint32_t v;
+  std::memcpy(&v, col + i * stride, sizeof v);
+  return v;
+}
+
+static inline bool span_inside(int64_t off, int64_t len, int64_t size) {
+  return off >= 0 && len >= 0 && off <= size && len <= size - off;
+}
+
+extern "C" {
+
+enum : int64_t {
+  UDA_SLAB_BAD_SEGMENT = 1,  // seg >= segments
+  UDA_SLAB_BAD_ROW = 2,      // row >= records[seg]
+  UDA_SLAB_BAD_SPAN = 3,     // key or value span outside the data
+};
+
+// Pass 1. Returns 0 with out = {sum of key_len, sum of val_len}, or a
+// UDA_SLAB_* code with out[2] = the slab record it was found at.
+int64_t uda_slab_lengths(const UdaSegment* table,
+                         const uint8_t* seg, int64_t seg_stride,
+                         const uint8_t* row, int64_t row_stride, int64_t n,
+                         int64_t segments, int64_t* key_len,
+                         int64_t* val_len, int64_t out[3]) {
+  int64_t kt = 0, vt = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    out[2] = i;
+    const uint32_t s = slab_u32(seg, seg_stride, i);
+    if ((int64_t)s >= segments) return UDA_SLAB_BAD_SEGMENT;
+    const UdaSegment& t = table[s];
+    const uint32_t r = slab_u32(row, row_stride, i);
+    if ((int64_t)r >= t.records) return UDA_SLAB_BAD_ROW;
+    const int64_t kl = t.key_len[r], vl = t.val_len[r];
+    if (!span_inside(t.key_off[r], kl, t.data_size) ||
+        !span_inside(t.val_off[r], vl, t.data_size))
+      return UDA_SLAB_BAD_SPAN;
+    key_len[i] = kl;
+    val_len[i] = vl;
+    kt += kl;
+    vt += vl;
+  }
+  out[0] = kt;
+  out[1] = vt;
+  return 0;
+}
+
+// Pass 2, over the records and lengths pass 1 accepted (nothing is
+// re-checked): buf = all keys then all values, key_dst / val_dst the
+// offset of each inside it; buf holds key_total + val_total bytes.
+void uda_slab_copy(const UdaSegment* table,
+                   const uint8_t* seg, int64_t seg_stride,
+                   const uint8_t* row, int64_t row_stride, int64_t n,
+                   const int64_t* key_len, const int64_t* val_len,
+                   int64_t key_total, uint8_t* buf,
+                   int64_t* key_dst, int64_t* val_dst) {
+  int64_t kp = 0, vp = key_total;
+  for (int64_t i = 0; i < n; ++i) {
+    const UdaSegment& t = table[slab_u32(seg, seg_stride, i)];
+    const uint32_t r = slab_u32(row, row_stride, i);
+    std::memcpy(buf + kp, t.data + t.key_off[r], (size_t)key_len[i]);
+    std::memcpy(buf + vp, t.data + t.val_off[r], (size_t)val_len[i]);
+    key_dst[i] = kp;
+    val_dst[i] = vp;
+    kp += key_len[i];
+    vp += val_len[i];
+  }
+}
+
+}  // extern "C"

@@ -172,6 +172,13 @@ METRICS_REGISTRY: Dict[str, tuple] = {
     # -- counters: supplier / emit / merge / exchange --------------------
     "supplier.bytes": ("counter", "bytes served by the DataEngine"),
     "emit.bytes": ("counter", "framed bytes handed to the consumer"),
+    "emit.gather.native_slabs": ("counter", "output slabs gathered by "
+                                            "the native routine over "
+                                            "the per-task segment table "
+                                            "(slab_batch); short of the "
+                                            "emit_gather span count = "
+                                            "slabs that fell back to "
+                                            "the numpy path"),
     "merge.records": ("counter", "records through the merge "
                                  "(staged or device-merged)"),
     "spool.bytes": ("counter", "bytes spooled to sorted run files "
