@@ -39,10 +39,15 @@ def test_device_bytes_model_shape():
     assert dev > 16 << 30
     # ... and is ~1.08x shuffle bytes x working-set factor at that shape
     assert dev == int((10 << 30) * 1.08 * WORKING_SET_FACTOR)
-    # tiny keys still charge the row matrix (row bytes dominate when
-    # records are smaller than a row)
+    # tiny records charge the run forest (row bytes dominate when
+    # records are smaller than a row): 7 columns are stored as 8, a run
+    # pads to at most twice its rows, the merger holds at most three
+    # times the staged rows
     assert device_bytes_estimate(1000, key_width=16, record_bytes=10) \
-        >= 100 * 28
+        == 100 * 32 * 2 * 3
+    # 13 columns (a 40 B key) are stored as 16
+    assert device_bytes_estimate(1000, key_width=40, record_bytes=10) \
+        == 100 * 64 * 2 * 3
     assert device_bytes_estimate(0, 16) == 0
 
 

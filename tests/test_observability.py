@@ -253,7 +253,12 @@ def test_serve_spans_carry_reduce_trace_id_and_merge(tmp_path):
                   records_per_map=50, seed=5)
     metrics.enable_spans()
     engine = DataEngine(DirIndexResolver(str(mof)), Config())
-    server = ShuffleServer(engine, Config(), host="127.0.0.1", port=0)
+    # the byte path: the zero-copy fd path serves a resolved partition
+    # inline with no engine.pread span, and the task's size probe (the
+    # chip-wide HBM ledger's, at every merge approach) has resolved
+    # every partition before its first fetch
+    server = ShuffleServer(engine, Config({"uda.tpu.net.zerocopy": False}),
+                           host="127.0.0.1", port=0)
     server.start()
     try:
         router = HostRoutingClient(config=Config())

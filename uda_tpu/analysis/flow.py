@@ -185,6 +185,15 @@ DEFAULT_PAIRS: Tuple[ObligationPair, ...] = (
         "gauge.push.staged", kind="gauge", gauge="push.staged.bytes",
         description="pushed bytes staged reduce-side but not yet "
                     "adopted or discarded (net/push.py PushStaging)"),
+    ObligationPair(
+        "gauge.tasks.live", kind="gauge", gauge="reduce.tasks.live",
+        description="reduce tasks on the chip-wide HBM ledger's books "
+                    "(utils/budget.py HbmLedger.reserve / HbmHold."
+                    "release)"),
+    ObligationPair(
+        "gauge.hbm.reserved", kind="gauge", gauge="budget.hbm.reserved",
+        description="device bytes the live reduce tasks hold reserved "
+                    "in the chip-wide HBM ledger (utils/budget.py)"),
 )
 
 

@@ -40,7 +40,7 @@ from uda_tpu.merger import LocalFetchClient, MergeManager
 from uda_tpu.merger.segment import InputClient
 from uda_tpu.mofserver import DataEngine, IndexRecord, IndexResolver
 from uda_tpu.utils import compile_cache
-from uda_tpu.utils.budget import MemoryBudget
+from uda_tpu.utils.budget import MemoryBudget, hbm_ledger
 from uda_tpu.utils.config import Config
 from uda_tpu.utils.errors import FallbackSignal, ProtocolError, UdaError
 from uda_tpu.utils.failpoints import failpoint
@@ -366,12 +366,10 @@ class UdaBridge:
             else:
                 raise ProtocolError(
                     f"INIT needs >= 4 params, got {len(params)}")
-            # the reduce task's tenant identity (uda.tpu.tenant.id):
-            # RemoteFetchClients read their binding from the same cfg;
-            # this process-global install feeds the hot-path metric
-            # labels (fetch.bytes{tenant=}) and diagnostics
-            from uda_tpu.tenant import set_current_tenant
-            set_current_tenant(str(self.cfg.get("uda.tpu.tenant.id")))
+            # the reduce task's tenant identity (uda.tpu.tenant.id) is
+            # read from this task's cfg by those who stamp it: the
+            # RemoteFetchClients for their binding, the MergeManager for
+            # its hot-path metric labels (fetch.bytes{tenant=})
             # INIT-time admission: the fetch-window + staging working
             # set must fit the host budget (the reducer.cc:56-133
             # buffer validation, generalized; with a tenant budget
@@ -437,8 +435,13 @@ class UdaBridge:
                 self._stats = None
             # the reduce task is over: EVERY obligation — leases, fd
             # pins, paired-gauge increments, scoped failpoints — must
-            # be settled (the process-end full drain, no pair filter)
-            resledger.drain("bridge.exit")
+            # be settled (the process-end full drain, no pair filter).
+            # The books are the PROCESS's: while another reduce task of
+            # this process is live (a node's reduce slots) its open
+            # obligations are legitimate, and the last task's EXIT
+            # drains for all
+            if hbm_ledger.holders == 0:
+                resledger.drain("bridge.exit")
         else:
             raise ProtocolError(f"unexpected command {header.name} for "
                                 "NetMerger role")

@@ -47,7 +47,6 @@ from uda_tpu.utils.errors import (FallbackSignal, MergeError, StorageError,
 from uda_tpu.utils.failpoints import failpoints
 from uda_tpu.utils.flightrec import flightrec
 from uda_tpu.utils.locks import TrackedLock
-from uda_tpu.tenant import current_tenant
 from uda_tpu.utils.ifile import RecordBatch
 from uda_tpu.utils.logging import get_logger
 from uda_tpu.utils.metrics import metrics
@@ -78,7 +77,8 @@ class PenaltyBox:
     the box on every lucky fetch."""
 
     def __init__(self, threshold: int = 2, penalty_s: float = 1.0,
-                 reset_successes: int = 3):
+                 reset_successes: int = 3, tenant: str = ""):
+        self.tenant = tenant  # labels fetch.penalties (the task's own)
         self.threshold = max(1, threshold)
         self.penalty_s = penalty_s
         self.reset_successes = max(1, reset_successes)
@@ -97,7 +97,7 @@ class PenaltyBox:
             if n < self.threshold:
                 return False
             self._until[key] = time.monotonic() + self.penalty_s
-        tenant = current_tenant()
+        tenant = self.tenant
         if tenant:
             metrics.add("fetch.penalties", supplier=key, tenant=tenant)
         else:
@@ -205,9 +205,14 @@ class MergeManager:
         self.seed = seed
         self.emitter = FramedEmitter(self.chunk_size)
         self.retry_policy = RetryPolicy.from_config(self.cfg)
+        # the task's tenant identity labels its hot-path fetch counters
+        # (fetch.bytes{tenant=}); task-local, never process-global:
+        # reduce tasks of several tenants may share this process
+        self.tenant = str(self.cfg.get("uda.tpu.tenant.id") or "")
         self.penalty_box = PenaltyBox(
             threshold=self.cfg.get("uda.tpu.fetch.penalty.threshold"),
-            penalty_s=self.cfg.get("uda.tpu.fetch.penalty.ms") / 1e3)
+            penalty_s=self.cfg.get("uda.tpu.fetch.penalty.ms") / 1e3,
+            tenant=self.tenant)
         # the survivable-shuffle layer (ISSUE 8): speculation, resume
         # and k-of-n reconstruction all share ONE recovery ledger
         self.ledger = RecoveryLedger(self.penalty_box)
@@ -254,6 +259,7 @@ class MergeManager:
         self._watchdog: Optional[StallWatchdog] = None
         self._stall_error: Optional[StallError] = None
         self._emit_progress = 0
+        self._admit_ticks = 0  # polls of a wait for the chip's HBM ledger
         # push plane (ISSUE 19): reduce-side staging, armed by
         # arm_push() — ideally by the embedder the moment the reduce
         # task is SCHEDULED (pushes then overlap the entire map phase);
@@ -454,7 +460,8 @@ class MergeManager:
                         policy=self.retry_policy, hosts=hosts,
                         ledger=self.ledger,
                         speculation=self.speculation,
-                        resume=self.resume_fetch, stripe=stripe_ctx)
+                        resume=self.resume_fetch, stripe=stripe_ctx,
+                        tenant=self.tenant)
                 for i, (hosts, mid) in enumerate(entries)]
         for i, kw in (preload or {}).items():
             if segs[i] is None:
@@ -773,7 +780,7 @@ class MergeManager:
         ckpt = self._ckpt
         return (len(segs), ndone, nrec, noff, nret, om_sig,
                 self.ledger.version, getattr(self, "_emit_progress", 0),
-                ckpt.version if ckpt is not None else 0)
+                self._admit_ticks, ckpt.version if ckpt is not None else 0)
 
     def _start_watchdog(self, reduce_id: int) -> Optional[StallWatchdog]:
         stall_s = float(self.cfg.get("uda.tpu.watchdog.stall.s"))
@@ -1006,7 +1013,64 @@ class MergeManager:
             segments = self.fetch_all(job_id, map_ids, reduce_id)
             merged = self.merge_segments(segments)
             return self.emit_framed(merged, consumer)
+        # the overlapped route builds the device row forest. Admission
+        # may already have rerouted here BECAUSE that forest would blow
+        # the HBM budget: then the streaming merger must not stage runs
+        # to the device at all — run files + bounded k-way merge
+        # instead ("streaming with bounded device runs")
+        adm = self.last_admission
+        bounded_device = (streaming and adm is not None
+                          and adm.cause == "hbm")
+        # the in-flight staging cap clamps to a budget only when the
+        # auto policy built one (stage_inflight_cap); the ledger's
+        # budget below must not move it
+        cap_budget = self._budget_obj
+        # the chip is shared with every other live reduce task of this
+        # process (a node's reduce slots): reserve this task's device
+        # estimate in the chip-wide ledger BEFORE anything is staged —
+        # waiting here while the live tasks leave no room, like the
+        # reference's occupy_chunk. At every merge approach: the auto
+        # policy's route() sized the task against the whole chip, the
+        # ledger sizes it against what the live tasks leave
+        est = None
+        if adm is not None:
+            est = adm.estimate_bytes
+        elif not bounded_device:
+            # a transport that cannot say (or a duck-typed one that has
+            # no such method) leaves the size unknown
+            probe = getattr(self.client, "estimate_partition_bytes", None)
+            if callable(probe):
+                est = probe(job_id, map_ids, reduce_id)
+        hold, reroute = self.budget().admit_device(
+            est, bounded=bounded_device, stopped=self._admit_poll)
+        if reroute is not None:
+            # the chip cannot hold this task even alone: the bounded-
+            # device route, never an OOM that takes the live tasks along
+            self.last_admission = reroute
+            streaming = bounded_device = True
+            flightrec.record("admission", decision=reroute.decision,
+                             cause=reroute.cause, rejected=False,
+                             estimate=est)
+        with hold:
+            return self._run_overlapped(job_id, map_ids, reduce_id,
+                                        consumer, streaming, bounded_device,
+                                        cap_budget)
 
+    def _admit_poll(self) -> bool:
+        """Polled by the HBM ledger while this task waits: whether the
+        task is being torn down. A task parked behind the live tasks'
+        reservations is waiting, not wedged (each holder's own watchdog
+        guards its liveness): every poll counts as progress."""
+        self._admit_ticks += 1
+        return self._stop.is_set()
+
+    def _run_overlapped(self, job_id: str, map_ids: Sequence,
+                        reduce_id: int,
+                        consumer: Callable[[memoryview], None],
+                        streaming: bool, bounded_device: bool,
+                        cap_budget: Optional[MemoryBudget]) -> int:
+        """The overlapped fetch/merge route (streaming or in-memory),
+        run while the task holds its reservation of the chip's HBM."""
         from uda_tpu.merger.overlap import OverlappedMerger
 
         store = None
@@ -1044,13 +1108,6 @@ class MergeManager:
             else:
                 store = RunStore(spill_dirs(self.cfg),
                                  tag=f"{job_id}.r{reduce_id}")
-        # admission may have rerouted here BECAUSE the device row forest
-        # would blow the HBM budget: then the streaming merger must not
-        # stage runs to the device at all — run files + bounded k-way
-        # merge instead ("streaming with bounded device runs")
-        adm = self.last_admission
-        bounded_device = (streaming and adm is not None
-                          and adm.cause == "hbm")
         # staged pipeline (uda.tpu.stage.pipeline, default on): stage
         # pool + merge consumer with an in-flight byte budget; off =
         # the serial stage loop (the A/B twin). Pool width:
@@ -1070,7 +1127,7 @@ class MergeManager:
             pipeline=pipelined,
             inflight_bytes=stage_inflight_cap(
                 self.cfg, self.window, self.chunk_size,
-                budget=self._budget_obj),
+                budget=cap_budget),
             on_spool=((lambda i: ckpt.maybe_save(collect))
                       if ckpt is not None else None))
         self._active_overlap = om  # observability (tests/diagnostics)

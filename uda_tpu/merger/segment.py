@@ -25,7 +25,6 @@ import zlib
 from typing import Optional
 
 from uda_tpu.mofserver.data_engine import DataEngine, FetchResult, ShuffleRequest
-from uda_tpu.tenant import current_tenant
 from uda_tpu.utils.errors import (MergeError, StorageError, TenantError,
                                   TransportError, attribute_supplier)
 from uda_tpu.utils.failpoints import failpoint
@@ -471,8 +470,13 @@ class Segment:
                  retries: int = 3, policy: Optional[RetryPolicy] = None,
                  *, hosts=None, ledger=None,
                  speculation: Optional[SpeculationPolicy] = None,
-                 resume: bool = False, stripe=None):
+                 resume: bool = False, stripe=None, tenant: str = ""):
         self.client = client
+        # the task's tenant identity (uda.tpu.tenant.id, from the
+        # task's own MergeManager): labels the hot-path fetch counters.
+        # Task-local on purpose — several reduce tasks of different
+        # tenants may be live in one process (a node's reduce slots)
+        self.tenant = tenant
         self.job_id = job_id
         self.map_id = map_id
         self.reduce_id = reduce_id
@@ -708,7 +712,7 @@ class Segment:
             if epoch not in (self._epoch, spec_epoch) \
                     or self._epoch_settled:
                 return  # the attempt completed first
-        tenant = current_tenant()
+        tenant = self.tenant
         if tenant:
             metrics.add("fetch.timeouts", supplier=self.supplier,
                         tenant=tenant)
@@ -997,7 +1001,7 @@ class Segment:
                 else:
                     log.warn(f"fetch of {self.map_id} failed ({result}); "
                              f"retrying ({self._retries_left} left)")
-                tenant = current_tenant()
+                tenant = self.tenant
                 if tenant:
                     metrics.add("fetch.retries", supplier=self.supplier,
                                 tenant=tenant)
@@ -1097,10 +1101,10 @@ class Segment:
                 self._carry = data[consumed:] if not last else b""
                 self._next_offset = res.offset + len(res.data)
             issue_t0 = self._issue_t0
-        tenant = current_tenant()
+        tenant = self.tenant
         if tenant:
             # tenanted reduce tasks label the hot-path fetch counters
-            # (one module-global read per chunk; untenanted jobs keep
+            # (one attribute read per chunk; untenanted jobs keep
             # the exact two-series shape of PRs 2-13)
             metrics.add("fetch.bytes", len(res.data),
                         supplier=self.supplier, tenant=tenant)

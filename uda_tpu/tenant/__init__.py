@@ -15,10 +15,11 @@ shuffle as a SHARED service rather than a per-job plugin. The pieces:
   per-tenant ``MemoryBudget`` shares on the reduce side
   (``uda.tpu.tenant.budget.share``).
 
-``current_tenant()`` is the process-local tenant identity the reduce
-side stamps onto its hot-path metric labels (set once at bridge INIT
-from ``uda.tpu.tenant.id``; a module-global read so the per-chunk cost
-is one attribute load).
+The reduce side's tenant identity (``uda.tpu.tenant.id``) is the
+TASK's: each ``MergeManager`` reads it from its own config and hands it
+to its segments and penalty box, which stamp it onto their hot-path
+metric labels. It is not process state — the reduce tasks of one
+process (a node's reduce slots) may belong to different tenants.
 """
 
 from __future__ import annotations
@@ -28,18 +29,4 @@ from uda_tpu.tenant.registry import (DEFAULT_TENANT, TenantRecord,
 from uda_tpu.tenant.sched import CreditScheduler
 
 __all__ = ["TenantRegistry", "TenantRecord", "CreditScheduler",
-           "DEFAULT_TENANT", "sign_job", "current_tenant",
-           "set_current_tenant"]
-
-_CURRENT_TENANT = ""
-
-
-def set_current_tenant(tenant: str) -> None:
-    """Install this process's tenant identity (bridge INIT; empty =
-    untenanted, labels stay off the hot paths)."""
-    global _CURRENT_TENANT
-    _CURRENT_TENANT = str(tenant or "")
-
-
-def current_tenant() -> str:
-    return _CURRENT_TENANT
+           "DEFAULT_TENANT", "sign_job"]

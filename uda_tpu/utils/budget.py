@@ -33,20 +33,37 @@ working-set bytes, and two admission points:
 
 Every decision is logged and counted (``budget.admitted`` /
 ``budget.rerouted`` / ``budget.rejected``).
+
+:class:`HbmLedger` (the module's :data:`hbm_ledger`) is the CHIP's side
+of admission: a TPU host's one process holds the chip, so a node's
+reduce slots are several reduce tasks in that process sharing one HBM.
+``MemoryBudget`` sizes one task against the chip; the ledger books what
+the LIVE tasks hold, so the next one is admitted against the budget
+less their reservations (:meth:`MemoryBudget.admit_device`, called by
+``MergeManager._run`` before a task stages its first run to the device,
+at every merge approach that builds the device forest). A task that
+does not fit beside the live ones WAITS for a release — the reference
+blocked on pool exhaustion too (``occupy_chunk``) — and one that does
+not fit the chip alone is not the ledger's to hold: it takes the
+bounded-device route. The ledger is told no slot count; it observes.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Optional
+from collections import deque
+from typing import Callable, Optional
 
-from uda_tpu.utils.errors import UdaError
+from uda_tpu.utils.errors import MergeError, UdaError
+from uda_tpu.utils.locks import TrackedCondition, TrackedLock
 from uda_tpu.utils.logging import get_logger
 from uda_tpu.utils.metrics import metrics
 
-__all__ = ["MemoryBudget", "Admission", "device_bytes_estimate",
+__all__ = ["MemoryBudget", "Admission", "HbmLedger", "HbmHold",
+           "hbm_ledger", "device_bytes_estimate",
            "stage_inflight_cap", "ROW_OVERHEAD_WORDS",
+           "HBM_ROW_ALIGN_WORDS", "RUN_PAD_FACTOR", "FOREST_FACTOR",
            "WORKING_SET_FACTOR", "HBM_RESERVE_FRACTION",
            "PLATFORM_HBM_MB", "STAGE_INFLIGHT_FLOOR_MB"]
 
@@ -84,29 +101,43 @@ def stage_inflight_cap(cfg, window: int, chunk_size: int,
 
 # -- the device-bytes model -------------------------------------------------
 #
-# Per record the engine holds one uint32 row of (key words, content
-# length, segment index, row index) = key_width/4 + ROW_OVERHEAD_WORDS
-# words. At the TeraSort shape the *sort-network* ladder carries ~27
-# words/record (~108 B, ≈1.08x shuffle bytes): key + payload surrogate
-# columns ride along on the fully device-resident sort path. The
-# admission model uses the larger of the two (row matrix vs the 1.08x
-# sort ladder) so it is conservative for both the forest-merge and the
-# whole-run-sort engines.
+# Two engines hold a partition on the device, and the model takes the
+# larger so that it covers both:
+#
+# - the RUN FOREST of the overlapped merge (merger/overlap.py, the route
+#   the served reduce path runs). Per record one uint32 row of (key
+#   words, content length, segment index, row index) = key_width/4 +
+#   ROW_OVERHEAD_WORDS columns, which libtpu stores long-dimension-minor
+#   with the columns rounded up to HBM_ROW_ALIGN_WORDS: 7 columns cost
+#   32 B a record (measured, v5e, PR 22 — not the 28 B of the logical
+#   matrix). Every run is padded to a power-of-two capacity: at worst
+#   RUN_PAD_FACTOR x its rows. And the merger holds at most
+#   FOREST_FACTOR x the staged rows at once: one executed copy of every
+#   row (in the forest, or as the input of a merge that has not run
+#   yet) and the outputs of merges dispatched but perhaps not executed,
+#   which OverlappedMerger._await_device_room holds to FOREST_FACTOR - 1
+#   times the staged bytes (one carry chain's outputs add up to 2x);
+# - the SORT LADDER of the whole-run sort engines: ~27 words a record
+#   at the TeraSort shape (~108 B, SORT_LADDER_RATIO x the shuffle
+#   bytes: key and payload surrogate columns ride along), times
+#   WORKING_SET_FACTOR for the transient (a pairwise step holds both
+#   operands and the output; 2x bounds it).
+#
+# Against the device (v5e, memory_stats peak_bytes_in_use over
+# back-to-back tasks): a 1.05 GB partition in 64 runs staged 537 MB of
+# rows; before the dispatch bound a warm task peaked at 2,429 MB (4.5x
+# the staged rows — the host outran the device) where this model said
+# 2,268 MB, the unsafe side; a 131 MB partition in 1,024 runs peaked at
+# 211 MB against 283 MB. PERF.md has what they read since. What memory_stats does NOT count is an
+# executable's own temporaries: the largest merge of the 1.05 GB task
+# needs 4.3 GB of them while it runs (memory_analysis; PERF.md 7).
 ROW_OVERHEAD_WORDS = 3        # length, segment index, row index columns
+HBM_ROW_ALIGN_WORDS = 8       # a row's columns as the device stores them
+RUN_PAD_FACTOR = 2.0          # power-of-two run capacity, at worst
+FOREST_FACTOR = 3.0           # executed rows + pending merge outputs
 SORT_LADDER_RATIO = 1.08      # device bytes / shuffle bytes, TeraSort shape
 RECORD_BYTES_DEFAULT = 100    # TeraSort record (10 B key + 90 B value)
-
-# Transient working set: a pairwise merge holds both operands plus the
-# output simultaneously, and binary-counter runs pad to a power of two —
-# 2x the resident matrix bounds both.
-#
-# Against the device (chip_smoke.py Phase A, v5e, 2026-09-26, one run):
-# a staged uint32[n, 7] run costs 32 B/record in HBM (libtpu stores it
-# long-dimension-minor, 7 columns padded to 8 — not the 28 B modeled
-# and not a 128-word lane pad), and the overlapped merge of a 1.05 GB
-# partition peaked at 1.60 GB where this model says 2.27 GB: the model
-# is conservative by 1.4x, which is the side admission should err on.
-WORKING_SET_FACTOR = 2.0
+WORKING_SET_FACTOR = 2.0      # the sort ladder's transient
 
 # Fraction of physical HBM the budget may claim by default (the rest is
 # XLA scratch, compiled executables, and the exchange path's buffers).
@@ -178,16 +209,140 @@ def _detect_hbm_mb() -> int:
 def device_bytes_estimate(partition_bytes: int, key_width: int,
                           record_bytes: int = RECORD_BYTES_DEFAULT) -> int:
     """Device-resident bytes the merge would hold for a partition of
-    ``partition_bytes`` on-disk bytes: max(row matrix, sort ladder) x
-    the transient working-set factor. Conservative by construction —
-    admission errs toward the bounded path."""
+    ``partition_bytes`` on-disk bytes: the larger of the run forest and
+    the sort ladder (see the model above). An upper bound by
+    construction — admission errs toward the bounded path, and what the
+    chip-wide ledger reserves for a task covers what the task can
+    hold."""
     if partition_bytes <= 0:
         return 0
-    row_bytes = 4 * (max(4, key_width) // 4 + ROW_OVERHEAD_WORDS)
+    cols = max(4, key_width) // 4 + ROW_OVERHEAD_WORDS
+    row_bytes = 4 * -(-cols // HBM_ROW_ALIGN_WORDS) * HBM_ROW_ALIGN_WORDS
     records = max(1, partition_bytes // max(1, record_bytes))
-    row_matrix = records * row_bytes
-    ladder = int(partition_bytes * SORT_LADDER_RATIO)
-    return int(max(row_matrix, ladder) * WORKING_SET_FACTOR)
+    forest = records * row_bytes * RUN_PAD_FACTOR * FOREST_FACTOR
+    ladder = partition_bytes * SORT_LADDER_RATIO * WORKING_SET_FACTOR
+    return int(max(forest, ladder))
+
+
+# -- the chip-wide HBM ledger ----------------------------------------------
+
+
+class HbmHold:
+    """One live task's reservation. ``release()`` is idempotent and is
+    the ONLY way bytes leave the ledger: the holder calls it on every
+    exit (end, abort, exception) — :meth:`MemoryBudget.admit_device`
+    hands it out as a context manager for exactly that."""
+
+    __slots__ = ("_ledger", "nbytes")
+
+    def __init__(self, ledger: "HbmLedger", nbytes: int):
+        self._ledger = ledger
+        self.nbytes = nbytes
+
+    def release(self) -> None:
+        ledger, self._ledger = self._ledger, None
+        if ledger is not None:
+            ledger._release(self.nbytes)
+
+    def __enter__(self) -> "HbmHold":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
+
+class HbmLedger:
+    """What the live reduce tasks of this process hold reserved of the
+    chip's HBM. One instance a process (:data:`hbm_ledger`): the
+    process is what holds the chip.
+
+    ``reserve(nbytes, budget_bytes)`` admits the caller when the live
+    reservations plus ``nbytes`` fit ``budget_bytes`` (the CALLER's
+    view of the chip's budget — a tenant's share is its own), else
+    blocks until releases make room. Admission is first come, first
+    served: a task waiting for a large reservation is not overtaken by
+    later small ones, so it cannot starve. A caller that asks for more
+    than the budget can ever hold is a bug of the caller
+    (``admit_device`` sends such a task down the bounded-device route
+    instead) and raises. ``stopped`` is polled while waiting (each poll
+    is also the waiter's sign of life): a task that is being torn down
+    leaves the queue with a ``MergeError``.
+
+    Gauges ``reduce.tasks.live`` and ``budget.hbm.reserved`` follow the
+    books (their high-water marks are kept, metrics.PEAK_GAUGES);
+    ``budget.waited`` counts the tasks that had to wait, the
+    ``hbm_admit`` timer their seconds."""
+
+    POLL_S = 0.1
+
+    def __init__(self) -> None:
+        self._cv = TrackedCondition(TrackedLock("budget.hbm"))
+        self._reserved = 0
+        self._holders = 0
+        self._queue: deque = deque()      # tickets, oldest first
+
+    @property
+    def reserved_bytes(self) -> int:
+        with self._cv:
+            return self._reserved
+
+    @property
+    def holders(self) -> int:
+        """Live tasks holding a reservation (possibly of 0 bytes)."""
+        with self._cv:
+            return self._holders
+
+    def reserve(self, nbytes: int, budget_bytes: int,
+                stopped: Optional[Callable[[], bool]] = None) -> HbmHold:
+        nbytes = max(0, int(nbytes))
+        if nbytes > budget_bytes:
+            raise UdaError(f"HBM reservation of {nbytes} B can never fit "
+                           f"the budget of {budget_bytes} B")
+        ticket = object()
+        waited = False
+        with metrics.timer("hbm_admit"):
+            with self._cv:
+                self._queue.append(ticket)
+                try:
+                    while (self._queue[0] is not ticket
+                           or self._reserved + nbytes > budget_bytes):
+                        if stopped is not None and stopped():
+                            raise MergeError(
+                                "stopped while waiting for the chip's HBM "
+                                f"ledger ({nbytes} B beside "
+                                f"{self._reserved} B reserved by "
+                                f"{self._holders} live task(s))")
+                        if not waited:
+                            waited = True
+                            metrics.add("budget.waited")
+                            log.info(
+                                f"HBM ledger: waiting for {nbytes} B; "
+                                f"{self._reserved} of {budget_bytes} B are "
+                                f"reserved by {self._holders} live task(s)")
+                        self._cv.wait(timeout=self.POLL_S)
+                    self._reserved += nbytes
+                    self._holders += 1
+                finally:
+                    self._queue.remove(ticket)
+                    self._cv.notify_all()     # the next ticket's turn
+        # the books above are the truth; the gauges mirror them. The +x
+        # rides the returned hold: every reserve() is paired with
+        # exactly one _release() through HbmHold.release
+        metrics.gauge_add("reduce.tasks.live", 1)  # udalint: disable=UDA101
+        metrics.gauge_add(  # udalint: disable=UDA101
+            "budget.hbm.reserved", nbytes)
+        return HbmHold(self, nbytes)
+
+    def _release(self, nbytes: int) -> None:
+        with self._cv:
+            self._reserved -= nbytes
+            self._holders -= 1
+            self._cv.notify_all()
+        metrics.gauge_add("reduce.tasks.live", -1)
+        metrics.gauge_add("budget.hbm.reserved", -nbytes)
+
+
+hbm_ledger = HbmLedger()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -420,6 +575,38 @@ class MemoryBudget:
                             estimate_bytes, dev, hbm, host)
         self._record(adm, "budget.admitted")
         return adm
+
+    # -- admission point 3: the chip-wide HBM ledger ------------------------
+
+    def admit_device(self, estimate_bytes: Optional[int],
+                     bounded: bool = False,
+                     stopped: Optional[Callable[[], bool]] = None
+                     ) -> tuple:
+        """Put one task on the chip's books (:data:`hbm_ledger`) before
+        it stages its first run to the device: reserve its device
+        estimate against this budget's view of the chip, blocking while
+        the live tasks leave no room (see :class:`HbmLedger`; a lone
+        task never waits). Returns ``(hold, reroute)``: the hold to
+        release on every exit, and None — or, when the chip cannot hold
+        the task even alone, the :class:`Admission` that sends it down
+        the bounded-device route (cause ``"hbm"``), on which it is live
+        on the books and reserves nothing. ``bounded`` says the caller
+        is on that route already. An unknown estimate reserves the
+        whole budget: a task of unknown size has the chip to itself."""
+        hbm = self.hbm_budget_bytes
+        reroute = None
+        dev = hbm if estimate_bytes is None \
+            else self.device_bytes(estimate_bytes)
+        if bounded:
+            dev = 0
+        elif dev > hbm:
+            reroute = Admission(
+                "streaming", f"over-hbm-budget: device working set "
+                f"{dev} B > {hbm} B", estimate_bytes, dev, hbm,
+                self.host_budget_bytes, cause="hbm", rerouted=True)
+            self._record(reroute, "budget.rerouted")
+            dev = 0
+        return hbm_ledger.reserve(dev, hbm, stopped), reroute
 
     # -- bookkeeping --------------------------------------------------------
 

@@ -61,8 +61,9 @@ SPAN_BUCKETS: Dict[str, str] = {
     # the MSG_JOB tenant registration is fetch-plane control traffic)
     "fetch": "fetch", "fetch.segment": "fetch", "net.fetch": "fetch",
     "net.size_probe": "fetch", "net.job_bind": "fetch",
-    # wait: blocked-on-memory / blocked-on-staging idle
-    "wait_mem": "wait", "merge.wait": "wait",
+    # wait: blocked-on-memory / blocked-on-staging idle (hbm_admit: a
+    # task parked behind the live tasks' HBM reservations)
+    "wait_mem": "wait", "merge.wait": "wait", "hbm_admit": "wait",
     # decompress+pack: host staging compute (materialize, vint-decode,
     # pack, row build, run spooling)
     "overlap_pack": "decompress_pack", "pack": "decompress_pack",

@@ -53,7 +53,7 @@ from uda_tpu.utils.locks import TrackedLock
 from uda_tpu.utils.resledger import resledger as _resledger
 
 __all__ = ["Metrics", "Span", "metrics", "device_trace",
-           "METRICS_REGISTRY", "REGISTRY_PREFIXES", "NAME_RE",
+           "METRICS_REGISTRY", "REGISTRY_PREFIXES", "NAME_RE", "PEAK_GAUGES",
            "SPAN_REGISTRY", "PARITY_ALIASES", "stats_enabled_from_env",
            "percentile_from_summary", "active_span_of_thread",
            "enable_thread_span_registry"]
@@ -142,6 +142,11 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                    "window)"),
     "budget.rejected": ("counter", "tasks refused before allocation "
                                    "(hard ceiling / unfittable INIT)"),
+    "budget.waited": ("counter", "reduce tasks that had to wait for the "
+                                 "chip-wide HBM ledger because the live "
+                                 "tasks' reservations left no room "
+                                 "(utils/budget.py HbmLedger; the seconds "
+                                 "are the hbm_admit timer's)"),
     "watchdog.stalls": ("counter", "stall-watchdog firings (diagnostic "
                                    "dump + optional fallback)"),
     "arena.pressure_events": ("counter", "arena acquires that waited "
@@ -466,6 +471,15 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                       "tenant's WDRR queue (absolute, "
                                       "set at each grant sweep — not "
                                       "paired)"),
+    "reduce.tasks.live": ("gauge", "reduce tasks holding a reservation "
+                                   "of the chip-wide HBM ledger (one per "
+                                   "task on the overlapped route, from "
+                                   "admission to the end of its emit); "
+                                   "high-water mark kept (PEAK_GAUGES)"),
+    "budget.hbm.reserved": ("gauge", "device bytes the live reduce tasks "
+                                     "hold reserved in the chip-wide HBM "
+                                     "ledger (utils/budget.py); high-water "
+                                     "mark kept (PEAK_GAUGES)"),
     "profile.hz": ("gauge", "sampling-profiler rate currently armed "
                             "(0 = off; set absolutely at start/stop, "
                             "deliberately NOT a paired gauge — the "
@@ -688,6 +702,14 @@ PARITY_ALIASES = {
     "total_fetch_time": "fetch_time",
     "total_merge_time": "merge_time",
 }
+
+# Gauges whose high-water mark the hub keeps beside the level
+# (gauge_add): what a reader that samples after the fact needs in order
+# to say how many tasks WERE live at once, or how much HBM the ledger
+# had out. Read with gauge_peaks_snapshot(); restart_gauge_peaks()
+# restarts every mark from its gauge's current level (a measurement
+# window's opening).
+PEAK_GAUGES = ("reduce.tasks.live", "budget.hbm.reserved")
 
 # Fixed histogram buckets: powers of two from 1/16 to 2^30, shared by
 # every histogram (latencies in ms and sizes in bytes both fit; fixed
@@ -923,6 +945,7 @@ class Metrics:
         self._ledger = ledger
         self.counters: Dict[str, float] = defaultdict(float)
         self.gauges: Dict[str, float] = {}
+        self.gauge_peaks: Dict[str, float] = {}   # PEAK_GAUGES only
         self.histograms: Dict[str, _Hist] = {}
         self.spans: list[dict] = []
         # construction-time default, restored by reset(): the global
@@ -1009,7 +1032,9 @@ class Metrics:
         next drain point."""
         key = _series_key(name, labels) if labels else name
         with self._lock:
-            self.gauges[key] = self.gauges.get(key, 0.0) + delta
+            level = self.gauges[key] = self.gauges.get(key, 0.0) + delta
+            if key in PEAK_GAUGES and level > self.gauge_peaks.get(key, 0.0):
+                self.gauge_peaks[key] = level
         led = self._ledger
         if led is not None and led.enabled and not labels:
             led.note_gauge(name, delta)
@@ -1212,6 +1237,20 @@ class Metrics:
         with self._lock:
             return dict(self.gauges)
 
+    def gauge_peaks_snapshot(self) -> Dict[str, float]:
+        """High-water marks of the :data:`PEAK_GAUGES`, by gauge name,
+        since process start, :meth:`reset` or the last
+        :meth:`restart_gauge_peaks`."""
+        with self._lock:
+            return dict(self.gauge_peaks)
+
+    def restart_gauge_peaks(self) -> None:
+        """Restart every high-water mark from its gauge's current
+        level: what a measurement window calls as it opens."""
+        with self._lock:
+            self.gauge_peaks = {k: self.gauges.get(k, 0.0)
+                                for k in PEAK_GAUGES}
+
     def reset(self) -> None:
         """Restore a fully pristine state: counters, gauges, histograms
         and spans cleared; histogram/span enablement back to the
@@ -1220,6 +1259,7 @@ class Metrics:
         with self._lock:
             self.counters.clear()
             self.gauges.clear()
+            self.gauge_peaks.clear()
             self.histograms.clear()
             self.spans.clear()
             self._hist_enabled = self._default_stats

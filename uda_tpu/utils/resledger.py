@@ -85,6 +85,8 @@ PAIRED_GAUGES: Dict[str, str] = {
     "store.migrate.bytes.on_air": "gauge.store.migrate",
     "push.on_air": "gauge.push.on_air",
     "push.staged.bytes": "gauge.push.staged",
+    "reduce.tasks.live": "gauge.tasks.live",
+    "budget.hbm.reserved": "gauge.hbm.reserved",
 }
 
 
