@@ -48,9 +48,14 @@ def test_overlap_matches_global_resort():
 
 
 @pytest.mark.slow
-def test_overlap_pallas_engine_matches_host():
+def test_overlap_pallas_engine_matches_host(monkeypatch):
     # force the device merge-path kernel (interpret mode on CPU): the
-    # integration the TPU deployment runs, against the host twin
+    # integration the TPU deployment runs, against the host twin. Every
+    # size class on the device, or these small runs would merge on the
+    # host and never reach the kernel (tests/test_forest_host_classes.py)
+    from uda_tpu.merger import overlap
+    monkeypatch.setattr(overlap, "DEVICE_MIN_BUCKET",
+                        overlap.MIN_RUN_CAPACITY)
     kt = comparators.get_key_type("uda.tpu.RawBytes")
     batches = [_batch(_rand_recs(100 + s, 30 + s)) for s in range(3)]
     om_p = OverlappedMerger(kt, width=16, engine="pallas")

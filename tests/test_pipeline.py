@@ -96,7 +96,11 @@ def test_pipeline_identity_spool(tmp_path):
 
 
 @pytest.mark.slow
-def test_pipeline_identity_pallas_engine():
+def test_pipeline_identity_pallas_engine(monkeypatch):
+    # every size class on the device: the kernel is what this compares
+    from uda_tpu.merger import overlap
+    monkeypatch.setattr(overlap, "DEVICE_MIN_BUCKET",
+                        overlap.MIN_RUN_CAPACITY)
     batches = [_batch(_rand_recs(70 + s, 30)) for s in range(4)]
     a = _finish_bytes(batches, pipeline=False, engine="pallas")
     b = _finish_bytes(batches, pipeline=True, engine="pallas")
@@ -508,3 +512,4 @@ def test_pipeline_abort_releases_blocked_feed():
         assert not th.is_alive()
     assert om._inflight == 0
     assert metrics.get_gauge("stage.inflight.bytes") == 0
+

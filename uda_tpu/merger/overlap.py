@@ -19,6 +19,14 @@ VPU) but a **log-structured run forest**:
   work O(n log k), and only O(log) distinct kernel shapes ever compile
   (pallas_call executables are shape-specialized; unconstrained segment
   sizes would compile a fresh kernel per (na, nb) pair);
+- the forest's SMALL size classes never reach the device: a run whose
+  class is below ``DEVICE_MIN_BUCKET`` rows stays a host array and
+  carries with the native linear row merge; a carry whose output
+  reaches that class is transferred once and continues on the device.
+  Asking the device for a merge costs the host milliseconds whatever
+  the run's size, so a 1,024-map task puts tens of runs on the device,
+  not 1,024. Rows are totally ordered (every column is key), so the
+  merged run is the same bytes whichever engine merged a class;
 - ``finish()`` merges the O(log k) leftover runs, largest-capacity
   last, and gathers the final byte permutation on host.
 
@@ -91,7 +99,7 @@ from uda_tpu.utils.logging import get_logger
 from uda_tpu.utils.metrics import metrics
 from uda_tpu.utils.resledger import resledger
 
-__all__ = ["OverlappedMerger", "MIN_RUN_CAPACITY"]
+__all__ = ["OverlappedMerger", "MIN_RUN_CAPACITY", "DEVICE_MIN_BUCKET"]
 
 log = get_logger()
 
@@ -100,6 +108,29 @@ MIN_RUN_CAPACITY = merge_ops.MIN_RUN_CAPACITY
 _PAD_WORD = merge_ops.PAD_WORD
 
 _next_pow2 = merge_ops.next_run_capacity
+
+# Pallas engine: the smallest size class (``_Run.bucket``, rows) that
+# lives on the device; smaller classes carry on the host with the native
+# row merge (module docstring). It sits where asking the device for a
+# merge stops costing the host more than doing the merge. Both costs as
+# host seconds on the chip's host, 7-column rows, runs filled to 62.5 %
+# (TPU v5 lite machine, chip runs of PR 27, scripts/forest_class_costs.py;
+# PERF.md §3):
+#   native row merge   8.4 ns an output row from class 2^15 up (19 ns at
+#                      2^11): 0.34 ms for a class-2^16 output, 0.69 ms at
+#                      2^17, 1.40 ms at 2^18
+#   one device merge   a device_put + wait of the run that joins (0.57 ms
+#                      at a capacity of 2^10 rows, 0.89 at 2^15, 1.15 at
+#                      2^16, 1.79 at 2^17: half the output's class) +
+#                      0.27-0.33 ms to dispatch the merge: 0.85 ms
+#                      at class 2^11, 1.47 at 2^17, 2.1 at 2^18 alone in a
+#                      process; 2.7 ms whatever the class inside a task
+#                      whose stage pool contends for the interpreter
+#                      (ledger, PR 26: 2.81 s in 1,023 merges + 1,024 puts)
+# The host merge wins at every class up to 2^18 by these numbers; the
+# constant is held at 2^17 because a 16 MB map output (164,062 rows,
+# class 2^18) must go to the device as it always has.
+DEVICE_MIN_BUCKET = 1 << 17
 
 # widest per-key content the vectorized overflow lexsort materializes
 # as an n-by-width matrix; rarer/wider keys keep the comparator loop
@@ -119,8 +150,9 @@ class _Run:
     ``bucket`` is the binary-counter size class: staging assigns
     next_pow2(valid), each merge doubles it — so every record passes
     through at most log2(k) merges regardless of engine. ``lease`` is
-    the pool-owned host buffer backing ``rows`` (host-engine pipeline
-    mode), recycled when this run merges into a larger one.
+    the pool-owned host buffer backing a host run's ``rows`` (pipeline
+    mode), recycled when this run merges into a larger one or moves to
+    the device.
     """
 
     __slots__ = ("rows", "valid", "bucket", "lease")
@@ -134,6 +166,10 @@ class _Run:
     @property
     def capacity(self) -> int:
         return int(self.rows.shape[0])
+
+    @property
+    def on_host(self) -> bool:
+        return isinstance(self.rows, np.ndarray)
 
 
 class _StagedRun:
@@ -156,11 +192,12 @@ class _StagedRun:
 
 # Reusable pre-allocated host row buffers (ops.merge.RowBufferPool).
 # Pallas engine: stage workers lease, the merge consumer recycles once
-# the jax.device_put transfer completes. Host engine (pipeline mode):
-# staged runs AND merge outputs lease, each buffer recycled when its
-# run merges into a larger one — killing the per-merge large-alloc
-# page-fault churn that would otherwise dominate k*log2(k) merge
-# traffic on this class of host.
+# the jax.device_put transfer completes. Host runs (pipeline mode: the
+# host engine's, and the pallas engine's small classes): staged runs
+# AND merge outputs lease, each buffer recycled when its run merges
+# into a larger one — killing the per-merge large-alloc page-fault
+# churn that would otherwise dominate k*log2(k) merge traffic on this
+# class of host.
 _RowBufferPool = merge_ops.RowBufferPool
 
 # host-engine merges at/above this many output rows split across
@@ -254,14 +291,25 @@ class OverlappedMerger:
         self._inflight_cap = max(0, int(inflight_bytes))
         self._inflight = 0
         self._inflight_cv = TrackedCondition(TrackedLock("stage.inflight"))
+        # the host merges dispatch to the native row merge; resolve it
+        # ONCE here so a cold .so compiles before any carry runs under
+        # _forest_lock (a make inside the lock would stall the whole
+        # staging pool) and the per-merge hot path pays no imports
+        native_rows_merge = merge_ops.resolve_native_rows_merge()
         self._native_rows_merge = None
+        # pallas engine: size classes below this carry on the host. 0 =
+        # every run goes to the device (no native merge to carry with:
+        # the numpy lexsort would cost more than the calls it saves)
+        self._device_min_bucket = 0
         if self.engine == "host":
-            # the host merge path dispatches to the native row merge;
-            # resolve it ONCE here so a cold .so compiles before any
-            # carry runs under _forest_lock (a make inside the lock
-            # would stall the whole staging pool) and the per-merge hot
-            # path pays no imports
-            self._native_rows_merge = merge_ops.resolve_native_rows_merge()
+            self._native_rows_merge = native_rows_merge
+        elif native_rows_merge is not None:
+            self._device_min_bucket = DEVICE_MIN_BUCKET
+        # a task with none of these (the host engine; every run large)
+        # reads 0, where a program without host classes reads nothing
+        metrics.add("merge.device_runs", 0)
+        metrics.add("merge.host_merges", 0)
+        metrics.declare_timer("merge_host_batch")
         self.pipeline = bool(pipeline)
         self._consumer_thread: Optional[threading.Thread] = None
         if self.pipeline:
@@ -580,7 +628,7 @@ class OverlappedMerger:
         metrics.add("merge.records", n)
         if self._overflow or not self.device_runs:
             return
-        cap = _next_pow2(n) if self.engine == "pallas" else n
+        cap = self._staged_capacity(n)
         rows = np.empty((cap, kw + merge_ops.ROW_EXTRA_COLS), np.uint32)
         merge_ops.fill_run_rows(rows, packed, None, seg_index)
         self._consume_run(_StagedRun(seg_index, rows, n, None,
@@ -646,9 +694,7 @@ class OverlappedMerger:
         if self._overflow or not self.device_runs:
             self._observe_wait(fed_t)
             return None  # forest output won't be consumed; runs suffice
-        # device runs pad to a power-of-two capacity (bounded set of
-        # kernel shapes); host runs stay exact-sized
-        cap = _next_pow2(n) if self.engine == "pallas" else n
+        cap = self._staged_capacity(n)
         if self._buf_pool is not None:
             lease = self._buf_pool.lease(cap, kw + merge_ops.ROW_EXTRA_COLS)
             try:
@@ -691,47 +737,110 @@ class OverlappedMerger:
         return np.asarray(sorted(range(n), key=functools.cmp_to_key(
             lambda i, j: cmp(keys[i], keys[j]) or (i - j))), np.int64)
 
+    def _device_class(self, bucket: int) -> bool:
+        """Whether a run of this size class lives on the device."""
+        return self.engine == "pallas" and bucket >= self._device_min_bucket
+
+    def _staged_capacity(self, n: int) -> int:
+        """Rows of the buffer an ``n``-row run is staged in: device-bound
+        runs pad to a power-of-two capacity (bounded set of kernel
+        shapes); host runs stay exact-sized."""
+        bucket = _next_pow2(n)
+        return bucket if self._device_class(bucket) else n
+
     def _consume_run(self, staged: _StagedRun) -> None:
         """The device half of staging: transfer + forest insert. The
         merges this triggers dispatch asynchronously; the only block is
-        the transfer completion that frees a leased host buffer."""
-        rows = staged.rows
+        the transfer completion that frees a leased host buffer. A run
+        of a host class skips the transfer."""
+        bucket = _next_pow2(staged.valid)
         with metrics.timer("overlap_stage"):
-            if self.engine == "pallas":
-                with metrics.span("merge.device_put", rows=staged.valid):
-                    dev = jax.device_put(rows)
-                    if staged.lease is not None:
-                        # accounting point: the host buffer may only be
-                        # reused once the transfer is done. Merges of
-                        # the PREVIOUS run keep executing under this
-                        # wait.
-                        t0 = time.perf_counter()
-                        jax.block_until_ready(dev)
-                        metrics.observe("merge.pipeline.put_ms",
-                                        (time.perf_counter() - t0) * 1e3)
-                        self._recycle(staged)
-                rows = dev
-            # host engine: the run KEEPS its pool lease (recycled when
-            # it merges away); ownership moves to the _Run so an
-            # error-path _recycle can never double-release it
+            # the run takes the pool lease: a device run's recycles once
+            # its transfer is done, a host run keeps it until it merges
+            # away; either way an error-path _recycle can never
+            # double-release it
             lease, staged.lease = staged.lease, None
-            self._insert(_Run(rows, staged.valid, _next_pow2(staged.valid),
-                              lease=lease))
+            if self._device_class(bucket):
+                run = self._put_on_device(staged.rows, staged.valid, bucket,
+                                          lease)
+            else:
+                run = _Run(staged.rows, staged.valid, bucket, lease)
+            self._insert(run)
+
+    def _put_on_device(self, rows: np.ndarray, valid: int, bucket: int,
+                       lease) -> _Run:
+        """ONE ``jax.device_put`` of a padded host run; its pool lease,
+        if it has one, goes home whether the transfer succeeds or not."""
+        try:
+            with metrics.span("merge.device_put", rows=valid):
+                dev = jax.device_put(rows)
+                if lease is not None:
+                    # accounting point: the host buffer may only be
+                    # reused once the transfer is done. Merges of the
+                    # PREVIOUS run keep executing under this wait.
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(dev)
+                    metrics.observe("merge.pipeline.put_ms",
+                                    (time.perf_counter() - t0) * 1e3)
+        finally:
+            if lease is not None:
+                self._buf_pool.release(lease)
+        metrics.add("merge.device_runs")
+        return _Run(dev, valid, bucket)
+
+    def _host_rows(self, rows: int, cols: int):
+        """A host row buffer and its pool lease. No pool in this mode
+        (serial staging, interpret-mode pallas): a fresh array that
+        nobody reuses, lease None."""
+        if self._buf_pool is None:
+            return np.empty((rows, cols), np.uint32), None
+        buf = self._buf_pool.lease(rows, cols)
+        return buf, buf
+
+    def _promote(self, run: _Run, capacity: int) -> _Run:
+        """Move a host-class run of the pallas engine to the device:
+        padded to ``capacity`` and transferred once; from here on it is
+        a device run like any staged one. A merge output already has
+        its padded capacity (_merge_rows_host); a run still as staged
+        is copied into a padded buffer first."""
+        if run.capacity != capacity:
+            rows, lease = self._host_rows(capacity, int(run.rows.shape[1]))
+            rows[:run.valid] = run.rows[:run.valid]
+            self._release_run(run)
+        else:
+            rows, lease, run.lease = run.rows, run.lease, None
+        rows[run.valid:] = _PAD_WORD
+        dev = self._put_on_device(rows, run.valid, run.bucket, lease)
+        self._device_staged_bytes += int(dev.rows.nbytes)
+        return dev
 
     def _insert(self, run: _Run) -> None:
         # binary-counter carry: equal size classes merge immediately.
         # The lock serializes carries across the staging pool (pack/
         # sort/spool of other segments proceed concurrently).
         with self._forest_lock:
-            if self.engine == "pallas":
+            if not run.on_host:
                 self._device_staged_bytes += int(run.rows.nbytes)
-            while run.bucket in self._forest:
-                other = self._forest.pop(run.bucket)
-                # the transitive join() is the split merge waiting on
-                # its OWN compute workers — bounded work on data already
-                # in hand, not a wait on external progress; serializing
-                # carries under the lock is the forest design
-                run = self._merge(other, run)  # udalint: disable=UDA102
+            other = None
+            try:
+                while run.bucket in self._forest:
+                    other = self._forest.pop(run.bucket)
+                    # the transitive join() is the split merge waiting
+                    # on its OWN compute workers — bounded work on data
+                    # already in hand, not a wait on external progress;
+                    # serializing carries under the lock is the forest
+                    # design
+                    run = self._merge(other, run)  # udalint: disable=UDA102
+                    if run.on_host and self._device_class(run.bucket):
+                        # the carry left the host classes: the capacity
+                        # today's all-device forest holds at this class
+                        run = self._promote(run, run.bucket)
+            except BaseException:
+                # a failed carry: neither run is in the forest any more,
+                # so no later drain would find their leases
+                self._release_run(other)
+                self._release_run(run)
+                raise
             self._forest[run.bucket] = run
 
     def _merge(self, a: _Run, b: _Run) -> _Run:
@@ -747,8 +856,9 @@ class OverlappedMerger:
         into a pool-leased output buffer (no per-merge large-alloc
         page faults) and splits large merges across threads at
         merge-path partition points (the native call releases the GIL);
-        the inputs' leases recycle immediately. Every other
-        engine/mode keeps the plain merge_row_pair path."""
+        the inputs' leases recycle immediately. The pallas engine's
+        host classes: _merge_rows_host. Every other engine/mode keeps
+        the plain merge_row_pair path."""
         if self.engine == "host" and self._buf_pool is not None:
             total = a.valid + b.valid
             out = self._buf_pool.lease(total, int(a.rows.shape[1]))
@@ -770,6 +880,8 @@ class OverlappedMerger:
                 return out, out
             self._buf_pool.release(out)  # native .so went missing
         on_device = self.engine == "pallas"
+        if on_device and a.on_host:
+            return self._merge_rows_host(a, b)
         if on_device:
             nbytes = int(a.rows.nbytes) + int(b.rows.nbytes)
             self._await_device_room(nbytes)
@@ -781,6 +893,31 @@ class OverlappedMerger:
             self._device_pending.append((merged, nbytes))
             self._device_pending_bytes += nbytes
         return merged, None
+
+    def _merge_rows_host(self, a: _Run, b: _Run):
+        """One carry of the pallas engine's host classes: the native
+        linear merge into a buffer of the output's padded capacity, so
+        that moving it to the device later is a tail fill, not a copy
+        (the pages behind ``total`` are never touched until then). The
+        inputs' leases recycle at once."""
+        total = a.valid + b.valid
+        out, lease = self._host_rows(_next_pow2(total), int(a.rows.shape[1]))
+        try:
+            with metrics.timer("merge_host_batch"):
+                ok = merge_ops.merge_rows_split_into(
+                    a.rows[:a.valid], b.rows[:b.valid], out[:total], 1)
+            if not ok:
+                # resolved at construction and gone since: fail the
+                # task rather than sort in numpy
+                raise MergeError("native row merge went missing mid-task")
+        except BaseException:
+            if lease is not None:
+                self._buf_pool.release(lease)
+            raise
+        self._release_run(a)
+        self._release_run(b)
+        metrics.add("merge.host_merges")
+        return out, lease
 
     def _await_device_room(self, nbytes: int) -> None:
         """Bound how far merge dispatch runs ahead of the device. A
@@ -920,8 +1057,9 @@ class OverlappedMerger:
         the pallas engine, pad the smaller run up to the larger capacity
         first (padding rows sort last, so the validity prefix is
         preserved) — capacities stay powers of two, so kernel shapes
-        stay in the O(log) compiled set. Returns None when nothing was
-        staged."""
+        stay in the O(log) compiled set. A task whose every run stayed
+        in the host classes ends with one transfer and no device merge.
+        Returns None when nothing was staged."""
         # UDA202 (udarace): _insert writes the forest under
         # _forest_lock; take it here too — the leftover merge runs
         # after the stage pool quiesces, but "after join" is an
@@ -931,6 +1069,22 @@ class OverlappedMerger:
                 return None
             runs = [self._forest[c] for c in sorted(self._forest)]
             self._forest = {}  # release device-resident runs when done
+        if self.engine == "pallas" and runs[0].on_host:
+            # the host classes (all below the device ones) fold on the
+            # host into one run, which goes to the device once, however
+            # small: one path from here on
+            nhost = sum(r.on_host for r in runs)
+            acc = runs[0]
+            try:
+                for nxt in runs[1:nhost]:
+                    acc = self._merge(acc, nxt)
+                runs[:nhost] = [self._promote(acc, _next_pow2(acc.valid))]
+            except BaseException:
+                # the forest is already empty: these are the only
+                # references to the host runs' leases
+                for run in (acc, *runs[:nhost]):
+                    self._release_run(run)
+                raise
         acc = runs[0]
         for nxt in runs[1:]:
             if self.engine == "pallas" and acc.capacity < nxt.capacity:

@@ -70,8 +70,10 @@ SPAN_BUCKETS: Dict[str, str] = {
     "run_spool": "decompress_pack",
     # device-put: host->device transfer + buffer-recycle wait
     "overlap_stage": "device_put", "merge.device_put": "device_put",
-    # merge: device/host merge + sort compute
+    # merge: device/host merge + sort compute (merge_host_batch runs
+    # inside overlap_device_merge: the forest's host-class carries)
     "merge": "merge", "overlap_device_merge": "merge",
+    "merge_host_batch": "merge",
     "device_sort": "merge", "lpq_spill": "merge", "lpq_phase": "merge",
     "rpq_phase": "merge",
     # emit: the reduce side's output path after the forest is merged —

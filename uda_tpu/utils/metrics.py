@@ -186,6 +186,18 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                             "the numpy path"),
     "merge.records": ("counter", "records through the merge "
                                  "(staged or device-merged)"),
+    "merge.device_runs": ("counter", "sorted runs transferred to the "
+                                     "device, one jax.device_put each "
+                                     "(merger/overlap.py): a staged run "
+                                     "of a device size class, a carry "
+                                     "leaving the host classes, the "
+                                     "host classes' fold at finish"),
+    "merge.host_merges": ("counter", "pairwise merges of the pallas "
+                                     "engine's forest done on the host "
+                                     "with the native row merge (size "
+                                     "classes below overlap."
+                                     "DEVICE_MIN_BUCKET); their seconds "
+                                     "are the merge_host_batch timer's"),
     "spool.bytes": ("counter", "bytes spooled to sorted run files "
                                "(streaming online mode)"),
     # -- counters: staging pipeline (merger/overlap stage pool) ----------
@@ -1207,6 +1219,16 @@ class Metrics:
             dt = time.perf_counter() - t0
             with self._lock:
                 self.counters[name + "_time"] += dt
+
+    def declare_timer(self, name: str) -> None:
+        """Make ``<name>_time`` read 0.0 before the timer first fires,
+        so a reader of counter deltas can tell a phase that took no
+        time from a program that has no such phase. Not
+        ``add(name + "_time", 0)``: a timer's counter is outside the
+        dotted namespace that call sites of ``add`` are held to
+        (UDA002); this class alone writes it."""
+        with self._lock:
+            self.counters[name + "_time"] += 0.0
 
     # -- reads --------------------------------------------------------------
 
