@@ -140,18 +140,18 @@ echo "-- fleet observability smoke (udafleet --once --json)" | tee -a "$ART/ci.l
 env JAX_PLATFORMS=cpu \
   python scripts/fleet_smoke.py 2>&1 | tee -a "$ART/ci.log" | tail -1
 
-# Tuning-cache round trip: a quick io.read fly-off probe must persist
+# Tuning-cache round trip: a quick io.read probe must persist
 # a winner, and a SECOND probe run must serve from the cache without
 # re-measuring (tune_probe prints "0 probe(s)" — the self-service
 # routing contract; the full lifecycle matrix rides
 # tests/test_tuncache.py in tier-1).
 echo "-- tuning-cache probe round trip (quick)" | tee -a "$ART/ci.log"
 env JAX_PLATFORMS=cpu \
-  python scripts/tune_probe.py --cache "$ART/tune_cache.json" --quick \
-  --domain io.read 2>&1 | tee -a "$ART/ci.log" | tail -2
+  python scripts/tune_probe.py --cache "$ART/tune_cache.json" --quick 2>&1 \
+  | tee -a "$ART/ci.log" | tail -2
 env JAX_PLATFORMS=cpu \
-  python scripts/tune_probe.py --cache "$ART/tune_cache.json" --quick \
-  --domain io.read 2>&1 | tee -a "$ART/ci.log" | grep -q "0 probe(s) run" \
+  python scripts/tune_probe.py --cache "$ART/tune_cache.json" --quick 2>&1 \
+  | tee -a "$ART/ci.log" | grep -q "0 probe(s) run" \
   || { echo "FAIL: second tune_probe run re-probed a fresh cache" \
        | tee -a "$ART/ci.log"; exit 1; }
 

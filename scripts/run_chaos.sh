@@ -11,9 +11,9 @@
 # came up wrong. Extra pytest args pass through ("$@").
 #
 # Telemetry: the run accumulates the session's fault/recovery counters
-# (tests/conftest.py) and writes CHAOS_TELEMETRY.json — the same
-# comparable "telemetry" block bench.py embeds — wrapped with the seed
-# and schedule so chaos rounds diff against each other.
+# (tests/conftest.py) and writes CHAOS_TELEMETRY.json — a "telemetry"
+# block in the metrics snapshot's schema — wrapped with the seed and
+# schedule so chaos rounds diff against each other.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

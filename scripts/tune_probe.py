@@ -1,21 +1,15 @@
 #!/usr/bin/env python
-"""tune_probe: seeded fly-off probes that populate the online tuning
+"""tune_probe: the seeded probe that populates the online tuning
 cache (uda_tpu/utils/tuncache.py).
 
-The generalization of the repo's hand-deployed sweep winners
-(``UDA_TPU_SORT_PATH``/``UDA_TPU_CHUNK_COLS``; ROADMAP item 5): instead
-of a human reading BENCH_*.json and exporting env vars, this probe
-measures on THIS host and persists per-(key-shape, platform, backend)
-winners that ``ops.sort.route_engine`` and the batched host-I/O plane
-consult at routing time. Env-var winners still override the cache —
-precedence is env > cache > built-in, tested in
-tests/test_tuncache.py.
+Instead of a human reading a bench's output and exporting settings,
+this probe measures on THIS host and persists the winner that the
+batched host-I/O plane consults when it resolves its parameters. An
+explicitly set flag still overrides the cache (tested in
+tests/test_tuncache.py).
 
-Domains probed (``--domain`` selects one, default both):
+Domain probed:
 
-- ``sort.engine``: a bench_step fly-off over the pure-XLA engine set
-  (plus the Pallas lanes engines on a TPU backend) at two row-bucket
-  shapes, one winner per (backend, rows-bucket, lanes-capability) key.
 - ``io.read``: a submit_batch burst A/B over coalesce-gap settings on
   a synthetic MOF (the io_bench hot-burst shape, in-process), one
   winner per platform: {batch, gap_kb, batch_max, backend}.
@@ -31,7 +25,7 @@ exactly this skip.
 Usage::
 
     UDA_TPU_TUNE_CACHE=/path/tune.json python scripts/tune_probe.py --quick
-    python scripts/tune_probe.py --cache /path/tune.json --domain io.read
+    python scripts/tune_probe.py --cache /path/tune.json
 """
 
 from __future__ import annotations
@@ -60,68 +54,6 @@ def _fresh(cache, domain: str, key: str, reprobe_age: float,
     if reprobe_age <= 0:
         return True  # a winner exists and no staleness horizon: keep it
     return age <= reprobe_age
-
-
-def probe_sort_engine(cache, quick: bool, reprobe_age: float,
-                      force: bool, seed: int) -> list:
-    """Fly-off per (backend, rows-bucket, lanes-capability): time each
-    candidate engine with bench_step (sortedness + checksum asserted —
-    a broken engine can never be crowned) and persist the winner."""
-    import jax
-    import numpy as np
-
-    from uda_tpu.models import terasort
-    from uda_tpu.ops import sort as sort_ops
-    from uda_tpu.utils.metrics import metrics
-    from uda_tpu.utils.tuncache import rows_bucket
-
-    backend = jax.default_backend()
-    sizes = (1 << 14,) if quick else (1 << 16, 1 << 20)
-    out = []
-    for n in sizes:
-        for lanes_ok in (False, True):
-            key = f"{backend}|rows{rows_bucket(n)}|lanes{int(lanes_ok)}"
-            if _fresh(cache, "sort.engine", key, reprobe_age, force):
-                out.append((key, "fresh", None))
-                continue
-            metrics.add("tune.probes", domain="sort.engine")
-            candidates = ["carry", "gather", "gather2", "carrychunk"]
-            if lanes_ok and backend == "tpu":
-                # interpret-mode lanes on CPU are pathologically slow
-                # and would never win honestly — probe them only where
-                # they compile for real
-                candidates += list(sort_ops.LANES_ENGINES)
-            best = None
-            times = {}
-            for path in candidates:
-                try:
-                    def one(s):
-                        t0 = time.perf_counter()
-                        viol, ck_in, ck_out = terasort.bench_step(
-                            jax.random.key(s), n, 1, path=path,
-                            tile=min(1024, n))
-                        assert int(viol) == 0
-                        assert np.uint32(ck_in) == np.uint32(ck_out)
-                        return time.perf_counter() - t0
-
-                    one(seed)  # warmup/compile
-                    dt = min(one(seed + 1), one(seed + 2))
-                    times[path] = round(dt, 5)
-                    if best is None or dt < best[1]:
-                        best = (path, dt)
-                except Exception as e:  # noqa: BLE001 - one engine's
-                    # failure (unsupported shape/backend) must not
-                    # kill the fly-off; it just cannot win
-                    times[path] = f"error: {type(e).__name__}"
-            if best is None:
-                out.append((key, "no-winner", None))
-                continue
-            gbps = n * terasort.RECORD_BYTES / 1e9 / best[1]
-            cache.record("sort.engine", key,
-                         {"engine": best[0], "times_s": times},
-                         metric=round(gbps, 4), probe="tune_probe")
-            out.append((key, "probed", best[0]))
-    return out
 
 
 def probe_io_read(cache, quick: bool, reprobe_age: float, force: bool,
@@ -225,8 +157,6 @@ def main() -> int:
     ap.add_argument("--cache", default="",
                     help="tuning-cache path (default: UDA_TPU_TUNE_CACHE"
                          " env, required one way or the other)")
-    ap.add_argument("--domain", choices=["sort.engine", "io.read"],
-                    help="probe one domain only (default: both)")
     ap.add_argument("--quick", action="store_true",
                     help="small shapes (CI / test sizes)")
     ap.add_argument("--force", action="store_true",
@@ -253,14 +183,8 @@ def main() -> int:
         for k, v in sorted(cache.entries().items()):
             print(f"{k}: {v.get('winner')} (metric {v.get('metric')})")
         return 0
-    reports = []
-    if args.domain in (None, "io.read"):
-        reports += probe_io_read(cache, args.quick, args.reprobe_age,
-                                 args.force, args.seed)
-    if args.domain in (None, "sort.engine"):
-        reports += probe_sort_engine(cache, args.quick,
-                                     args.reprobe_age, args.force,
-                                     args.seed)
+    reports = probe_io_read(cache, args.quick, args.reprobe_age,
+                            args.force, args.seed)
     probes = int(metrics.get("tune.probes"))
     for key, status, winner in reports:
         line = f"tune_probe: {key}: {status}"

@@ -35,7 +35,7 @@ compile_cache.enable()
 # per-test snapshots accumulate into a session-level counter sum that
 # pytest_sessionfinish dumps as a telemetry JSON when
 # UDA_TPU_CHAOS_TELEMETRY names a path (scripts/run_chaos.sh does),
-# giving chaos runs the same comparable telemetry block bench.py emits.
+# giving chaos runs one comparable telemetry block.
 
 import collections  # noqa: E402
 

@@ -34,8 +34,9 @@ def test_rehearsal_runs_end_to_end():
     verdicts = smoke["b"]["verdicts"]
     assert smoke["b"]["interpret"] is True
     assert all(v["ok"] for v in verdicts.values()), verdicts
-    assert {"merge_sorted_pair:plain", "merge_sorted_pair:keys8",
-            "sort:carrychunk", "sort:lanes"} <= set(verdicts)
+    assert set(verdicts) == {"sort:auto=carry@2^12", "sort:carry",
+                             "sort:lanes", "sort:keys8",
+                             "merge_sorted_pair"}
     assert set(smoke["c"]["runs"]) == {
         "ici:4/auto", "ici:4/lanes", "dcn:2,ici:2/auto", "dcn:2,ici:2/lanes"}
     assert smoke["c"]["default_engine"] == "carry"   # the CPU's

@@ -95,40 +95,3 @@ def test_merge_pair_max_width_31():
         pallas_merge.merge_sorted_pair(
             np.zeros((4, 32), np.uint32), np.zeros((4, 32), np.uint32), 2,
             interpret=True)
-
-
-def test_merge_pair_two_phase_matches_default():
-    a = _sorted_run(700, 7, 3, seed=11, dup_rate=1.0)
-    b = _sorted_run(500, 7, 3, seed=12, dup_rate=1.0)
-    d = np.asarray(pallas_merge.merge_sorted_pair(a, b, 3, interpret=True))
-    t = np.asarray(pallas_merge.merge_sorted_pair(a, b, 3, interpret=True,
-                                                  two_phase=True))
-    np.testing.assert_array_equal(d, t)
-
-
-def test_merge_pair_keys8_matches_default():
-    # the keys-only merge + row gather must be byte-identical to the
-    # full-width pass, duplicate keys (stability) included
-    a = _sorted_run(700, 7, 3, seed=13, dup_rate=1.0)
-    b = _sorted_run(500, 7, 3, seed=14, dup_rate=1.0)
-    d = np.asarray(pallas_merge.merge_sorted_pair(a, b, 3, interpret=True))
-    k = np.asarray(pallas_merge.merge_sorted_pair(a, b, 3, interpret=True,
-                                                  keys8=True))
-    np.testing.assert_array_equal(d, k)
-
-
-def test_merge_pair_keys8_wide_records():
-    # keys8 has no 31-word width limit: 40-word records merge fine
-    a = _sorted_run(96, 40, 2, seed=15)
-    b = _sorted_run(64, 40, 2, seed=16)
-    got = np.asarray(pallas_merge.merge_sorted_pair(a, b, 2, keys8=True,
-                                                    interpret=True))
-    assert (got == _host_merge(a, b, 2)).all()
-    # 7 keys still fit (rows 0-6 + tie-break at 7); 8 do not
-    got7 = np.asarray(pallas_merge.merge_sorted_pair(a, b, 7, keys8=True,
-                                                     interpret=True))
-    assert (got7 == _host_merge(a, b, 7)).all()
-    import pytest
-
-    with pytest.raises(ValueError, match="num_keys"):
-        pallas_merge.merge_sorted_pair(a, b, 8, keys8=True, interpret=True)

@@ -91,21 +91,3 @@ def test_shape_validation():
     with pytest.raises(ValueError):
         pallas_sort.sort_lanes(np.zeros((pallas_sort.ROWS, 512), np.uint32),
                                3, tile=192, interpret=True)
-
-
-def test_two_phase_engine_matches_default():
-    # the keys-view + payload-gather engine must be byte-identical to
-    # the full-width network, incl. duplicate keys (arrival stability
-    # rides the tie-break row in both) and multi-pass merges
-    rng = np.random.default_rng(77)
-    for n, dup in ((1024, False), (4096, True)):
-        words = rng.integers(0, 2**32, size=(n, 6), dtype=np.uint32)
-        if dup:
-            words[:, :2] = rng.integers(0, 3, size=(n, 2), dtype=np.uint32)
-        x = pallas_sort.rows_to_lanes(words)
-        a = np.asarray(pallas_sort.sort_lanes(x, num_keys=2, tile=1024,
-                                              interpret=True))
-        b = np.asarray(pallas_sort.sort_lanes(x, num_keys=2, tile=1024,
-                                              interpret=True,
-                                              two_phase=True))
-        np.testing.assert_array_equal(a, b)

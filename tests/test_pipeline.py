@@ -20,7 +20,6 @@ from uda_tpu.merger.streaming import RunStore
 from uda_tpu.mofserver import DataEngine, DirIndexResolver
 from uda_tpu.mofserver.writer import MOFWriter
 from uda_tpu.ops import merge as merge_ops
-from uda_tpu.ops import sort as sort_ops
 from uda_tpu.utils import comparators
 from uda_tpu.utils.budget import STAGE_INFLIGHT_FLOOR_MB, stage_inflight_cap
 from uda_tpu.utils.config import Config
@@ -244,58 +243,6 @@ def test_resolve_merge_mode_routing():
     assert merge_ops.resolve_merge_mode("auto", 8) == "resort"
     with pytest.raises(Exception):
         merge_ops.resolve_merge_mode("sideways", 2)
-
-
-def test_route_engine_honors_explicit_and_refines_auto():
-    # explicit path is never overridden by batch-size routing
-    assert sort_ops.route_engine(1 << 10, "gather") == "gather"
-    # auto on CPU resolves like resolve_sort_path (no TPU steering here)
-    assert sort_ops.route_engine(1 << 10, "auto") == \
-        sort_ops.resolve_sort_path("auto")
-    assert sort_ops.SMALL_BATCH_ROWS == 1 << 20
-    for cc in sort_ops.CC_LADDER:
-        assert cc in (8, 12, 23)
-
-
-def test_route_engine_steers_deployed_gather_engine(monkeypatch):
-    # the steering branch is live once a gather-bound fly-off winner
-    # deploys as the auto default (UDA_TPU_SORT_PATH); the built-in
-    # defaults are never gather-bound, so this is its reachability test
-    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "keys8f")
-    monkeypatch.setattr(sort_ops.jax, "default_backend", lambda: "tpu")
-    # big batch: the deployed winner is honored
-    assert sort_ops.route_engine(1 << 22, "auto", lanes_ok=True) == "keys8f"
-    # small batch on TPU: steered off the gather-bound engine
-    assert sort_ops.route_engine(1 << 16, "auto",
-                                 lanes_ok=True) == "carrychunk"
-    # a lanes-incapable caller ignores the lanes-engine deploy rather
-    # than failing (pure-XLA paths must survive any deploy value)
-    assert sort_ops.resolve_sort_path("auto") == "carrychunk"
-    # explicit path still honored at any size
-    assert sort_ops.route_engine(1 << 16, "keys8f", lanes_ok=True) == "keys8f"
-    # a typo'd deploy value fails loudly, not silently
-    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "sideways")
-    with pytest.raises(ValueError):
-        sort_ops.resolve_sort_path("auto")
-
-
-def test_distributed_auto_never_routes_carrychunk_on_tpu(monkeypatch):
-    # carrychunk did not compile inside the fused step on four chips
-    # (chip run of 2026-09-26), so the step's "auto" lands on lanes
-    # whichever way carrychunk was reached; explicit stays explicit
-    from uda_tpu.parallel.distributed import _resolve_payload_path
-
-    assert _resolve_payload_path("auto", 25, 3, 1 << 24) == "carry"  # CPU
-    monkeypatch.setattr(sort_ops.jax, "default_backend", lambda: "tpu")
-    assert sort_ops.route_engine(1 << 24, "auto", lanes_ok=True) == \
-        "carrychunk"                    # the single-chip default stays
-    assert _resolve_payload_path("auto", 25, 3, 1 << 24) == "lanes"
-    assert _resolve_payload_path("carrychunk", 25, 3, 1 << 24) == \
-        "carrychunk"
-    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "keys8")
-    assert _resolve_payload_path("auto", 25, 3, 1 << 24) == "keys8"
-    # small-batch steering would hand the step carrychunk: lanes instead
-    assert _resolve_payload_path("auto", 25, 3, 1 << 16) == "lanes"
 
 
 def test_feed_racing_abort_releases_charge():

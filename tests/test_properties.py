@@ -123,10 +123,9 @@ def test_lzo_pure_python_round_trip(data):
 @given(st.integers(min_value=1, max_value=400), st.integers(0, 2 ** 32 - 1),
        st.floats(0.0, 1.0))
 def test_sort_engines_agree(n, seed, dup_rate):
-    # every payload-movement engine must produce byte-identical output
-    # (stability included) for arbitrary record counts, key
-    # distributions, and duplicate rates — the equivalence the fly-off
-    # depends on
+    # every engine must produce byte-identical output (stability
+    # included) for arbitrary record counts, key distributions, and
+    # duplicate rates
     import jax
 
     from uda_tpu.models import terasort
@@ -137,12 +136,7 @@ def test_sort_engines_agree(n, seed, dup_rate):
     if ndup:
         words[:ndup, :3] = words[n - ndup:, :3]  # forced duplicate keys
     want = np.asarray(terasort.single_chip_sort(words, path="carry"))
-    for path in ("gather", "gather2", "carrychunk", "keys8", "keys8f",
-                 "lanes", "lanes2"):
-        # tile=256 lets keys8f fold when n > 128 (pad_pow2 clamps the
-        # tile for smaller n and keys8f falls back to the standard
-        # cascade; tests/test_pallas_fold.py covers folding
-        # deterministically)
+    for path in ("keys8", "lanes"):
         got = np.asarray(terasort.single_chip_sort(
             words, path=path, tile=256, interpret=True))
         np.testing.assert_array_equal(want, got, err_msg=path)

@@ -194,40 +194,24 @@ def test_empty_batch():
     assert merged.num_records == 0
 
 
-def test_apply_perm_chunked_all_sweep_widths():
-    # every chunk width of the ladder (cc=6/8/12/23) plus the
-    # degenerate and over-wide extremes must be a pure refactoring of
-    # the same permutation apply — byte-identical outputs per column
+@pytest.mark.parametrize("backend,path,want", [
+    ("cpu", "auto", "carry"), ("tpu", "auto", "lanes"),
+    ("cpu", "carry", "carry"), ("cpu", "lanes", "lanes"),
+    ("tpu", "keys8", "keys8")])
+def test_resolve_sort_path(monkeypatch, backend, path, want):
+    # the whole engine policy: auto is a function of the backend alone,
+    # an explicit name is honoured on either backend
     import jax
-    import numpy as np
 
-    from uda_tpu.ops.sort import apply_perm_chunked
-
-    rng = np.random.default_rng(7)
-    n, ncols = 257, 23
-    cols = [rng.integers(0, 1 << 32, n, dtype=np.uint32)
-            for _ in range(ncols)]
-    perm = rng.permutation(n).astype(np.int32)
-    want = [c[perm] for c in cols]
-    for cc in (1, 2, 6, 8, 12, 23, 40):
-        got = jax.jit(lambda p, cs: apply_perm_chunked(p, cs, cc))(
-            perm, [np.asarray(c) for c in cols])
-        assert len(got) == ncols, cc
-        for w, g in zip(want, got):
-            np.testing.assert_array_equal(w, np.asarray(g), err_msg=str(cc))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert sort.resolve_sort_path(path) == want
 
 
-def test_bench_step_carrychunk_sweep_widths_validate():
-    # the sweep drives bench_step with explicit chunk_cols; the
-    # in-graph validation (order + checksum) must hold at every width
-    import jax
-    import numpy as np
-
-    from uda_tpu.models import terasort
-
-    for cc in (6, 12, 23):
-        viol, ck_in, ck_out = terasort.bench_step(
-            jax.random.key(11), 1024, 1, path="carrychunk", tile=256,
-            chunk_cols=cc)
-        assert int(viol) == 0, cc
-        assert np.uint32(ck_in) == np.uint32(ck_out), cc
+@pytest.mark.parametrize("stale", ["gather", "gather2", "carrychunk",
+                                   "lanes2", "keys8f", "sideways"])
+def test_resolve_sort_path_rejects(stale):
+    # an engine name of an older deployment fails loudly, naming the
+    # three that exist
+    with pytest.raises(ValueError) as e:
+        sort.resolve_sort_path(stale)
+    assert all(name in str(e.value) for name in sort.SORT_PATHS)

@@ -25,133 +25,58 @@ def test_single_chip_sort_total_order():
     terasort.validate_sorted(out, words)
 
 
-def test_single_chip_sort_gather_path_matches_carry():
-    # the bounded-compile accelerator path must produce byte-identical
-    # output to the operand-carry path (stability included: duplicate
-    # keys keep arrival order in both)
-    words = np.asarray(terasort.teragen(jax.random.key(7), 2048)).copy()
-    words[100:300, :3] = words[700:900, :3]  # inject duplicate keys
-    a = np.asarray(terasort.single_chip_sort(words, path="carry"))
-    b = np.asarray(terasort.single_chip_sort(words, path="gather"))
-    np.testing.assert_array_equal(a, b)
+def _lexsorted(words):
+    return words[np.lexsort((words[:, 2], words[:, 1], words[:, 0]))]
 
 
-@pytest.mark.slow
-def test_single_chip_sort_all_engines_match_carry():
-    # every public engine, byte-identical to the carry oracle — with a
-    # non-power-of-two n (padding engages), duplicate keys (stability),
-    # and records whose keys are all 0xFFFFFFFF (they TIE with the
-    # padding lanes' +inf keys; the arrival tie-break must still place
-    # every real record before the padding)
+@pytest.mark.parametrize("path", ["carry", "lanes", "keys8"])
+def test_single_chip_sort_engine_matches_lexsort(path):
+    # each engine against the host oracle (np.lexsort is stable, so
+    # equal keys must keep arrival order) — with a non-power-of-two n
+    # (padding engages), duplicate keys, and records whose keys are all
+    # 0xFFFFFFFF (they TIE with the padding lanes' +inf keys; the
+    # arrival tie-break must still place every real record first)
     words = np.asarray(terasort.teragen(jax.random.key(21), 1000)).copy()
     words[5:8, :3] = 0xFFFFFFFF
     words[100:200, :3] = words[300:400, :3]
-    a = np.asarray(terasort.single_chip_sort(words, path="carry"))
-    for path in ("lanes", "lanes2", "keys8", "keys8f", "gather",
-                 "gather2", "carrychunk"):
-        b = np.asarray(terasort.single_chip_sort(words, path=path,
-                                                 tile=512, interpret=True))
-        np.testing.assert_array_equal(a, b, err_msg=path)
+    got = np.asarray(terasort.single_chip_sort(words, path=path, tile=512,
+                                               interpret=True))
+    np.testing.assert_array_equal(got, _lexsorted(words))
 
 
-def test_bench_step_both_paths_validate():
-    for path in ("carry", "gather"):
-        viol, ck_in, ck_out = terasort.bench_step(
-            jax.random.key(5), 4096, 2, path=path)
-        assert int(viol) == 0, path
-        assert np.uint32(ck_in) == np.uint32(ck_out), path
+@pytest.mark.parametrize("backend,want", [("cpu", "carry"),
+                                          ("tpu", "lanes")])
+def test_auto_is_one_answer_for_both_surfaces(monkeypatch, backend, want):
+    # single_chip_sort and the distributed step resolve "auto" to the
+    # same engine on either backend (the Pallas kernels interpreted:
+    # the mesh is the CPU's whatever default_backend is made to say)
+    from uda_tpu.parallel import distributed
 
+    seen = {}
 
-def test_teragen_lanes_matches_layout():
-    from uda_tpu.ops.pallas_sort import ROWS
+    def spy(mod, name, surface, engine_of):
+        real = getattr(mod, name)
 
-    x = np.asarray(terasort.teragen_lanes(jax.random.key(9), 512))
-    assert x.shape == (ROWS, 512)
-    assert (x[2] & 0xFFFF).max() == 0          # key pad bytes zero
-    assert x[terasort.RECORD_WORDS:].max() == 0  # layout pad rows zero
+        def wrapper(*args, **kw):
+            seen[surface] = engine_of(args)
+            return real(*args, **kw)
 
+        monkeypatch.setattr(mod, name, wrapper)
 
-@pytest.mark.slow
-def test_bench_step_lanes_path_validates():
-    # interpret=True: Pallas kernels run on the CPU test backend
-    viol, ck_in, ck_out = terasort.bench_step(
-        jax.random.key(5), 2048, 2, path="lanes", tile=512, interpret=True)
-    assert int(viol) == 0
-    assert np.uint32(ck_in) == np.uint32(ck_out)
-
-
-@pytest.mark.slow
-def test_bench_step_keys8_path_validates():
-    for path in ("keys8", "keys8f"):
-        viol, ck_in, ck_out = terasort.bench_step(
-            jax.random.key(5), 2048, 2, path=path, tile=512,
-            interpret=True)
-        assert int(viol) == 0, path
-        assert np.uint32(ck_in) == np.uint32(ck_out), path
-
-
-def test_bench_step_gather2_path_validates():
-    viol, ck_in, ck_out = terasort.bench_step(
-        jax.random.key(5), 2048, 2, path="gather2", tile=512)
-    assert int(viol) == 0
-    assert np.uint32(ck_in) == np.uint32(ck_out)
-
-
-def test_bench_step_carrychunk_path_validates():
-    viol, ck_in, ck_out = terasort.bench_step(
-        jax.random.key(5), 2048, 2, path="carrychunk", tile=512)
-    assert int(viol) == 0
-    assert np.uint32(ck_in) == np.uint32(ck_out)
-
-
-@pytest.mark.slow
-def test_sort_lanes_keys8_matches_sort_lanes():
-    # the keys8 engine (keys-only cascade + one global payload gather)
-    # must be byte-identical to the 32-row pipeline, stability included,
-    # in both the standard and folded cascade variants
-    from uda_tpu.ops import pallas_sort
-
-    x = np.asarray(terasort.teragen_lanes(jax.random.key(12), 2048)).copy()
-    x[:3, 100:300] = x[:3, 700:900]  # duplicate keys
-    a = np.asarray(pallas_sort.sort_lanes(x, num_keys=terasort.KEY_WORDS,
-                                          tile=512, interpret=True))
-    for folded in (False, True):
-        b = np.asarray(terasort.sort_lanes_keys8(x, tile=512,
-                                                 interpret=True,
-                                                 folded=folded))
-        np.testing.assert_array_equal(a, b, err_msg=f"folded={folded}")
-
-
-@pytest.mark.slow
-def test_bench_step_lanes_checksum_matches_oracle():
-    # the lanes checksum must use the same per-column multipliers as the
-    # SoA paths: a sorted output altered by a column swap fails
-    import jax.numpy as jnp
-
-    from uda_tpu.ops import pallas_sort
-
-    x = terasort.teragen_lanes(jax.random.key(11), 1024)
-    out = pallas_sort.sort_lanes(x, num_keys=terasort.KEY_WORDS, tile=512,
-                                 interpret=True)
-    got = np.asarray(pallas_sort.lanes_to_rows(out, terasort.RECORD_WORDS))
-    rows = np.asarray(pallas_sort.lanes_to_rows(x, terasort.RECORD_WORDS))
-    terasort.validate_sorted(got, rows)
-
-
-def test_distributed_terasort_gather_payload_path():
-    from uda_tpu.parallel.distributed import (distributed_sort_step,
-                                              uniform_splitters)
-
-    mesh = make_mesh(4)
-    words = np.asarray(terasort.teragen(jax.random.key(6), 4 * 256))
-    res = distributed_sort_step(words, uniform_splitters(4), mesh,
-                                "shuffle", capacity=256, num_keys=3,
-                                payload_path="gather")
+    spy(terasort, "_single_chip_sort", "single", lambda a: "carry")
+    spy(terasort, "_single_chip_sort_lanes", "single", lambda a: a[1])
+    spy(distributed, "_sort_step", "step", lambda a: a[6])
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    words = np.asarray(terasort.teragen(jax.random.key(6), 4 * 128))
+    out = np.asarray(terasort.single_chip_sort(words, interpret=True))
+    np.testing.assert_array_equal(out, _lexsorted(words))
+    res = terasort.distributed_terasort(words, make_mesh(4))
     res.check()
-    out = np.asarray(res.words).reshape(4, -1, terasort.RECORD_WORDS)
+    shards = np.asarray(res.words).reshape(4, -1, terasort.RECORD_WORDS)
     nvalid = np.asarray(res.valid_counts).reshape(-1)
-    rows = np.concatenate([out[d, :nvalid[d]] for d in range(4)])
-    terasort.validate_sorted(rows, words)
+    rows = np.concatenate([shards[d, :nvalid[d]] for d in range(4)])
+    np.testing.assert_array_equal(rows, _lexsorted(words))
+    assert seen == {"single": want, "step": want}
 
 
 def test_validate_sorted_catches_violation():

@@ -1,37 +1,23 @@
-"""Online tuning cache lifecycle (ISSUE 13): probe persists winners, a
-second/fresh process routes from the cache without re-probing,
-corrupt/truncated/version-bumped files are ignored (counted, never
-fatal), env-var winners beat the cache, and a cold cache is
-byte-for-byte today's built-in routing."""
+"""Online tuning cache lifecycle (ISSUE 13): a probe persists winners,
+a second instance serves them without re-probing, corrupt / truncated /
+version-bumped files are ignored (counted, never fatal), an explicit
+flag beats the cache, and a cold cache is the built-in defaults."""
 
 import json
-import os
-import subprocess
 import sys
 import time
 
 import pytest
 
-from uda_tpu.ops import sort as sort_ops
 from uda_tpu.utils import tuncache
 from uda_tpu.utils.config import Config
 from uda_tpu.utils.metrics import metrics
-from uda_tpu.utils.tuncache import TuneCache, rows_bucket
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _sort_key(n_rows, lanes_ok=False):
-    import jax
-
-    return (f"{jax.default_backend()}|rows{rows_bucket(n_rows)}"
-            f"|lanes{int(lanes_ok)}")
+from uda_tpu.utils.tuncache import TuneCache
 
 
 @pytest.fixture()
 def cache_at(tmp_path, monkeypatch):
-    """A fresh cache file wired in as the process-default instance
-    (what route_engine consults)."""
+    """A fresh cache file wired in as the process-default instance."""
     path = str(tmp_path / "tune.json")
     cache = TuneCache(path)
     monkeypatch.setattr(tuncache, "tune_cache", cache)
@@ -42,15 +28,15 @@ def cache_at(tmp_path, monkeypatch):
 
 
 def test_record_lookup_round_trip(cache_at):
-    cache_at.record("sort.engine", "cpu|rows16|lanes0",
-                    {"engine": "gather"}, metric=1.25, probe="t")
-    rec = cache_at.lookup("sort.engine", "cpu|rows16|lanes0")
-    assert rec["winner"] == {"engine": "gather"}
+    cache_at.record("io.read", "linux", {"batch": "on"}, metric=1.25,
+                    probe="t")
+    rec = cache_at.lookup("io.read", "linux")
+    assert rec["winner"] == {"batch": "on"}
     assert rec["metric"] == 1.25
-    assert cache_at.age_s("sort.engine", "cpu|rows16|lanes0") < 60
-    assert cache_at.lookup("sort.engine", "nope") is None
-    assert metrics.get("tune.cache.hits", domain="sort.engine") == 1
-    assert metrics.get("tune.cache.misses", domain="sort.engine") == 1
+    assert cache_at.age_s("io.read", "linux") < 60
+    assert cache_at.lookup("io.read", "nope") is None
+    assert metrics.get("tune.cache.hits", domain="io.read") == 1
+    assert metrics.get("tune.cache.misses", domain="io.read") == 1
 
 
 def test_second_instance_reads_persisted_winner(cache_at):
@@ -63,11 +49,11 @@ def test_second_instance_reads_persisted_winner(cache_at):
 
 
 def test_concurrent_domains_merge_not_clobber(cache_at):
-    cache_at.record("sort.engine", "k1", {"engine": "carry"})
+    cache_at.record("io.read", "k1", {"batch": "off"})
     other = TuneCache(cache_at.path)
-    other.record("io.read", "k2", {"batch": "on"})
-    assert cache_at.lookup("sort.engine", "k1") is not None
-    assert cache_at.lookup("io.read", "k2") is not None
+    other.record("other.domain", "k2", {"batch": "on"})
+    assert cache_at.lookup("io.read", "k1") is not None
+    assert cache_at.lookup("other.domain", "k2") is not None
 
 
 # -- invalid files: ignored, counted, never fatal -----------------------------
@@ -82,101 +68,18 @@ def test_concurrent_domains_merge_not_clobber(cache_at):
 def test_invalid_cache_ignored_and_counted(cache_at, content):
     with open(cache_at.path, "w") as f:
         f.write(content)
-    assert cache_at.lookup("sort.engine", "anything") is None
+    assert cache_at.lookup("io.read", "anything") is None
     assert metrics.get("tune.cache.invalid") >= 1
-    # routing still works on the defaults
-    assert sort_ops.route_engine(1 << 16, "auto") \
-        == sort_ops.resolve_sort_path("auto")
 
 
 def test_invalid_entries_filtered_not_fatal(cache_at):
     with open(cache_at.path, "w") as f:
         json.dump({"schema": 1, "entries": {
-            "sort.engine|good": {"winner": {"engine": "gather"}},
-            "sort.engine|bad": "not-a-record",
+            "io.read|good": {"winner": {"batch": "on"}},
+            "io.read|bad": "not-a-record",
         }}, f)
-    assert cache_at.lookup("sort.engine", "good") is not None
-    assert cache_at.lookup("sort.engine", "bad") is None
-
-
-# -- route_engine integration -------------------------------------------------
-
-
-def test_cold_cache_routes_exactly_todays_defaults(cache_at,
-                                                   monkeypatch):
-    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "")
-    for n in (1, 1 << 10, 1 << 16, 1 << 20, 1 << 22):
-        for lanes_ok in (False, True):
-            assert sort_ops.route_engine(n, "auto", lanes_ok) == \
-                sort_ops.resolve_sort_path("auto", lanes_ok)
-    # explicit paths bypass the cache entirely
-    assert sort_ops.route_engine(1 << 16, "gather") == "gather"
-
-
-def test_route_engine_consults_cached_winner(cache_at, monkeypatch):
-    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "")
-    n = 1 << 16
-    cache_at.record("sort.engine", _sort_key(n),
-                    {"engine": "gather2"}, metric=2.0)
-    assert sort_ops.route_engine(n, "auto") == "gather2"
-    assert metrics.get("tune.cache.hits", domain="sort.engine") >= 1
-    # a different size class misses the cache -> built-in default
-    assert sort_ops.route_engine(1 << 22, "auto") == \
-        sort_ops.resolve_sort_path("auto")
-
-
-def test_env_winner_beats_cache(cache_at, monkeypatch):
-    n = 1 << 16
-    cache_at.record("sort.engine", _sort_key(n),
-                    {"engine": "gather2"})
-    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "carrychunk")
-    assert sort_ops.route_engine(n, "auto") == "carrychunk"
-
-
-def test_invalid_cached_engine_ignored(cache_at, monkeypatch):
-    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "")
-    n = 1 << 16
-    cache_at.record("sort.engine", _sort_key(n, lanes_ok=False),
-                    {"engine": "totally-made-up"})
-    assert sort_ops.route_engine(n, "auto") == \
-        sort_ops.resolve_sort_path("auto")
-    # a lanes winner cached for a lanes-capable key must not leak to a
-    # lanes-incapable caller (validation per lookup, not per file)
-    cache_at.record("sort.engine", _sort_key(n, lanes_ok=False),
-                    {"engine": "lanes"})
-    assert sort_ops.route_engine(n, "auto", lanes_ok=False) == \
-        sort_ops.resolve_sort_path("auto", lanes_ok=False)
-
-
-def test_fresh_process_routes_from_cache_without_probe(cache_at):
-    """THE acceptance round trip: a persisted winner is consulted by
-    route_engine in a FRESH interpreter — cache hit recorded, probe
-    counter ZERO (nothing re-measures on the routing path)."""
-    n = 1 << 16
-    # the fresh process is CPU-backend (env below): key accordingly
-    key = f"cpu|rows{rows_bucket(n)}|lanes0"
-    cache_at.record("sort.engine", key, {"engine": "gather2"},
-                    metric=9.9, probe="lifecycle-test")
-    code = (
-        "import os\n"
-        "from uda_tpu.ops import sort as sort_ops\n"
-        "from uda_tpu.utils.metrics import metrics\n"
-        f"engine = sort_ops.route_engine({n}, 'auto')\n"
-        "print('ENGINE', engine)\n"
-        "print('PROBES', int(metrics.get('tune.probes')))\n"
-        "print('HITS', int(metrics.get('tune.cache.hits')))\n"
-    )
-    env = dict(os.environ)
-    env.pop("UDA_TPU_SORT_PATH", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["UDA_TPU_TUNE_CACHE"] = cache_at.path
-    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
-                         env=env, capture_output=True, text=True,
-                         timeout=120)
-    assert out.returncode == 0, out.stderr
-    assert "ENGINE gather2" in out.stdout
-    assert "PROBES 0" in out.stdout
-    assert "HITS 1" in out.stdout
+    assert cache_at.lookup("io.read", "good") is not None
+    assert cache_at.lookup("io.read", "bad") is None
 
 
 # -- the io.read consumer -----------------------------------------------------
@@ -221,31 +124,61 @@ def test_io_plane_explicit_config_beats_cache(tmp_path, cache_at):
         engine.stop()
 
 
-def test_config_path_installs_process_default(tmp_path, cache_at,
-                                              monkeypatch):
-    """An explicitly-configured uda.tpu.tune.cache.path must reach
-    route_engine too (which has no Config in scope): constructing the
-    engine installs the path as the process default — unless the env
-    var is set, which always wins."""
-    monkeypatch.setattr(sort_ops, "DEPLOYED_SORT_PATH", "")
+def test_configured_path_beats_env_path(tmp_path, cache_at, monkeypatch):
+    """An explicitly configured uda.tpu.tune.cache.path is the table the
+    engine reads, whatever UDA_TPU_TUNE_CACHE (the process default,
+    ``cache_at`` here) holds; with no path configured the process
+    default serves."""
+    monkeypatch.setenv("UDA_TPU_TUNE_CACHE", cache_at.path)
+    cache_at.record("io.read", sys.platform, {"batch_max": 32})
     other = str(tmp_path / "other_tune.json")
-    TuneCache(other).record("sort.engine", _sort_key(1 << 16),
-                            {"engine": "gather2"})
-    monkeypatch.delenv("UDA_TPU_TUNE_CACHE", raising=False)
+    TuneCache(other).record("io.read", sys.platform, {"batch_max": 48})
     engine = _engine_with_cache(tmp_path, other)
     try:
-        assert tuncache.tune_cache.path == other
-        assert sort_ops.route_engine(1 << 16, "auto") == "gather2"
+        assert engine.batch_max == 48
     finally:
         engine.stop()
-    # with the env channel set, config must NOT displace it
-    monkeypatch.setenv("UDA_TPU_TUNE_CACHE", cache_at.path)
-    before = tuncache.tune_cache
-    engine = _engine_with_cache(tmp_path, str(tmp_path / "third.json"))
+    from tests.test_iobatch import SyntheticResolver
+    from uda_tpu.mofserver.data_engine import DataEngine
+
+    engine = DataEngine(SyntheticResolver(str(tmp_path / "f.mof"), 4096),
+                        Config())
     try:
-        assert tuncache.tune_cache is before
+        assert engine.batch_max == 32
     finally:
         engine.stop()
+
+
+def test_stale_sort_engine_record_is_inert(tmp_path, cache_at):
+    """A cache file written by a deployment that still had the
+    sort.engine domain loads, serves io.read, and moves no sort: the
+    engine policy reads no cache."""
+    import jax
+
+    from uda_tpu.ops import sort as sort_ops
+
+    backend = jax.default_backend()
+    with open(cache_at.path, "w") as f:
+        json.dump({"schema": 1, "entries": {
+            f"sort.engine|{backend}|rows17|lanes1": {
+                "winner": {"engine": "gather"}, "metric": 2.0,
+                "probed_unix": 1.0, "probe": "tune_probe"},
+            f"sort.engine|{backend}|rows17|lanes0": {
+                "winner": {"engine": "keys8"}, "metric": 2.0,
+                "probed_unix": 1.0, "probe": "tune_probe"},
+            f"io.read|{sys.platform}": {
+                "winner": {"batch": "off", "batch_max": 32},
+                "metric": 9.0, "probed_unix": 1.0, "probe": "tune_probe"},
+        }}, f)
+    engine = _engine_with_cache(tmp_path, cache_at.path)
+    try:
+        assert engine.batch_enabled is False and engine.batch_max == 32
+    finally:
+        engine.stop()
+    assert metrics.get("tune.cache.invalid") == 0
+    assert sort_ops.resolve_sort_path("auto") == "carry"   # the CPU's
+    assert metrics.get("tune.cache.hits", domain="sort.engine") == 0
+    assert metrics.get("tune.cache.misses", domain="sort.engine") == 0
 
 
 def test_io_plane_invalid_winner_values_ignored(tmp_path, cache_at):
@@ -267,22 +200,22 @@ def test_io_plane_invalid_winner_values_ignored(tmp_path, cache_at):
 
 def test_ensure_fresh_reprobes_stale_entry(cache_at, monkeypatch):
     calls = []
-    monkeypatch.setitem(tuncache._PROBES, "sort.engine",
+    monkeypatch.setitem(tuncache._PROBES, "io.read",
                         lambda key: calls.append(key))
-    cache_at.record("sort.engine", "k", {"engine": "carry"})
+    cache_at.record("io.read", "k", {"batch": "on"})
     # fresh: no re-probe
-    tuncache.ensure_fresh(cache_at, "sort.engine", "k", 3600.0)
+    tuncache.ensure_fresh(cache_at, "io.read", "k", 3600.0)
     assert not calls
     # absent: no re-probe either (first measurement is the probe
     # script's job, never the routing hot path's)
-    tuncache.ensure_fresh(cache_at, "sort.engine", "absent", 0.001)
+    tuncache.ensure_fresh(cache_at, "io.read", "absent", 0.001)
     # stale: the background thread re-measures
     with open(cache_at.path) as f:
         doc = json.load(f)
-    doc["entries"]["sort.engine|k"]["probed_unix"] = time.time() - 999
+    doc["entries"]["io.read|k"]["probed_unix"] = time.time() - 999
     with open(cache_at.path, "w") as f:
         json.dump(doc, f)
-    tuncache.ensure_fresh(cache_at, "sort.engine", "k", 1.0)
+    tuncache.ensure_fresh(cache_at, "io.read", "k", 1.0)
     deadline = time.monotonic() + 5.0
     while not calls and time.monotonic() < deadline:
         time.sleep(0.01)
@@ -290,6 +223,6 @@ def test_ensure_fresh_reprobes_stale_entry(cache_at, monkeypatch):
     assert metrics.get("tune.reprobes") == 1
     # disabled horizon (0): never
     calls.clear()
-    tuncache.ensure_fresh(cache_at, "sort.engine", "k", 0.0)
+    tuncache.ensure_fresh(cache_at, "io.read", "k", 0.0)
     time.sleep(0.05)
     assert not calls

@@ -575,18 +575,8 @@ class DataEngine:
             from uda_tpu.utils.tuncache import cache_path_from_env
             tc_path = cache_path_from_env()
         if tc_path:
-            from uda_tpu.utils.tuncache import (TuneCache,
-                                                set_default_cache,
-                                                tune_cache)
-            if explicit:
-                # one explicitly-configured engine makes the whole
-                # process self-service: route_engine (no Config in
-                # scope) consults the same table; the env var wins
-                cache = set_default_cache(tc_path)
-                if cache.path != tc_path:
-                    cache = TuneCache(tc_path)
-            else:
-                cache = tune_cache
+            from uda_tpu.utils.tuncache import TuneCache, tune_cache
+            cache = TuneCache(tc_path) if explicit else tune_cache
             rec = cache.lookup("io.read", sys.platform)
             if rec is not None and isinstance(rec.get("winner"), dict):
                 winner = rec["winner"]
@@ -614,8 +604,8 @@ class DataEngine:
         self.max_run_bytes = self.batch_max * (64 << 10)
         want_backend = str(cfg.get("uda.tpu.read.backend")).strip().lower()
         if want_backend not in BATCH_BACKENDS + ("auto",):
-            # typo'd deploy values fail loudly (the UDA_TPU_SORT_PATH
-            # posture), never silently serve the slow rung
+            # typo'd deploy values fail loudly, never silently serve
+            # the slow rung
             raise ConfigError(f"uda.tpu.read.backend={want_backend!r} "
                               f"is not one of {BATCH_BACKENDS + ('auto',)}")
         if want_backend == "auto" and winner.get("backend") \

@@ -110,32 +110,7 @@ def _ceil_runs(na: int, nb: int, tile: int) -> int:
 
 
 @partial(jax.jit, static_argnames=("num_keys", "tile", "interpret"))
-def _merge_sorted_pair_keys8(a, b, num_keys: int, tile: int,
-                             interpret: bool):
-    """keys8 variant: the merge network runs on an 8-row keys-only pair
-    (key words + the arrival-index tie-break, which doubles as the
-    GLOBAL ROW INDEX into concat(a, b)), and the full-width rows move
-    once via an XLA gather by the merged tie-break row. 4x less VPU and
-    HBM work in the kernel than the 32-row pass; requires
-    num_keys <= 7 (key rows + tie-break fit one 8-row sublane tile)."""
-    na, nb = a.shape[0], b.shape[0]
-    tb = 7
-    L = _ceil_runs(na, nb, tile)
-    x8 = _pack_bitonic_pair(a, b, num_keys, 8, tb, L)
-    splits = _pass_splits(x8, jnp.int32(L), jnp.bool_(True), tile,
-                          num_keys, tb)
-    out8 = _merge_pass(x8, splits, tile, num_keys, tb,
-                       interpret=interpret)
-    perm = out8[tb, :na + nb].astype(jnp.int32)
-    cat = jnp.concatenate([a, b], axis=0)
-    return jnp.take(cat.T, perm, axis=1,
-                    unique_indices=True, mode="clip").T
-
-
-@partial(jax.jit, static_argnames=("num_keys", "tile", "interpret",
-                                   "two_phase"))
-def _merge_sorted_pair_jit(a, b, num_keys: int, tile: int, interpret: bool,
-                           two_phase: bool):
+def _merge_sorted_pair_jit(a, b, num_keys: int, tile: int, interpret: bool):
     """Shape-specialized core: jit so repeat calls at the same (na, nb)
     hit the executable cache instead of re-tracing the pallas_call
     (the overlapped merger calls this many times per job)."""
@@ -145,40 +120,27 @@ def _merge_sorted_pair_jit(a, b, num_keys: int, tile: int, interpret: bool,
     x = _pack_bitonic_pair(a, b, wcols, pallas_sort.ROWS, tb, L)
     splits = _pass_splits(x, jnp.int32(L), jnp.bool_(True), tile,
                           num_keys, tb)
-    out = _merge_pass(x, splits, tile, num_keys, tb, interpret=interpret,
-                      two_phase=two_phase)
+    out = _merge_pass(x, splits, tile, num_keys, tb, interpret=interpret)
     return out[:wcols, :na + nb].T
 
 
 def merge_sorted_pair(a, b, num_keys: int, tile: int = 512,
-                      interpret: bool = False, two_phase: bool = False,
-                      keys8: bool = False):
+                      interpret: bool = False):
     """Merge two key-sorted row matrices into one (stable: A's rows
     precede B's on equal keys). ``a``/``b``: uint32[n, W] with key words
     in the leading ``num_keys`` columns, W <= 31. The output has
-    a.shape[0]+b.shape[0] rows. ``two_phase`` selects the keys-view +
-    in-kernel payload-gather kernel variant (see
-    pallas_sort.sort_lanes); ``keys8`` runs the network on an 8-row
-    keys-only pair and moves full rows once via an XLA gather
-    (num_keys <= 7; record width unconstrained by the lanes layout)."""
+    a.shape[0]+b.shape[0] rows."""
     if tile <= 0 or (tile & (tile - 1)) != 0 or tile % 128:
         raise ValueError(f"tile must be a power of two multiple of 128, "
                          f"got {tile} (the lanes merge kernel requires "
                          "it)")
-    if two_phase and keys8:
-        raise ValueError("two_phase and keys8 are mutually exclusive")
     a = jnp.asarray(a, jnp.uint32)
     b = jnp.asarray(b, jnp.uint32)
-    if keys8 and num_keys > 7:
-        raise ValueError(f"keys8 needs num_keys <= 7, got {num_keys}")
-    if not keys8 and a.shape[1] > pallas_sort.TB_ROW_DEFAULT:
+    if a.shape[1] > pallas_sort.TB_ROW_DEFAULT:
         raise ValueError(f"{a.shape[1]} record words do not fit the "
                          f"{pallas_sort.ROWS}-row lanes layout")
     if a.shape[0] == 0:
         return b
     if b.shape[0] == 0:
         return a
-    if keys8:
-        return _merge_sorted_pair_keys8(a, b, num_keys, tile, interpret)
-    return _merge_sorted_pair_jit(a, b, num_keys, tile, interpret,
-                                  two_phase)
+    return _merge_sorted_pair_jit(a, b, num_keys, tile, interpret)

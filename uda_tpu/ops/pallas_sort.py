@@ -36,7 +36,7 @@ Stability: the tie-break row makes all sort keys distinct, so the
 ``sort_lanes`` builds the whole pipeline (1 tile-sort + log2(n/T)
 merge passes) in one traced, jit-compatible function. Unlike the
 operand-carry ``lax.sort`` (whose TPU compile time grows superlinearly
-in operand count, uda_tpu.ops.sort.resolve_sort_path), every kernel
+in operand count, uda_tpu.ops.sort.SORT_PATHS), every kernel
 here has a fixed small operand surface, so compile cost is bounded
 regardless of record width.
 """
@@ -116,24 +116,7 @@ def _cmp_exchange(x, j: int, asc_mask, key_rows_idx):
     return jnp.where(keep_self, x, other)
 
 
-def _keys_view(x, num_keys, tb_row):
-    """8-row (one sublane tile) working set for the two-phase engine:
-    rows [keys..., tie-break, lane-position, zero pad]. The network runs
-    on THIS view (4x less data movement per compare-exchange than the
-    full 32 rows); the position row rides through as payload and ends
-    up holding, for each sorted position, its SOURCE lane — the gather
-    index that then moves the full-width payload ONCE."""
-    n = x.shape[1]
-    pos = lax.broadcasted_iota(jnp.uint32, (1, n), 1)
-    pad = jnp.zeros((8 - num_keys - 2, n), jnp.uint32)
-    seq8 = jnp.concatenate([x[:num_keys], x[tb_row:tb_row + 1], pos, pad],
-                           axis=0)
-    key_rows = list(range(num_keys)) + [num_keys]
-    return seq8, key_rows, num_keys + 1  # (view, key row idx, pos row)
-
-
-def _tile_sort_kernel(x_ref, o_ref, *, tile, num_keys, tb_row, alternate,
-                      two_phase):
+def _tile_sort_kernel(x_ref, o_ref, *, tile, num_keys, tb_row, alternate):
     t = pl.program_id(0)
     x = x_ref[...]
     lane = lax.broadcasted_iota(jnp.int32, (1, tile), 1)
@@ -148,10 +131,7 @@ def _tile_sort_kernel(x_ref, o_ref, *, tile, num_keys, tb_row, alternate,
     else:
         tile_asc = jnp.broadcast_to(jnp.bool_(True), (1, tile))
 
-    if two_phase:
-        net, key_rows_idx, pos_row = _keys_view(x, num_keys, tb_row)
-    else:
-        net, key_rows_idx = x, list(range(num_keys)) + [tb_row]
+    key_rows_idx = list(range(num_keys)) + [tb_row]
     k = 2
     while k <= tile:
         if k == tile:
@@ -162,13 +142,10 @@ def _tile_sort_kernel(x_ref, o_ref, *, tile, num_keys, tb_row, alternate,
             asc = ((lane & k) == 0) == tile_asc
         j = k // 2
         while j >= 1:
-            net = _cmp_exchange(net, j, asc, key_rows_idx)
+            x = _cmp_exchange(x, j, asc, key_rows_idx)
             j //= 2
         k *= 2
-    if two_phase:
-        o_ref[...] = jnp.take(x, net[pos_row].astype(jnp.int32), axis=1)
-    else:
-        o_ref[...] = net
+    o_ref[...] = x
 
 
 def _uint32_struct(shape, x):
@@ -179,13 +156,13 @@ def _uint32_struct(shape, x):
 
 
 @partial(jax.jit, static_argnames=("tile", "num_keys", "tb_row",
-                                   "alternate", "interpret", "two_phase"))
+                                   "alternate", "interpret"))
 def _tile_sort(x, tile: int, num_keys: int, tb_row: int, alternate: bool,
-               interpret: bool = False, two_phase: bool = False):
+               interpret: bool = False):
     rows, n = x.shape
     return pl.pallas_call(
         partial(_tile_sort_kernel, tile=tile, num_keys=num_keys,
-                tb_row=tb_row, alternate=alternate, two_phase=two_phase),
+                tb_row=tb_row, alternate=alternate),
         grid=(n // tile,),
         in_specs=[pl.BlockSpec((rows, tile), lambda t: (0, t))],
         out_specs=pl.BlockSpec((rows, tile), lambda t: (0, t)),
@@ -296,14 +273,10 @@ def _pass_splits(x, run_len, final, tile: int, num_keys: int, tb_row: int):
 
 def _merge_pass_kernel(splits_ref, splits_nxt_ref, x_hbm, o_ref, a_bufs,
                        b_bufs, sem_a, sem_b, *, tile, num_keys, tb_row,
-                       split_blk, two_phase):
+                       split_blk):
     """One output tile of one merge pass (see _pass_splits for the rank
     bookkeeping; every pass-dependent scalar arrives via splits_ref, so
     this kernel compiles once and serves all log2(n/tile) passes).
-
-    MAINTENANCE: ops.pallas_fold._merge_pass_kernel_folded mirrors this
-    kernel's DMA protocol and roll contract — apply hardware-erratum
-    fixes to both.
 
     DMA double buffering: the windows for tile t+1 (whose aligned starts
     arrive via splits_nxt_ref, the splits table shifted by one row) are
@@ -375,33 +348,17 @@ def _merge_pass_kernel(splits_ref, splits_nxt_ref, x_hbm, o_ref, a_bufs,
 
     seq = jnp.concatenate([a_rows, b_rows], axis=1)
     asc_mask = jnp.broadcast_to(out_asc, (1, 2 * tile))
-    if two_phase:
-        net, key_rows_idx, pos_row = _keys_view(seq, num_keys, tb_row)
-    else:
-        net, key_rows_idx = seq, list(range(num_keys)) + [tb_row]
+    key_rows_idx = list(range(num_keys)) + [tb_row]
     j = tile
     while j >= 1:
-        net = _cmp_exchange(net, j, asc_mask, key_rows_idx)
+        seq = _cmp_exchange(seq, j, asc_mask, key_rows_idx)
         j //= 2
-    if two_phase:
-        # Mosaic's gather rule requires input == indices == output
-        # shape, so a narrowing take([32, 2T] by [T]) does not lower:
-        # gather the full 2T window with the broadcast permutation row,
-        # then slice the kept half (2x the gather traffic, but it's the
-        # only formulation the lowering accepts — scripts/probe_gather)
-        perm = jnp.broadcast_to(net[pos_row].astype(jnp.int32)[None, :],
-                                seq.shape)
-        gathered = jnp.take_along_axis(seq, perm, axis=1)
-        o_ref[...] = jnp.where(out_asc, gathered[:, :tile],
-                               gathered[:, tile:])
-    else:
-        o_ref[...] = jnp.where(out_asc, net[:, :tile], net[:, tile:])
+    o_ref[...] = jnp.where(out_asc, seq[:, :tile], seq[:, tile:])
 
 
-@partial(jax.jit, static_argnames=("tile", "num_keys", "tb_row", "interpret",
-                                   "two_phase"))
+@partial(jax.jit, static_argnames=("tile", "num_keys", "tb_row", "interpret"))
 def _merge_pass(x, splits, tile: int, num_keys: int, tb_row: int,
-                interpret: bool = False, two_phase: bool = False):
+                interpret: bool = False):
     rows, n = x.shape
     # The splits table is BLOCKED into SMEM a few rows per grid step: a
     # whole-table scalar prefetch would put [num_tiles, 8] int32 in SMEM
@@ -418,7 +375,7 @@ def _merge_pass(x, splits, tile: int, num_keys: int, tb_row: int,
                        memory_space=pltpu.SMEM)
     return pl.pallas_call(
         partial(_merge_pass_kernel, tile=tile, num_keys=num_keys,
-                tb_row=tb_row, split_blk=split_blk, two_phase=two_phase),
+                tb_row=tb_row, split_blk=split_blk),
         grid=(n // tile,),
         in_specs=[blk, blk, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((rows, tile), lambda t: (0, t)),
@@ -442,10 +399,9 @@ def pad_pow2(n: int, tile: int) -> tuple[int, int]:
     return m, min(tile, m)
 
 
-def keys8_sort_perm(keyrows, tile: int = 1024, interpret: bool = False,
-                    folded: bool = False):
-    """The keys8 cascade core, shared by every keys8 engine (the
-    single-chip sort, the bench bodies, the distributed local sort):
+def keys8_sort_perm(keyrows, tile: int = 1024, interpret: bool = False):
+    """The keys8 cascade core, shared by both keys8 surfaces (the
+    single-chip sort, the distributed local sort):
     run the FULL bitonic pipeline on an 8-row keys-only matrix and
     return ``(sorted_key_rows, perm)`` — ``perm[j]`` is the source lane
     of sorted position j (int32), stable by arrival order among equal
@@ -461,21 +417,6 @@ def keys8_sort_perm(keyrows, tile: int = 1024, interpret: bool = False,
     k, m = keyrows.shape
     if not 0 < k <= 7:
         raise ValueError(f"keys8 needs 1..7 key rows, got {k}")
-    if folded and k <= 3 and tile % (2 * _LANE) == 0:
-        # the folded cascade (ops.pallas_fold): half the network work
-        # AND half the inter-pass HBM traffic (slim [4, n] layout);
-        # needs the compare set to fit a 4-row slot. Tiles below two
-        # lane blocks cannot fold (the half width must stay
-        # lane-aligned) and quietly use the standard cascade — the
-        # output contract is identical.
-        from uda_tpu.ops.pallas_fold import sort_lanes_folded4
-
-        mat4 = jnp.concatenate(
-            [jnp.asarray(keyrows, jnp.uint32),
-             jnp.zeros((4 - k, m), jnp.uint32)], axis=0)
-        out4 = sort_lanes_folded4(mat4, num_keys=k, tile=tile,
-                                  interpret=interpret)
-        return out4[:k], out4[3].astype(jnp.int32)
     mat8 = jnp.concatenate(
         [jnp.asarray(keyrows, jnp.uint32),
          jnp.zeros((8 - k, m), jnp.uint32)], axis=0)
@@ -485,20 +426,13 @@ def keys8_sort_perm(keyrows, tile: int = 1024, interpret: bool = False,
 
 
 def sort_lanes(x, num_keys: int, tb_row: int = TB_ROW_DEFAULT,
-               tile: int = 1024, interpret: bool = False,
-               two_phase: bool = False):
+               tile: int = 1024, interpret: bool = False):
     """Full stable sort of records in lanes layout.
 
     ``x``: uint32[ROWS, n] with key words in rows [0, num_keys); row
     ``tb_row`` is overwritten with the arrival index (stability) and
     holds it in the output. n must be a power-of-two multiple of
     ``tile`` (pad with +inf-key records otherwise).
-
-    ``two_phase``: run every bitonic network on an 8-row keys view and
-    move the 32-row payload with ONE lane gather per kernel instead of
-    through every compare-exchange stage (~4x less data movement per
-    stage; requires Mosaic to lower a dynamic lane-axis gather — see
-    scripts/probe_gather.py; needs num_keys <= 6).
 
     Returns the sorted [ROWS, n] array (ascending by keys, stable by
     arrival among equal keys).
@@ -513,11 +447,9 @@ def sort_lanes(x, num_keys: int, tb_row: int = TB_ROW_DEFAULT,
                          f"tile={tile}")
     if not 0 < num_keys <= tb_row < rows:
         raise ValueError(f"bad num_keys={num_keys} / tb_row={tb_row}")
-    if two_phase and num_keys + 2 > 8:
-        raise ValueError(f"two_phase needs num_keys <= 6, got {num_keys}")
     levels = int(np.log2(n // tile))
     x = _tile_sort(x, tile, num_keys, tb_row, alternate=levels > 0,
-                   interpret=interpret, two_phase=two_phase)
+                   interpret=interpret)
     if levels == 0:
         return x
 
@@ -530,6 +462,6 @@ def sort_lanes(x, num_keys: int, tb_row: int = TB_ROW_DEFAULT,
         final = lvl == levels - 1
         splits = _pass_splits(x, run_len, final, tile, num_keys, tb_row)
         return _merge_pass(x, splits, tile, num_keys, tb_row,
-                           interpret=interpret, two_phase=two_phase)
+                           interpret=interpret)
 
     return lax.fori_loop(0, levels, body, x)

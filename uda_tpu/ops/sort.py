@@ -25,7 +25,6 @@ per job (reference src/Merger/reducer.cc:56-133).
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Sequence
 
@@ -37,201 +36,40 @@ from jax import lax
 from uda_tpu.ops.packing import PackedKeys
 
 __all__ = ["sort_permutation", "merge_runs", "sort_records_fixed",
-           "concat_packed", "resolve_sort_path", "apply_perm_chunked",
-           "route_engine", "LANES_ENGINES", "FLYOFF_ENGINES",
-           "BENCH_FLYOFF", "ALL_SORT_PATHS", "UNCOMPILED_ENGINES",
-           "SELECTABLE_SORT_PATHS", "GATHER_BOUND_ENGINES",
-           "CC_LADDER", "SMALL_BATCH_ROWS"]
+           "concat_packed", "resolve_sort_path", "SORT_PATHS"]
 
-# The single source of truth for engine path names. LANES_ENGINES are
-# the Pallas-pipeline variants (bounded compile; interpret mode on CPU
-# meshes): "lanes" carries payload through the network, "lanes2" uses
-# the in-kernel two-phase gather, "keys8" runs the cascade on an 8-row
-# keys view + one global XLA payload gather. "gather2" is keys8's
-# XLA-native twin: the permutation comes from a narrow lax.sort
-# instead of the Pallas cascade, the payload moves with the same
-# single minor-dim gather (differs from "gather", which does one
-# gather PER COLUMN on [n] arrays). The remaining lax.sort paths are
-# "carry" (operand-carry) and "gather". bench.py, parallel.distributed
-# and models.terasort all import these — adding an engine means
-# extending ONE tuple.
-# "carrychunk" applies the narrow-sort permutation with a few SMALL
-# operand-carry sorts (invert the permutation with a 2-operand sort,
-# then re-sort payload chunks of ~6 columns by it): no gathers, no
-# Pallas, and every sort stays far below the operand count where XLA's
-# variadic-sort compile time blows up. "keys8f" is keys8 with the
-# FOLDED cascade (ops.pallas_fold: two element-halves share the 8-row
-# tile, halving per-stage work) — it needs the compare set to fit a
-# 4-row slot, so it is a narrow-key specialization (<= 3 compare rows
-# + tie-break; the TeraSort flagship shape) and joins the bench
-# fly-off but not the general-purpose engine set.
-# carrychunk's payload-chunk width; overridable for deployment tuning
-# (resolved once at import — see apply_perm_chunked)
-DEFAULT_CHUNK_COLS = int(os.environ.get("UDA_TPU_CHUNK_COLS", "6"))
-
-# The engine the "auto" policy deploys — how a fly-off/sweep winner
-# reaches every production call site at once (the engine analogue of
-# UDA_TPU_CHUNK_COLS). Empty = the built-in per-backend defaults
-# below. Read ONCE at import, never inside a jitted trace. A deployed
-# LANES engine applies only to lanes-capable callers (lanes_ok=True);
-# others keep the built-in default rather than failing — the deploy
-# var must never break a pure-XLA code path.
-DEPLOYED_SORT_PATH = os.environ.get("UDA_TPU_SORT_PATH", "")
-
-LANES_ENGINES = ("lanes", "lanes2", "keys8", "keys8f")
-FLYOFF_ENGINES = ("lanes", "keys8", "gather2", "carrychunk")
-BENCH_FLYOFF = FLYOFF_ENGINES + ("keys8f",)
-# Engines whose kernels Mosaic refuses (chip_smoke.py Phase B records
-# the compiler's message): known by name, so interpret-mode tests can
-# still diff them, but in no fly-off and selectable by no policy — a
-# deployed or cached winner naming one is rejected like an unknown
-# engine. "lanes2": the in-kernel lane gather does not lower.
-UNCOMPILED_ENGINES = ("lanes2",)
-SELECTABLE_SORT_PATHS = ("carry", "gather") + BENCH_FLYOFF
-ALL_SORT_PATHS = SELECTABLE_SORT_PATHS + UNCOMPILED_ENGINES
-
-# Engines whose payload movement is one (or more) global HBM gathers.
-# A take-ramp probe of 2026-07-31, on a backend that no longer exists
-# (git history; not measured on this machine), found the gather
-# LATENCY-bound below SMALL_BATCH_ROWS — fixed per-row random-access
-# cost dominates before the streaming rate amortizes it — so small
-# batches route to a gather-free engine (route_engine below).
-GATHER_BOUND_ENGINES = ("gather", "gather2", "keys8", "keys8f")
-SMALL_BATCH_ROWS = 1 << 20
-
-# carrychunk chunk-width ladder (words per payload-chunk sort). For the
-# TeraSort shape's 23 payload words: cc=6 -> 4 chunk sorts moving 27
-# operand-words/record, cc=8 -> 3 (26), cc=12 -> 2 (25), cc=23 -> the
-# single-sort extreme (24 words/record — the ROADMAP "27->24" lever).
-# Larger cc strictly reduces sort-network traffic, bounded by XLA's
-# superlinear variadic-sort compile time; a sweep's winner deploys via
-# UDA_TPU_CHUNK_COLS.
-CC_LADDER = (8, 12, 23)
+# The engine names, in one place. Every engine implements the same
+# stable order (equal keys keep arrival order), so outputs are
+# byte-identical across them.
+# - "carry": XLA's operand-carry ``lax.sort`` — all record columns ride
+#   the sort network. Compile time grows superlinearly in operand count
+#   (minutes on a TPU at TeraSort's 26 words), cheap on a CPU.
+# - "lanes": the Pallas lanes pipeline (ops.pallas_sort.sort_lanes) —
+#   two Mosaic kernels whatever n and the record width; records up to
+#   the 32-row layout.
+# - "keys8": the same pipeline on an 8-row keys-only view plus one XLA
+#   payload gather; no record-width limit. Explicit only.
+SORT_PATHS = ("carry", "lanes", "keys8")
 
 
-def resolve_sort_path(path: str, lanes_ok: bool = False) -> str:
-    """Resolve a payload-movement strategy name. "auto" picks
-    operand-carry on CPU (compile is cheap there) and "carrychunk" on
-    TPU — the winner of the fly-off of 2026-07-31 on a backend that no
-    longer exists (git history; not measured on this machine), with
-    bounded compile (no sort exceeds chunk_cols+1 operands; XLA's
-    variadic-sort compile time grows superlinearly in operand count)
-    and no record-width limit.
-    ``lanes_ok`` additionally admits the Pallas-pipeline engines
-    (LANES_ENGINES) for callers that implement them; the pure-XLA
-    strategies (carry/gather/gather2/carrychunk) are valid everywhere.
+def resolve_sort_path(path: str) -> str:
+    """The whole engine policy: "auto" is "carry" on a CPU backend and
+    "lanes" on a TPU; an explicit name of SORT_PATHS is honoured.
     Resolution happens EAGERLY, never inside a jitted trace: a
     trace-time choice would be baked into the jit cache and survive a
     later platform switch."""
-    valid = (ALL_SORT_PATHS if lanes_ok
-             else tuple(p for p in ALL_SORT_PATHS
-                        if p not in LANES_ENGINES))
     if path == "auto":
-        if DEPLOYED_SORT_PATH:
-            if DEPLOYED_SORT_PATH not in SELECTABLE_SORT_PATHS:
-                raise ValueError(
-                    f"UDA_TPU_SORT_PATH={DEPLOYED_SORT_PATH!r} is not a "
-                    f"selectable sort path {SELECTABLE_SORT_PATHS}")
-            if DEPLOYED_SORT_PATH in valid:
-                return DEPLOYED_SORT_PATH
-            # deployed lanes engine, lanes-incapable caller: keep the
-            # built-in default
         backend = jax.default_backend()
         if backend == "cpu":
-            path = "carry"
-        elif backend == "tpu":
-            path = "carrychunk"
-        else:
-            raise ValueError(f"no default sort path for backend "
-                             f"{backend!r} (cpu and tpu are supported)")
-    if path not in valid:
-        raise ValueError(f"unknown sort path {path!r}")
+            return "carry"
+        if backend == "tpu":
+            return "lanes"
+        raise ValueError(f"no default sort path for backend "
+                         f"{backend!r} (cpu and tpu are supported)")
+    if path not in SORT_PATHS:
+        raise ValueError(f"unknown sort path {path!r}; the engines are "
+                         f"{SORT_PATHS}")
     return path
-
-
-def _cached_engine(n_rows: int, lanes_ok: bool) -> "str | None":
-    """The tuning-cache consult for "auto" routing (utils/tuncache.py):
-    a fly-off winner persisted per (backend, row-bucket, lanes
-    capability) by scripts/tune_probe.py. Returns None — today's
-    built-in default — on a cold cache, an unreadable file, or a
-    winner this caller cannot run (validation here, so a stale or
-    hand-edited cache can never force an invalid engine name onto a
-    production sort surface). Precedence is env > cache > built-in:
-    callers consult this only when UDA_TPU_SORT_PATH is unset."""
-    from uda_tpu.utils.tuncache import rows_bucket, tune_cache
-
-    backend = jax.default_backend()
-    key = f"{backend}|rows{rows_bucket(n_rows)}|lanes{int(lanes_ok)}"
-    rec = tune_cache.lookup("sort.engine", key)
-    if rec is None:
-        return None
-    engine = (rec.get("winner") or {}).get("engine")
-    valid = (SELECTABLE_SORT_PATHS if lanes_ok
-             else tuple(p for p in SELECTABLE_SORT_PATHS
-                        if p not in LANES_ENGINES))
-    if engine not in valid:
-        return None
-    return engine
-
-
-def route_engine(n_rows: int, path: str = "auto",
-                 lanes_ok: bool = False) -> str:
-    """Batch-size-aware engine routing: resolve ``path`` like
-    :func:`resolve_sort_path` — consulting the persisted tuning cache
-    for "auto" when no env winner is deployed (env > cache > built-in;
-    a cold cache is byte-for-byte today's defaults) — then, for "auto"
-    only, steer batches below :data:`SMALL_BATCH_ROWS` away from
-    :data:`GATHER_BOUND_ENGINES` onto "carrychunk" on TPU (its
-    permutation apply rides small sort networks, no global gather —
-    the only engine shape that holds up in the latency-bound take-ramp
-    regime). The steering applies to deployed AND cached winners
-    alike: a gather-bound fly-off champion (keys8f/gather2/...) must
-    not be routed into the regime the take-ramp datum says it loses.
-    An EXPLICIT path is always honored: routing refines the default,
-    it never overrides the operator. This is the resolution entry for
-    the production sort surfaces (models.terasort.single_chip_sort,
-    parallel.distributed). Resolution is eager, never inside a jitted
-    trace."""
-    if path != "auto":
-        return resolve_sort_path(path, lanes_ok)
-    resolved = resolve_sort_path("auto", lanes_ok)
-    if not DEPLOYED_SORT_PATH:
-        cached = _cached_engine(n_rows, lanes_ok)
-        if cached is not None:
-            resolved = cached
-    if (n_rows < SMALL_BATCH_ROWS and jax.default_backend() == "tpu"
-            and resolved in GATHER_BOUND_ENGINES):
-        return "carrychunk"
-    return resolved
-
-
-def apply_perm_chunked(perm, cols, chunk_cols: int | None = None) -> list:
-    """Apply ``perm`` to columns WITHOUT gathers: ``out[c][j] ==
-    cols[c][perm[j]]``. Inverts the permutation with a 2-operand sort
-    (iota carried through a sort BY perm lands at the inverse), then
-    re-sorts payload chunks of ``chunk_cols`` columns by it — every
-    sort stays far below the operand count where XLA's variadic-sort
-    compile time blows up. The single implementation behind the
-    "carrychunk" engine (terasort bench and the distributed step).
-
-    ``chunk_cols=None`` resolves ``UDA_TPU_CHUNK_COLS`` so a
-    sweep-tuned value reaches every production call site at once. The
-    env var is read ONCE at import (module constant), never inside a
-    jitted trace — a trace-time read would bake into the jit cache
-    without being part of its key."""
-    if chunk_cols is None:
-        chunk_cols = DEFAULT_CHUNK_COLS
-    n = perm.shape[0]
-    iota = lax.iota(jnp.int32, n)
-    # perm keys are distinct, so unstable sorts are exact
-    _, inv = lax.sort((perm.astype(jnp.int32), iota), num_keys=1,
-                      is_stable=False)
-    out_cols: list = []
-    for base in range(0, len(cols), chunk_cols):
-        chunk = tuple(cols[base:base + chunk_cols])
-        out = lax.sort((inv, *chunk), num_keys=1, is_stable=False)
-        out_cols.extend(out[1:])
-    return out_cols
 
 
 @partial(jax.jit, static_argnames=("num_key_words",))

@@ -31,6 +31,7 @@ from jax import lax
 from uda_tpu.parallel import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from uda_tpu.ops.sort import resolve_sort_path
 from uda_tpu.parallel.multihost import put_global, put_rows, zeros_global
 from uda_tpu.utils.errors import TransportError
 
@@ -44,42 +45,12 @@ _INVALID = np.uint32(0xFFFFFFFF)
 
 
 def _lanes_interpret(payload_path: str, mesh: Mesh) -> bool:
-    """Pallas interpret-mode flag for the lanes paths, resolved EAGERLY
-    off the MESH's device platform (CPU meshes — tests, dryruns — have
-    no Mosaic lowering, even when the host's default backend is a TPU).
-    False for every other path so it never splits their jit cache."""
-    from uda_tpu.ops.sort import LANES_ENGINES
-
-    return (payload_path in LANES_ENGINES
+    """Pallas interpret-mode flag for the Pallas engines, resolved
+    EAGERLY off the MESH's device platform (CPU meshes — tests, dryruns
+    — have no Mosaic lowering, even when the host's default backend is
+    a TPU). False for "carry" so it never splits its jit cache."""
+    return (payload_path != "carry"
             and mesh.devices.flat[0].platform == "cpu")
-
-
-def _resolve_payload_path(path: str, wcols: int, num_keys: int,
-                          n_rows: int = 0) -> str:
-    """route_engine with the lanes engines admitted, and one repair of
-    its answer: on a TPU, "auto" never yields "carrychunk" here — the
-    built-in default, a cached winner and the small-batch steering all
-    land on "lanes" instead. Inside the fused shard_map program
-    carrychunk's variadic sorts were still in XLA's compiler after
-    1,280 s on a four-chip v5e host (chip run of 2026-09-26, 2^22
-    records per chip; the kill left the chip unresponsive), where the
-    lanes engine compiled and passed on both meshes in 44 s and 158 s
-    all told — a default has to start. An EXPLICIT "carrychunk" is
-    still honored. A record too wide for the 32-row lanes layout fails
-    loudly in _sort_valid_rows_lanes, which names the explicit
-    alternative. ``n_rows`` is the GLOBAL row count — per-device shards
-    are smaller, so the small-batch steering (route_engine) is
-    conservative: a globally-small batch is certainly small per device.
-    ``wcols``/``num_keys`` stay in the signature for that error path's
-    callers."""
-    del wcols, num_keys  # the lanes body checks the width itself
-    from uda_tpu.ops.sort import route_engine
-
-    engine = route_engine(n_rows, path, lanes_ok=True)
-    if (path == "auto" and engine == "carrychunk"
-            and jax.default_backend() == "tpu"):
-        return "lanes"
-    return engine
 
 
 def uniform_splitters(num_partitions: int) -> np.ndarray:
@@ -132,17 +103,6 @@ class DistributedSortResult:
                 "raise capacity or use the multi-round path")
 
 
-def _vma_check_on(payload_path: str, interpret: bool) -> bool:
-    """shard_map varying-manual-axes checker gate: ON everywhere except
-    lanes engines under INTERPRET mode (the Pallas interpreter's grid
-    machinery mis-types; scripts/repro_check_vma.py is the committed
-    repro — the compiled path traces clean since the _pass_splits carry
-    pcast)."""
-    from uda_tpu.ops.sort import LANES_ENGINES
-
-    return not (payload_path in LANES_ENGINES and interpret)
-
-
 def _sort_valid_rows(flat, valid, num_keys, payload_path, interpret=False):
     """Stable local sort of ``flat``'s rows by the first ``num_keys``
     columns, with ``valid``-masked rows forced past every real key (the
@@ -152,67 +112,31 @@ def _sort_valid_rows(flat, valid, num_keys, payload_path, interpret=False):
     (ops.pallas_sort.sort_lanes) — bounded compile (two Mosaic kernels
     regardless of n and width) AND streaming payload movement; the TPU
     default. "keys8": same pipeline on an 8-row keys-only view plus one
-    global XLA payload gather (see _sort_valid_rows_lanes). "lanes2":
-    the in-kernel two-phase variant (needs Mosaic dynamic-gather
-    lowering). The (masked keys, invalid flag) sort key rides as lanes
-    rows, stability via the pipeline's arrival tie-break, so equal-key
-    order is IDENTICAL to the lax.sort paths below. "carry": all record
-    columns ride the sort network (fast runtime, but XLA variadic-sort
-    compile time grows superlinearly in operand count — minutes on the
-    TPU). "gather": a narrow sort computes the permutation and
-    per-column gathers on [n] arrays apply it. "gather2": the same
-    narrow-sort permutation applied with ONE minor-dim gather on the
-    transposed [W, n] view instead — deliberately trading layouts; the
-    faster of the two is backend-dependent and bench.py's fly-off
-    measures it. "carrychunk": the same permutation applied with NO
-    gathers at all — inverted via a 2-operand sort and re-applied in
-    narrow carry-sort chunks (ops.sort.apply_perm_chunked), every sort
-    far below the operand count where compile blows up."""
-    from uda_tpu.ops.sort import LANES_ENGINES
-
-    n, wcols = flat.shape
-    if payload_path in LANES_ENGINES:
-        return _sort_valid_rows_lanes(
-            flat, valid, num_keys, interpret,
-            two_phase=payload_path == "lanes2",
-            keys8=payload_path in ("keys8", "keys8f"),
-            folded=payload_path == "keys8f")
+    global XLA payload gather (see _sort_valid_rows_lanes). The (masked
+    keys, invalid flag) sort key rides as lanes rows, stability via the
+    pipeline's arrival tie-break, so equal-key order is IDENTICAL to
+    the lax.sort path below. "carry": all record columns ride the sort
+    network (XLA variadic-sort compile time grows superlinearly in
+    operand count — minutes on the TPU); the CPU default."""
+    if payload_path != "carry":
+        return _sort_valid_rows_lanes(flat, valid, num_keys, interpret,
+                                      keys8=payload_path == "keys8")
     keycols = tuple(jnp.where(valid, flat[:, i], _INVALID)
                     for i in range(num_keys))
     invalid_last = jnp.where(valid, 0, 1)
-    if payload_path == "carry":
-        payload = tuple(flat[:, i] for i in range(wcols))
-        sorted_ops = lax.sort(
-            (*keycols, invalid_last, *payload),
-            num_keys=num_keys + 1, is_stable=True)
-        return jnp.stack(sorted_ops[num_keys + 1:], axis=1)
-    row = jnp.arange(n, dtype=jnp.int32)
-    *_, perm = lax.sort((*keycols, invalid_last, row),
-                        num_keys=num_keys + 1, is_stable=True)
-    if payload_path == "gather2":
-        # one minor-dim gather of all columns at once (vs "gather"'s
-        # per-column takes) — same permutation, same output
-        return jnp.take(flat.T, perm, axis=1,
-                        unique_indices=True, mode="clip").T
-    if payload_path == "carrychunk":
-        # gather-free permutation apply (ops.sort.apply_perm_chunked)
-        from uda_tpu.ops.sort import apply_perm_chunked
-
-        cols = apply_perm_chunked(perm,
-                                  [flat[:, i] for i in range(wcols)])
-        return jnp.stack(cols, axis=1)
-    return jnp.stack(tuple(jnp.take(flat[:, i], perm, axis=0)
-                           for i in range(wcols)), axis=1)
+    payload = tuple(flat[:, i] for i in range(flat.shape[1]))
+    sorted_ops = lax.sort((*keycols, invalid_last, *payload),
+                          num_keys=num_keys + 1, is_stable=True)
+    return jnp.stack(sorted_ops[num_keys + 1:], axis=1)
 
 
-def _sort_valid_rows_lanes(flat, valid, num_keys, interpret,
-                           two_phase=False, keys8=False, folded=False):
+def _sort_valid_rows_lanes(flat, valid, num_keys, interpret, keys8=False):
     """Lanes-path body of _sort_valid_rows: pack rows into the [32, n]
     lanes layout with sort key (masked key words, invalid flag), pad the
     lane count to a power of two with +inf-key lanes, run the Pallas
     pipeline, unpack the payload rows.
 
-    Order parity with the lax.sort paths: identical sort key, and the
+    Order parity with the lax.sort path: identical sort key, and the
     pipeline's arrival-index tie-break == their stable row order. The
     padding lanes share the invalid rows' (+inf, 1) key but have LARGER
     arrival indices than every real lane, so they sort strictly after
@@ -243,31 +167,24 @@ def _sort_valid_rows_lanes(flat, valid, num_keys, interpret,
             raise ValueError(
                 f"num_keys={num_keys} does not fit the 8-row keys view; "
                 "use payload_path='lanes'")
-        if folded and k8 > 3:
-            raise ValueError(
-                f"keys8f needs num_keys <= 2 here (keys + invalid flag "
-                f"must fit the folded 4-row slot); got {num_keys} — use "
-                "payload_path='keys8'")
         base = jnp.full((k8, npad), _INVALID, jnp.uint32)
         keyr = lax.dynamic_update_slice(base, keyrows, (0, 0))
         # the n real lanes sort strictly before the padding, so the
         # first n arrival indices all reference real rows of flat
         _, perm = pallas_sort.keys8_sort_perm(keyr, tile=tile,
-                                              interpret=interpret,
-                                              folded=folded)
+                                              interpret=interpret)
         return jnp.take(flat.T, perm[:n], axis=1,
                         unique_indices=True, mode="clip").T
     if first_pay + wcols > tb:
         raise ValueError(
             f"record width {wcols} + {num_keys} keys does not fit the "
             f"{pallas_sort.ROWS}-row lanes layout; use payload_path="
-            "'gather'")
+            "'keys8'")
     mat = jnp.full((pallas_sort.ROWS, npad), _INVALID, jnp.uint32)
     mat = lax.dynamic_update_slice(mat, keyrows, (0, 0))
     mat = lax.dynamic_update_slice(mat, flat.T, (first_pay, 0))
     out = pallas_sort.sort_lanes(mat, num_keys=num_keys + 1, tb_row=tb,
-                                 tile=tile, interpret=interpret,
-                                 two_phase=two_phase)
+                                 tile=tile, interpret=interpret)
     return out[first_pay:first_pay + wcols, :n].T
 
 
@@ -278,19 +195,17 @@ def _sort_valid_rows_lanes(flat, valid, num_keys, interpret,
 def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
                payload_path="carry", interpret=False,
                exchange_mode="flat", dcn_axis=None, ici_axis=None):
-    # check_vma now runs on the REAL lanes path too: the merge-pass
-    # fori_loop carry is pcast to the data's vma at init
-    # (ops/pallas_sort.py _pass_splits), which was the only mis-typing
-    # in our own code — all four lanes engines trace clean with
-    # check_vma=True and interpret=False (r5; previously bypassed
-    # wholesale). The one REMAINING bypass is interpret mode: the
+    # check_vma is ON everywhere except interpret mode (which only the
+    # Pallas engines on a CPU mesh ever set, _lanes_interpret): the
     # Pallas interpreter expands pallas_call into eval_jaxpr whose
     # grid-machinery dynamic_slice mixes replicated block indices with
     # varying operands — an emulator limitation, not a property of the
-    # compiled kernel (minimal repro: scripts/repro_check_vma.py).
+    # compiled kernel (minimal repro: scripts/repro_check_vma.py). The
+    # compiled path traces clean since the merge-pass fori_loop carry
+    # is pcast to the data's vma at init (ops/pallas_sort._pass_splits).
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P()),
              out_specs=(P(axis), P(axis), P(axis)),
-             check_vma=_vma_check_on(payload_path, interpret))
+             check_vma=not interpret)
     def _go(w, spl):
         from uda_tpu.parallel.exchange import run_round_body
 
@@ -355,12 +270,10 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     plan approves — so ``multiround="always"`` is the fully-coded
     entry and the auto overflow re-run inherits it.
     ``capacity``: per-(src, dst) records per round — the credit window.
-    ``payload_path``: how the local sort moves value columns ("auto":
-    operand-carry on CPU, the Pallas "lanes" pipeline on TPU — the
-    engine the four-chip run proved inside this program, see
-    _resolve_payload_path; the other lanes engines, "carrychunk" and
-    the gather paths stay available explicitly — see _sort_valid_rows
-    for the trade-offs).
+    ``payload_path``: the local sort's engine, one of
+    ops.sort.SORT_PATHS or "auto" (ops.sort.resolve_sort_path:
+    operand-carry on CPU, the Pallas "lanes" pipeline on TPU; see
+    _sort_valid_rows for the trade-offs).
     ``multiround``: skew completion policy. "auto" (default) runs the
     fused single-round program and, if any (src, dst) bucket overflowed
     the credit window, re-runs the shuffle through the windowed
@@ -373,8 +286,7 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     from uda_tpu.parallel.exchange import (exchange_dispatch,
                                            resolve_exchange_mode)
 
-    payload_path = _resolve_payload_path(payload_path, int(words.shape[1]),
-                                         num_keys, int(words.shape[0]))
+    payload_path = resolve_sort_path(payload_path)
     if multiround not in ("auto", "never", "always"):
         raise ValueError(f"unknown multiround policy {multiround!r}")
     topo, hier, _coded = resolve_exchange_mode(mesh, axis, exchange_mode)
@@ -447,10 +359,10 @@ def _sort_shard(acc, nvalid, mesh, axis, num_keys, payload_path,
     valid flag) reproduces exactly the fused single-round program's
     equal-key order."""
 
-    # same interpret-mode-only checker gate as _sort_step
+    # same interpret-mode-only checker exception as _sort_step
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P(axis)),
              out_specs=P(axis),
-             check_vma=_vma_check_on(payload_path, interpret))
+             check_vma=not interpret)
     def _go(a, nv):
         row = jnp.arange(a.shape[0], dtype=jnp.int32)
         return _sort_valid_rows(a, row < nv[0], num_keys, payload_path,
@@ -483,8 +395,7 @@ def distributed_sort_multiround(words, splitters, mesh: Mesh, axis: str,
     from uda_tpu.parallel.planner import (plan_layout_rounds,
                                           record_plan_skips)
 
-    payload_path = _resolve_payload_path(payload_path, int(words.shape[1]),
-                                         num_keys, int(words.shape[0]))
+    payload_path = resolve_sort_path(payload_path)
     p = int(np.prod(list(mesh.shape.values())))
     spec = NamedSharding(mesh, P(axis))
     words = put_rows(words, mesh, axis)

@@ -19,8 +19,8 @@ Where the cache lives is decided HERE and nowhere else:
 
 ``enable()`` is idempotent, cheap, and never initializes a backend;
 every uda_tpu entry point calls it (``UdaBridge.start``,
-``MergeManager.__init__``, ``bench.py``, ``__graft_entry__``,
-``chip_smoke.py``'s children, ``tests/conftest.py``).
+``MergeManager.__init__``, ``__graft_entry__``, ``chip_smoke.py``'s
+children, ``tests/conftest.py``).
 """
 
 from __future__ import annotations

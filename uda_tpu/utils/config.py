@@ -312,18 +312,13 @@ _FLAG_LIST = [
          "metric label"),
     # --- online tuning cache (utils/tuncache.py) ------------------------
     Flag("uda.tpu.tune.cache.path", "", str,
-         "persisted per-(key-shape, platform, backend) fly-off winner "
-         "table (JSON) consulted by ops.sort.route_engine and the "
-         "batched-I/O plane's parameters; populated by "
+         "persisted per-platform probe winner table (JSON) consulted "
+         "by the batched-I/O plane's parameters; populated by "
          "scripts/tune_probe.py. Corrupt/truncated/version-bumped "
-         "files are ignored (tune.cache.invalid), never fatal; "
-         "env-var winners (UDA_TPU_SORT_PATH) still override the "
-         "cache. Setting this explicitly also installs the path as "
-         "the PROCESS-default cache (tuncache.set_default_cache) so "
-         "config-less consumers like route_engine consult the same "
-         "table — unless UDA_TPU_TUNE_CACHE is set, which always "
-         "wins. empty = UDA_TPU_TUNE_CACHE env, else no cache "
-         "(today's built-in defaults)"),
+         "files are ignored (tune.cache.invalid), never fatal; an "
+         "explicitly set read flag still overrides the cache. "
+         "empty = UDA_TPU_TUNE_CACHE env, else no cache "
+         "(the built-in defaults)"),
     Flag("uda.tpu.tune.reprobe.s", 0.0, float,
          "tuning-cache staleness horizon in seconds: an entry older "
          "than this is re-measured by the background re-probe rung "

@@ -16,8 +16,9 @@ records/s, retry rate), and emits
 The final record (``"final": true``, emitted by ``stop()`` or the
 bridge's ``reduce_exit``) carries the reference-parity per-task trio
 ``total_wait_mem_time`` / ``total_fetch_time`` / ``total_merge_time``
-plus histogram p50/p95/p99 summaries — the same block ``bench.py``
-embeds in its JSON output (``telemetry_block``).
+plus histogram p50/p95/p99 summaries — the same block
+``telemetry_block`` returns (the bridge's ``telemetry`` call, the
+tenant bench's JSON output).
 
 JSON-lines schema (one object per line)::
 

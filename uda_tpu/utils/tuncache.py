@@ -1,27 +1,21 @@
-"""Online tuning cache: persisted per-(key-shape, platform, backend)
-fly-off winners that make routing self-service.
+"""Online tuning cache: persisted per-(platform, backend) probe
+winners that make tuned parameters self-service.
 
-The repo's tuned constants were all hand-deployed sweep results:
-``UDA_TPU_SORT_PATH``/``UDA_TPU_CHUNK_COLS`` carry a fly-off winner to
-every call site via the environment, and thresholds like
-``SMALL_BATCH_ROWS`` or the ``CC_LADDER`` crossovers are literals from
-one measured host. This module is the Exoshuffle posture applied to
-that machinery (arXiv:2203.05072 — shuffle policy should adapt
-per-workload, not be baked in): a small persisted winner table
+A small persisted winner table (the Exoshuffle posture,
+arXiv:2203.05072 — shuffle policy should adapt per-workload, not be
+baked in):
 
-- **written** by seeded fly-off probes (``scripts/tune_probe.py``,
-  riding the bench_pipeline/net_bench harness pattern; any in-process
-  probe can call :meth:`TuneCache.record` too),
-- **consulted** by ``ops.sort.route_engine`` (engine choice per
-  (backend, row-bucket, lanes-capability)) and by the batched host-I/O
-  plane (``mofserver/data_engine.py``: batch on/off, coalesce gap,
-  backend rung),
+- **written** by seeded probes (``scripts/tune_probe.py``; any
+  in-process probe can call :meth:`TuneCache.record` too),
+- **consulted** by the batched host-I/O plane
+  (``mofserver/data_engine.py``: batch on/off, coalesce gap, backend
+  rung),
 - **refreshed** by a background re-probe rung: entries older than
   ``uda.tpu.tune.reprobe.s`` are re-measured by a registered probe on
   a daemon thread (:func:`ensure_fresh`) or by
   ``tune_probe.py --reprobe-age``.
 
-Precedence is strict and tested: **explicit env/config winner > cached
+Precedence is strict and tested: **explicit config value > cached
 winner > built-in default**. A cold cache is byte-for-byte today's
 defaults; a corrupt, truncated or version-bumped cache file is ignored
 (counted ``tune.cache.invalid``), never fatal — losing the cache must
@@ -33,12 +27,13 @@ File format (JSON, atomic tmp+rename writes)::
         "<domain>|<key>": {"winner": {...}, "metric": <float|null>,
                            "probed_unix": <float>, "probe": "<name>"}}}
 
-``domain`` names the consumer contract (``sort.engine``, ``io.read``);
-``key`` encodes the shape/platform/backend coordinates the consumer
-can cheaply reproduce at lookup time (e.g.
-``cpu|rows20|lanes1``). ``winner`` is an opaque dict the consumer
-validates — a cache can never force an invalid engine name or knob
-value onto a caller (validation failures count as misses).
+``domain`` names the consumer contract (``io.read``); ``key`` encodes
+the platform coordinates the consumer can cheaply reproduce at lookup
+time (``sys.platform``). ``winner`` is an opaque dict the consumer
+validates — a cache can never force an invalid knob value onto a
+caller (validation failures count as misses). Entries of a domain no
+consumer reads (a file written by an older deployment) load and are
+never looked up.
 """
 
 from __future__ import annotations
@@ -53,8 +48,7 @@ from uda_tpu.utils.logging import get_logger
 from uda_tpu.utils.metrics import metrics
 
 __all__ = ["TuneCache", "tune_cache", "cache_path_from_env",
-           "register_probe", "ensure_fresh", "rows_bucket",
-           "SCHEMA_VERSION"]
+           "register_probe", "ensure_fresh", "SCHEMA_VERSION"]
 
 log = get_logger()
 
@@ -68,20 +62,13 @@ def cache_path_from_env() -> str:
     return os.environ.get("UDA_TPU_TUNE_CACHE", "").strip()
 
 
-def rows_bucket(n_rows: int) -> int:
-    """Shape-class key for row counts: the power-of-two bucket
-    (bit_length), so one probed winner covers its whole size class
-    instead of one exact row count."""
-    return max(0, int(n_rows)).bit_length()
-
-
 class TuneCache:
     """One winner table bound to one file path (``path=''`` = a purely
     in-memory table: lookups miss until something records).
 
-    Reads are cached per (path, mtime): route_engine sits on production
-    sort surfaces, so a lookup is a dict access, not a file parse —
-    the file is re-read only when another process replaced it."""
+    Reads are cached per (path, mtime): a lookup is a dict access, not
+    a file parse — the file is re-read only when another process
+    replaced it."""
 
     def __init__(self, path: str = ""):
         self.path = path or ""
@@ -175,7 +162,7 @@ class TuneCache:
     def record(self, domain: str, key: str, winner: dict,
                metric: Optional[float] = None,
                probe: str = "") -> None:
-        """Persist one fly-off winner (atomic write; merges with the
+        """Persist one probe winner (atomic write; merges with the
         entries already on disk so concurrent probes of different
         domains don't clobber each other)."""
         rec = {"winner": dict(winner), "metric": metric,
@@ -206,36 +193,16 @@ class TuneCache:
             return {k: dict(v) for k, v in self._entries.items()}
 
 
-# The process-default cache (UDA_TPU_TUNE_CACHE): what config-less
-# consumers (ops.sort.route_engine) consult. Consumers holding a
-# Config with uda.tpu.tune.cache.path set read their own instance AND
-# install the path as the process default via set_default_cache, so
-# one explicitly-configured engine makes the whole process
-# self-service — the env var always wins.
+# The process-default cache (UDA_TPU_TUNE_CACHE): what a consumer with
+# no ``uda.tpu.tune.cache.path`` configured consults.
 tune_cache = TuneCache(cache_path_from_env())
-
-
-def set_default_cache(path: str) -> TuneCache:
-    """Install ``path`` as the process-default cache — unless
-    UDA_TPU_TUNE_CACHE is set (the env channel outranks config, like
-    every deploy override). Called by DataEngine when
-    ``uda.tpu.tune.cache.path`` is explicitly configured, so
-    route_engine (which has no Config in scope) consults the same
-    table. Returns the instance now serving the path (consumers that
-    read the module attribute at call time pick it up immediately)."""
-    global tune_cache
-    if not path or cache_path_from_env():
-        return tune_cache
-    if path != tune_cache.path:
-        tune_cache = TuneCache(path)
-    return tune_cache
 
 
 # -- background re-probe rung -------------------------------------------------
 # A consumer that wants its winner tracked against hardware drift
 # registers a probe callable; ensure_fresh() then re-measures a stale
 # entry on a single daemon thread (at most one re-probe in flight per
-# process — routing hot paths must never block on a fly-off).
+# process — a consumer's hot path must never block on a probe).
 
 _PROBES: Dict[str, Callable[[str], None]] = {}
 _REPROBE_MU = threading.Lock()
@@ -254,8 +221,7 @@ def ensure_fresh(cache: TuneCache, domain: str, key: str,
     """Kick a background re-probe when the entry exists but is older
     than ``max_age_s`` (0/negative = never re-probe). Non-blocking;
     the CURRENT lookup keeps the stale winner — the refreshed one
-    lands for later consumers (the fly-off generalized into an online
-    autotuner, ROADMAP item 5)."""
+    lands for later consumers."""
     global _REPROBE_ACTIVE
     if max_age_s <= 0:
         return
@@ -276,7 +242,7 @@ def ensure_fresh(cache: TuneCache, domain: str, key: str,
             metrics.add("tune.reprobes")
             fn(key)
         except Exception as e:  # noqa: BLE001 - a failed re-probe must
-            # never surface into the routing caller; the stale winner
+            # never surface into the consulting caller; the stale winner
             # keeps serving
             metrics.add("errors.swallowed")
             log.warn(f"tune re-probe of {domain}|{key} failed: {e}")
