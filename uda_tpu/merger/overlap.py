@@ -89,7 +89,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from uda_tpu.ops import merge as merge_ops
-from uda_tpu.ops import packing
 from uda_tpu.utils.budget import FOREST_FACTOR
 from uda_tpu.utils.comparators import KeyType, uses_default_bytewise
 from uda_tpu.utils.errors import MergeError
@@ -309,6 +308,7 @@ class OverlappedMerger:
         # reads 0, where a program without host classes reads nothing
         metrics.add("merge.device_runs", 0)
         metrics.add("merge.host_merges", 0)
+        metrics.add("stage.native_segments", 0)
         metrics.declare_timer("merge_host_batch")
         self.pipeline = bool(pipeline)
         self._consumer_thread: Optional[threading.Thread] = None
@@ -615,10 +615,8 @@ class OverlappedMerger:
         n = batch.num_records
         if n == 0:
             return
-        with metrics.timer("overlap_pack"):
-            packed = packing.pack_keys(batch, self.key_type, self.width)
-        kw = packed.key_words.shape[1]
-        if int(np.max(packed.key_lens, initial=0)) > self.width:
+        rows, lease, _, longest, _ = self._stage_rows(seg_index, batch)
+        if longest > self.width:
             # oversize keys: same posture as _prepare — disable the fast
             # path; finish_streaming's comparator k-way file merge (which
             # reads this adopted run file) is the correctness fallback
@@ -627,12 +625,33 @@ class OverlappedMerger:
             self._staged += 1
         metrics.add("merge.records", n)
         if self._overflow or not self.device_runs:
+            self._release_rows(lease)
             return
-        cap = self._staged_capacity(n)
-        rows = np.empty((cap, kw + merge_ops.ROW_EXTRA_COLS), np.uint32)
-        merge_ops.fill_run_rows(rows, packed, None, seg_index)
-        self._consume_run(_StagedRun(seg_index, rows, n, None,
+        self._consume_run(_StagedRun(seg_index, rows, n, lease,
                                      time.perf_counter(), 0))
+
+    def _stage_rows(self, seg_index: int, batch: RecordBatch):
+        """The key work of staging one non-empty segment, under the
+        ``overlap_pack`` timer: a row buffer (pool-leased when there is
+        a pool) filled by ``ops.merge.stage_run_rows`` — one native
+        pass, or the numpy passes. Returns ``(rows, lease, presorted,
+        longest, nbytes)``; the caller owns the lease, which goes home
+        here if the fill raises (a leaked lease pins staging budget
+        forever, and the abort drain asserts the pool is whole)."""
+        cap = self._staged_capacity(batch.num_records)
+        rows, lease = self._host_rows(
+            cap, self.width // 4 + merge_ops.ROW_EXTRA_COLS)
+        try:
+            with metrics.timer("overlap_pack"):
+                return (rows, lease) + merge_ops.stage_run_rows(
+                    rows, batch, self.key_type, self.width, seg_index)
+        except BaseException:
+            self._release_rows(lease)
+            raise
+
+    def _release_rows(self, lease) -> None:
+        if lease is not None:
+            self._buf_pool.release(lease)
 
     def _prepare(self, seg_index: int, source,
                  fed_t: float) -> Optional[_StagedRun]:
@@ -650,66 +669,57 @@ class OverlappedMerger:
             if streaming:
                 self._release(source)
             return None
-        with metrics.timer("overlap_pack"):
-            packed = packing.pack_keys(batch, self.key_type, self.width)
-        kw = packed.key_words.shape[1]
-        metrics.add("stage.bytes",
-                    int(batch.key_len.sum() + batch.val_len.sum()))
-        if int(np.max(packed.key_lens, initial=0)) > self.width:
-            # rank-bearing keys: cross-run rank consistency needs the
-            # global view; disable the fast path (see module docstring)
-            self._overflow = True
-            if not streaming:
+        rows, lease, presorted, longest, nbytes = self._stage_rows(
+            seg_index, batch)
+        kept = False  # whether the forest takes the rows (and the lease)
+        try:
+            metrics.add("stage.bytes", nbytes)
+            if longest > self.width:
+                # rank-bearing keys: cross-run rank consistency needs
+                # the global view; disable the fast path (see module
+                # docstring)
+                self._overflow = True
+                if not streaming:
+                    return None
+                # streaming keeps spooling: this run is ordered by the
+                # FULL comparator, so finish falls back to the
+                # comparator-level k-way merge over the run files —
+                # still O(window) host memory
+                order = self._overflow_order(batch, n)
+                self.run_store.write_run(seg_index, batch, order)
+                with self._state_lock:
+                    self._staged += 1
+                metrics.add("merge.records", n)
+                self._notify_spool(seg_index)
+                self._observe_wait(fed_t)
+                self._release(source)
                 return None
-            # streaming keeps spooling: this run is ordered by the FULL
-            # comparator, so finish falls back to the comparator-level
-            # k-way merge over the run files — still O(window) host
-            # memory
-            order = self._overflow_order(batch, n)
-            self.run_store.write_run(seg_index, batch, order)
+            # per-segment sort on host key order: Hadoop map outputs
+            # arrive ALREADY comparator-sorted (the map-side sort
+            # contract), and for within-width keys comparator order ==
+            # (words, len) order, so the O(n·k) monotonicity check
+            # usually replaces the O(n log n) sort — the staging hot
+            # path collapses to pack+spool at memory bandwidth. Unsorted
+            # input (exchange-path buckets, foreign writers) was sorted
+            # as its rows were filled: their row-index column is the
+            # order.
+            if streaming:
+                spool_order = (np.arange(n, dtype=np.int64) if presorted
+                               else rows[:n, -1].astype(np.int64))
+                self.run_store.write_run(seg_index, batch, spool_order)
+                self._release(source)
+                self._notify_spool(seg_index)
             with self._state_lock:
                 self._staged += 1
             metrics.add("merge.records", n)
-            self._notify_spool(seg_index)
-            self._observe_wait(fed_t)
-            self._release(source)
-            return None
-        # per-segment sort on host key order: Hadoop map outputs arrive
-        # ALREADY comparator-sorted (the map-side sort contract), and
-        # for within-width keys comparator order == (words, len) order,
-        # so the O(n·k) monotonicity check usually replaces the
-        # O(n log n) lexsort (run_row_order) — the staging hot path
-        # collapses to pack+spool at memory bandwidth. Unsorted input
-        # (exchange-path buckets, foreign writers) still sorts.
-        order = merge_ops.run_row_order(packed)
-        if streaming:
-            spool_order = (np.arange(n, dtype=np.int64) if order is None
-                           else order)
-            self.run_store.write_run(seg_index, batch, spool_order)
-            self._release(source)
-            self._notify_spool(seg_index)
-        with self._state_lock:
-            self._staged += 1
-        metrics.add("merge.records", n)
-        if self._overflow or not self.device_runs:
-            self._observe_wait(fed_t)
-            return None  # forest output won't be consumed; runs suffice
-        cap = self._staged_capacity(n)
-        if self._buf_pool is not None:
-            lease = self._buf_pool.lease(cap, kw + merge_ops.ROW_EXTRA_COLS)
-            try:
-                merge_ops.fill_run_rows(lease, packed, order, seg_index)
-                return _StagedRun(seg_index, lease, n, lease, fed_t, 0)
-            except BaseException:
-                # a packing failure (bad order vector, width drift)
-                # must not strand the host buffer: the abort drain
-                # asserts the pool is whole, and a leaked lease pins
-                # staging budget forever
-                self._buf_pool.release(lease)
-                raise
-        rows = np.empty((cap, kw + merge_ops.ROW_EXTRA_COLS), np.uint32)
-        merge_ops.fill_run_rows(rows, packed, order, seg_index)
-        return _StagedRun(seg_index, rows, n, None, fed_t, 0)
+            if self._overflow or not self.device_runs:
+                self._observe_wait(fed_t)
+                return None  # forest output won't be consumed; runs suffice
+            kept = True
+            return _StagedRun(seg_index, rows, n, lease, fed_t, 0)
+        finally:
+            if not kept:
+                self._release_rows(lease)
 
     def _overflow_order(self, batch: RecordBatch, n: int) -> np.ndarray:
         """Full-comparator sort order for an oversize-key run. Default
@@ -783,8 +793,7 @@ class OverlappedMerger:
                     metrics.observe("merge.pipeline.put_ms",
                                     (time.perf_counter() - t0) * 1e3)
         finally:
-            if lease is not None:
-                self._buf_pool.release(lease)
+            self._release_rows(lease)
         metrics.add("merge.device_runs")
         return _Run(dev, valid, bucket)
 
@@ -911,8 +920,7 @@ class OverlappedMerger:
                 # task rather than sort in numpy
                 raise MergeError("native row merge went missing mid-task")
         except BaseException:
-            if lease is not None:
-                self._buf_pool.release(lease)
+            self._release_rows(lease)
             raise
         self._release_run(a)
         self._release_run(b)
@@ -1203,9 +1211,11 @@ class OverlappedMerger:
             raise MergeError("finish_streaming without a run store")
         acc = None
         try:
-            no_forest = self._overflow or not self.device_runs
             with metrics.timer("merge"):
                 self._drain()
+                # read the latch only now: a segment still being staged
+                # when finish was called may be the one that sets it
+                no_forest = self._overflow or not self.device_runs
                 acc = None if no_forest else self._merge_leftovers()
             total = store.total_records
             if expected_records is not None and total != expected_records:

@@ -26,7 +26,8 @@ from uda_tpu.utils.logging import get_logger
 __all__ = ["available", "build", "crack_native", "crack_partial_native",
            "decode_vlongs_native", "write_records_native", "frame_batch",
            "iter_framed_chunks", "ReadPool", "kway_supported",
-           "kway_merge_paths", "SegmentTable", "gather_slab_native"]
+           "kway_merge_paths", "SegmentTable", "gather_slab_native",
+           "stage_segment_native"]
 
 log = get_logger()
 
@@ -124,6 +125,14 @@ def _bind(lib):
     lib.uda_slab_copy.restype = None
     lib.uda_slab_copy.argtypes = slab + [i64p, i64p, ctypes.c_int64, u8p,
                                          i64p, i64p]
+    # data + size, key_off, key_len, val_len, n, key mode, key words,
+    # segment index, rows + capacity, out: stage_segment_native owns the
+    # layout checks
+    lib.uda_stage_segment.restype = ctypes.c_int64
+    lib.uda_stage_segment.argtypes = [
+        addr, ctypes.c_int64, addr, addr, addr, ctypes.c_int64,
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_uint32, addr,
+        ctypes.c_int64, i64p]
     return lib
 
 
@@ -487,6 +496,60 @@ def gather_slab_native(table: SegmentTable, seg: np.ndarray,
     lib.uda_slab_copy(*slab, _i64ptr(k_len), _i64ptr(v_len), out[0],
                       _u8ptr(buf), _i64ptr(k_off), _i64ptr(v_off))
     return RecordBatch(buf, k_off, k_len, v_off, v_len)
+
+
+_STAGE_ERRORS = {1: "empty serialized Text key",
+                 2: "BytesWritable key shorter than its length field",
+                 3: "key content outside the segment's data",
+                 4: "out of memory sorting the segment"}
+
+
+def stage_segment_native(batch: RecordBatch, kt, width: int, seg_index: int,
+                         rows: np.ndarray) -> Optional[tuple[bool, int, int]]:
+    """Stage one cracked segment in ONE C pass: ``rows`` (uint32
+    ``[cap >= n, width/4 + 3]``, written in place) gets the sorted
+    composite-key rows ``ops.merge.fill_run_rows`` builds from
+    ``pack_keys`` + ``run_row_order`` — byte-identical — and its tail
+    ``PAD_WORD``. Returns ``(presorted, longest content length,
+    key + value bytes)``: whether the segment arrived in (words, len)
+    order (if not, the rows were sorted and their row-index column is
+    the stable order vector), the overflow test's operand, and
+    ``stage.bytes``. A key longer than ``width`` is reported, not
+    ranked: the caller leaves the fast path as it always has and the
+    rows are dropped. A key type outside ``_KWAY_MODES`` packs its
+    serialized bytes, as ``packing.content_spans`` does. A malformed
+    key raises MergeError. Returns None when the library isn't
+    available."""
+    lib = _load()
+    if lib is None:
+        return None
+    if width % 4 != 0 or width <= 0:
+        raise MergeError(f"key width must be a positive multiple of 4, "
+                         f"got {width}")
+    data = np.ascontiguousarray(batch.data, np.uint8)
+    key_off, key_len, val_len = (
+        np.ascontiguousarray(c, np.int64)
+        for c in (batch.key_off, batch.key_len, batch.val_len))
+    n = key_off.shape[0]
+    if data.ndim != 1 or key_len.shape != (n,) or val_len.shape != (n,):
+        raise ValueError("ragged record columns")
+    # rows is written through its raw pointer: demand the layout outright
+    if (rows.dtype != np.uint32 or not rows.flags["C_CONTIGUOUS"]
+            or rows.ndim != 2 or rows.shape[1] != width // 4 + 3
+            or rows.shape[0] < n):
+        raise ValueError(f"row matrix must be contiguous uint32 "
+                         f"[>= {n}, {width // 4 + 3}], got {rows.dtype}"
+                         f"{list(rows.shape)}")
+    out = (ctypes.c_int64 * 4)()
+    rc = lib.uda_stage_segment(
+        data.ctypes.data, data.size, key_off.ctypes.data,
+        key_len.ctypes.data, val_len.ctypes.data, n,
+        _KWAY_MODES.get(kt.name, (0, 0))[0], width // 4, seg_index,
+        rows.ctypes.data, rows.shape[0], out)
+    if rc:
+        raise MergeError(f"segment {seg_index}: {_STAGE_ERRORS.get(rc, rc)} "
+                         f"at record {out[3]}")
+    return bool(out[0]), int(out[1]), int(out[2])
 
 
 def merge_rows_native(a: np.ndarray, b: np.ndarray) -> Optional[np.ndarray]:

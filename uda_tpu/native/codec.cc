@@ -9,9 +9,13 @@
 // pure-Python reference implementation these functions are parity-tested
 // against (tests/test_native.py).
 
+#include <algorithm>
 #include <cstdint>
 #include <cstddef>
 #include <cstring>
+#include <new>
+#include <numeric>
+#include <vector>
 
 #include "vlong.h"
 
@@ -212,6 +216,118 @@ void uda_slab_copy(const UdaSegment* table,
     kp += key_len[i];
     vp += val_len[i];
   }
+}
+
+}  // extern "C"
+
+// One-pass segment staging: everything the stage pool does to one
+// cracked segment's keys (uda_tpu/merger/overlap.py:_prepare) in ONE
+// call, so a stage worker gives up the interpreter lock once a segment
+// and not once per numpy pass. For every record: the key's comparable
+// content (key modes 0-3 of merge.cc / _KWAY_MODES), its first
+// 4 * key_words bytes zero padded as big-endian uint32 words (mode 3:
+// sign bit flipped), then content length, segment index and row index —
+// the composite-key row of ops.merge.fill_run_rows — written straight
+// into the row matrix, with the (words, length) order check and the
+// sums staging needs taken on the way. The numpy path (pack_keys,
+// run_row_order, fill_run_rows) is the fallback and the reference this
+// is parity-tested against (tests/test_stage_native.py).
+
+// Rows not in (words, length) order: sort them whole. The last column
+// is the row index, so rows are totally ordered and the result is the
+// stable (words, length) order np.lexsort gives; that column then IS
+// the order vector.
+static bool stage_sort_rows(uint32_t* rows, int64_t n, int64_t cols) {
+  try {
+    std::vector<uint32_t> idx((size_t)n);
+    std::iota(idx.begin(), idx.end(), 0u);
+    const std::vector<uint32_t> src(rows, rows + n * cols);
+    std::sort(idx.begin(), idx.end(), [&](uint32_t a, uint32_t b) {
+      return std::lexicographical_compare(
+          &src[a * cols], &src[a * cols] + cols,
+          &src[b * cols], &src[b * cols] + cols);
+    });
+    for (int64_t i = 0; i < n; ++i)
+      std::memcpy(rows + i * cols, &src[idx[(size_t)i] * cols],
+                  (size_t)cols * sizeof(uint32_t));
+    return true;
+  } catch (const std::bad_alloc&) {
+    return false;
+  }
+}
+
+extern "C" {
+
+enum : int64_t {
+  UDA_STAGE_EMPTY_TEXT = 1,   // serialized Text key of no bytes
+  UDA_STAGE_SHORT_BYTES = 2,  // BytesWritable key under its length field
+  UDA_STAGE_BAD_SPAN = 3,     // key content negative or outside the data
+  UDA_STAGE_NO_MEMORY = 4,    // no scratch to sort an unsorted segment
+};
+
+// rows is uint32[cap, key_words + 3], cap >= n; rows [n, cap) are set
+// to 0xFFFFFFFF (ops.merge.PAD_WORD). Returns 0 with out = {1 if the
+// segment arrived in (words, length) order, largest content length,
+// sum of key_len + val_len}, or a UDA_STAGE_* code with out[3] = the
+// record it was found at (rows are then unspecified).
+int64_t uda_stage_segment(const uint8_t* data, int64_t data_size,
+                          const int64_t* key_off, const int64_t* key_len,
+                          const int64_t* val_len, int64_t n,
+                          int32_t key_mode, int32_t key_words,
+                          uint32_t seg_index, uint32_t* rows, int64_t cap,
+                          int64_t out[4]) {
+  const int64_t kw = key_words, cols = kw + 3, width = 4 * kw;
+  int64_t longest = 0, bytes = 0;
+  bool sorted = true;
+  for (int64_t i = 0; i < n; ++i) {
+    out[3] = i;
+    int64_t off = key_off[i], len = key_len[i];
+    bytes += len + val_len[i];
+    if (key_mode == 1) {  // Text: skip the VInt length prefix
+      if (len < 1) return UDA_STAGE_EMPTY_TEXT;
+      if (off < 0 || off >= data_size) return UDA_STAGE_BAD_SPAN;
+      const int8_t first = (int8_t)data[off];
+      const int skip = first >= -112 ? 1
+                       : first >= -120 ? -111 - first : -119 - first;
+      off += skip;
+      len -= skip;
+    } else if (key_mode == 2) {  // BytesWritable: skip the 4-byte length
+      if (len < 4) return UDA_STAGE_SHORT_BYTES;
+      off += 4;
+      len -= 4;
+    }
+    const int64_t take = std::min(len, width);
+    if (len < 0 || off < 0 || take > data_size - off)
+      return UDA_STAGE_BAD_SPAN;
+    const uint8_t* k = data + off;
+    uint32_t* r = rows + i * cols;
+    for (int64_t w = 0; w < kw; ++w) {
+      const int64_t have = take - 4 * w;
+      uint32_t v = 0;
+      if (have >= 4) {
+        v = (uint32_t)k[4 * w] << 24 | (uint32_t)k[4 * w + 1] << 16 |
+            (uint32_t)k[4 * w + 2] << 8 | (uint32_t)k[4 * w + 3];
+      } else {
+        for (int64_t b = 0; b < have; ++b)
+          v |= (uint32_t)k[4 * w + b] << (24 - 8 * b);
+      }
+      r[w] = v;
+    }
+    if (key_mode == 3) r[0] ^= 0x80000000u;  // numeric order == memcmp order
+    r[kw] = (uint32_t)len;
+    r[kw + 1] = seg_index;
+    r[kw + 2] = (uint32_t)i;
+    if (len > longest) longest = len;
+    if (sorted && i)  // (words, length) of the row before against this one
+      sorted = !std::lexicographical_compare(r, r + kw + 1,
+                                             r - cols, r - cols + kw + 1);
+  }
+  if (!sorted && !stage_sort_rows(rows, n, cols)) return UDA_STAGE_NO_MEMORY;
+  std::fill(rows + n * cols, rows + cap * cols, 0xFFFFFFFFu);
+  out[0] = sorted;
+  out[1] = longest;
+  out[2] = bytes;
+  return 0;
 }
 
 }  // extern "C"

@@ -36,10 +36,11 @@ from typing import Iterator, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from uda_tpu import native
 from uda_tpu.ops import packing, sort
 from uda_tpu.ops.pallas_merge import merge_sorted_pair
 from uda_tpu.utils.comparators import KeyType
-from uda_tpu.utils.ifile import RecordBatch
+from uda_tpu.utils.ifile import RecordBatch, native_enabled
 from uda_tpu.utils.metrics import metrics
 from uda_tpu.utils.resledger import resledger
 
@@ -54,6 +55,7 @@ __all__ = ["merge_batches", "merge_batches_host", "merge_iter_host",
            "merge_batches_two_phase", "resolve_merge_mode",
            "resolve_run_engine", "resolve_native_rows_merge",
            "lex_cols_sorted", "run_row_order", "fill_run_rows",
+           "stage_run_rows",
            "merge_row_pair", "merge_split_point", "merge_rows_split_into",
            "RowBufferPool", "next_run_capacity", "pad_rows_to",
            "PAD_WORD", "MIN_RUN_CAPACITY", "ROW_EXTRA_COLS"]
@@ -100,9 +102,6 @@ def resolve_native_rows_merge():
     Resolved ONCE per consumer so a cold .so compiles before any merge
     runs under a forest lock (a make inside the lock would stall the
     whole staging pool)."""
-    from uda_tpu import native
-    from uda_tpu.utils.ifile import native_enabled
-
     if native_enabled() and native.build():
         return native.merge_rows_native
     return None
@@ -142,8 +141,6 @@ def merge_rows_split_into(a_rows: np.ndarray, b_rows: np.ndarray,
     see :func:`merge_split_point`. Returns False when the native
     library isn't built (caller falls back); single-part calls degrade
     to one plain native merge."""
-    from uda_tpu import native
-
     na, nb = int(a_rows.shape[0]), int(b_rows.shape[0])
     total = na + nb
     parts = max(1, min(int(parts), max(1, total)))
@@ -286,6 +283,41 @@ def fill_run_rows(rows: np.ndarray, packed: packing.PackedKeys,
     rows[:n, kw + 1] = np.uint32(seg_index)
     if rows.shape[0] > n:
         rows[n:] = PAD_WORD
+
+
+def stage_run_rows(rows: np.ndarray, batch: RecordBatch, kt: KeyType,
+                   width: int, seg_index: int) -> Tuple[bool, int, int]:
+    """One segment's staging work on its keys: fill ``rows`` (cap >= n,
+    width/4 + 3) with the segment's sorted composite-key rows and
+    PAD_WORD tail. Returns ``(presorted, longest, nbytes)``: whether
+    the segment arrived in (words, len) order — if not, the rows'
+    row-index column is the stable order vector — the longest key
+    content, and the key + value bytes.
+
+    With the native library (and ``uda.tpu.use.native`` on) this is ONE
+    C pass, ``native.stage_segment_native``, counted in
+    ``stage.native_segments``: a stage worker gives up the interpreter
+    lock once a segment, where the numpy path below — ``pack_keys``,
+    ``run_row_order``, ``fill_run_rows``, the fallback and the plain
+    reference, same bytes — gives it up ~50 times, and a pool of
+    workers staging small segments then spends its time handing the
+    lock around. A segment with a key longer than ``width`` leaves
+    ``rows`` unspecified on either path: such keys order by rank, which
+    a run alone cannot compute."""
+    if native_enabled():
+        staged = native.stage_segment_native(batch, kt, width, seg_index,
+                                             rows)
+        if staged is not None:
+            metrics.add("stage.native_segments")
+            return staged
+    packed = packing.pack_keys(batch, kt, width)
+    nbytes = int(batch.key_len.sum() + batch.val_len.sum())
+    longest = int(np.max(packed.key_lens, initial=0))
+    if longest > width:
+        return False, longest, nbytes
+    order = run_row_order(packed)
+    fill_run_rows(rows, packed, order, seg_index)
+    return order is None, longest, nbytes
 
 
 def merge_row_pair(a_rows, b_rows, a_valid: int, b_valid: int,

@@ -203,6 +203,13 @@ METRICS_REGISTRY: Dict[str, tuple] = {
     # -- counters: staging pipeline (merger/overlap stage pool) ----------
     "stage.bytes": ("counter", "record content bytes through the "
                                "staging path (pack + row build)"),
+    "stage.native_segments": ("counter", "segments staged by the one "
+                                         "native pass (ops.merge."
+                                         "stage_run_rows: pack, order "
+                                         "check or sort, row fill); short "
+                                         "of the task's non-empty segments "
+                                         "= segments that took the numpy "
+                                         "passes"),
     "stage.backpressure_events": ("counter", "feed() calls that blocked "
                                             "on the in-flight staging "
                                             "byte budget "
