@@ -97,8 +97,12 @@ def test_budget_defaults_resolve_lazily_and_from_config():
     [
         # in budget, under the hybrid crossover -> hybrid
         (10, 4096, 0, "hybrid", "budget.admitted"),
-        # in budget, over the crossover -> streaming (still admitted)
-        (600, 4096, 0, "streaming", "budget.admitted"),
+        # in budget (1.3 GB of rows + 4.3 GB of merge temporaries), over
+        # the crossover -> streaming (still admitted)
+        (600, 8192, 0, "streaming", "budget.admitted"),
+        # the rows alone fit, not beside the merge's temporaries ->
+        # streaming reroute (merged on the device in groups)
+        (600, 4096, 0, "streaming", "budget.rerouted"),
         # device working set over the HBM budget -> streaming reroute
         (1024, 512, 0, "streaming", "budget.rerouted"),
         # over the hard ceiling -> reject (FallbackSignal at the caller)
@@ -150,7 +154,7 @@ def test_auto_approach_over_hbm_budget_reroutes_to_streaming(tmp_path):
     engine = DataEngine(DirIndexResolver(str(tmp_path)))
     # pretend the partition is 1 GB against a 64 MB HBM budget: the
     # fast path would OOM, so routing must land on streaming and the
-    # merger must not stage any device run (bounded device)
+    # merger must hold no more than a group on the device at a time
     client = _FixedEstimateClient(engine, 1 << 30)
     cfg = Config({"mapred.netmerger.merge.approach": 0,
                   "uda.tpu.hbm.budget.mb": 64,
@@ -165,8 +169,10 @@ def test_auto_approach_over_hbm_budget_reroutes_to_streaming(tmp_path):
     adm = mm.last_admission
     assert adm is not None and adm.decision == "streaming" and adm.rerouted
     om = mm._active_overlap
-    assert om is not None and not om.device_runs
-    assert om.stats["device_merges"] == 0  # nothing staged on device
+    assert om is not None and om.device_runs
+    assert adm.cause == "hbm" and adm.group_rows == 1 << 17
+    assert om.stats["device_groups"] == 1   # four small runs: one group
+    assert metrics.get("budget.rerouted") == 1  # route's, not twice
     got = list(IFileReader(io.BytesIO(b"".join(blocks))))
     assert got == sorted(expected[0])
 
@@ -563,7 +569,8 @@ def test_memory_pressure_schedule_reroutes_not_crashes(tmp_path):
     finally:
         engine.stop()
     assert mm.last_admission.rerouted
-    assert not mm._active_overlap.device_runs
+    assert mm.last_admission.group_rows == 1 << 16
+    assert mm._active_overlap.stats["device_groups"] == 1
     got = list(IFileReader(io.BytesIO(b"".join(blocks))))
     import functools
     want = sorted(expected[0], key=functools.cmp_to_key(

@@ -217,13 +217,16 @@ def _budget(hbm_mb: int) -> MemoryBudget:
 
 
 def test_ledger_admits_a_lone_task_without_waiting():
-    budget = _budget(10)
-    est = 2 * MB                      # device estimate 4.32 MB of 10
+    budget = _budget(32)
+    est = 2 * MB          # 4.32 MB of rows + 16 MB of merge temporaries
     hold, reroute = budget.admit_device(est)
-    assert reroute is None and hold.nbytes == budget.device_bytes(est)
+    assert reroute is None
+    assert (hold.nbytes, hold.temp_bytes) == budget.device_need(est)
+    assert hold.nbytes == budget.device_bytes(est) and hold.temp_bytes > 0
     assert hbm_ledger.holders == 1
-    assert hbm_ledger.reserved_bytes == hold.nbytes
-    assert metrics.get_gauge("budget.hbm.reserved") == hold.nbytes
+    assert hbm_ledger.reserved_bytes == hold.nbytes + hold.temp_bytes
+    assert metrics.get_gauge("budget.hbm.reserved") \
+        == hold.nbytes + hold.temp_bytes
     hold.release()
     hold.release()                    # idempotent
     assert metrics.get("budget.waited") == 0
@@ -231,8 +234,9 @@ def test_ledger_admits_a_lone_task_without_waiting():
 
 
 def test_ledger_waits_when_the_sum_exceeds_the_budget_and_wakes_on_release():
-    budget = _budget(10)
-    est = 3 * MB                      # 6.48 MB each: two do not fit 10
+    budget = _budget(26)
+    est = 3 * MB      # 6.48 MB of rows each beside 16 MB of temporaries,
+    #                   booked once: one needs 22.5 MB, two 29 of the 26
     first, _ = budget.admit_device(est)
     admitted = threading.Event()
     second: list = []
@@ -249,7 +253,8 @@ def test_ledger_waits_when_the_sum_exceeds_the_budget_and_wakes_on_release():
     first.release()
     assert admitted.wait(10)
     t.join()
-    assert hbm_ledger.reserved_bytes == second[0].nbytes
+    assert hbm_ledger.reserved_bytes \
+        == second[0].nbytes + second[0].temp_bytes
     assert metrics.get("hbm_admit_time") >= 0.3
     assert metrics.gauge_peaks_snapshot()["reduce.tasks.live"] == 1
     second[0].release()
@@ -257,7 +262,7 @@ def test_ledger_waits_when_the_sum_exceeds_the_budget_and_wakes_on_release():
 
 
 def test_ledger_releases_on_exception_and_a_stopped_waiter_leaves_the_queue():
-    budget = _budget(10)
+    budget = _budget(26)
     est = 3 * MB
     with pytest.raises(RuntimeError):
         with budget.admit_device(est)[0]:
@@ -314,27 +319,50 @@ def test_ledger_is_first_come_first_served():
         ledger.reserve(11, budget)    # can never fit: the caller's bug
 
 
-def test_a_task_too_large_alone_takes_the_bounded_route_and_never_waits():
-    budget = _budget(10)
-    held, _ = budget.admit_device(4 * MB)     # the chip is nearly full
-    t0 = time.perf_counter()
-    hold, reroute = budget.admit_device(64 * MB)
-    assert time.perf_counter() - t0 < 1.0
+def test_a_task_too_large_alone_is_sized_into_groups_and_waits_its_turn():
+    """A task the chip cannot hold whole reserves what the largest
+    group the budget holds needs, first come first served like any
+    task: beside a live one that leaves no room it waits, and the live
+    one is never failed."""
+    from uda_tpu.utils.budget import (FOREST_FACTOR, MERGE_TEMP_ROW_BYTES,
+                                      group_capacity_rows)
+
+    budget = _budget(26)
+    held, _ = budget.admit_device(3 * MB)     # 22.5 of the 26 MB booked
+    admitted = threading.Event()
+    got: list = []
+
+    def big() -> None:
+        got.append(budget.admit_device(64 * MB))
+        admitted.set()
+
+    t = threading.Thread(target=big)
+    t.start()
+    assert not admitted.wait(0.4)             # parked behind the live task
+    assert metrics.get("budget.waited") == 1
+    assert hbm_ledger.holders == 1
+    held.release()                            # the live task finishes
+    assert admitted.wait(10)
+    t.join()
+    hold, reroute = got[0]
     assert reroute is not None and reroute.cause == "hbm"
     assert reroute.decision == "streaming" and reroute.rerouted
-    assert hold.nbytes == 0 and hbm_ledger.holders == 2
+    group = group_capacity_rows(26 * MB, budget.key_width)
+    assert reroute.group_rows == group == 1 << 16
+    assert hold.nbytes == FOREST_FACTOR * 32 * group
+    assert hold.temp_bytes == MERGE_TEMP_ROW_BYTES * group
+    assert hold.nbytes + hold.temp_bytes <= 26 * MB
+    assert hbm_ledger.reserved_bytes == hold.nbytes + hold.temp_bytes
     assert metrics.get("budget.rerouted") == 1
-    assert metrics.get("budget.waited") == 0
     hold.release()
-    held.release()
     _books_are_empty()
 
 
 def test_default_approach_reserves_and_reroutes_over_a_small_budget(tmp_path):
     """At the DEFAULT merge approach (1) a task reserves its device
     estimate from the ledger; one the chip cannot hold alone takes the
-    bounded-device streaming route — same bytes out, nothing staged to
-    the device, nothing left on the books."""
+    streaming route and is merged on the device in groups — same bytes
+    out, nothing left on the books."""
     expected = make_mof_tree(str(tmp_path), "jobL", 4, 1, 60, seed=3)
     want = sorted(expected[0])
     engine = DataEngine(DirIndexResolver(str(tmp_path)))
@@ -379,7 +407,10 @@ def test_default_approach_reserves_and_reroutes_over_a_small_budget(tmp_path):
         adm = mm.last_admission
         assert adm is not None and adm.cause == "hbm" and adm.rerouted
         om = mm._active_overlap
-        assert not om.device_runs and om.stats["device_merges"] == 0
+        assert om.device_runs and adm.group_rows == 1 << 17
+        # four runs of 60 rows fill a fraction of one group
+        assert om.stats["device_groups"] == 1 \
+            == metrics.get("merge.device_groups")
         assert metrics.get("budget.rerouted") == 1
         _books_are_empty()
     finally:
@@ -393,12 +424,14 @@ def test_a_stopped_manager_leaves_the_ledger_queue(tmp_path):
 
     make_mof_tree(str(tmp_path), "jobS", 2, 1, 20, seed=5)
     engine = DataEngine(DirIndexResolver(str(tmp_path)))
-    cfg = Config({"uda.tpu.hbm.budget.mb": 64,
+    cfg = Config({"uda.tpu.hbm.budget.mb": 200,
                   "uda.tpu.host.budget.mb": 1024})
 
     class Big(LocalFetchClient):
         def estimate_partition_bytes(self, job_id, mids, reduce_id):
-            return 20 * MB            # 43 MB of device bytes: one fits 64
+            # 43 MB of rows each and 128 MB of merge temporaries between
+            # them: one fits the 200 MB, two do not
+            return 20 * MB
 
     held, _ = MemoryBudget.from_config(cfg).admit_device(20 * MB)
     mm = MergeManager(Big(engine), KT, cfg)

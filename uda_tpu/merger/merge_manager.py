@@ -969,8 +969,9 @@ class MergeManager:
             #     with O(window) host memory: 10.24 GB: 579 s vs 866 s
             #     at a third of the RSS) — REGRESSION_cpu_
             #     x{,x}large_r05.json;
-            #   over the HBM/host budget -> streaming with bounded
-            #     device runs (the degradation, never an OOM);
+            #   over the HBM budget -> streaming, merged on the device
+            #     in budget-sized groups (never an OOM); over the host
+            #     budget -> streaming;
             #   over the hard ceiling (uda.tpu.budget.hard.mb) ->
             #     FallbackSignal BEFORE any fetch or allocation;
             #   unknown size -> streaming: bounded memory is the only
@@ -985,7 +986,8 @@ class MergeManager:
             # ckpt dir steers the auto policy away from hybrid
             adm = self.budget().route(
                 est, threshold,
-                prefer_streaming=bool(str(self.cfg.get("uda.tpu.ckpt.dir"))))
+                prefer_streaming=bool(str(self.cfg.get("uda.tpu.ckpt.dir"))),
+                segments=len(map_ids))
             self.last_admission = adm
             # admission decisions carry their STRUCTURED cause into the
             # black box — a post-mortem reads why the task took the
@@ -1013,47 +1015,43 @@ class MergeManager:
             segments = self.fetch_all(job_id, map_ids, reduce_id)
             merged = self.merge_segments(segments)
             return self.emit_framed(merged, consumer)
-        # the overlapped route builds the device row forest. Admission
-        # may already have rerouted here BECAUSE that forest would blow
-        # the HBM budget: then the streaming merger must not stage runs
-        # to the device at all — run files + bounded k-way merge
-        # instead ("streaming with bounded device runs")
+        # the overlapped route builds the device row forest. The chip
+        # is shared with every other live reduce task of this process
+        # (a node's reduce slots): reserve this task's device need in
+        # the chip-wide ledger BEFORE anything is staged — waiting here
+        # while the live tasks leave no room, like the reference's
+        # occupy_chunk. At every merge approach: the auto policy's
+        # route() sized the task against the whole chip, the ledger
+        # sizes it against what the live tasks leave
         adm = self.last_admission
-        bounded_device = (streaming and adm is not None
-                          and adm.cause == "hbm")
         # the in-flight staging cap clamps to a budget only when the
         # auto policy built one (stage_inflight_cap); the ledger's
         # budget below must not move it
         cap_budget = self._budget_obj
-        # the chip is shared with every other live reduce task of this
-        # process (a node's reduce slots): reserve this task's device
-        # estimate in the chip-wide ledger BEFORE anything is staged —
-        # waiting here while the live tasks leave no room, like the
-        # reference's occupy_chunk. At every merge approach: the auto
-        # policy's route() sized the task against the whole chip, the
-        # ledger sizes it against what the live tasks leave
-        est = None
         if adm is not None:
             est = adm.estimate_bytes
-        elif not bounded_device:
+        else:
             # a transport that cannot say (or a duck-typed one that has
             # no such method) leaves the size unknown
             probe = getattr(self.client, "estimate_partition_bytes", None)
-            if callable(probe):
-                est = probe(job_id, map_ids, reduce_id)
+            est = probe(job_id, map_ids, reduce_id) if callable(probe) \
+                else None
         hold, reroute = self.budget().admit_device(
-            est, bounded=bounded_device, stopped=self._admit_poll)
+            est, segments=len(map_ids), stopped=self._admit_poll,
+            counted=adm is not None and adm.cause == "hbm")
+        group_rows = 0
         if reroute is not None:
-            # the chip cannot hold this task even alone: the bounded-
-            # device route, never an OOM that takes the live tasks along
+            # the chip cannot hold this task whole: the streaming route,
+            # its forest folded and taken off the device a group at a
+            # time — never an OOM that takes the live tasks along
             self.last_admission = reroute
-            streaming = bounded_device = True
+            streaming, group_rows = True, reroute.group_rows
             flightrec.record("admission", decision=reroute.decision,
                              cause=reroute.cause, rejected=False,
                              estimate=est)
         with hold:
             return self._run_overlapped(job_id, map_ids, reduce_id,
-                                        consumer, streaming, bounded_device,
+                                        consumer, streaming, group_rows,
                                         cap_budget)
 
     def _admit_poll(self) -> bool:
@@ -1067,10 +1065,12 @@ class MergeManager:
     def _run_overlapped(self, job_id: str, map_ids: Sequence,
                         reduce_id: int,
                         consumer: Callable[[memoryview], None],
-                        streaming: bool, bounded_device: bool,
+                        streaming: bool, group_rows: int,
                         cap_budget: Optional[MemoryBudget]) -> int:
         """The overlapped fetch/merge route (streaming or in-memory),
-        run while the task holds its reservation of the chip's HBM."""
+        run while the task holds its reservation of the chip's HBM;
+        ``group_rows`` > 0: the reservation holds a group of that many
+        rows of run capacity, not the task (streaming only)."""
         from uda_tpu.merger.overlap import OverlappedMerger
 
         store = None
@@ -1123,7 +1123,7 @@ class MergeManager:
             self.key_type, self.key_width, run_store=store,
             max_pending=self.window if streaming else 0,
             stagers=pool if (pipelined and pool > 0) else stagers,
-            device_runs=not bounded_device,
+            group_rows=group_rows,
             pipeline=pipelined,
             inflight_bytes=stage_inflight_cap(
                 self.cfg, self.window, self.chunk_size,

@@ -28,7 +28,17 @@ VPU) but a **log-structured run forest**:
   not 1,024. Rows are totally ordered (every column is key), so the
   merged run is the same bytes whichever engine merged a class;
 - ``finish()`` merges the O(log k) leftover runs, largest-capacity
-  last, and gathers the final byte permutation on host.
+  last, and gathers the final byte permutation on host;
+- a partition the chip cannot hold whole (``group_rows``, streaming
+  mode: what admission reserved holds one GROUP of that many rows of
+  run capacity, utils/budget.py) is merged on the device a group at a
+  time: when the next run would not fit, the forest is folded into one
+  sorted run, its rows are read back to a host row run and the device
+  memory is released; after the last segment the few group runs are
+  joined on the host with the native row merge into the one row order
+  the streaming emit consumes. Rows are totally ordered — equal keys
+  by (segment, row) — so the joined order is the order one forest
+  would have given, whichever group a record went through.
 
 **Staging pipeline** (``pipeline=True``, the deployment default via
 ``uda.tpu.stage.pipeline``): staging is a true fetch→decompress→pack→
@@ -205,6 +215,13 @@ _RowBufferPool = merge_ops.RowBufferPool
 _MERGE_SPLIT_MIN_ROWS = 1 << 18
 
 
+def _auto_width() -> int:
+    """A few threads: the stage pool's auto width and the parts a large
+    host row merge is split into (the native calls release the GIL, so
+    width ~ cores, held to four)."""
+    return max(2, min(4, os.cpu_count() or 2))
+
+
 class OverlappedMerger:
     """Consumes completed segments during the fetch phase; produces the
     final permutation over the concatenated batches.
@@ -227,7 +244,8 @@ class OverlappedMerger:
     def __init__(self, key_type: KeyType, width: int, engine: str = "auto",
                  run_store=None, max_pending: int = 0, stagers: int = 0,
                  device_runs: bool = True, pipeline: bool = False,
-                 inflight_bytes: int = 0, on_spool=None):
+                 inflight_bytes: int = 0, on_spool=None,
+                 group_rows: int = 0):
         self.key_type = key_type
         self.width = width
         # run-spool boundary hook (merger/checkpoint.py): called with the
@@ -235,18 +253,34 @@ class OverlappedMerger:
         # natural crash-consistent snapshot trigger. Contract: the hook
         # never raises (TaskCheckpoint.maybe_save catches internally).
         self._on_spool = on_spool
-        # device_runs=False (streaming mode only): admission control
-        # decided the full row forest would not fit the HBM budget —
-        # segments still spool to sorted run files, but no run is ever
-        # staged to the device; finish_streaming() merges the run FILES
-        # with the bounded k-way path instead of the device forest.
-        # Run files are written in (words, len) row order, which equals
+        # device_runs=False (streaming mode only): the caller wants no
+        # run staged to the device — segments still spool to sorted run
+        # files and finish_streaming() merges the run FILES with the
+        # bounded k-way path instead of the device forest. Run files
+        # are written in (words, len) row order, which equals
         # comparator order for within-width keys, so the k-way merge is
         # correct on both the fast path and the overflow path.
+        # MergeManager never asks for it: an over-budget task merges on
+        # the device in groups (below).
         self.device_runs = bool(device_runs)
         if not self.device_runs and run_store is None:
             raise MergeError("device_runs=False requires streaming mode "
                              "(a run store)")
+        # group_rows > 0 (streaming mode only): the forest may hold
+        # that many rows of run capacity at a time (module docstring);
+        # _group_held counts what it holds, _group_runs are the host
+        # row runs of the groups flushed so far.
+        # udarace: lockfree=_group_held,_group_runs,_groups - confined
+        # to _group_lock holders and, after _drain() has joined every
+        # stage thread, to the finish path
+        self._group_rows = max(0, int(group_rows))
+        if self._group_rows and run_store is None:
+            raise MergeError("group_rows requires streaming mode "
+                             "(a run store)")
+        self._group_lock = threading.Lock()
+        self._group_held = 0
+        self._group_runs: list = []
+        self._groups = 0                  # groups flushed (stats)
         self.engine = merge_ops.resolve_run_engine(engine)
         # off-TPU, a forced pallas engine runs in interpret mode
         self.interpret = jax.default_backend() == "cpu"
@@ -309,15 +343,17 @@ class OverlappedMerger:
         metrics.add("merge.device_runs", 0)
         metrics.add("merge.host_merges", 0)
         metrics.add("stage.native_segments", 0)
-        metrics.declare_timer("merge_host_batch")
+        metrics.add("merge.device_groups", 0)
+        for timer in ("merge_host_batch", "merge_group_flush",
+                      "merge_group_join", "run_spool"):
+            metrics.declare_timer(timer)
         self.pipeline = bool(pipeline)
         self._consumer_thread: Optional[threading.Thread] = None
         if self.pipeline:
             # bounded stage pool + single merge consumer. Pool width:
             # explicit ``stagers`` wins; auto = a few workers (staging
             # is numpy-heavy and releases the GIL, so width ~ cores).
-            width_auto = max(2, min(4, os.cpu_count() or 2))
-            nworkers = stagers if stagers > 0 else width_auto
+            nworkers = stagers if stagers > 0 else _auto_width()
             # staged-run queue is bounded: a slow device consumer
             # backpressures the workers (and, through the in-flight
             # budget, the transports feeding feed())
@@ -337,7 +373,7 @@ class OverlappedMerger:
             elif (self.engine == "host"
                   and self._native_rows_merge is not None):
                 self._buf_pool = _RowBufferPool()
-                self._merge_parts = max(2, min(4, os.cpu_count() or 2))
+                self._merge_parts = _auto_width()
             self._workers = [
                 threading.Thread(target=self._worker_loop, daemon=True,
                                  name=f"uda-stage-w{i}")
@@ -764,6 +800,16 @@ class OverlappedMerger:
         the transfer completion that frees a leased host buffer. A run
         of a host class skips the transfer."""
         bucket = _next_pow2(staged.valid)
+        if self._group_rows:
+            # one run at a time makes its way into a grouped forest: the
+            # room made for it must still be there when it lands
+            with self._group_lock:
+                if self._make_group_room(staged, bucket):
+                    self._stage_run(staged, bucket)
+        else:
+            self._stage_run(staged, bucket)
+
+    def _stage_run(self, staged: _StagedRun, bucket: int) -> None:
         with metrics.timer("overlap_stage"):
             # the run takes the pool lease: a device run's recycles once
             # its transfer is done, a host run keeps it until it merges
@@ -776,6 +822,81 @@ class OverlappedMerger:
             else:
                 run = _Run(staged.rows, staged.valid, bucket, lease)
             self._insert(run)
+
+    # -- device groups (group_rows > 0; callers hold _group_lock) -----------
+
+    def _make_group_room(self, staged: _StagedRun, bucket: int) -> bool:
+        """Book ``bucket`` rows of run capacity in the current group,
+        flushing the group first when they would not fit. False when
+        the run is larger than any group: it is a sorted run already,
+        so its rows become a group run as they are and never see the
+        device."""
+        if bucket > self._group_rows:
+            self._group_runs.append(
+                np.array(staged.rows[:staged.valid], np.uint32))
+            self._recycle(staged)
+            return False
+        if self._group_held + bucket > self._group_rows:
+            self._flush_group()
+        self._group_held += bucket
+        return True
+
+    def _flush_group(self) -> None:
+        """Fold the forest into one sorted run, take its rows off the
+        device into a host row run, release the device memory."""
+        acc = None
+        with metrics.timer("merge_group_flush"):
+            try:
+                acc = self._merge_leftovers()
+                if acc is not None:
+                    self._group_runs.append(self._rows_on_host(acc))
+            finally:
+                self._release_run(acc)
+                self._release_forest()
+                self._device_staged_bytes = 0
+                self._group_held = 0
+        if acc is not None:
+            self._groups += 1
+            metrics.add("merge.device_groups")
+
+    @staticmethod
+    def _rows_on_host(run: _Run) -> np.ndarray:
+        """A run's valid rows as a host array of its own; a device
+        run's are read back in slabs (the first one waits for the
+        fold)."""
+        from uda_tpu.merger.streaming import SLAB_RECORDS
+
+        if run.on_host:
+            return np.array(run.rows[:run.valid], np.uint32)
+        out = np.empty((run.valid, int(run.rows.shape[1])), np.uint32)
+        for start in range(0, run.valid, SLAB_RECORDS):
+            stop = min(start + SLAB_RECORDS, run.valid)
+            out[start:stop] = np.asarray(run.rows[start:stop])
+        return out
+
+    def _join_groups(self) -> Optional[_Run]:
+        """After the last segment: flush what the forest still holds,
+        then join the group runs, smallest two first, with the native
+        row merge split across threads (the numpy lexsort where the
+        library is missing). Returns the one merged row run, on the
+        host, or None when nothing was staged."""
+        with self._group_lock:
+            self._flush_group()
+            runs, self._group_runs = self._group_runs, []
+        if not runs:
+            return None
+        parts = _auto_width()
+        with metrics.timer("merge_group_join"):
+            while len(runs) > 1:
+                runs.sort(key=len, reverse=True)
+                b, a = runs.pop(), runs.pop()
+                out = np.empty((len(a) + len(b), a.shape[1]), np.uint32)
+                if not merge_ops.merge_rows_split_into(a, b, out, parts):
+                    out = merge_ops.merge_row_pair(a, b, len(a), len(b),
+                                                   "host")
+                runs.append(out)
+        rows = runs[0]
+        return _Run(rows, len(rows), _next_pow2(len(rows)))
 
     def _put_on_device(self, rows: np.ndarray, valid: int, bucket: int,
                        lease) -> _Run:
@@ -966,6 +1087,7 @@ class OverlappedMerger:
         if self._staged_q is not None:
             pending += self._staged_q.qsize()
         return {"device_merges": self._merges, "staged_runs": self._staged,
+                "device_groups": self._groups,
                 "pending": pending, "overflow": self._overflow,
                 "pipeline": self.pipeline,
                 "inflight_bytes": self._inflight}
@@ -1038,6 +1160,7 @@ class OverlappedMerger:
         asserts this merger's pool books are empty."""
         self._release_run(acc)
         self._release_forest()
+        self._group_runs = []     # an overflow fallback leaves them unjoined
         self._ledger_drain("merger.finish")
 
     def _ledger_drain(self, point: str) -> None:
@@ -1216,7 +1339,9 @@ class OverlappedMerger:
                 # read the latch only now: a segment still being staged
                 # when finish was called may be the one that sets it
                 no_forest = self._overflow or not self.device_runs
-                acc = None if no_forest else self._merge_leftovers()
+                if not no_forest:
+                    acc = (self._join_groups() if self._group_rows
+                           else self._merge_leftovers())
             total = store.total_records
             if expected_records is not None and total != expected_records:
                 raise MergeError(
@@ -1232,7 +1357,7 @@ class OverlappedMerger:
                 if self._overflow:
                     self._warn_overflow("k-way merge over run files")
                 else:
-                    log.info("bounded-device streaming: k-way merge "
+                    log.info("streaming without device runs: k-way merge "
                              "over run files (no device forest)")
                 paths = [store.run_path(s) for s in sorted(store.counts)]
                 if (native_enabled() and native.kway_supported(self.key_type)
@@ -1250,7 +1375,10 @@ class OverlappedMerger:
             return emitter.emit_framed(
                 stream_mod.interleave_runs(slabs, store, kw), consumer)
         finally:
-            store.cleanup()
+            # the spool's other end: removing hundreds of large run
+            # files is seconds of a 10 GB task, not nothing
+            with metrics.timer("run_spool"):
+                store.cleanup()
             self._finish_cleanup(acc)
 
     def abort(self) -> None:
@@ -1300,4 +1428,5 @@ class OverlappedMerger:
             # point asserts nothing else is still open (a straggler
             # thread may still legitimately hold leases — no drain)
             self._release_forest()
+            self._group_runs = []
             self._ledger_drain("merger.abort")

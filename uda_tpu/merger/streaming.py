@@ -19,9 +19,14 @@ reference's memory model around the device permutation:
   merged device rows already encode, for every output position, which
   segment supplies the next record. Because each run is sorted, every
   run is consumed strictly *sequentially* — the emit phase is k
-  buffered file cursors and one output slab, no comparisons, no random
+  file cursors and one output slab, no comparisons, no random
   access ever (the property that let the reference emit from 1 MB
-  staging buffers, MergeQueue.h:276-427).
+  staging buffers, MergeQueue.h:276-427). With the native library a
+  slab is one C pass through a per-task table of run cursors, one
+  pread buffer a run (O(records), whatever the run count); the
+  numpy path (buffered cursors, two masked passes per run per slab) is
+  the fallback and the reference the native one is parity-tested
+  against.
 - **Slab gather** (:func:`slab_batch`): the in-memory twin used when
   streaming is off — gathers each output slab's bytes directly from the
   per-segment batches, so even the memory-resident path never
@@ -420,10 +425,28 @@ def interleave_runs(slabs: Iterator[np.ndarray], store: RunStore,
     ``slabs`` yields merged rows whose column ``num_key_words + 1`` is
     the segment index (the OverlappedMerger row layout). Each slab
     becomes one framed output piece; runs are read strictly
-    sequentially (2 file handles per segment, like the hybrid RPQ's one
-    cursor per spill). The concatenation of the yielded pieces plus the
+    sequentially. The concatenation of the yielded pieces plus the
     EOF marker is the complete merged IFile stream.
+
+    With the native library each slab is gathered by
+    ``native.gather_runs_native`` through a per-task ``native.RunTable``
+    (built before the first slab, under no span: one read buffer a run,
+    descriptors held between fills only for ``MAX_OPEN_CURSORS`` runs
+    or fewer) and counted in ``emit.gather.native_slabs``; such a piece
+    is a view of the table's output buffer and holds until the next
+    piece is asked for. Without the library, or with
+    ``uda.tpu.use.native`` off as the task starts to emit, the numpy
+    cursors below run (2 file handles per open segment, at most
+    ``MAX_OPEN_CURSORS`` open at a time) — the plain reference, same
+    bytes. A task takes one path from its first slab to its last: the
+    two keep their cursors apart.
     """
+    table = None
+    if _native_ready():
+        table = native.RunTable(
+            {s: (store.run_path(s), n, store.bytes[s])
+             for s, n in store.counts.items()},
+            keep_open=len(store.counts) <= MAX_OPEN_CURSORS)
     cursors: dict[int, _RunCursor] = {}
     open_lru: dict[int, None] = {}  # insertion-ordered set of open segs
 
@@ -477,17 +500,29 @@ def interleave_runs(slabs: Iterator[np.ndarray], store: RunStore,
             if rows.shape[0] == 0:
                 continue
             with metrics.timer("emit_gather"):
-                piece = gather_slab(rows)
+                if table is not None:
+                    # a view of the table's output buffer: consumed
+                    # before the next slab is asked for
+                    piece = native.gather_runs_native(
+                        table, rows[:, num_key_words + 1])
+                    metrics.add("emit.gather.native_slabs")
+                else:
+                    piece = gather_slab(rows)
             yield piece
+        # verify every run was fully consumed (lost-records guard)
+        for s, n in store.counts.items():
+            if table is not None:
+                done = table.consumed(s)
+            else:
+                done = cursors[s].consumed_records if s in cursors else 0
+            if done != n:
+                raise MergeError(
+                    f"run {s}: merged rows consumed {done} of {n} records")
     finally:
         for cur in cursors.values():
             cur.close()
-    # verify every run was fully consumed (lost-records guard)
-    for s, n in store.counts.items():
-        cur_records = cursors[s].consumed_records if s in cursors else 0
-        if cur_records != n:
-            raise MergeError(
-                f"run {s}: merged rows consumed {cur_records} of {n} records")
+        if table is not None:
+            table.close()
     yield EOF_MARKER
 
 

@@ -27,7 +27,7 @@ __all__ = ["available", "build", "crack_native", "crack_partial_native",
            "decode_vlongs_native", "write_records_native", "frame_batch",
            "iter_framed_chunks", "ReadPool", "kway_supported",
            "kway_merge_paths", "SegmentTable", "gather_slab_native",
-           "stage_segment_native"]
+           "RunTable", "gather_runs_native", "stage_segment_native"]
 
 log = get_logger()
 
@@ -125,6 +125,19 @@ def _bind(lib):
     lib.uda_slab_copy.restype = None
     lib.uda_slab_copy.argtypes = slab + [i64p, i64p, ctypes.c_int64, u8p,
                                          i64p, i64p]
+    # RunTable / gather_runs_native own the layout checks
+    lib.uda_runs_open.restype = ctypes.c_void_p
+    lib.uda_runs_open.argtypes = [ctypes.POINTER(ctypes.c_char_p), i64p,
+                                  i64p, ctypes.c_int64, ctypes.c_int64,
+                                  ctypes.c_int32]
+    lib.uda_runs_gather.restype = ctypes.c_int64
+    lib.uda_runs_gather.argtypes = [addr, addr, ctypes.c_int64,
+                                    ctypes.c_int64, addr, ctypes.c_int64,
+                                    i64p]
+    lib.uda_runs_consumed.restype = ctypes.c_int64
+    lib.uda_runs_consumed.argtypes = [addr, ctypes.c_int64]
+    lib.uda_runs_close.restype = None
+    lib.uda_runs_close.argtypes = [addr]
     # data + size, key_off, key_len, val_len, n, key mode, key words,
     # segment index, rows + capacity, out: stage_segment_native owns the
     # layout checks
@@ -496,6 +509,99 @@ def gather_slab_native(table: SegmentTable, seg: np.ndarray,
     lib.uda_slab_copy(*slab, _i64ptr(k_len), _i64ptr(v_len), out[0],
                       _u8ptr(buf), _i64ptr(k_off), _i64ptr(v_off))
     return RecordBatch(buf, k_off, k_len, v_off, v_len)
+
+
+class RunTable:
+    """One task's table of run cursors for :func:`gather_runs_native`
+    (the C side's ``RunTable``): per run its path, record count and
+    framed size, one read buffer (``buffer_size`` bytes at most, filled
+    by ``pread``) and how far it has read. Built once per task in
+    O(runs); each run file is checked against the size the store
+    recorded when it wrote it. ``keep_open`` holds a run's descriptor
+    between fills — for few runs; otherwise a fill opens and closes,
+    and the run count is not held to the fd limit. ``runs`` maps
+    segment index to ``(run_path, records, framed_bytes)``; an index
+    nobody staged stays empty. The gathered slab lives in one output
+    buffer the table reuses, slab after slab."""
+
+    __slots__ = ("runs", "_h", "_out")
+
+    def __init__(self, runs: dict, keep_open: bool = False,
+                 buffer_size: int = 1 << 20):
+        from uda_tpu.utils.ifile import EOF_MARKER
+
+        lib = _load()
+        if lib is None:
+            raise StorageError("native library unavailable")
+        self.runs = max(runs, default=-1) + 1
+        paths = (ctypes.c_char_p * self.runs)()
+        records = np.zeros(self.runs, np.int64)
+        sizes = np.zeros(self.runs, np.int64)
+        for s, (run_path, n, nbytes) in runs.items():
+            if n <= 0:
+                continue
+            have = os.path.getsize(run_path)
+            if have != nbytes + len(EOF_MARKER):
+                raise MergeError(f"run {s}: {run_path} holds {have} bytes, "
+                                 f"the store wrote "
+                                 f"{nbytes + len(EOF_MARKER)}")
+            paths[s] = os.fsencode(run_path)
+            records[s], sizes[s] = n, nbytes
+        self._out = np.empty(buffer_size, np.uint8)
+        self._h = lib.uda_runs_open(paths, _i64ptr(records), _i64ptr(sizes),
+                                    self.runs, int(buffer_size),
+                                    int(bool(keep_open)))
+        if not self._h:
+            raise MemoryError("run table allocation failed")
+
+    def consumed(self, s: int) -> int:
+        """Records of run ``s`` gathered so far."""
+        return int(_load().uda_runs_consumed(self._h, s)) if self._h else 0
+
+    def close(self) -> None:
+        h, self._h = self._h, None
+        if h:
+            _load().uda_runs_close(h)
+
+
+_RUNS_ERRORS = {-1: "merged rows reference an unstaged segment",
+                -2: "merged rows ask a run for more records than it holds",
+                -3: "run framing runs past the run file's records",
+                -4: "run file read failure"}
+
+
+def gather_runs_native(table: RunTable, seg: np.ndarray) -> memoryview:
+    """One output slab's framed bytes: each record the next unread one
+    of the run ``seg[i]`` names, copied verbatim, in one C pass over
+    the slab whatever the run count (the native twin of
+    ``merger/streaming.py:interleave_runs``' numpy gather,
+    byte-identical). The view is of the table's own output buffer: it
+    holds until the next call. The C loop checks every record; a bad
+    index, an exhausted run, framing past the file or a failed read
+    raises MergeError."""
+    lib = _load()
+    seg = _u32_column(seg, "segment")
+    n = seg.shape[0]
+    done = written = 0
+    out = (ctypes.c_int64 * 3)()
+    while True:
+        got = lib.uda_runs_gather(
+            table._h, seg.ctypes.data + done * seg.strides[0],
+            seg.strides[0], n - done,
+            table._out.ctypes.data + written, table._out.size - written, out)
+        if got < 0:
+            at = done + out[2]
+            raise MergeError(f"run gather: {_RUNS_ERRORS.get(got, got)} at "
+                             f"slab record {at} (segment {int(seg[at])})")
+        done += got
+        written += out[0]
+        if done == n:
+            return memoryview(table._out)[:written]
+        # the next record did not fit: at least double the buffer
+        grown = np.empty(max(2 * table._out.size, written + out[1]),
+                         np.uint8)
+        grown[:written] = table._out[:written]
+        table._out = grown
 
 
 _STAGE_ERRORS = {1: "empty serialized Text key",

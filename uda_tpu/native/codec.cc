@@ -15,7 +15,12 @@
 #include <cstring>
 #include <new>
 #include <numeric>
+#include <string>
 #include <vector>
+
+#include <cerrno>
+#include <fcntl.h>
+#include <unistd.h>
 
 #include "vlong.h"
 
@@ -216,6 +221,180 @@ void uda_slab_copy(const UdaSegment* table,
     kp += key_len[i];
     vp += val_len[i];
   }
+}
+
+}  // extern "C"
+
+// Run gather over a per-task table of run cursors: the streaming emit
+// path's byte movement (uda_tpu/merger/streaming.py:interleave_runs).
+// The merged rows name, for every output record, the sorted run that
+// supplies it; a run is consumed strictly in file order, so one cursor
+// per run — its path, a read buffer, how far it has read — is all the
+// state there is, and a slab is gathered in O(records), whatever the
+// run count. The runs are IFile-framed already: a record's span is read
+// off its two VInt lengths and copied verbatim, so the slab's bytes are
+// the records' framed spans back to back (the offset sidecars are the
+// numpy path's). Host memory is one read buffer a run, filled by pread;
+// a descriptor is held between fills only when the table was opened to
+// keep them (few runs): a fill is rare (a buffer holds thousands of
+// records), the run count is not held to the fd limit.
+struct RunCursor {
+  std::string path;            // empty: a run nobody staged
+  int fd = -1;
+  int64_t file_off = 0;        // next unread byte of the file
+  int64_t data_size = 0;       // framed bytes, EOF marker excluded
+  int64_t records = 0;
+  int64_t consumed = 0;
+  std::vector<uint8_t> buf;
+  int64_t pos = 0, filled = 0;
+};
+
+struct RunTable {
+  std::vector<RunCursor> runs;
+  int64_t buf_bytes = 1 << 20;
+  bool keep_open = false;
+};
+
+enum : int64_t {
+  UDA_RUNS_BAD_RUN = -1,      // seg >= runs, or a run nobody staged
+  UDA_RUNS_EXHAUSTED = -2,    // more records asked of a run than it has
+  UDA_RUNS_CORRUPT = -3,      // framing runs past the run's bytes
+  UDA_RUNS_IO = -4,           // open() / pread() failed or came short
+};
+
+// Make at least `want` bytes available at c.pos (compacting, growing
+// the buffer for a record larger than it). 0 ok, or UDA_RUNS_*.
+static int64_t run_fill(RunTable& t, RunCursor& c, int64_t want) {
+  if (c.filled - c.pos >= want) return 0;
+  if (c.pos > 0) {
+    std::memmove(c.buf.data(), c.buf.data() + c.pos, c.filled - c.pos);
+    c.filled -= c.pos;
+    c.pos = 0;
+  }
+  const int64_t cap = std::max<int64_t>(
+      want, std::min<int64_t>(t.buf_bytes, c.filled + c.data_size - c.file_off));
+  if ((int64_t)c.buf.size() < cap) c.buf.resize(cap);
+  int64_t to_read = std::min<int64_t>((int64_t)c.buf.size() - c.filled,
+                                      c.data_size - c.file_off);
+  if (c.filled + to_read < want) return UDA_RUNS_CORRUPT;
+  if (c.fd < 0) c.fd = open(c.path.c_str(), O_RDONLY | O_CLOEXEC);
+  if (c.fd < 0) return UDA_RUNS_IO;
+  int64_t rc = 0;
+  while (to_read > 0) {
+    const ssize_t n = pread(c.fd, c.buf.data() + c.filled, (size_t)to_read,
+                            (off_t)c.file_off);
+    if (n < 0 && errno == EINTR) continue;
+    if (n <= 0) {
+      rc = UDA_RUNS_IO;
+      break;
+    }
+    c.filled += n;
+    c.file_off += n;
+    to_read -= n;
+  }
+  if (!t.keep_open || c.file_off >= c.data_size) {
+    close(c.fd);
+    c.fd = -1;
+  }
+  return rc;
+}
+
+extern "C" {
+
+// paths[i] NULL = a run nobody staged. sizes = framed bytes without the
+// EOF marker (the caller checked the files against them).
+void* uda_runs_open(const char* const* paths, const int64_t* records,
+                    const int64_t* sizes, int64_t n, int64_t buf_bytes,
+                    int32_t keep_open) {
+  RunTable* t = new (std::nothrow) RunTable();
+  if (!t) return nullptr;
+  t->buf_bytes = std::max<int64_t>(buf_bytes, 64);
+  t->keep_open = keep_open != 0;
+  try {
+    t->runs.resize((size_t)n);
+    for (int64_t i = 0; i < n; ++i) {
+      if (!paths[i]) continue;
+      t->runs[i].path = paths[i];
+      t->runs[i].records = records[i];
+      t->runs[i].data_size = sizes[i];
+    }
+  } catch (const std::bad_alloc&) {
+    delete t;
+    return nullptr;
+  }
+  return t;
+}
+
+// Gather the slab's records, in order, into dst[0, cap): returns how
+// many were gathered, with out[0] = bytes written. Fewer than n means
+// the next record did not fit: out[1] = the bytes it needs (the caller
+// grows dst and asks for the rest). A UDA_RUNS_* code on failure, with
+// out[2] = the slab record it was found at (the task fails).
+int64_t uda_runs_gather(void* h, const uint8_t* seg, int64_t seg_stride,
+                        int64_t n, uint8_t* dst, int64_t cap,
+                        int64_t out[3]) {
+  RunTable& t = *static_cast<RunTable*>(h);
+  int64_t written = 0;
+  out[0] = out[1] = 0;
+  try {
+    for (int64_t i = 0; i < n; ++i) {
+      out[2] = i;
+      const uint32_t s = slab_u32(seg, seg_stride, i);
+      if ((size_t)s >= t.runs.size() || t.runs[s].path.empty())
+        return UDA_RUNS_BAD_RUN;
+      RunCursor& c = t.runs[s];
+      if (c.consumed >= c.records) return UDA_RUNS_EXHAUSTED;
+      int64_t len = 0;
+      for (;;) {     // the record's framed length, off its two VInts
+        const int64_t avail = c.filled - c.pos;
+        int64_t klen, vlen, want;
+        const int u1 = uda::decode_vlong(c.buf.data(), c.filled, c.pos,
+                                         &klen);
+        const int u2 = u1 ? uda::decode_vlong(c.buf.data(), c.filled,
+                                              c.pos + u1, &vlen) : 0;
+        if (u2) {
+          if (klen < 0 || vlen < 0 || klen > c.data_size ||
+              vlen > c.data_size)
+            return UDA_RUNS_CORRUPT;
+          len = u1 + u2 + klen + vlen;
+          if (avail >= len) break;
+          want = len;
+        } else {
+          // the header itself is cut off: two VInts take 18 bytes at most
+          if (avail >= 18) return UDA_RUNS_CORRUPT;
+          want = avail + 1;     // the fill reads as much as the buffer holds
+        }
+        const int64_t rc = run_fill(t, c, want);
+        if (rc) return rc;
+      }
+      if (len > cap - written) {
+        out[0] = written;
+        out[1] = len;
+        return i;
+      }
+      std::memcpy(dst + written, c.buf.data() + c.pos, (size_t)len);
+      written += len;
+      c.pos += len;
+      ++c.consumed;
+    }
+  } catch (const std::bad_alloc&) {
+    return UDA_RUNS_IO;
+  }
+  out[0] = written;
+  return n;
+}
+
+int64_t uda_runs_consumed(void* h, int64_t run) {
+  RunTable& t = *static_cast<RunTable*>(h);
+  return run >= 0 && (size_t)run < t.runs.size() ? t.runs[run].consumed : 0;
+}
+
+void uda_runs_close(void* h) {
+  RunTable* t = static_cast<RunTable*>(h);
+  if (!t) return;
+  for (RunCursor& c : t->runs)
+    if (c.fd >= 0) close(c.fd);
+  delete t;
 }
 
 }  // extern "C"

@@ -44,8 +44,14 @@ less their reservations (:meth:`MemoryBudget.admit_device`, called by
 at every merge approach that builds the device forest). A task that
 does not fit beside the live ones WAITS for a release — the reference
 blocked on pool exhaustion too (``occupy_chunk``) — and one that does
-not fit the chip alone is not the ledger's to hold: it takes the
-bounded-device route. The ledger is told no slot count; it observes.
+not fit the chip alone is sized into GROUPS: it reserves what the
+largest group of rows the chip can hold needs (:func:`group_capacity_rows`),
+waits its turn like any task, and merges its partition on the device a
+group at a time (merger/overlap.py). A reservation has two parts: the
+rows a task holds, which add up over the live tasks, and the
+temporaries of its largest merge program, of which the chip needs only
+the largest at a time — one program runs at a time. The ledger is told
+no slot count; it observes.
 """
 
 from __future__ import annotations
@@ -62,8 +68,10 @@ from uda_tpu.utils.metrics import metrics
 
 __all__ = ["MemoryBudget", "Admission", "HbmLedger", "HbmHold",
            "hbm_ledger", "device_bytes_estimate",
+           "merge_temp_bytes_estimate", "group_capacity_rows",
            "stage_inflight_cap", "ROW_OVERHEAD_WORDS",
            "HBM_ROW_ALIGN_WORDS", "RUN_PAD_FACTOR", "FOREST_FACTOR",
+           "MERGE_TEMP_ROW_BYTES",
            "WORKING_SET_FACTOR", "HBM_RESERVE_FRACTION",
            "PLATFORM_HBM_MB", "STAGE_INFLIGHT_FLOOR_MB"]
 
@@ -128,13 +136,32 @@ def stage_inflight_cap(cfg, window: int, chunk_size: int,
 # rows; before the dispatch bound a warm task peaked at 2,429 MB (4.5x
 # the staged rows — the host outran the device) where this model said
 # 2,268 MB, the unsafe side; a 131 MB partition in 1,024 runs peaked at
-# 211 MB against 283 MB. PERF.md has what they read since. What memory_stats does NOT count is an
-# executable's own temporaries: the largest merge of the 1.05 GB task
-# needs 4.3 GB of them while it runs (memory_analysis; PERF.md 7).
+# 211 MB against 283 MB. PERF.md has what they read since.
+#
+# What memory_stats does NOT count is an executable's own temporaries,
+# and the pairwise merge program (ops/pallas_merge.py) has large ones:
+# it packs both runs into the lanes layout, uint32[32, rows] whatever
+# the row's width, and the merge pass writes a second matrix of that
+# shape — 2 x 32 x 4 = MERGE_TEMP_ROW_BYTES for every row of the
+# OUTPUT's capacity, beside 32 B a row each of arguments and output.
+# memory_analysis() of the compiled merge of two runs of 2^k rows of 7
+# columns (v5e; compiled for the described chip and, at k = 18, 20, 23
+# and 24, on the chip itself, which reported the same bytes; PR 31):
+#   k = 17     0 B      k = 20    537,452,544 B (256.3 B a row)
+#   k = 18   128.4 B    k = 22  2,148,520,448 B (256.1)
+#      a row            k = 23  4,296,266,240 B (256.08)
+#                       k = 24  8,591,983,616 B (256.06)
+# One program runs at a time, so the chip needs the largest live
+# task's temporaries, not their sum (HbmLedger books them so). The
+# output's capacity follows from the forest's shape: every run is
+# padded to a power of two and so is every merge's output, so the
+# largest merge of a task writes the power of two at or above the sum
+# of its runs' capacities (merge_temp_bytes_estimate).
 ROW_OVERHEAD_WORDS = 3        # length, segment index, row index columns
 HBM_ROW_ALIGN_WORDS = 8       # a row's columns as the device stores them
 RUN_PAD_FACTOR = 2.0          # power-of-two run capacity, at worst
 FOREST_FACTOR = 3.0           # executed rows + pending merge outputs
+MERGE_TEMP_ROW_BYTES = 256    # merge temporaries a row of output capacity
 SORT_LADDER_RATIO = 1.08      # device bytes / shuffle bytes, TeraSort shape
 RECORD_BYTES_DEFAULT = 100    # TeraSort record (10 B key + 90 B value)
 WORKING_SET_FACTOR = 2.0      # the sort ladder's transient
@@ -206,22 +233,67 @@ def _detect_hbm_mb() -> int:
         f"uda.tpu.hbm.budget.mb)")
 
 
+def _row_bytes(key_width: int) -> int:
+    """Device bytes of one composite-key row, as the chip stores it."""
+    cols = max(4, key_width) // 4 + ROW_OVERHEAD_WORDS
+    return 4 * -(-cols // HBM_ROW_ALIGN_WORDS) * HBM_ROW_ALIGN_WORDS
+
+
+def _pow2_at_least(n: int) -> int:
+    return 1 << max(0, int(n) - 1).bit_length()
+
+
 def device_bytes_estimate(partition_bytes: int, key_width: int,
                           record_bytes: int = RECORD_BYTES_DEFAULT) -> int:
-    """Device-resident bytes the merge would hold for a partition of
-    ``partition_bytes`` on-disk bytes: the larger of the run forest and
-    the sort ladder (see the model above). An upper bound by
-    construction — admission errs toward the bounded path, and what the
-    chip-wide ledger reserves for a task covers what the task can
-    hold."""
+    """Device-resident bytes of ROWS the merge would hold for a
+    partition of ``partition_bytes`` on-disk bytes: the larger of the
+    run forest and the sort ladder (see the model above) — what
+    ``memory_stats`` can see of a task. An upper bound by construction
+    — admission errs toward the grouped path, and what the chip-wide
+    ledger reserves for a task covers what the task can hold. The
+    merge program's temporaries are booked beside it
+    (:func:`merge_temp_bytes_estimate`)."""
     if partition_bytes <= 0:
         return 0
-    cols = max(4, key_width) // 4 + ROW_OVERHEAD_WORDS
-    row_bytes = 4 * -(-cols // HBM_ROW_ALIGN_WORDS) * HBM_ROW_ALIGN_WORDS
     records = max(1, partition_bytes // max(1, record_bytes))
-    forest = records * row_bytes * RUN_PAD_FACTOR * FOREST_FACTOR
+    forest = (records * _row_bytes(key_width) * RUN_PAD_FACTOR
+              * FOREST_FACTOR)
     ladder = partition_bytes * SORT_LADDER_RATIO * WORKING_SET_FACTOR
     return int(max(forest, ladder))
+
+
+def merge_temp_bytes_estimate(partition_bytes: int,
+                              segments: Optional[int] = None,
+                              record_bytes: int = RECORD_BYTES_DEFAULT
+                              ) -> int:
+    """Temporaries of the largest merge program a task of
+    ``partition_bytes`` in ``segments`` equal runs dispatches (see the
+    model above): ``MERGE_TEMP_ROW_BYTES`` for every row of its
+    output's capacity, the power of two at or above the sum of the
+    runs' power-of-two capacities. Without a segment count the runs
+    are taken at their worst padding (``RUN_PAD_FACTOR``)."""
+    if partition_bytes <= 0:
+        return 0
+    records = max(1, partition_bytes // max(1, record_bytes))
+    if segments and segments > 0:
+        capacity = segments * _pow2_at_least(-(-records // segments))
+    else:
+        capacity = int(records * RUN_PAD_FACTOR)
+    return MERGE_TEMP_ROW_BYTES * _pow2_at_least(capacity)
+
+
+def group_capacity_rows(budget_bytes: int, key_width: int) -> int:
+    """Rows of run capacity one device GROUP of an over-budget task may
+    hold in ``budget_bytes``: the largest power of two M for which the
+    group's forest (``FOREST_FACTOR`` x M rows, what the dispatch bound
+    of merger/overlap.py keeps it to) and the temporaries of the merge
+    that folds it into one run of M rows fit together. A power of two
+    because the fold's output capacity is one: a group filled to M
+    folds into exactly M, one row more and the fold would write 2M.
+    0 when not even the smallest run fits."""
+    per_row = FOREST_FACTOR * _row_bytes(key_width) + MERGE_TEMP_ROW_BYTES
+    rows = int(budget_bytes // per_row)
+    return 1 << (rows.bit_length() - 1) if rows > 0 else 0
 
 
 # -- the chip-wide HBM ledger ----------------------------------------------
@@ -233,16 +305,18 @@ class HbmHold:
     exit (end, abort, exception) — :meth:`MemoryBudget.admit_device`
     hands it out as a context manager for exactly that."""
 
-    __slots__ = ("_ledger", "nbytes")
+    __slots__ = ("_ledger", "nbytes", "temp_bytes")
 
-    def __init__(self, ledger: "HbmLedger", nbytes: int):
+    def __init__(self, ledger: "HbmLedger", nbytes: int,
+                 temp_bytes: int = 0):
         self._ledger = ledger
-        self.nbytes = nbytes
+        self.nbytes = nbytes              # rows: add up over the tasks
+        self.temp_bytes = temp_bytes      # merge temporaries: the max
 
     def release(self) -> None:
         ledger, self._ledger = self._ledger, None
         if ledger is not None:
-            ledger._release(self.nbytes)
+            ledger._release(self.nbytes, self.temp_bytes)
 
     def __enter__(self) -> "HbmHold":
         return self
@@ -256,15 +330,17 @@ class HbmLedger:
     chip's HBM. One instance a process (:data:`hbm_ledger`): the
     process is what holds the chip.
 
-    ``reserve(nbytes, budget_bytes)`` admits the caller when the live
-    reservations plus ``nbytes`` fit ``budget_bytes`` (the CALLER's
-    view of the chip's budget — a tenant's share is its own), else
-    blocks until releases make room. Admission is first come, first
-    served: a task waiting for a large reservation is not overtaken by
-    later small ones, so it cannot starve. A caller that asks for more
-    than the budget can ever hold is a bug of the caller
-    (``admit_device`` sends such a task down the bounded-device route
-    instead) and raises. ``stopped`` is polled while waiting (each poll
+    ``reserve(nbytes, budget_bytes, temp_bytes=...)`` admits the caller
+    when what the chip would then hold booked — the live tasks' rows
+    plus ``nbytes``, and the LARGEST of the live tasks' and the
+    caller's merge temporaries (one program runs at a time) — fits
+    ``budget_bytes`` (the CALLER's view of the chip's budget — a
+    tenant's share is its own), else blocks until releases make room.
+    Admission is first come, first served: a task waiting for a large
+    reservation is not overtaken by later small ones, so it cannot
+    starve. A caller that asks for more than the budget can ever hold
+    is a bug of the caller (``admit_device`` sizes such a task into
+    groups instead) and raises. ``stopped`` is polled while waiting (each poll
     is also the waiter's sign of life): a task that is being torn down
     leaves the queue with a ``MergeError``.
 
@@ -277,14 +353,20 @@ class HbmLedger:
 
     def __init__(self) -> None:
         self._cv = TrackedCondition(TrackedLock("budget.hbm"))
-        self._reserved = 0
+        self._reserved = 0                # the live tasks' rows
+        self._temps: list = []            # each live task's temporaries
         self._holders = 0
         self._queue: deque = deque()      # tickets, oldest first
+
+    def _booked(self) -> int:
+        """Under the lock: the live tasks' rows and the largest of
+        their merge temporaries."""
+        return self._reserved + max(self._temps, default=0)
 
     @property
     def reserved_bytes(self) -> int:
         with self._cv:
-            return self._reserved
+            return self._booked()
 
     @property
     def holders(self) -> int:
@@ -293,11 +375,14 @@ class HbmLedger:
             return self._holders
 
     def reserve(self, nbytes: int, budget_bytes: int,
-                stopped: Optional[Callable[[], bool]] = None) -> HbmHold:
+                stopped: Optional[Callable[[], bool]] = None,
+                temp_bytes: int = 0) -> HbmHold:
         nbytes = max(0, int(nbytes))
-        if nbytes > budget_bytes:
-            raise UdaError(f"HBM reservation of {nbytes} B can never fit "
-                           f"the budget of {budget_bytes} B")
+        temp_bytes = max(0, int(temp_bytes))
+        if nbytes + temp_bytes > budget_bytes:
+            raise UdaError(f"HBM reservation of {nbytes} B of rows and "
+                           f"{temp_bytes} B of merge temporaries can never "
+                           f"fit the budget of {budget_bytes} B")
         ticket = object()
         waited = False
         with metrics.timer("hbm_admit"):
@@ -305,7 +390,9 @@ class HbmLedger:
                 self._queue.append(ticket)
                 try:
                     while (self._queue[0] is not ticket
-                           or self._reserved + nbytes > budget_bytes):
+                           or self._reserved + nbytes
+                           + max([temp_bytes, *self._temps])
+                           > budget_bytes):
                         if stopped is not None and stopped():
                             raise MergeError(
                                 "stopped while waiting for the chip's HBM "
@@ -316,30 +403,39 @@ class HbmLedger:
                             waited = True
                             metrics.add("budget.waited")
                             log.info(
-                                f"HBM ledger: waiting for {nbytes} B; "
-                                f"{self._reserved} of {budget_bytes} B are "
-                                f"reserved by {self._holders} live task(s)")
+                                f"HBM ledger: waiting for {nbytes} B of "
+                                f"rows and {temp_bytes} B of temporaries; "
+                                f"{self._booked()} of {budget_bytes} B are "
+                                f"booked by {self._holders} live task(s)")
                         self._cv.wait(timeout=self.POLL_S)
+                    before = self._booked()
                     self._reserved += nbytes
+                    self._temps.append(temp_bytes)
                     self._holders += 1
+                    grew = self._booked() - before
                 finally:
                     self._queue.remove(ticket)
                     self._cv.notify_all()     # the next ticket's turn
-        # the books above are the truth; the gauges mirror them. The +x
-        # rides the returned hold: every reserve() is paired with
-        # exactly one _release() through HbmHold.release
+        # the books above are the truth; the gauges mirror them: each
+        # change of what is booked is taken under the lock and added
+        # here, so the gauge's sum is the books' whatever order the
+        # adds land in. The +x rides the returned hold: every reserve()
+        # is paired with exactly one _release() through HbmHold.release
         metrics.gauge_add("reduce.tasks.live", 1)  # udalint: disable=UDA101
         metrics.gauge_add(  # udalint: disable=UDA101
-            "budget.hbm.reserved", nbytes)
-        return HbmHold(self, nbytes)
+            "budget.hbm.reserved", grew)
+        return HbmHold(self, nbytes, temp_bytes)
 
-    def _release(self, nbytes: int) -> None:
+    def _release(self, nbytes: int, temp_bytes: int) -> None:
         with self._cv:
+            before = self._booked()
             self._reserved -= nbytes
+            self._temps.remove(temp_bytes)
             self._holders -= 1
+            shrank = before - self._booked()
             self._cv.notify_all()
         metrics.gauge_add("reduce.tasks.live", -1)
-        metrics.gauge_add("budget.hbm.reserved", -nbytes)
+        metrics.gauge_add("budget.hbm.reserved", -shrank)
 
 
 hbm_ledger = HbmLedger()
@@ -364,6 +460,9 @@ class Admission:
     # meaning no budget was binding)
     cause: str = ""
     rerouted: bool = False
+    # rows of run capacity a device group may hold when the chip cannot
+    # hold the task whole (admit_device); 0 = the task is not grouped
+    group_rows: int = 0
 
     @property
     def rejected(self) -> bool:
@@ -447,6 +546,24 @@ class MemoryBudget:
     def device_bytes(self, partition_bytes: int) -> int:
         return device_bytes_estimate(partition_bytes, self.key_width)
 
+    def device_need(self, partition_bytes: int,
+                    segments: Optional[int] = None) -> tuple:
+        """``(rows, temporaries)`` bytes the chip must have free to
+        hold the task whole: its rows and the temporaries of its
+        largest merge program."""
+        return (self.device_bytes(partition_bytes),
+                merge_temp_bytes_estimate(partition_bytes, segments))
+
+    def group_reservation(self) -> tuple:
+        """``(group_rows, rows, temporaries)``: the largest device group
+        this budget holds (:func:`group_capacity_rows`) and the bytes a
+        task merged in such groups reserves — the group's forest and
+        the temporaries of the merge that folds it."""
+        group = group_capacity_rows(self.hbm_budget_bytes, self.key_width)
+        return (group,
+                int(FOREST_FACTOR * group * _row_bytes(self.key_width)),
+                MERGE_TEMP_ROW_BYTES * group)
+
     # -- admission point 1: INIT buffer validation --------------------------
 
     def validate_init(self, cfg) -> Admission:
@@ -511,15 +628,18 @@ class MemoryBudget:
 
     def route(self, estimate_bytes: Optional[int],
               threshold_bytes: int,
-              prefer_streaming: bool = False) -> Admission:
+              prefer_streaming: bool = False,
+              segments: Optional[int] = None) -> Admission:
         """The budget-aware auto merge-approach decision.
 
         - unknown estimate -> streaming (bounded memory for unbounded
           input);
         - over the hard ceiling -> reject (caller raises
           ``FallbackSignal`` before any allocation);
-        - device estimate over the HBM budget, or host-resident bytes
-          over the host budget -> streaming with bounded device runs;
+        - device need (rows and merge temporaries, for a partition in
+          ``segments`` runs) over the HBM budget -> streaming, merged
+          on the device in groups (``admit_device`` sizes them);
+          host-resident bytes over the host budget -> streaming;
         - small (within the measured hybrid crossover AND in budget) ->
           hybrid; in-budget above the crossover -> streaming (the
           measured-fastest large-scale path, which is also bounded).
@@ -537,7 +657,7 @@ class MemoryBudget:
                             hbm, host)
             self._record(adm, "budget.admitted")
             return adm
-        dev = self.device_bytes(estimate_bytes)
+        dev, temps = self.device_need(estimate_bytes, segments)
         hard = self.hard_ceiling_bytes
         if hard and estimate_bytes > hard:
             adm = Admission(
@@ -546,11 +666,11 @@ class MemoryBudget:
                 hbm, host, cause="hard")
             self._record(adm, "budget.rejected")
             return adm
-        if dev > hbm:
+        if dev + temps > hbm:
             adm = Admission(
                 "streaming", f"over-hbm-budget: device working set "
-                f"{dev} B > {hbm} B", estimate_bytes, dev, hbm, host,
-                cause="hbm", rerouted=True)
+                f"{dev} B + {temps} B of merge temporaries > {hbm} B",
+                estimate_bytes, dev, hbm, host, cause="hbm", rerouted=True)
             self._record(adm, "budget.rerouted")
             return adm
         # hybrid/in-memory additionally hold the fetched bytes host-
@@ -579,34 +699,42 @@ class MemoryBudget:
     # -- admission point 3: the chip-wide HBM ledger ------------------------
 
     def admit_device(self, estimate_bytes: Optional[int],
-                     bounded: bool = False,
-                     stopped: Optional[Callable[[], bool]] = None
-                     ) -> tuple:
+                     segments: Optional[int] = None,
+                     stopped: Optional[Callable[[], bool]] = None,
+                     counted: bool = False) -> tuple:
         """Put one task on the chip's books (:data:`hbm_ledger`) before
-        it stages its first run to the device: reserve its device
-        estimate against this budget's view of the chip, blocking while
-        the live tasks leave no room (see :class:`HbmLedger`; a lone
-        task never waits). Returns ``(hold, reroute)``: the hold to
-        release on every exit, and None — or, when the chip cannot hold
-        the task even alone, the :class:`Admission` that sends it down
-        the bounded-device route (cause ``"hbm"``), on which it is live
-        on the books and reserves nothing. ``bounded`` says the caller
-        is on that route already. An unknown estimate reserves the
-        whole budget: a task of unknown size has the chip to itself."""
+        it stages its first run to the device: reserve its device need
+        — rows and the temporaries of its largest merge, for a
+        partition in ``segments`` runs — against this budget's view of
+        the chip, blocking while the live tasks leave no room (see
+        :class:`HbmLedger`; a lone task never waits). Returns ``(hold,
+        reroute)``: the hold to release on every exit, and None — or,
+        when the chip cannot hold the task even alone, the
+        :class:`Admission` (cause ``"hbm"``) that sends it down the
+        streaming route to be merged on the device in GROUPS of
+        ``reroute.group_rows`` rows of run capacity: the largest group
+        the whole budget holds, which is what the task then reserves,
+        waiting its turn like any other. ``counted`` says ``route``
+        has sent this task there already and counted it. An unknown
+        estimate reserves the whole budget: a task of unknown size has
+        the chip to itself."""
         hbm = self.hbm_budget_bytes
+        if estimate_bytes is None:
+            return hbm_ledger.reserve(hbm, hbm, stopped), None
+        dev, temps = self.device_need(estimate_bytes, segments)
         reroute = None
-        dev = hbm if estimate_bytes is None \
-            else self.device_bytes(estimate_bytes)
-        if bounded:
-            dev = 0
-        elif dev > hbm:
+        if dev + temps > hbm:
+            group, group_bytes, group_temps = self.group_reservation()
             reroute = Admission(
                 "streaming", f"over-hbm-budget: device working set "
-                f"{dev} B > {hbm} B", estimate_bytes, dev, hbm,
-                self.host_budget_bytes, cause="hbm", rerouted=True)
-            self._record(reroute, "budget.rerouted")
-            dev = 0
-        return hbm_ledger.reserve(dev, hbm, stopped), reroute
+                f"{dev} B + {temps} B of merge temporaries > {hbm} B; "
+                f"merged on the device in groups of {group} rows",
+                estimate_bytes, dev, hbm, self.host_budget_bytes,
+                cause="hbm", rerouted=True, group_rows=group)
+            if not counted:
+                self._record(reroute, "budget.rerouted")
+            dev, temps = group_bytes, group_temps
+        return hbm_ledger.reserve(dev, hbm, stopped, temps), reroute
 
     # -- bookkeeping --------------------------------------------------------
 

@@ -71,9 +71,12 @@ SPAN_BUCKETS: Dict[str, str] = {
     # device-put: host->device transfer + buffer-recycle wait
     "overlap_stage": "device_put", "merge.device_put": "device_put",
     # merge: device/host merge + sort compute (merge_host_batch runs
-    # inside overlap_device_merge: the forest's host-class carries)
+    # inside overlap_device_merge: the forest's host-class carries;
+    # merge_group_flush / merge_group_join: an over-budget task's
+    # groups folded and read back, then joined on the host)
     "merge": "merge", "overlap_device_merge": "merge",
-    "merge_host_batch": "merge",
+    "merge_host_batch": "merge", "merge_group_flush": "merge",
+    "merge_group_join": "merge",
     "device_sort": "merge", "lpq_spill": "merge", "lpq_phase": "merge",
     "rpq_phase": "merge",
     # emit: the reduce side's output path after the forest is merged —

@@ -138,7 +138,8 @@ METRICS_REGISTRY: Dict[str, tuple] = {
     "budget.admitted": ("counter", "admission decisions that kept the "
                                    "requested path (utils/budget.py)"),
     "budget.rerouted": ("counter", "over-budget tasks rerouted to a "
-                                   "bounded path (streaming / shrunken "
+                                   "bounded path (streaming, merged on "
+                                   "the device in groups / shrunken "
                                    "window)"),
     "budget.rejected": ("counter", "tasks refused before allocation "
                                    "(hard ceiling / unfittable INIT)"),
@@ -180,7 +181,8 @@ METRICS_REGISTRY: Dict[str, tuple] = {
     "emit.gather.native_slabs": ("counter", "output slabs gathered by "
                                             "the native routine over "
                                             "the per-task segment table "
-                                            "(slab_batch); short of the "
+                                            "(slab_batch) or run table "
+                                            "(interleave_runs); short of the "
                                             "emit_gather span count = "
                                             "slabs that fell back to "
                                             "the numpy path"),
@@ -198,6 +200,11 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                      "classes below overlap."
                                      "DEVICE_MIN_BUCKET); their seconds "
                                      "are the merge_host_batch timer's"),
+    "merge.device_groups": ("counter", "groups of an over-budget task "
+                                       "folded on the device into one run "
+                                       "and read back to a host row run "
+                                       "(merger/overlap.py:_flush_group); "
+                                       "0 for a task the chip holds whole"),
     "spool.bytes": ("counter", "bytes spooled to sorted run files "
                                "(streaming online mode)"),
     # -- counters: staging pipeline (merger/overlap stage pool) ----------
@@ -497,7 +504,9 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                    "high-water mark kept (PEAK_GAUGES)"),
     "budget.hbm.reserved": ("gauge", "device bytes the live reduce tasks "
                                      "hold reserved in the chip-wide HBM "
-                                     "ledger (utils/budget.py); high-water "
+                                     "ledger (utils/budget.py): their rows "
+                                     "and the largest of their merge "
+                                     "temporaries; high-water "
                                      "mark kept (PEAK_GAUGES)"),
     "profile.hz": ("gauge", "sampling-profiler rate currently armed "
                             "(0 = off; set absolutely at start/stop, "
