@@ -409,3 +409,101 @@ def test_distributed_sort_step_engine_matches_host_oracle(engine):
         want = mine[np.lexsort((mine[:, 1], mine[:, 0]))]
         np.testing.assert_array_equal(out[d, :nvalid[d]], want,
                                       err_msg=f"shard {d}")
+
+
+def _scatter_round_body(w, d, q, lo, axis, capacity):
+    """The plain reference of ``window_round_body``: the send buffer
+    scattered row by row at ``(destination, in-window slot)``, rows
+    outside the window onto a drop row — how the round was written
+    before its windows were read as slices (PR 33). It needs no order."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    p = lax.psum(1, axis)
+    in_round = (q >= lo) & (q < lo + capacity)
+    slot = jnp.where(in_round, q - lo, capacity)
+    send = jnp.zeros((p, capacity + 1, w.shape[1]), w.dtype)
+    send = send.at[d, slot].set(w, mode="drop")
+    send_counts = jnp.bincount(
+        jnp.where(in_round, d, p), length=p + 1)[:p].astype(jnp.int32)
+    recv = lax.all_to_all(send[:, :capacity], axis, split_axis=0,
+                          concat_axis=0, tiled=False)
+    recv_counts = lax.all_to_all(send_counts[:, None], axis, split_axis=0,
+                                 concat_axis=0, tiled=False).reshape(p)
+    return recv.reshape(p * capacity, w.shape[1]), recv_counts
+
+
+# (local rows, capacity, window index, how a device's rows pick their
+# destinations)
+_WINDOW_CASES = {
+    "capacity_over_rows": (24, 40, 0, "uniform"),
+    "bucket_over_capacity_window0": (48, 8, 0, "skew"),
+    "bucket_over_capacity_window1": (48, 8, 1, "skew"),
+    "bucket_over_capacity_window2": (48, 8, 2, "skew"),
+    "empty_bucket": (32, 16, 0, "skip_last"),
+    "all_to_one": (32, 12, 0, "one"),
+    "all_to_one_window2": (32, 12, 2, "one"),
+    "window_past_every_bucket": (16, 4, 5, "uniform"),
+}
+
+
+def _case_dest(kind, rng, n, p, device):
+    if kind == "uniform":
+        return rng.integers(0, p, size=n)
+    if kind == "skew":      # over half to one destination: > 3 windows
+        hot = (device + 1) % p
+        return np.where(rng.random(n) < 0.6, hot, rng.integers(0, p, size=n))
+    if kind == "skip_last":                 # nobody sends to p - 1
+        return rng.integers(0, max(p - 1, 1), size=n)
+    return np.full(n, p // 2)               # "one"
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+@pytest.mark.parametrize("p", [1, 2, 4, 8])
+def test_window_round_body_delivers_what_the_scatter_does(p, case):
+    # flat WHOLE — the zeros past each peer's count included, they ride
+    # through the sort as invalid rows' payload — and recv_counts, on
+    # rows in _bucket_local's order (window_round_body's precondition)
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from uda_tpu.parallel import shard_map
+    from uda_tpu.parallel.exchange import window_round_body
+
+    n, capacity, window, kind = _WINDOW_CASES[case]
+    rng = np.random.default_rng(1000 * p + sorted(_WINDOW_CASES).index(case))
+    dest = np.concatenate([_case_dest(kind, rng, n, p, device)
+                           for device in range(p)]).astype(np.int32)
+    words = rng.integers(1, 2**32, size=(p * n, 3), dtype=np.uint32)
+    mesh = make_mesh(p, AXIS)
+    # the order every caller hands the body: the program's own bucketing
+    layout = prepare_layout(words, dest, mesh, AXIS)
+    if kind == "skew":
+        assert layout.counts.max() > 2 * capacity
+
+    def run(body):
+        @jax.jit
+        @partial(shard_map, mesh=mesh,
+                 in_specs=(P(AXIS), P(AXIS), P(AXIS), P()),
+                 out_specs=(P(AXIS), P(AXIS)))
+        def go(w, d, q, lo):        # lo traced, as the round programs do
+            flat, counts = body(w, d, q, lo[0], AXIS, capacity)
+            return flat, counts.reshape(1, -1)
+
+        flat, counts = go(layout.words, layout.dest, layout.pos,
+                          jnp.asarray([window * capacity], jnp.int32))
+        return np.asarray(flat), np.asarray(counts)
+
+    got_flat, got_counts = run(window_round_body)
+    want_flat, want_counts = run(_scatter_round_body)
+    np.testing.assert_array_equal(got_counts, want_counts)
+    np.testing.assert_array_equal(got_flat, want_flat)
+    assert got_flat.shape == (p * p * capacity, 3)
+    # and the reference itself delivers what the counts say it should:
+    # recv_counts[dst, src] = the part of bucket (src, dst) in the window
+    np.testing.assert_array_equal(
+        want_counts,
+        np.clip(layout.counts.T - window * capacity, 0, capacity))

@@ -9,6 +9,12 @@ merge (src/Merger/MergeManager.cc) into ONE jitted SPMD program:
     partition (splitter search) -> bucket -> all_to_all (ICI) ->
     local lexicographic sort -> globally sorted, device-sharded output
 
+"bucket" PERMUTES the rows once — a stable argsort of the destinations
+and one ``take`` — and after that only COPIES them: in destination
+order a (destination, window) is a contiguous run of rows, so the round
+body (parallel/exchange.py ``window_round_body``) fills the send buffer
+with one slice a destination, and nothing is scattered row by row.
+
 Global order: destinations are monotone in key-prefix, so after the
 exchange device d holds exactly range-partition d and the concatenation
 of per-device sorted shards is the total order — the same contract as

@@ -153,8 +153,12 @@ class ShuffleLayout:
 
     All arrays are mesh-sharded along axis 0 (one row block per device):
 
-    - ``words``: uint32[N, W] records, locally ordered by destination;
-    - ``dest``: int32[N] destination partition of each local record;
+    - ``words``: uint32[N, W] records, locally SORTED by destination
+      (stable by arrival). The order is load-bearing, not descriptive:
+      :func:`window_round_body` reads a destination's window as one
+      contiguous slice of these rows;
+    - ``dest``: int32[N] destination partition of each local record
+      (ascending within a device's block, by the same order);
     - ``pos``: int32[N] position of the record within its (src, dst)
       bucket — ``pos // capacity`` is the round it travels in;
     - ``counts``: int32[P, P] full count matrix (row = src device,
@@ -190,7 +194,9 @@ class ShuffleLayout:
 
 def _bucket_local(words, dest, axis):
     """Stable local bucket-by-destination; returns sorted rows, dest,
-    in-bucket positions and per-dest counts."""
+    in-bucket positions and per-dest counts. This order — rows sorted
+    by destination, ``pos`` the index inside the bucket — is the
+    precondition of :func:`window_round_body`, which slices it."""
     p = lax.psum(1, axis)
     order = jnp.argsort(dest, stable=True)
     sdest = jnp.take(dest, order)
@@ -237,18 +243,43 @@ def window_round_body(w, d, q, lo, axis: str, capacity: int):
     the multiround scatter in uda_tpu.parallel.distributed both build on
     it). ``lo`` (the window base, round * capacity) may be traced.
 
+    PRECONDITION (every caller's ``_bucket_local`` order): the rows of
+    ``w`` are sorted by destination ``d``, ascending, and ``q`` is each
+    row's position inside its destination's bucket. In that order
+    destination k's window ``[lo, lo + capacity)`` IS the contiguous
+    rows ``starts[k] + lo ...`` of ``w``, so the send buffer is P
+    window copies — one ``dynamic_slice`` a destination, the rows past
+    the bucket's end zeroed in the same pass — and ``q``, implied by
+    the order, is not read. Why slices: a ``[n, W]`` row matrix is
+    stored long-dimension-minor on the chip, so scattering rows to
+    ``(d, q - lo)`` is W lane scatters n long — a quarter of the fused
+    step at 2^24 rows a chip (PERF.md §6, PR 33). That scatter is the
+    reference tests/test_exchange.py holds this body to.
+
     Returns ``(flat, recv_counts)``: the local [P*capacity, W] delivery
-    (row block i = peer i's contribution) and per-peer valid counts [P].
+    (row block i = peer i's contribution, zeros past its count) and
+    per-peer valid counts [P].
     """
     p = lax.psum(1, axis)
     wcols = w.shape[1]
-    in_round = (q >= lo) & (q < lo + capacity)
-    slot = jnp.where(in_round, q - lo, capacity)  # overflow -> dropped row
-    send = jnp.zeros((p, capacity + 1, wcols), w.dtype)
-    send = send.at[d, slot].set(w, mode="drop")
-    send_counts = jnp.bincount(
-        jnp.where(in_round, d, p), length=p + 1)[:p].astype(jnp.int32)
-    recv = lax.all_to_all(send[:, :capacity], axis, split_axis=0,
+    counts = jnp.bincount(d, length=p).astype(jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    send_counts = jnp.clip(counts - lo, 0, capacity)
+    # a window may run past the last row (the last bucket's always can,
+    # and capacity >= n whenever P <= 2), where dynamic_slice would
+    # clamp its start and shift the rows: the slices read w padded with
+    # zeros (fused into each slice by XLA, no padded copy exists)
+    wpad = jnp.pad(w, ((0, capacity), (0, 0)))
+    slot = jnp.arange(capacity, dtype=jnp.int32)[:, None]
+    # updates in place, not jnp.stack: the compiler's memory_analysis()
+    # of the fused step is 134 MB a chip lower this way (v5e:2x2)
+    send = jnp.zeros((p, capacity, wcols), w.dtype)
+    for k in range(p):
+        window = lax.dynamic_slice_in_dim(wpad, starts[k] + lo, capacity)
+        send = lax.dynamic_update_slice(
+            send, jnp.where(slot < send_counts[k], window, 0)[None],
+            (k, 0, 0))
+    recv = lax.all_to_all(send, axis, split_axis=0,
                           concat_axis=0, tiled=False)
     recv_counts = lax.all_to_all(send_counts[:, None], axis,
                                  split_axis=0, concat_axis=0,
