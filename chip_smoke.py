@@ -19,7 +19,9 @@ and exits 0 only if all of it happened on the TPU:
   interpreted) and checked against ``np.lexsort``;
 - Phase C, ``distributed_terasort`` with its defaults over four chips
   on the ``ici:4`` and ``dcn:2,ici:2`` meshes, when the machine has
-  four, and the lanes engine by name.
+  four, and the lanes engine by name; then, on ``ici:4``, the same
+  entry with ``splitters="sampled"`` on Zipf id keys that all share
+  their first word (records back, every one, in one total order).
 
 The parent process never imports JAX: a chip belongs to one process at
 a time, so the phases run as sequential children that share one compile
@@ -479,6 +481,30 @@ def phase_c(args, sizes: dict) -> dict:
         for d in range(p):
             terasort.validate_sorted(out[d, :nvalid[d]], words[dest == d])
 
+    def run_sampled(spec: str) -> None:
+        """Keys of unknown distribution: Zipf ids (s = 1 over 2^20) as
+        10-byte big-endian numbers, so every first key word is 0 and
+        the uniform splitters above would send every record to one
+        chip. The step samples its own splitters; the shards in order
+        must be one sorted sequence holding every record."""
+        ids = np.floor(2 ** (20 * rng.random(n))).astype(np.uint32)
+        skewed = words.copy()
+        skewed[:, 0] = 0
+        skewed[:, 1] = ids >> 16
+        skewed[:, 2] = (ids & 0xFFFF) << 16
+        mesh = mesh_from_config(Config({"uda.tpu.mesh.shape": spec}))
+        res = terasort.distributed_terasort(skewed, mesh, mesh.axis_names[0],
+                                            splitters="sampled")
+        res.check()
+        nvalid = np.asarray(res.valid_counts).reshape(-1)
+        out = np.asarray(res.words).reshape(p, -1, terasort.RECORD_WORDS)
+        del res
+        if int(nvalid.sum()) != n or int(nvalid.max()) > 0.31 * n:
+            raise SmokeFailure(f"shards hold {nvalid.tolist()} of {n} "
+                               f"records")
+        terasort.validate_sorted(
+            np.concatenate([out[d, :nvalid[d]] for d in range(p)]), skewed)
+
     # the default engine first — a default that cannot start fails the
     # smoke — then the lanes engine by name where the default is another
     # (on the CPU; on a TPU the step's default IS lanes)
@@ -489,6 +515,8 @@ def phase_c(args, sizes: dict) -> dict:
         for spec in MESHES:
             _attempt(runs, f"{spec}/{engine}",
                      lambda spec=spec, engine=engine: run(spec, engine))
+    _attempt(runs, f"{MESHES[0]}/sampled-zipf",
+             lambda: run_sampled(MESHES[0]))
     obs = {"device": device, "records_per_chip": n // p,
            "default_engine": default_engine, "runs": runs}
     if not args.rehearse_cpu:

@@ -38,7 +38,8 @@ def test_rehearsal_runs_end_to_end():
                              "sort:lanes", "sort:keys8",
                              "merge_sorted_pair"}
     assert set(smoke["c"]["runs"]) == {
-        "ici:4/auto", "ici:4/lanes", "dcn:2,ici:2/auto", "dcn:2,ici:2/lanes"}
+        "ici:4/auto", "ici:4/lanes", "dcn:2,ici:2/auto", "dcn:2,ici:2/lanes",
+        "ici:4/sampled-zipf"}
     assert smoke["c"]["default_engine"] == "carry"   # the CPU's
     assert "peak_bytes_in_use" not in smoke["c"]
 

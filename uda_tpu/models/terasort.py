@@ -125,20 +125,36 @@ def single_chip_sort(words: jax.Array, path: str = "auto",
 
 
 def distributed_terasort(words, mesh: Mesh, axis: str = SHUFFLE_AXIS,
-                         capacity: Optional[int] = None
+                         capacity: Optional[int] = None,
+                         splitters: str = "uniform"
                          ) -> DistributedSortResult:
     """Multi-chip TeraSort step over the mesh (BASELINE config 5 shape).
 
-    ``capacity`` defaults to 2x the balanced per-(src,dst) share —
-    uniform keys stay far under it; heavy skew should use
-    parallel.exchange.shuffle_exchange's multi-round path instead.
+    ``splitters`` is the job's statement about its keys, the sort
+    benchmark's two categories: ``"uniform"`` (Indy: TeraGen's keys,
+    equal ranges of the keyspace balance the shards) or ``"sampled"``
+    (Daytona: nothing assumed — ids, words, duplicates, shared
+    prefixes; the step samples its own input on the device and
+    partitions by the sample's quantiles,
+    parallel/distributed.py:distributed_sort_step with
+    ``splitters=None``). Either way the partition compares whole keys,
+    equal keys leave in input order, and ``res.splitters`` says which
+    range each shard holds.
+
+    ``capacity`` defaults to 2x the balanced per-(src,dst) share. A
+    bucket that overflows it — uniform splitters on skewed keys, or a
+    key too hot for one window — sends the step through the windowed
+    rounds, same splitters, same result: any distribution completes.
     """
+    if splitters not in ("uniform", "sampled"):
+        raise ValueError(f"unknown splitters {splitters!r}")
     p = int(np.prod(list(mesh.shape.values())))
     n = int(words.shape[0])
     if capacity is None:
         capacity = max(1, (2 * n) // (p * p))
-    return distributed_sort_step(words, uniform_splitters(p), mesh, axis,
-                                 capacity=capacity, num_keys=KEY_WORDS)
+    return distributed_sort_step(
+        words, uniform_splitters(p) if splitters == "uniform" else None,
+        mesh, axis, capacity=capacity, num_keys=KEY_WORDS)
 
 
 @jax.jit

@@ -21,9 +21,13 @@ of per-device sorted shards is the total order — the same contract as
 the reference's per-reducer partition files, but computed in one XLA
 program with no host round-trips.
 
-Range splitters come from the host (uniform for TeraSort-style keys, or
-sampled quantiles), mirroring how Hadoop's TotalOrderPartitioner feeds
-TeraSort.
+Range splitters are WHOLE keys, as Hadoop's TotalOrderPartitioner
+compares them: uniform edges handed in by the caller (keys known to be
+uniform, the sort benchmark's Indy category), or — ``splitters=None``,
+keys of unknown distribution, its Daytona category — quantiles of a
+sample the program takes of its own input before it partitions, on the
+device (TeraSort's ``TeraInputFormat.writePartitionFile`` without the
+job-start host pass).
 """
 
 from __future__ import annotations
@@ -40,9 +44,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from uda_tpu.ops.sort import resolve_sort_path
 from uda_tpu.parallel.multihost import put_global, put_rows, zeros_global
 from uda_tpu.utils.errors import TransportError
+from uda_tpu.utils.metrics import metrics
 
 __all__ = ["uniform_splitters", "sample_splitters", "distributed_sort_step",
-           "distributed_sort_multiround", "DistributedSortResult"]
+           "distributed_sort_multiround", "DistributedSortResult",
+           "SAMPLE_KEYS"]
+
+# Keys sampled a step, over all chips, when the program chooses its own
+# splitters: Hadoop TeraSort's mapreduce.terasort.partitions.sample
+# (TeraInputFormat.writePartitionFile), 100,000.
+SAMPLE_KEYS = 100_000
 
 # numpy scalar, NOT jnp: a module-level jnp constant would materialize
 # a device array at import time, initializing the XLA backend and
@@ -68,34 +79,134 @@ def uniform_splitters(num_partitions: int) -> np.ndarray:
     return edges.astype(np.uint32)
 
 
-def sample_splitters(first_words: np.ndarray, num_partitions: int,
-                     oversample: int = 64) -> np.ndarray:
-    """Sampled quantile splitters for skewed key distributions (the
-    TotalOrderPartitioner analogue). ``first_words`` is any sample of
-    first key words."""
-    sample = np.sort(np.asarray(first_words, dtype=np.uint32))
-    if sample.size == 0:
-        return uniform_splitters(num_partitions)
-    idx = (np.arange(1, num_partitions) * sample.size) // num_partitions
-    return sample[np.minimum(idx, sample.size - 1)]
+def sample_splitters(sample_keys: np.ndarray,
+                     num_partitions: int) -> np.ndarray:
+    """The quantile rule on the host: the ``num_partitions - 1`` keys
+    that cut a sorted sample into equal parts (the
+    TotalOrderPartitioner analogue). ``sample_keys`` is any sample of
+    keys, first words ``[m]`` or whole keys ``[m, K]``; the splitters
+    come back in the same form. The program's own sampling stage
+    (``_sampled_splitters``) applies this rule on the device; this is
+    the reference the tests hold it to."""
+    sample = np.asarray(sample_keys, dtype=np.uint32)
+    if sample.shape[0] == 0:
+        edges = uniform_splitters(num_partitions)
+        return edges if sample.ndim == 1 else _whole_keys(edges,
+                                                          sample.shape[1])
+    cols = sample[:, None] if sample.ndim == 1 else sample
+    sample = sample[np.lexsort(cols.T[::-1])]
+    idx = (np.arange(1, num_partitions) * len(sample)) // num_partitions
+    return sample[np.minimum(idx, len(sample) - 1)]
+
+
+def _whole_keys(splitters, num_keys: int) -> np.ndarray:
+    """Splitters as whole keys ``uint32[P-1, num_keys]``: first-word
+    edges ``[P-1]`` become ``(edge, 0, ..., 0)``, the least key with
+    that first word, which partitions as the edge alone did."""
+    spl = np.asarray(splitters, dtype=np.uint32)
+    if spl.ndim == 2:
+        if spl.shape[1] != num_keys:
+            raise ValueError(f"splitters of {spl.shape[1]} words for keys "
+                             f"of {num_keys}")
+        return spl
+    whole = np.zeros((spl.shape[0], num_keys), np.uint32)
+    whole[:, 0] = spl
+    return whole
+
+
+def _partition(w, spl, num_keys: int):
+    """Destination of every row of ``w``: the number of splitters (whole
+    keys ``[P-1, num_keys]``, ascending) at or below the row's key in
+    lexicographic order of the ``num_keys`` key words — THE partition,
+    for splitters of either origin and for both routes. One elementwise
+    pass a splitter over the key columns (P is the chip count: a
+    search would gather, this fuses)."""
+    dest = jnp.zeros(w.shape[0], jnp.int32)
+    last = num_keys - 1
+    for j in range(spl.shape[0]):
+        at_or_above = w[:, last] >= spl[j, last]
+        for c in reversed(range(last)):
+            at_or_above = ((w[:, c] > spl[j, c])
+                           | ((w[:, c] == spl[j, c]) & at_or_above))
+        dest = dest + at_or_above.astype(jnp.int32)
+    return dest
+
+
+def _sample_size(n_local: int, p: int) -> int:
+    """Rows a chip samples: its share of ``SAMPLE_KEYS``, or all it has."""
+    return min(n_local, max(1, SAMPLE_KEYS // p))
+
+
+def _sample_rows(n_local: int, p: int) -> np.ndarray:
+    """The local rows a chip samples, evenly spaced — a function of
+    (n, p) alone, so a step is a pure function of its input."""
+    take = _sample_size(n_local, p)
+    return ((np.arange(take, dtype=np.int64) * n_local + n_local // 2)
+            // take).astype(np.int32)
+
+
+def _count_sample(n: int, p: int) -> None:
+    metrics.add("exchange.sample.keys", p * _sample_size(n // p, p))
+
+
+def _replicated(x, mesh):
+    """One copy of a value every chip computed alike."""
+    return lax.with_sharding_constraint(x, NamedSharding(mesh, P()))
+
+
+def _sampled_splitters(w, axis, num_keys: int, payload_path: str,
+                       interpret: bool):
+    """The sampling stage, INSIDE a shard_map body: each chip's
+    systematic sample of its own rows' whole keys, gathered to every
+    chip, sorted by the step's own engine (``_sort_valid_rows``: a
+    3-operand ``lax.sort`` of 100,000 keys alone took a minute to
+    compile for a v5e, PR 34), cut at the P-1 quantiles
+    (``sample_splitters``' rule). Returns ``uint32[P-1, num_keys]``,
+    the same on every chip (an ``all_gather``'s result is typed per-chip
+    all the same: it leaves the body as one copy a chip, ``_replicated``
+    keeps one)."""
+    with jax.named_scope("exchange_sample"):
+        p = lax.psum(1, axis)
+        rows = _sample_rows(w.shape[0], p)
+        mine = jnp.stack([jnp.take(w[:, c], rows) for c in range(num_keys)],
+                         axis=1)
+        sample = lax.all_gather(mine, axis).reshape(-1, num_keys)
+        m = sample.shape[0]
+        sample = _sort_valid_rows(sample, jnp.ones(m, jnp.bool_), num_keys,
+                                  payload_path, interpret)
+        idx = np.minimum((np.arange(1, p, dtype=np.int64) * m) // p, m - 1)
+        return jnp.take(sample, idx.astype(np.int32), axis=0)
 
 
 class DistributedSortResult:
     """Device-sharded sorted output of one distributed sort step."""
 
     def __init__(self, words: jax.Array, valid_counts: jax.Array,
-                 send_overflow: jax.Array, overflow_total=None):
+                 send_overflow: jax.Array, totals=None, splitters=None,
+                 input_rows: int = 0):
         self.words = words              # [P*cap_total rows, W] sharded
         self.valid_counts = valid_counts  # [P] valid rows per device
         self.send_overflow = send_overflow  # [P] records dropped (0 = ok)
-        # replicated scalar: readable on EVERY process of a multi-host
-        # mesh (the per-device vector is not addressable cross-process)
-        self._overflow_total = overflow_total
+        # the whole keys [P-1, K] the step partitioned by (replicated):
+        # shard d holds the keys in [splitters[d-1], splitters[d])
+        self.splitters = splitters
+        # replicated int32[2], (records dropped, largest shard's valid
+        # rows): readable on EVERY process of a multi-host mesh (the
+        # per-device vectors are not addressable cross-process), and
+        # ONE readback for both
+        self._totals = totals
+        self._input_rows = input_rows   # n, the gauge's denominator
+        self._read = False
 
     def overflow(self) -> int:
-        if self._overflow_total is not None:
-            return int(np.asarray(self._overflow_total))
-        return int(np.asarray(self.send_overflow).sum())
+        if self._totals is None:
+            return int(np.asarray(self.send_overflow).sum())
+        if not self._read:
+            self._totals, self._read = np.asarray(self._totals), True
+            metrics.gauge("exchange.shard.max_permille",
+                          1000.0 * int(self._totals[1])
+                          / max(1, self._input_rows))
+        return int(self._totals[0])
 
     def check(self) -> None:
         total = self.overflow()
@@ -197,10 +308,17 @@ def _sort_valid_rows_lanes(flat, valid, num_keys, interpret, keys8=False):
 @partial(jax.jit, static_argnames=("mesh", "axis", "capacity", "num_keys",
                                    "payload_path", "interpret",
                                    "exchange_mode", "dcn_axis",
-                                   "ici_axis"))
+                                   "ici_axis", "sample"))
 def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
                payload_path="carry", interpret=False,
-               exchange_mode="flat", dcn_axis=None, ici_axis=None):
+               exchange_mode="flat", dcn_axis=None, ici_axis=None,
+               sample=False):
+    """The fused step. ``splitters``: whole keys ``uint32[P-1,
+    num_keys]``, replicated; with ``sample`` they are ignored and the
+    program takes its own from ``words`` (``_sampled_splitters``) before
+    it partitions. Returns the sorted shards, their valid counts, the
+    per-device overflow, the replicated ``(overflow, largest shard)``
+    pair and the splitters the step partitioned by."""
     # check_vma is ON everywhere except interpret mode (which only the
     # Pallas engines on a CPU mesh ever set, _lanes_interpret): the
     # Pallas interpreter expands pallas_call into eval_jaxpr whose
@@ -210,15 +328,18 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
     # compiled path traces clean since the merge-pass fori_loop carry
     # is pcast to the data's vma at init (ops/pallas_sort._pass_splits).
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P()),
-             out_specs=(P(axis), P(axis), P(axis)),
+             out_specs=(P(axis), P(axis), P(axis), P(axis)),
              check_vma=not interpret)
     def _go(w, spl):
         from uda_tpu.parallel.exchange import run_round_body
 
         p = lax.psum(1, axis)
         n, wcols = w.shape
-        # 1. partition: monotone in the first key word
-        dest = jnp.searchsorted(spl[0], w[:, 0], side="right").astype(jnp.int32)
+        # 0. splitters from the input itself, when none were handed in
+        spl = _sampled_splitters(w, axis, num_keys, payload_path,
+                                 interpret) if sample else spl[0]
+        # 1. partition: monotone in the whole key
+        dest = _partition(w, spl, num_keys)
         # 2. bucket locally (stable by arrival)
         order = jnp.argsort(dest, stable=True)
         sd = jnp.take(dest, order)
@@ -240,12 +361,13 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
         out = _sort_valid_rows(flat, valid, num_keys, payload_path,
                                interpret)
         nvalid = jnp.sum(recv_counts)
-        return out, nvalid[None], overflow[None]
+        return out, nvalid[None], overflow[None], spl[None]
 
-    out, nvalid, overflow = _go(words, splitters[None, :])
-    # replicated total: host-readable on every process of a multi-host
-    # mesh, where the per-device overflow vector is not addressable
-    return out, nvalid, overflow, jnp.sum(overflow)
+    out, nvalid, overflow, spl = _go(words, splitters[None])
+    # replicated totals: host-readable on every process of a multi-host
+    # mesh, where the per-device vectors are not addressable
+    totals = jnp.stack([jnp.sum(overflow), jnp.max(nvalid)])
+    return out, nvalid, overflow, totals, _replicated(spl[0], mesh)
 
 
 def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
@@ -258,6 +380,17 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
 
     ``words``: uint32[N, W] records (rows sharded over ``axis``; the
     first ``num_keys`` columns are the big-endian key words).
+    ``splitters``: the P-1 range splitters, ascending — whole keys
+    ``uint32[P-1, num_keys]``, or first-word edges ``[P-1]``
+    (``uniform_splitters``), read as the whole keys ``(edge, 0, ..)``;
+    destination = the number of splitters at or below the row's whole
+    key. ``None``: keys of unknown distribution — the program samples
+    ``SAMPLE_KEYS`` whole keys of its input on the device (each chip an
+    even stride of its own rows), gathers and sorts them and partitions
+    by their P-1 quantiles, inside the same fused program; a key never
+    straddles two shards, so the largest shard is at most
+    ``N * (1/P + h + e)``, ``h`` the most frequent key's share and ``e``
+    the sampling error. The splitters used ride with the result.
     ``axis``: one mesh axis name, or a TUPLE of axis names for
     multi-pod meshes — e.g. ``("dcn", "shuffle")`` on a (pods, chips)
     mesh shards rows over both; results are byte-identical to the flat
@@ -287,7 +420,8 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     reference's credit flow (RDMAComm.cc:707-752: no-credit sends queue
     on the backlog and drain as credits return, so ANY skew eventually
     completes). "never" reports overflow in the result (caller handles
-    it); "always" skips the fused attempt.
+    it); "always" skips the fused attempt. The rounds partition by the
+    SAME splitters as the fused attempt did (sampled once).
     """
     from uda_tpu.parallel.exchange import (exchange_dispatch,
                                            resolve_exchange_mode)
@@ -301,15 +435,23 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
                                            capacity, num_keys, payload_path,
                                            exchange_mode)
     words = put_rows(words, mesh, axis)
-    splitters_dev = put_global(np.asarray(splitters, dtype=np.uint32),
-                               NamedSharding(mesh, P()))
-    out, nvalid, overflow, total = _sort_step(
+    p = topo.num_devices
+    sample = splitters is None
+    splitters_dev = put_global(
+        np.zeros((p - 1, num_keys), np.uint32) if sample
+        else _whole_keys(splitters, num_keys), NamedSharding(mesh, P()))
+    out, nvalid, overflow, totals, used = _sort_step(
         words, splitters_dev, mesh, axis, capacity, num_keys, payload_path,
-        interpret=_lanes_interpret(payload_path, mesh),
+        interpret=_lanes_interpret(payload_path, mesh), sample=sample,
         **exchange_dispatch(topo, hier))
-    res = DistributedSortResult(out, nvalid, overflow, total)
+    if sample:
+        _count_sample(int(words.shape[0]), p)
+    metrics.add("exchange.fused.overflow_reruns", 0)
+    res = DistributedSortResult(out, nvalid, overflow, totals, used,
+                                int(words.shape[0]))
     if multiround == "auto" and res.overflow() != 0:
-        return distributed_sort_multiround(words, splitters, mesh, axis,
+        metrics.add("exchange.fused.overflow_reruns")
+        return distributed_sort_multiround(words, used, mesh, axis,
                                            capacity, num_keys, payload_path,
                                            exchange_mode)
     return res
@@ -377,6 +519,19 @@ def _sort_shard(acc, nvalid, mesh, axis, num_keys, payload_path,
     return _go(acc, nvalid)
 
 
+@partial(jax.jit, static_argnames=("mesh", "axis", "num_keys",
+                                   "payload_path", "interpret"))
+def _sample_step(words, mesh, axis, num_keys, payload_path, interpret):
+    """The sampling stage alone, for a sort that skips the fused attempt
+    (multiround="always"): replicated ``uint32[P-1, num_keys]``."""
+    spl = shard_map(
+        lambda w: _sampled_splitters(w, axis, num_keys, payload_path,
+                                     interpret)[None],
+        mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+        check_vma=not interpret)(words)
+    return _replicated(spl[0], mesh)
+
+
 def distributed_sort_multiround(words, splitters, mesh: Mesh, axis: str,
                                 capacity: int, num_keys: int,
                                 payload_path: str = "auto",
@@ -394,7 +549,8 @@ def distributed_sort_multiround(words, splitters, mesh: Mesh, axis: str,
     707-752, drained in RDMAClient.cc:64-92). Peak memory per device is
     O(largest destination shard + P x capacity): each round's delivery
     is compacted into the accumulator immediately (donated buffer), so
-    nothing scales with the round count.
+    nothing scales with the round count. ``splitters`` as in
+    ``distributed_sort_step``; the partition is the same function.
     """
     from uda_tpu.parallel.exchange import (execute_planned_window,
                                            prepare_layout)
@@ -405,16 +561,21 @@ def distributed_sort_multiround(words, splitters, mesh: Mesh, axis: str,
     p = int(np.prod(list(mesh.shape.values())))
     spec = NamedSharding(mesh, P(axis))
     words = put_rows(words, mesh, axis)
-    splitters_dev = put_global(np.asarray(splitters, dtype=np.uint32),
-                               NamedSharding(mesh, P()))
+    if splitters is None:
+        splitters_dev = _sample_step(
+            words, mesh, axis, num_keys, payload_path,
+            _lanes_interpret(payload_path, mesh))
+        _count_sample(int(words.shape[0]), p)
+    else:
+        splitters_dev = put_global(_whole_keys(splitters, num_keys),
+                                   NamedSharding(mesh, P()))
 
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P()),
              out_specs=P(axis))
     def _dests(w, spl):
-        return jnp.searchsorted(spl[0], w[:, 0],
-                                side="right").astype(jnp.int32)
+        return _partition(w, spl[0], num_keys)
 
-    dest = _dests(words, splitters_dev[None, :])
+    dest = _dests(words, splitters_dev[None])
     layout = prepare_layout(words, dest, mesh, axis, exchange_mode)
     counts = layout.counts                      # [src, dst]
     plan = plan_layout_rounds(layout, capacity)
@@ -449,5 +610,6 @@ def distributed_sort_multiround(words, splitters, mesh: Mesh, axis: str,
     out = _sort_shard(acc, nvalid, mesh, axis, num_keys, payload_path,
                       interpret=_lanes_interpret(payload_path, mesh))
     overflow = put_global(np.zeros(p, np.int32), spec)
-    return DistributedSortResult(out, nvalid, overflow,
-                                 overflow_total=np.int32(0))
+    return DistributedSortResult(
+        out, nvalid, overflow, np.array([0, per_dst.max()], np.int32),
+        splitters_dev, int(words.shape[0]))

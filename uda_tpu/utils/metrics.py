@@ -236,6 +236,19 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                             "instead of the "
                                             "concatenation re-sort"),
     "exchange.rounds": ("counter", "all-to-all exchange rounds executed"),
+    "exchange.sample.keys": ("counter", "whole keys a distributed sort "
+                                        "step sampled from its own input, "
+                                        "over all chips, to choose its "
+                                        "splitters (distributed.SAMPLE_KEYS "
+                                        "a step; 0 for a step handed its "
+                                        "splitters)"),
+    "exchange.fused.overflow_reruns": ("counter", "distributed sort steps "
+                                                  "whose fused attempt "
+                                                  "overflowed a credit "
+                                                  "window and ran again "
+                                                  "through the windowed "
+                                                  "rounds (multiround="
+                                                  "auto): the skew alarm"),
     "exchange.rounds.skipped": ("counter", "planned exchange windows the "
                                            "host round planner dropped "
                                            "because no device had "
@@ -508,6 +521,14 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                      "and the largest of their merge "
                                      "temporaries; high-water "
                                      "mark kept (PEAK_GAUGES)"),
+    "exchange.shard.max_permille": ("gauge", "largest shard's valid rows "
+                                             "of the last distributed sort "
+                                             "step read back, per mille of "
+                                             "its input rows (1000/P = "
+                                             "balanced); set absolutely "
+                                             "from the readback check() "
+                                             "makes anyway; high-water "
+                                             "mark kept (PEAK_GAUGES)"),
     "profile.hz": ("gauge", "sampling-profiler rate currently armed "
                             "(0 = off; set absolutely at start/stop, "
                             "deliberately NOT a paired gauge — the "
@@ -732,12 +753,13 @@ PARITY_ALIASES = {
 }
 
 # Gauges whose high-water mark the hub keeps beside the level
-# (gauge_add): what a reader that samples after the fact needs in order
+# (gauge, gauge_add): what a reader that samples after the fact needs in order
 # to say how many tasks WERE live at once, or how much HBM the ledger
 # had out. Read with gauge_peaks_snapshot(); restart_gauge_peaks()
 # restarts every mark from its gauge's current level (a measurement
 # window's opening).
-PEAK_GAUGES = ("reduce.tasks.live", "budget.hbm.reserved")
+PEAK_GAUGES = ("reduce.tasks.live", "budget.hbm.reserved",
+               "exchange.shard.max_permille")
 
 # Fixed histogram buckets: powers of two from 1/16 to 2^30, shared by
 # every histogram (latencies in ms and sizes in bytes both fit; fixed
@@ -1050,6 +1072,8 @@ class Metrics:
         key = _series_key(name, labels) if labels else name
         with self._lock:
             self.gauges[key] = value
+            if key in PEAK_GAUGES and value > self.gauge_peaks.get(key, 0.0):
+                self.gauge_peaks[key] = value
 
     def gauge_add(self, name: str, delta: float, **labels) -> None:
         """Adjust a gauge by ``delta`` (the on-air increment/decrement
