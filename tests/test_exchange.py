@@ -507,3 +507,184 @@ def test_window_round_body_delivers_what_the_scatter_does(p, case):
     np.testing.assert_array_equal(
         want_counts,
         np.clip(layout.counts.T - window * capacity, 0, capacity))
+
+
+# -- the fused step sorts first and merges after (PR 35): held to the
+# step that partitioned, permuted, exchanged and then sorted, kept as
+# tests/exchange_parent.py ---------------------------------------------
+
+_STEP_KEYS, _STEP_WIDTH, _STEP_ROWS = 2, 4, 48     # rows a chip
+
+
+def _step_words(case, p, seed):
+    """(words, capacity): 2 key words, the global row number (tells equal
+    keys apart), one payload word."""
+    rng = np.random.default_rng(seed)
+    n = p * _STEP_ROWS
+    words = rng.integers(0, 2**32, size=(n, _STEP_WIDTH), dtype=np.uint32)
+    words[:, _STEP_KEYS] = np.arange(n)
+    capacity = _STEP_ROWS           # a window holds a source's whole shard
+    if case == "zipf":              # ids, one shared first word, hot keys
+        words[:, 0] = 7
+        words[:, 1] = np.floor(2 ** (10 * rng.random(n)))
+    elif case == "one_key":
+        words[:, :_STEP_KEYS] = 5
+    elif case == "empty_bucket":    # nothing for the last chip
+        words[:, 0] = rng.integers(0, 2**32 // p, size=n)
+    elif case == "bucket_exactly_capacity":
+        # every row of chip 0 goes to the last chip: a full window
+        words[:, 0] = rng.integers(0, 2**32 // p, size=n)
+        words[:capacity, 0] = 2**32 - 1 - rng.integers(0, 99, size=capacity)
+    elif case == "ties_across_chips":   # every key on every source chip
+        words[:, 0] = (np.arange(n) % 5) * (2**32 // 5)
+        words[:, 1] = np.arange(n) % 3
+    else:
+        assert case == "uniform"
+    return words, capacity
+
+
+_STEP_CASES = ("uniform", "zipf", "one_key", "empty_bucket",
+               "bucket_exactly_capacity", "ties_across_chips")
+
+
+def _step_grew(before):
+    from uda_tpu.utils.metrics import metrics
+
+    after = metrics.snapshot()
+    return {k: after[k] - before.get(k, 0) for k in after}
+
+
+@pytest.mark.parametrize("case", _STEP_CASES)
+@pytest.mark.parametrize("p", [1, 2, 4, 8])
+@pytest.mark.parametrize("engine", ["carry", "lanes"])
+def test_fused_step_equals_the_scatter_then_sort_step(engine, p, case):
+    from tests.exchange_parent import scatter_then_sort_step
+    from uda_tpu.parallel import distributed as D
+    from uda_tpu.utils.metrics import metrics
+
+    mesh = make_mesh(p, AXIS)
+    words, capacity = _step_words(case, p, seed=10 * p + len(case))
+    spl = uniform_splitters(p)
+    before = metrics.snapshot()
+    res = distributed_sort_step(words, spl, mesh, AXIS, capacity=capacity,
+                                num_keys=_STEP_KEYS, multiround="never",
+                                payload_path=engine)
+    res.check()
+    grew = _step_grew(before)
+    # the runs a chip merged: one a source on lanes; 0, not nothing, on
+    # an engine that sorts them again
+    assert "exchange.merge.runs" in grew
+    assert grew["exchange.merge.runs"] == (p if engine == "lanes" else 0)
+    want, want_nvalid, want_over, want_spl = scatter_then_sort_step(
+        words, D._whole_keys(spl, _STEP_KEYS), mesh, AXIS, capacity,
+        _STEP_KEYS, engine, interpret=engine == "lanes")
+    # row for row, the zero rows behind each shard's valid ones included
+    np.testing.assert_array_equal(np.asarray(res.words), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(res.valid_counts),
+                                  np.asarray(want_nvalid))
+    np.testing.assert_array_equal(np.asarray(res.send_overflow),
+                                  np.asarray(want_over))
+    np.testing.assert_array_equal(np.asarray(res.splitters),
+                                  np.asarray(want_spl))
+    assert int(np.asarray(res.valid_counts).sum()) == len(words)
+    if case == "bucket_exactly_capacity":
+        dest = np.searchsorted(spl, words[:_STEP_ROWS, 0], side="right")
+        assert (dest == p - 1).sum() == capacity
+
+
+@pytest.mark.parametrize("p", [2, 4, 8])
+@pytest.mark.parametrize("engine", ["carry", "lanes"])
+def test_fused_step_overflow_reruns_with_the_same_splitters(engine, p):
+    from tests.exchange_parent import scatter_then_sort_step
+    from uda_tpu.parallel import distributed as D
+    from uda_tpu.utils.metrics import metrics
+
+    # a window a sixth of a source's rows: the fused attempt reports the
+    # parent's overflow (WHICH rows it dropped is its own business: they
+    # are the bucket's largest keys now, its last arrivals then), and the
+    # rerun through the rounds gives what a window that fits gives
+    mesh = make_mesh(p, AXIS)
+    words, _ = _step_words("zipf", p, seed=p)
+    spl = uniform_splitters(p)
+    capacity = _STEP_ROWS // 6
+    lost = distributed_sort_step(words, spl, mesh, AXIS, capacity=capacity,
+                                 num_keys=_STEP_KEYS, multiround="never",
+                                 payload_path=engine)
+    _, _, want_over, _ = scatter_then_sort_step(
+        words, D._whole_keys(spl, _STEP_KEYS), mesh, AXIS, capacity,
+        _STEP_KEYS, engine, interpret=engine == "lanes")
+    assert lost.overflow() == int(np.asarray(want_over).sum()) > 0
+    np.testing.assert_array_equal(np.asarray(lost.send_overflow),
+                                  np.asarray(want_over))
+    before = metrics.snapshot()
+    res = distributed_sort_step(words, spl, mesh, AXIS, capacity=capacity,
+                                num_keys=_STEP_KEYS, payload_path=engine)
+    res.check()
+    grew = _step_grew(before)
+    assert grew["exchange.fused.overflow_reruns"] == 1
+    assert grew["exchange.merge.runs"] == (p if engine == "lanes" else 0)
+    fits = distributed_sort_step(words, spl, mesh, AXIS, capacity=_STEP_ROWS,
+                                 num_keys=_STEP_KEYS, multiround="never",
+                                 payload_path=engine)
+    np.testing.assert_array_equal(np.asarray(res.splitters),
+                                  np.asarray(fits.splitters))
+    nv = np.asarray(res.valid_counts).reshape(-1)
+    np.testing.assert_array_equal(nv, np.asarray(fits.valid_counts))
+    got = np.asarray(res.words).reshape(p, -1, _STEP_WIDTH)
+    want = np.asarray(fits.words).reshape(p, -1, _STEP_WIDTH)
+    for d in range(p):
+        np.testing.assert_array_equal(got[d, :nv[d]], want[d, :nv[d]])
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("sample", [False, True])
+def test_lanes_step_neither_sorts_nor_gathers_its_input_rows(sample):
+    # sorted rows are in destination order as they stand: the traced
+    # lanes step holds no XLA sort (no argsort of the destinations) and
+    # no gather out of an [n, W] or a per-row [n] operand (no take of
+    # the rows or of their destinations); the Pallas kernels and the
+    # sample's 100,000-row reads are all that is left
+    import jax
+    import jax.numpy as jnp
+
+    from uda_tpu.parallel import distributed as D
+
+    p, n_local, w = 4, 32768, 6     # more rows a chip than it samples
+    mesh = make_mesh(p, AXIS)
+    words = jax.ShapeDtypeStruct((p * n_local, w), jnp.uint32)
+    spl = jax.ShapeDtypeStruct((p - 1, 3), jnp.uint32)
+    jaxpr = jax.make_jaxpr(
+        lambda a, b: D._sort_step(a, b, mesh, AXIS, 2 * n_local // p, 3,
+                                  "lanes", interpret=False, sample=sample))(
+        words, spl)
+    names = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+    assert "pallas_call" in names
+    assert "sort" not in names
+    for e in _eqns(jaxpr.jaxpr):
+        if e.primitive.name == "gather":
+            operand = e.invars[0].aval.shape
+            assert operand not in ((n_local, w), (n_local,)) or \
+                e.outvars[0].aval.shape[0] < n_local, (operand, e)
+    # and the reference does hold them, so the walk sees what it claims
+    from tests.exchange_parent import scatter_then_sort_step
+    old = jax.make_jaxpr(
+        lambda a, b: scatter_then_sort_step(a, b, mesh, AXIS,
+                                            2 * n_local // p, 3, "lanes",
+                                            interpret=False, sample=sample))(
+        words, spl)
+    old_names = [e.primitive.name for e in _eqns(old.jaxpr)]
+    assert "sort" in old_names
+    assert any(e.primitive.name == "gather"
+               and e.invars[0].aval.shape == (n_local, w)
+               and e.outvars[0].aval.shape == (n_local, w)
+               for e in _eqns(old.jaxpr))

@@ -184,7 +184,48 @@ def test_sample_splitters_keeps_the_form_it_was_given():
     assert sample_splitters(whole[:0], 4).shape == (3, KEYS)
 
 
-def test_sampled_sort_on_a_two_axis_mesh_matches_the_flat_mesh():
+@pytest.mark.parametrize("kind", ("zipf_ids", "all_equal", "two_keys",
+                                  "sorted"))
+@pytest.mark.parametrize("p", (2, 4, 8))
+@pytest.mark.parametrize("engine", ("carry", "lanes"))
+def test_sampled_step_equals_the_scatter_then_sort_step(engine, p, kind):
+    # the step that sorts first and merges the p received runs (lanes:
+    # the Pallas merge passes, interpreted) against the step that
+    # partitioned, permuted, exchanged and sorted (tests/
+    # exchange_parent.py), both sampling their own splitters: the same
+    # splitters, shards and counts, row for row — and the stable host
+    # sort, so equal keys are in input order whichever chip sent them
+    from tests.exchange_parent import scatter_then_sort_step
+
+    mesh = make_mesh(p, AXIS)
+    n = p * PER_CHIP
+    words = _records(kind, n, seed=50 + p)
+    before = metrics.snapshot()
+    res = distributed_sort_step(words, None, mesh, AXIS, capacity=n // p,
+                                num_keys=KEYS, multiround="never",
+                                payload_path=engine)
+    res.check()
+    after = metrics.snapshot()
+    assert after["exchange.merge.runs"] - before.get(
+        "exchange.merge.runs", 0) == (p if engine == "lanes" else 0)
+    want, want_nvalid, want_over, want_spl = scatter_then_sort_step(
+        words, np.zeros((p - 1, KEYS), np.uint32), mesh, AXIS, n // p, KEYS,
+        engine, interpret=engine == "lanes", sample=True)
+    np.testing.assert_array_equal(np.asarray(res.splitters),
+                                  np.asarray(want_spl))
+    np.testing.assert_array_equal(np.asarray(res.valid_counts),
+                                  np.asarray(want_nvalid))
+    np.testing.assert_array_equal(np.asarray(res.words), np.asarray(want))
+    assert int(np.asarray(want_over).sum()) == res.overflow() == 0
+    np.testing.assert_array_equal(np.concatenate(_shards(res, p)),
+                                  _stable_host_sort(words))
+
+
+@pytest.mark.parametrize("engine", ("carry", "lanes"))
+def test_sampled_sort_on_a_two_axis_mesh_matches_the_flat_mesh(engine):
+    # the hierarchical body delivers the flat body's layout — block k of
+    # the receive buffer source k's rows, slots kept — so on lanes its
+    # blocks are the sorted runs the last stage merges
     from uda_tpu.parallel.mesh import mesh_from_config
     from uda_tpu.utils.config import Config
 
@@ -193,13 +234,17 @@ def test_sampled_sort_on_a_two_axis_mesh_matches_the_flat_mesh():
     n = 8 * PER_CHIP
     words = _records("zipf_ids", n, seed=3)
     flat = distributed_sort_step(words, None, make_mesh(8, AXIS), AXIS,
-                                 capacity=n // 8, num_keys=KEYS)
+                                 capacity=n // 8, num_keys=KEYS,
+                                 payload_path=engine)
     both = distributed_sort_step(words, None, mesh2, names,
-                                 capacity=n // 8, num_keys=KEYS)
+                                 capacity=n // 8, num_keys=KEYS,
+                                 payload_path=engine)
     np.testing.assert_array_equal(np.asarray(flat.splitters),
                                   np.asarray(both.splitters))
     for a, b in zip(_shards(flat, 8), _shards(both, 8)):
         np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(np.concatenate(_shards(both, 8)),
+                                  _stable_host_sort(words))
 
 
 def test_distributed_terasort_names_its_two_categories():

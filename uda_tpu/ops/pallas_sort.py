@@ -34,7 +34,12 @@ Stability: the tie-break row makes all sort keys distinct, so the
 (unstable) bitonic networks reproduce stable arrival order exactly.
 
 ``sort_lanes`` builds the whole pipeline (1 tile-sort + log2(n/T)
-merge passes) in one traced, jit-compatible function. Unlike the
+merge passes) in one traced, jit-compatible function.
+``merge_lanes_runs`` is the pipeline's merge-only entry: input that is
+ALREADY R sorted runs (what a chip receives when every sender sorted
+before the exchange) is packed the way pass log2(run/T) would have
+found it and takes the last log2(R) passes alone — no tile sort, no
+pass below the run length. Unlike the
 operand-carry ``lax.sort`` (whose TPU compile time grows superlinearly
 in operand count, uda_tpu.ops.sort.SORT_PATHS), every kernel
 here has a fixed small operand surface, so compile cost is bounded
@@ -53,8 +58,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ROWS", "sort_lanes", "rows_to_lanes", "lanes_to_rows",
-           "keys8_sort_perm", "pad_pow2", "TB_ROW_DEFAULT"]
+__all__ = ["ROWS", "sort_lanes", "merge_lanes_runs", "rows_to_lanes",
+           "lanes_to_rows", "keys8_sort_perm", "pad_pow2", "TB_ROW_DEFAULT"]
 
 ROWS = 32               # sublane-padded row count of the lanes layout
 TB_ROW_DEFAULT = 31     # default tie-break row (last)
@@ -465,3 +470,68 @@ def sort_lanes(x, num_keys: int, tb_row: int = TB_ROW_DEFAULT,
                            interpret=interpret)
 
     return lax.fori_loop(0, levels, body, x)
+
+
+def merge_lanes_runs(x, run_len: int, num_keys: int,
+                     tb_row: int = TB_ROW_DEFAULT, tile: int = 1024,
+                     interpret: bool = False):
+    """Stable merge of R sorted runs in lanes layout: the merge passes of
+    ``sort_lanes`` from run length ``run_len`` up, and nothing below.
+
+    ``x``: uint32[ROWS, R * run_len]; run k is lanes [k * run_len,
+    (k + 1) * run_len), ASCENDING by the key rows [0, num_keys) with
+    equal keys in the order they are to keep — a run shorter than
+    ``run_len`` ends in lanes whose key rows sort after its real ones
+    (+inf), as sort_lanes' callers pad. Any R >= 1, any run_len >= 1.
+    Row ``tb_row`` is overwritten with the lane's index in ``x`` (run,
+    then slot: the arrival index of sort_lanes) and holds it in the
+    output, so equal keys come out by run, then by slot.
+
+    The runs are stored the way a merge pass wants its input — each
+    padded to a multiple of the tile, their count to a power of two,
+    with lanes that are +inf in EVERY row, tie-break included, so they
+    sort after a real all-0xFFFFFFFF key and are cut off again; runs of
+    odd index reversed (bitonic as stored, padding at their front) —
+    and log2(R) passes merge them, sort_lanes' loop from that level on.
+    The stored matrix is written as ONE concatenation, out of place: in
+    the fused multi-chip step (parallel/distributed.py) that is what
+    lets the compiler keep the step's total where a full sort of the
+    same buffer had it; reversing the odd runs in place cost it a
+    second lanes buffer, 806 MB a chip at 2^25 lanes (PERF.md, PR 35).
+
+    Returns the sorted [ROWS, R * run_len] array.
+    """
+    x = jnp.asarray(x, jnp.uint32)
+    rows, n = x.shape
+    if tile & (tile - 1) or tile % _LANE:
+        raise ValueError(f"tile={tile} must be a power of two multiple "
+                         f"of {_LANE}")
+    if run_len <= 0 or n % run_len:
+        raise ValueError(f"n={n} is not a whole number of runs of "
+                         f"{run_len}")
+    if not 0 < num_keys <= tb_row < rows:
+        raise ValueError(f"bad num_keys={num_keys} / tb_row={tb_row}")
+    runs = n // run_len
+    x = lax.dynamic_update_slice(
+        x, jnp.arange(n, dtype=jnp.uint32)[None], (tb_row, 0))
+    if runs == 1:
+        return x
+    _, tile = pad_pow2(run_len, tile)
+    blk = -(-run_len // tile) * tile
+    nblk = 1 << (runs - 1).bit_length()
+    if (nblk, blk) != (runs, run_len):
+        x = jnp.pad(x.reshape(rows, runs, run_len),
+                    ((0, 0), (0, nblk - runs), (0, blk - run_len)),
+                    constant_values=_INF).reshape(rows, nblk * blk)
+    x = jnp.concatenate(
+        [jnp.flip(x[:, k * blk:(k + 1) * blk], axis=1) if k % 2
+         else x[:, k * blk:(k + 1) * blk] for k in range(nblk)], axis=1)
+    levels = nblk.bit_length() - 1
+
+    def body(lvl, x):   # sort_lanes' pass, run lengths blk, 2 blk, ...
+        splits = _pass_splits(x, jnp.int32(blk) << lvl, lvl == levels - 1,
+                              tile, num_keys, tb_row)
+        return _merge_pass(x, splits, tile, num_keys, tb_row,
+                           interpret=interpret)
+
+    return lax.fori_loop(0, levels, body, x)[:, :n]

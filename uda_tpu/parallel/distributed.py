@@ -6,14 +6,20 @@ partition addressing jobid/mapid/reduceid, src/DataNet/RDMAClient.cc:
 575-586, src/MOFServer/MOFServlet.cc:28-96) fused with the reduce-side
 merge (src/Merger/MergeManager.cc) into ONE jitted SPMD program:
 
-    partition (splitter search) -> bucket -> all_to_all (ICI) ->
-    local lexicographic sort -> globally sorted, device-sharded output
+    local lexicographic sort -> partition (whole-key compares) ->
+    all_to_all (ICI) -> merge of the P received sorted runs ->
+    globally sorted, device-sharded output
 
-"bucket" PERMUTES the rows once — a stable argsort of the destinations
-and one ``take`` — and after that only COPIES them: in destination
-order a (destination, window) is a contiguous run of rows, so the round
-body (parallel/exchange.py ``window_round_body``) fills the send buffer
-with one slice a destination, and nothing is scattered row by row.
+The reference's own shape (maps sort, the reduce side merges what
+streams in): each chip SORTS its rows first. A range partition is
+monotone in the key, so sorted rows are already in destination order —
+no argsort of the destinations, no ``take`` — and a (destination,
+window) is a contiguous run of rows, which the round body
+(parallel/exchange.py ``window_round_body``) copies into the send
+buffer as one slice a destination. What a chip receives is P sorted
+runs, one a source: the lanes engine merges them (log2 P merge passes,
+ops/pallas_sort.py ``merge_lanes_runs``) where a sort of the whole
+receive buffer stood.
 
 Global order: destinations are monotone in key-prefix, so after the
 exchange device d holds exactly range-partition d and the concatenation
@@ -220,10 +226,25 @@ class DistributedSortResult:
                 "raise capacity or use the multi-round path")
 
 
-def _sort_valid_rows(flat, valid, num_keys, payload_path, interpret=False):
+def _merges_runs(payload_path: str) -> bool:
+    """Whether the engine combines sorted runs by merging them; the
+    others sort them again (same answer: their sort is stable)."""
+    return payload_path == "lanes"
+
+
+def _sort_valid_rows(flat, valid, num_keys, payload_path, interpret=False,
+                     run_len=None):
     """Stable local sort of ``flat``'s rows by the first ``num_keys``
     columns, with ``valid``-masked rows forced past every real key (the
-    shared tail of the fused step and the multi-round accumulator sort).
+    fused step's local sort and its receive side, the sampling stage and
+    the multi-round accumulator sort).
+
+    ``run_len``: ``flat`` is already sorted runs of that many rows each —
+    a run's valid rows first, ascending, equal keys in the order to
+    keep, as the fused step's receive buffer is (one run a source chip).
+    The answer is the same with or without it, equal keys by (run,
+    row); an engine that can (``_merges_runs``) merges the runs instead
+    of sorting the rows.
 
     payload_path="lanes": the Pallas bitonic pipeline
     (ops.pallas_sort.sort_lanes) — bounded compile (two Mosaic kernels
@@ -236,8 +257,9 @@ def _sort_valid_rows(flat, valid, num_keys, payload_path, interpret=False):
     network (XLA variadic-sort compile time grows superlinearly in
     operand count — minutes on the TPU); the CPU default."""
     if payload_path != "carry":
-        return _sort_valid_rows_lanes(flat, valid, num_keys, interpret,
-                                      keys8=payload_path == "keys8")
+        return _sort_valid_rows_lanes(
+            flat, valid, num_keys, interpret, keys8=payload_path == "keys8",
+            run_len=run_len if _merges_runs(payload_path) else None)
     keycols = tuple(jnp.where(valid, flat[:, i], _INVALID)
                     for i in range(num_keys))
     invalid_last = jnp.where(valid, 0, 1)
@@ -247,11 +269,15 @@ def _sort_valid_rows(flat, valid, num_keys, payload_path, interpret=False):
     return jnp.stack(sorted_ops[num_keys + 1:], axis=1)
 
 
-def _sort_valid_rows_lanes(flat, valid, num_keys, interpret, keys8=False):
+def _sort_valid_rows_lanes(flat, valid, num_keys, interpret, keys8=False,
+                           run_len=None):
     """Lanes-path body of _sort_valid_rows: pack rows into the [32, n]
     lanes layout with sort key (masked key words, invalid flag), pad the
     lane count to a power of two with +inf-key lanes, run the Pallas
-    pipeline, unpack the payload rows.
+    pipeline, unpack the payload rows. With ``run_len`` the same matrix,
+    unpadded — its runs are sorted by that very sort key, a run's
+    invalid rows behind its valid ones — goes through the pipeline's
+    merge passes alone (``merge_lanes_runs`` pads as it needs).
 
     Order parity with the lax.sort path: identical sort key, and the
     pipeline's arrival-index tie-break == their stable row order. The
@@ -263,7 +289,7 @@ def _sort_valid_rows_lanes(flat, valid, num_keys, interpret, keys8=False):
     n, wcols = flat.shape
     first_pay = num_keys + 1             # payload starts past the flag row
     tb = pallas_sort.TB_ROW_DEFAULT
-    npad, tile = pallas_sort.pad_pow2(n, 1024)
+    npad, tile = (n, 1024) if run_len else pallas_sort.pad_pow2(n, 1024)
     keyrows = jnp.stack([jnp.where(valid, flat[:, i], _INVALID)
                          for i in range(num_keys)]
                         + [jnp.where(valid, jnp.uint32(0), jnp.uint32(1))])
@@ -300,8 +326,12 @@ def _sort_valid_rows_lanes(flat, valid, num_keys, interpret, keys8=False):
     mat = jnp.full((pallas_sort.ROWS, npad), _INVALID, jnp.uint32)
     mat = lax.dynamic_update_slice(mat, keyrows, (0, 0))
     mat = lax.dynamic_update_slice(mat, flat.T, (first_pay, 0))
-    out = pallas_sort.sort_lanes(mat, num_keys=num_keys + 1, tb_row=tb,
-                                 tile=tile, interpret=interpret)
+    if run_len:
+        out = pallas_sort.merge_lanes_runs(mat, run_len, num_keys + 1, tb,
+                                           tile, interpret)
+    else:
+        out = pallas_sort.sort_lanes(mat, num_keys=num_keys + 1, tb_row=tb,
+                                     tile=tile, interpret=interpret)
     return out[first_pay:first_pay + wcols, :n].T
 
 
@@ -313,12 +343,22 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
                payload_path="carry", interpret=False,
                exchange_mode="flat", dcn_axis=None, ici_axis=None,
                sample=False):
-    """The fused step. ``splitters``: whole keys ``uint32[P-1,
-    num_keys]``, replicated; with ``sample`` they are ignored and the
-    program takes its own from ``words`` (``_sampled_splitters``) before
-    it partitions. Returns the sorted shards, their valid counts, the
-    per-device overflow, the replicated ``(overflow, largest shard)``
-    pair and the splitters the step partitioned by."""
+    """The fused step: sort, partition, exchange, combine. Each chip
+    sorts its own rows (stable by input order), so they are in
+    destination order with no permutation; the round body sends each
+    destination its window of them, and a chip receives P sorted runs —
+    block k of the receive buffer is source k's, ``recv_counts[k]`` rows
+    and zeros after them — which the last stage combines by
+    ``_sort_valid_rows(..., run_len=capacity)``: a merge of the runs on
+    the lanes engine, a stable sort of them on the others. Equal keys
+    come out by source chip, then source order: global input order.
+
+    ``splitters``: whole keys ``uint32[P-1, num_keys]``, replicated;
+    with ``sample`` they are ignored and the program takes its own from
+    the unsorted ``words`` (``_sampled_splitters``) before anything else.
+    Returns the sorted shards, their valid counts, the per-device
+    overflow, the replicated ``(overflow, largest shard)`` pair and the
+    splitters the step partitioned by."""
     # check_vma is ON everywhere except interpret mode (which only the
     # Pallas engines on a CPU mesh ever set, _lanes_interpret): the
     # Pallas interpreter expands pallas_call into eval_jaxpr whose
@@ -338,12 +378,12 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
         # 0. splitters from the input itself, when none were handed in
         spl = _sampled_splitters(w, axis, num_keys, payload_path,
                                  interpret) if sample else spl[0]
-        # 1. partition: monotone in the whole key
-        dest = _partition(w, spl, num_keys)
-        # 2. bucket locally (stable by arrival)
-        order = jnp.argsort(dest, stable=True)
-        sd = jnp.take(dest, order)
-        sw = jnp.take(w, order, axis=0)
+        # 1. local sort first (stable by input order)
+        sw = _sort_valid_rows(w, jnp.ones(n, jnp.bool_), num_keys,
+                              payload_path, interpret)
+        # 2. partition: monotone in the whole key, so the sorted rows
+        # are in destination order as they stand
+        sd = _partition(sw, spl, num_keys)
         counts = jnp.bincount(sd, length=p).astype(jnp.int32)
         starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
                                   jnp.cumsum(counts)[:-1].astype(jnp.int32)])
@@ -355,11 +395,12 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
         flat, recv_counts = run_round_body(sw, sd, pos, 0, capacity,
                                            axis, exchange_mode,
                                            dcn_axis, ici_axis)
-        # 4. local sort: invalid rows forced past every real key
-        row = jnp.arange(p * capacity, dtype=jnp.int32)
-        valid = (row % capacity) < jnp.take(recv_counts, row // capacity)
+        # 4. combine the P sorted runs, one a source: invalid rows
+        # forced past every real key
+        slot = jnp.arange(capacity, dtype=jnp.int32)
+        valid = (slot[None] < recv_counts[:, None]).reshape(p * capacity)
         out = _sort_valid_rows(flat, valid, num_keys, payload_path,
-                               interpret)
+                               interpret, run_len=capacity)
         nvalid = jnp.sum(recv_counts)
         return out, nvalid[None], overflow[None], spl[None]
 
@@ -376,7 +417,11 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
                           multiround: str = "auto",
                           exchange_mode: str = "auto"
                           ) -> DistributedSortResult:
-    """Run the fused partition/exchange/sort step.
+    """Run the fused step: each chip sorts its rows, partitions them
+    by the splitters, exchanges one window a destination and combines
+    the P sorted runs it receives (``_sort_step``; counter
+    ``exchange.merge.runs``: the runs a chip merged, P on the lanes
+    engine, 0 on an engine that sorts them again).
 
     ``words``: uint32[N, W] records (rows sharded over ``axis``; the
     first ``num_keys`` columns are the big-endian key words).
@@ -446,6 +491,10 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
         **exchange_dispatch(topo, hier))
     if sample:
         _count_sample(int(words.shape[0]), p)
+    # sorted runs a chip's receive side merged: one a source chip; 0,
+    # not nothing, where the last stage sorted its buffer from scratch
+    metrics.add("exchange.merge.runs",
+                p if _merges_runs(payload_path) else 0)
     metrics.add("exchange.fused.overflow_reruns", 0)
     res = DistributedSortResult(out, nvalid, overflow, totals, used,
                                 int(words.shape[0]))
@@ -538,7 +587,9 @@ def distributed_sort_multiround(words, splitters, mesh: Mesh, axis: str,
                                 exchange_mode: str = "auto"
                                 ) -> DistributedSortResult:
     """Skew-proof distributed sort: windowed multi-round exchange
-    scattered into a shard-sized accumulator, then one local sort.
+    scattered into a shard-sized accumulator, then one local sort
+    (scatter-then-sort, where the fused step sorts first and merges:
+    the rounds deliver a bucket piecewise, in arrival order).
 
     The round schedule comes from the gathered count matrix (one host
     readback per shuffle, planned by parallel/planner.py — globally-
@@ -606,6 +657,8 @@ def distributed_sort_multiround(words, splitters, mesh: Mesh, axis: str,
                                    jnp.int32(win.index), mesh, axis,
                                    capacity, **dispatch))
     record_plan_skips(plan)
+    # the rounds deliver piecewise into the accumulator: sorted whole
+    metrics.add("exchange.merge.runs", 0)
     nvalid = put_global(per_dst.astype(np.int32), spec)
     out = _sort_shard(acc, nvalid, mesh, axis, num_keys, payload_path,
                       interpret=_lanes_interpret(payload_path, mesh))

@@ -242,6 +242,13 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                         "splitters (distributed.SAMPLE_KEYS "
                                         "a step; 0 for a step handed its "
                                         "splitters)"),
+    "exchange.merge.runs": ("counter", "sorted runs a chip's receive side "
+                                       "merged in a fused distributed "
+                                       "sort step (every chip sorts "
+                                       "before the exchange: one run a "
+                                       "source chip on the lanes engine; "
+                                       "0 where the last stage sorted "
+                                       "the receive buffer from scratch)"),
     "exchange.fused.overflow_reruns": ("counter", "distributed sort steps "
                                                   "whose fused attempt "
                                                   "overflowed a credit "
