@@ -250,6 +250,54 @@ def test_critpath_span_buckets_cover_known_names():
     assert order.index("decompress_pack") < order.index("emit") \
         < order.index("serve")
     assert "emit" not in critpath.TRIO_MAP  # the trio has no emit term
+    # inside the completion upcall (ISSUE 36): the dispatch-queue wait
+    # and the crack are fetch's; feed()'s backpressure is caused by
+    # staging, so it is a wait
+    assert critpath.SPAN_BUCKETS["net.dispatch.wait"] == "fetch"
+    assert critpath.SPAN_BUCKETS["fetch_crack"] == "fetch"
+    assert critpath.SPAN_BUCKETS["fetch_feed_wait"] == "wait"
+
+
+@pytest.mark.parametrize("package", ("merger", "net", "mofserver"))
+def test_critpath_buckets_every_timer_call_site(package):
+    """The table in lockstep with the timer call sites: a timer of the
+    reduce path's packages that the table does not know would charge
+    'other' without anyone having decided it."""
+    import re
+    names = set()
+    for dirpath, _, files in os.walk(os.path.join(REPO, "uda_tpu", package)):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(dirpath, f)) as fh:
+                    names |= set(re.findall(
+                        r'metrics\.timer\(\s*"([a-z_.]+)"', fh.read()))
+    if package == "merger":
+        assert {"fetch", "fetch_crack", "fetch_feed_wait"} <= names
+    assert not [n for n in names if n not in critpath.SPAN_BUCKETS]
+
+
+def test_critpath_charges_the_upcall_spans():
+    """While the ``fetch`` timer is open its three children change no
+    bucket's seconds (fetch outranks wait, and they ARE fetch): the
+    split is read from their counters. Outside it each charges its
+    own bucket; staging outranks the feed wait it causes."""
+    spans = [
+        _span("reduce_task", 0.0, 10.0, 1),
+        _span("fetch", 0.0, 6.0, 2, parent=1),
+        _span("net.dispatch.wait", 1.0, 1.0, 3, parent=2),
+        _span("fetch_crack", 2.0, 1.0, 4, parent=2),
+        _span("fetch_feed_wait", 3.0, 2.0, 5, parent=2),
+        _span("net.dispatch.wait", 6.0, 1.0, 6, parent=1),
+        _span("fetch_crack", 7.0, 1.0, 7, parent=1),
+        _span("fetch_feed_wait", 8.0, 2.0, 8, parent=1),
+        _span("overlap_pack", 9.0, 1.0, 9, parent=1),
+    ]
+    b = critpath.analyze(spans)["buckets"]
+    assert b["fetch"]["critical_s"] == pytest.approx(8.0)
+    assert b["wait"]["critical_s"] == pytest.approx(1.0)    # [8, 9]
+    assert b["decompress_pack"]["critical_s"] == pytest.approx(1.0)
+    assert b["other"]["critical_s"] == pytest.approx(0.0)
+    assert b["wait"]["busy_s"] == pytest.approx(4.0)
 
 
 def test_critpath_charges_emit_spans_to_emit():

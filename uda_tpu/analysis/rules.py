@@ -127,7 +127,7 @@ class ConfigKeyRule(Rule):
 
 # -- UDA002 ------------------------------------------------------------------
 
-_METRIC_METHODS = ("add", "gauge", "gauge_add", "observe")
+_METRIC_METHODS = ("add", "gauge", "gauge_add", "observe", "series")
 
 
 class MetricsNameRule(Rule):

@@ -86,6 +86,7 @@ always stay on the fast path.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import queue
@@ -345,7 +346,8 @@ class OverlappedMerger:
         metrics.add("stage.native_segments", 0)
         metrics.add("merge.device_groups", 0)
         for timer in ("merge_host_batch", "merge_group_flush",
-                      "merge_group_join", "run_spool"):
+                      "merge_group_join", "run_spool", "fetch_crack",
+                      "fetch_feed_wait"):
             metrics.declare_timer(timer)
         self.pipeline = bool(pipeline)
         self._consumer_thread: Optional[threading.Thread] = None
@@ -410,23 +412,27 @@ class OverlappedMerger:
         in-flight bytes budget (``uda.tpu.stage.inflight.mb``) — which
         is the intended backpressure: the transport thread holds off
         until host memory frees (the reference's RDMA credit-flow
-        posture, MergeManager.cc:47-63)."""
+        posture, MergeManager.cc:47-63). Both blocking places run under
+        the ``fetch_feed_wait`` timer, entered only when they actually
+        block: it holds the process's one upcall thread, so one task's
+        wait here is every task's dispatch-queue wait."""
         charge = self._charge(source)
         if charge < 0:
             return  # aborted while waiting on the budget
         item = (seg_index, source, time.perf_counter(), charge)
-        if self._q.maxsize <= 0:
-            self._q.put(item)
-        else:
-            while True:
-                if self._aborted:
-                    self._release_charge(charge)
-                    return
-                try:
-                    self._q.put(item, timeout=0.1)
-                    break
-                except queue.Full:
-                    continue
+        try:
+            self._q.put_nowait(item)
+        except queue.Full:
+            with self._feed_wait(source):
+                while True:
+                    if self._aborted:
+                        self._release_charge(charge)
+                        return
+                    try:
+                        self._q.put(item, timeout=0.1)
+                        break
+                    except queue.Full:
+                        continue
         if self._aborted:
             # the put may have raced abort(): _charge() saw the flag
             # unset, abort() then drained _q (threads already joined)
@@ -436,6 +442,17 @@ class OverlappedMerger:
             # is consumed exactly once, so the charge releases exactly
             # once either way.
             self._reap_input_queue()
+
+    @staticmethod
+    @contextlib.contextmanager
+    def _feed_wait(source):
+        """The ``fetch_feed_wait`` timer under the fed segment's own
+        ``fetch.segment`` span (ended already, still the parent: the
+        upcall thread has no ambient span, and a span outside the
+        task's trace is one critpath never sees)."""
+        with metrics.use_span(getattr(source, "trace_span", None)), \
+                metrics.timer("fetch_feed_wait"):
+            yield
 
     @staticmethod
     def _source_bytes(source) -> int:
@@ -462,14 +479,12 @@ class OverlappedMerger:
         charge = self._source_bytes(source)
         if charge <= 0:
             return 0
-        blocked = False
         with self._inflight_cv:
-            while (not self._aborted and self._inflight > 0
-                   and self._inflight + charge > self._inflight_cap):
-                if not blocked:
-                    blocked = True
-                    metrics.add("stage.backpressure_events")
-                self._inflight_cv.wait(timeout=0.1)
+            if self._over_budget(charge):
+                metrics.add("stage.backpressure_events")
+                with self._feed_wait(source):
+                    while self._over_budget(charge):
+                        self._inflight_cv.wait(timeout=0.1)
             if self._aborted:
                 return -1
             self._inflight += charge
@@ -478,6 +493,12 @@ class OverlappedMerger:
         # (consumer dispatch, abort drain, or its own unwind)
         metrics.gauge_add("stage.inflight.bytes", charge)  # udalint: disable=UDA101
         return charge
+
+    def _over_budget(self, charge: int) -> bool:
+        """_inflight_cv held: would ``charge`` more bytes pass the cap
+        (never true with nothing in flight, or once aborted)."""
+        return (not self._aborted and self._inflight > 0
+                and self._inflight + charge > self._inflight_cap)
 
     def _release_charge(self, charge: int) -> None:
         if charge <= 0:

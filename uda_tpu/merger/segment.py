@@ -429,6 +429,26 @@ class HostRoutingClient(InputClient):
             c.stop()
 
 
+_K_CRACK = metrics.timer_series("fetch_crack")
+_CHUNK_KEYS: dict = {}
+
+
+def _chunk_keys(supplier: str, tenant: str) -> tuple:
+    """-> the (fetch.bytes, fetch.chunks) counter keys of one
+    (supplier, tenant) pair, built once: a chunk's counters go in with
+    ONE locked update (Metrics.add_keyed), not a key build and an
+    acquisition each."""
+    keys = _CHUNK_KEYS.get((supplier, tenant))
+    if keys is None:
+        labels = {"supplier": supplier}
+        if tenant:
+            labels["tenant"] = tenant
+        keys = _CHUNK_KEYS[(supplier, tenant)] = (
+            metrics.series("fetch.bytes", **labels),
+            metrics.series("fetch.chunks", **labels))
+    return keys
+
+
 class Segment:
     """One partition's record stream, fetched chunk-wise with a carry
     buffer for records split across chunk boundaries.
@@ -1083,48 +1103,59 @@ class Segment:
         Never calls callbacks and never touches them under self._lock —
         the completion callback may call record_batch(), which takes the
         same (non-reentrant) lock on this same thread."""
+        # the chunk's whole cracking — the carry concatenation (a copy
+        # of the chunk), crack_partial, the tail slice — is the
+        # fetch_crack timer. With spans on it is metrics.timer under the
+        # segment's span (the upcall thread has no ambient context, and
+        # a span outside the task's trace is one critpath never sees);
+        # with spans off two stamps, and its counter rides the chunk's
+        # one locked update below
         with self._lock:
-            self.raw_length = res.raw_length
-            data = self._carry + res.data
-            last = res.is_last
-            if last and not data:
-                # legitimately empty partition (raw_length == 0: a byte
-                # range with no records and no EOF marker, as foreign
-                # writers may produce for empty reducers)
-                self._carry = b""
+            if metrics.record_spans:
+                crack_s = None
+                with metrics.use_span(self.trace_span), \
+                        metrics.timer("fetch_crack"):
+                    last = self._absorb(res)
             else:
-                # crack up to the last complete record; keep the tail
-                batch, consumed, _ = crack_partial(data, expect_eof=last)
-                if batch.num_records:
-                    self.batches.append(batch)
-                    self.num_records += batch.num_records
-                self._carry = data[consumed:] if not last else b""
-                self._next_offset = res.offset + len(res.data)
+                t0 = time.perf_counter()
+                last = self._absorb(res)
+                crack_s = time.perf_counter() - t0
             issue_t0 = self._issue_t0
-        tenant = self.tenant
-        if tenant:
-            # tenanted reduce tasks label the hot-path fetch counters
-            # (one attribute read per chunk; untenanted jobs keep
-            # the exact two-series shape of PRs 2-13)
-            metrics.add("fetch.bytes", len(res.data),
-                        supplier=self.supplier, tenant=tenant)
-            metrics.add("fetch.chunks", supplier=self.supplier,
-                        tenant=tenant)
+        # tenanted reduce tasks label the hot-path fetch metrics;
+        # untenanted jobs keep the exact two-series shape of PRs 2-13
+        nbytes = len(res.data)
+        k_bytes, k_chunks = _chunk_keys(self.supplier, self.tenant)
+        if crack_s is None:
+            metrics.add_keyed((k_bytes, nbytes), (k_chunks, 1.0))
         else:
-            metrics.add("fetch.bytes", len(res.data),
-                        supplier=self.supplier)
-            metrics.add("fetch.chunks", supplier=self.supplier)
-        if tenant:
+            metrics.add_keyed((k_bytes, nbytes), (k_chunks, 1.0),
+                              (_K_CRACK, crack_s))
+        if metrics.stats_enabled:
+            tenant = {"tenant": self.tenant} if self.tenant else {}
             metrics.observe("fetch.latency_ms",
                             (time.perf_counter() - issue_t0) * 1e3,
-                            supplier=self.supplier, tenant=tenant)
-            metrics.observe("fetch.chunk.bytes", len(res.data),
-                            tenant=tenant)
+                            supplier=self.supplier, **tenant)
+            metrics.observe("fetch.chunk.bytes", nbytes, **tenant)
+        return last
+
+    def _absorb(self, res: FetchResult) -> bool:
+        """self._lock held: crack the chunk onto the carried tail."""
+        self.raw_length = res.raw_length
+        data = self._carry + res.data
+        last = res.is_last
+        if last and not data:
+            # legitimately empty partition (raw_length == 0: a byte
+            # range with no records and no EOF marker, as foreign
+            # writers may produce for empty reducers)
+            self._carry = b""
         else:
-            metrics.observe("fetch.latency_ms",
-                            (time.perf_counter() - issue_t0) * 1e3,
-                            supplier=self.supplier)
-            metrics.observe("fetch.chunk.bytes", len(res.data))
+            # crack up to the last complete record; keep the tail
+            batch, consumed, _ = crack_partial(data, expect_eof=last)
+            if batch.num_records:
+                self.batches.append(batch)
+                self.num_records += batch.num_records
+            self._carry = data[consumed:] if not last else b""
+            self._next_offset = res.offset + len(res.data)
         return last
 
     def _try_recover(self, cause: Exception) -> bool:

@@ -138,6 +138,10 @@ class FetchResult:
                          # multi-chunk streams once; producers must decide
     crc: Optional[int] = None  # CRC32 of the chunk as read from disk
                                # (uda.tpu.fetch.crc); None = unchecked
+    timing: Optional[tuple] = None  # (park_us, serve_us) a supplier
+                                    # reported in the DATA head (net/
+                                    # wire.py, _FLAG_TIMING); None = not
+                                    # reported (spans off, local fetch)
 
     @property
     def is_last(self) -> bool:

@@ -69,16 +69,26 @@ _WRITE = selectors.EVENT_WRITE
 
 _SIZE_PROBE_TIMEOUT_S = 30.0
 
+# a chunk's stage counters, keys built once (one locked update a chunk)
+_K_TIMED = metrics.series("fetch.chunk.timed")
+_K_PARK = metrics.series("fetch.chunk.park_seconds")
+_K_SERVE = metrics.series("fetch.chunk.serve_seconds")
+_K_WIRE = metrics.series("fetch.chunk.wire_seconds")
+
 
 class _Waiter:
     """One in-flight request's completion slot."""
 
-    __slots__ = ("on_complete", "span", "t0")
+    __slots__ = ("on_complete", "span", "t0", "t_decoded", "wait_span")
 
     def __init__(self, on_complete: Callable, span, t0: float):
         self.on_complete = on_complete
         self.span = span
-        self.t0 = t0
+        self.t0 = t0            # posted
+        # a DATA frame's decode time and the open net.dispatch.wait
+        # span (_complete -> _deliver: the dispatch-queue wait)
+        self.t_decoded = 0.0
+        self.wait_span = None
 
 
 class _ClientConn:
@@ -562,18 +572,50 @@ class EvLoopFetchClient(InputClient):
             # dead-connection leftovers / cancelled probe: count, move on
             metrics.add("net.frames.orphaned")
             return
+        now = time.perf_counter()
         if msg_type != wire.MSG_SIZE:
             metrics.observe("net.frame.latency_ms",
-                            (time.perf_counter() - waiter.t0) * 1e3,
-                            role="client")
+                            (now - waiter.t0) * 1e3, role="client")
         if isinstance(result, Exception):
             waiter.span.end(error=type(result).__name__)
+        elif msg_type == wire.MSG_DATA:
+            self._account_chunk(waiter, result.timing, now)
         else:
             waiter.span.end()
         conn.loop.dispatch(self._deliver, req_id, waiter, result)
 
+    @staticmethod
+    def _account_chunk(waiter: _Waiter, timing, now: float) -> None:
+        """Loop thread, one DATA frame decoded: split posted -> decoded
+        (chunk-seconds: a window of fetches is in flight, so the stages
+        sum past the wall). ``timing`` is the supplier's own (park_us,
+        serve_us) report, present only when this side's spans put the
+        trace tail on the REQ; wire is the remainder — both loops, both
+        socket queues, the supplier's send — and, with no report, the
+        whole remote time. Then stamp the hand-off to the one upcall
+        thread: ``_deliver`` closes it as the dispatch-queue wait."""
+        remote = now - waiter.t0
+        waiter.t_decoded = now
+        if timing is None:
+            waiter.span.end()
+            metrics.add_keyed((_K_WIRE, remote))
+        else:
+            waiter.span.end(park_us=timing[0], serve_us=timing[1])
+            park, serve = timing[0] * 1e-6, timing[1] * 1e-6
+            metrics.add_keyed((_K_TIMED, 1.0), (_K_PARK, park),
+                              (_K_SERVE, serve),
+                              (_K_WIRE, max(remote - park - serve, 0.0)))
+        if metrics.record_spans:
+            waiter.wait_span = metrics.start_span("net.dispatch.wait",
+                                                  parent=waiter.span)
+
     def _deliver(self, req_id: int, waiter: _Waiter, result) -> None:
         """Dispatcher thread: the actual upcall."""
+        if waiter.t_decoded:
+            metrics.add("fetch.chunk.dispatch_wait_seconds",
+                        time.perf_counter() - waiter.t_decoded)
+            if waiter.wait_span is not None:
+                waiter.wait_span.end()
         try:
             waiter.on_complete(result)
         except Exception as e:  # noqa: BLE001 - one waiter's bug must

@@ -45,11 +45,13 @@ import queue
 import selectors
 import socket
 import threading
+import time
 from collections import deque
 from typing import Callable, Optional
 
 from uda_tpu.utils.locks import TrackedLock
 from uda_tpu.utils.logging import get_logger
+from uda_tpu.utils.metrics import metrics
 
 __all__ = ["EventLoop", "loop_callback", "shared_client_loop"]
 
@@ -104,6 +106,12 @@ class EventLoop:
         self._dispatcher = threading.Thread(target=self._dispatch_loop,
                                             daemon=True,
                                             name=f"{name}-upcall")
+        # the upcall thread's own counters, keys built once (one locked
+        # update an upcall)
+        self._busy_keys = metrics.series("net.dispatch.busy_seconds",
+                                         loop=name)
+        self._upcall_keys = metrics.series("net.dispatch.upcalls",
+                                           loop=name)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -287,17 +295,27 @@ class EventLoop:
                           f"{type(e).__name__}: {e}")
 
     def _dispatch_loop(self) -> None:
+        """The ONE serial upcall thread: its busy seconds
+        (``net.dispatch.busy_seconds``, everything inside ``fn`` — a
+        Segment's crack, ``feed()``'s backpressure wait included)
+        compare directly with a wall. The queue wait before an upcall
+        is stamped by whoever dispatched it
+        (``EvLoopFetchClient._account_chunk`` -> ``_deliver``)."""
         while True:
             item = self._dispatchq.get()
             if item is None:
                 return
             fn, args = item
+            t0 = time.perf_counter()
             try:
                 fn(*args)
             except Exception as e:  # noqa: BLE001 - one consumer's bug
                 # must not starve every later completion of delivery
                 log.warn(f"net: dispatched completion raised: "
                          f"{type(e).__name__}: {e}")
+            metrics.add_keyed(
+                (self._busy_keys, time.perf_counter() - t0),
+                (self._upcall_keys, 1.0))
 
 
 # -- the shared client loop ---------------------------------------------------

@@ -222,6 +222,11 @@ def _pick_zerocopy_mode() -> str:
 _PROBED_MODE: Optional[str] = None
 _PROBE_LOCK = threading.Lock()
 
+# a served DATA frame's stage counters, keys built once
+_K_PARK = metrics.series("net.serve.park_seconds")
+_K_SERVE = metrics.series("net.serve.serve_seconds")
+_K_SEND = metrics.series("net.serve.send_seconds")
+
 
 class _BufItem:
     """An outbound frame already materialized as buffers: ERR, SIZE,
@@ -230,15 +235,19 @@ class _BufItem:
     mmap-mode zero-copy DATA frames (the chunk memoryview points into
     the MOF's page-cache mapping; ``slice`` pins it until written)."""
 
-    __slots__ = ("bufs", "credited", "t0", "close_after", "slice",
-                 "zc_bytes", "tenant")
+    __slots__ = ("bufs", "credited", "t0", "stamps", "close_after",
+                 "slice", "zc_bytes", "tenant")
 
     def __init__(self, bufs, credited: bool, t0: float,
                  close_after: bool = False, sl=None, zc_bytes: int = 0,
-                 tenant: str = ""):
+                 tenant: str = "", stamps: Optional[tuple] = None):
         self.bufs = [memoryview(b) for b in bufs]
         self.credited = credited
         self.t0 = t0
+        # a DATA frame's (park_s, serve_s, t_enc) from _serve_stamp,
+        # None for any other frame: counted, with the send wait (t_enc
+        # -> last byte written), as the frame leaves (_drain_locked)
+        self.stamps = stamps
         self.close_after = close_after
         self.slice = sl
         self.zc_bytes = zc_bytes
@@ -260,16 +269,17 @@ class _FileItem:
     head bytes then ``os.sendfile`` straight from the MOF fd."""
 
     __slots__ = ("head", "slice", "file_off", "remaining", "credited",
-                 "t0", "close_after", "tenant")
+                 "t0", "stamps", "close_after", "tenant")
 
     def __init__(self, head: bytes, sl: FdSlice, t0: float,
-                 tenant: str = ""):
+                 tenant: str = "", stamps: Optional[tuple] = None):
         self.head: Optional[memoryview] = memoryview(head)
         self.slice = sl
         self.file_off = sl.file_offset
         self.remaining = sl.length
         self.credited = True
         self.t0 = t0
+        self.stamps = stamps
         self.close_after = False
         self.tenant = tenant
 
@@ -431,7 +441,10 @@ class _EvConn:
         self._hdr_got = 0
         if msg_type == wire.MSG_REQ:
             req, trace = wire.decode_request_ex(payload)
-            self._admit(("req", req_id, (req, trace)))
+            # the decode stamp rides the entry through the credit
+            # gates: park = decoded -> _start (net.serve.park_seconds)
+            self._admit(("req", req_id,
+                         (req, trace, time.perf_counter())))
         elif msg_type == wire.MSG_SIZE_REQ:
             self._admit(("size", req_id,
                          wire.decode_size_request_ex(payload)))
@@ -745,9 +758,15 @@ class _EvConn:
     # -- serving -------------------------------------------------------------
 
     def _start_req(self, req_id: int, body) -> None:
-        req, trace = body
+        req, trace, t_dec = body
         metrics.add("net.requests")
         t0 = time.perf_counter()
+        # park: decoded -> here, behind the tenant and connection
+        # credit gates. Counted for every DATA frame as it leaves;
+        # reported in its head only to a REQ that carried the trace
+        # tail (the reduce side's spans are on), so an untraced REQ
+        # gets the pre-timing frame
+        parked = (t0 - t_dec, trace is not None)
         # wire-level trace adoption: a REQ that carried (trace_id,
         # parent_span_id) makes this serve span a CHILD of the remote
         # reduce task's fetch span — the supplier-side work it caused
@@ -773,7 +792,7 @@ class _EvConn:
                 # labels read the same stamp).
                 req = dataclasses.replace(
                     req, tenant=self._entry_tenant(
-                        ("req", req_id, (req, trace))))
+                        ("req", req_id, (req, trace, t_dec))))
                 self.server._validate_req(self, req)
             # the engine adopts the serve span across its pool handoff
             # (DataEngine.submit captures the current span), so
@@ -787,7 +806,8 @@ class _EvConn:
                     # first
                     plan = self.server.engine.try_plan(req)
                     if plan is not None:
-                        self._complete(req_id, plan, None, t0, span, req)
+                        self._complete(req_id, plan, None, t0, span, req,
+                                       parked)
                         return
                 if self.server.batch_reads and not (
                         self.server.zero_copy
@@ -797,7 +817,7 @@ class _EvConn:
                     # accumulate the burst and flush ONE submit_batch
                     # (uda.tpu.read.batch; the RDMAbox lesson) instead
                     # of one pool handoff per chunk
-                    self._batch.append((req_id, req, t0, span))
+                    self._batch.append((req_id, req, t0, span, parked))
                     return
                 if self.server.zero_copy:
                     fut = self.server.engine.submit_serve(req)
@@ -805,10 +825,10 @@ class _EvConn:
                     fut = self.server.engine.submit(req)
         except Exception as e:  # noqa: BLE001 - sync rejection (stopped
             # engine, admission push-back, bad offset) -> typed ERR
-            self._complete(req_id, None, e, t0, span, req)
+            self._complete(req_id, None, e, t0, span, req, parked)
             return
         fut.add_done_callback(
-            lambda f: self._engine_done(req_id, f, t0, span, req))
+            lambda f: self._engine_done(req_id, f, t0, span, req, parked))
 
     def _flush_batch(self) -> None:
         """Submit the accumulated byte-path burst (loop thread). The
@@ -828,15 +848,18 @@ class _EvConn:
                     futs = self.server.engine.submit_batch(
                         [ent[1] for ent in part],
                         parent_spans=[ent[3] for ent in part])
-                    for (req_id, req, t0, span), fut in zip(part, futs):
+                    for (req_id, req, t0, span, parked), fut in zip(part,
+                                                                     futs):
                         fut.add_done_callback(
                             lambda f, req_id=req_id, t0=t0, span=span,
-                            req=req:
-                            self._engine_done(req_id, f, t0, span, req))
+                            req=req, parked=parked:
+                            self._engine_done(req_id, f, t0, span, req,
+                                              parked))
         finally:
             self._batch_flushing = False
 
-    def _engine_done(self, req_id: int, f, t0: float, span, req) -> None:
+    def _engine_done(self, req_id: int, f, t0: float, span, req,
+                     parked: tuple = (0.0, False)) -> None:
         """Engine worker thread (or the loop, when the future was
         already resolved at callback registration)."""
         err = f.exception()
@@ -845,14 +868,28 @@ class _EvConn:
             self._settle_offloop(res, span,
                                  getattr(req, "tenant", ""))
             return
-        self._complete(req_id, res, err, t0, span, req)
+        self._complete(req_id, res, err, t0, span, req, parked)
+
+    @staticmethod
+    def _serve_stamp(t0: float, parked: tuple) -> tuple:
+        """-> (stamps, timing) as a DATA head is about to be encoded:
+        serve = _start_req -> now (index lookup, slice plan or pread,
+        pool hand-off). ``stamps`` = (park_s, serve_s, t_enc) ride the
+        outbound item to the counters; ``timing`` is the head's
+        ``(parked, serve_us)`` block, None for an untraced REQ."""
+        t_enc = time.perf_counter()
+        park, timed = parked
+        serve = t_enc - t0
+        return ((park, serve, t_enc),
+                (int(park * 1e6), int(serve * 1e6)) if timed else None)
 
     def _complete(self, req_id: int, res, err, t0: float, span,
-                  req=None) -> None:
+                  req=None, parked: tuple = (0.0, False)) -> None:
         """Engine completion -> outbound item, on the COMPLETING thread
         (inline-write fast path). Responses complete out of order
         across requests, exactly like the threaded core's
-        future->queue pipeline."""
+        future->queue pipeline. ``parked`` is the REQ's (park seconds,
+        carried the trace tail) pair from _start_req."""
         tenant = getattr(req, "tenant", "") if req is not None else ""
         try:
             if err is not None:
@@ -889,21 +926,23 @@ class _EvConn:
                     log.warn("net: zero-copy serve disabled (sendfile "
                              "refused and MOF not mappable); serving "
                              "via engine byte reads")
+                    stamps, timing = self._serve_stamp(t0, parked)
                     head = wire.encode_result_head(
                         req_id, raw_length=res.raw_length,
                         part_length=res.part_length, offset=res.offset,
                         last=res.last, path=res.path, crc=None,
-                        data_len=len(data))
+                        data_len=len(data), timing=timing)
                     item = _BufItem([head, data], credited=True, t0=t0,
-                                    tenant=tenant)
+                                    tenant=tenant, stamps=stamps)
                     self._count_serve("net.serve.copy", tenant)
                     span.end(bytes=len(data))
                 else:
+                    stamps, timing = self._serve_stamp(t0, parked)
                     head = wire.encode_result_head(
                         req_id, raw_length=res.raw_length,
                         part_length=res.part_length, offset=res.offset,
                         last=res.last, path=res.path, crc=None,
-                        data_len=res.length)
+                        data_len=res.length, timing=timing)
                     if view is not None:
                         # mmap mode: the chunk memoryview points into
                         # the MOF's page-cache mapping — sendmsg moves
@@ -911,19 +950,21 @@ class _EvConn:
                         item = _BufItem([head, view], credited=True,
                                         t0=t0, sl=res,
                                         zc_bytes=res.length,
-                                        tenant=tenant)
+                                        tenant=tenant, stamps=stamps)
                     else:
-                        item = _FileItem(head, res, t0, tenant=tenant)
+                        item = _FileItem(head, res, t0, tenant=tenant,
+                                         stamps=stamps)
                     self._count_serve("net.serve.fd", tenant)
                     span.end(bytes=res.length, zero_copy=True)
             else:
+                stamps, timing = self._serve_stamp(t0, parked)
                 head = wire.encode_result_head(
                     req_id, raw_length=res.raw_length,
                     part_length=res.part_length, offset=res.offset,
                     last=res.last, path=res.path, crc=res.crc,
-                    data_len=len(res.data))
+                    data_len=len(res.data), timing=timing)
                 item = _BufItem([head, res.data], credited=True, t0=t0,
-                                tenant=tenant)
+                                tenant=tenant, stamps=stamps)
                 self._count_serve("net.serve.copy", tenant)
                 span.end(bytes=len(res.data))
         except Exception as e:  # noqa: BLE001 - an unencodable response
@@ -1124,6 +1165,13 @@ class _EvConn:
                 break
             self._outq.popleft()
             completed.append(item)
+            if item.stamps is not None:
+                # a DATA frame's last byte is written: its three stages
+                # in one locked update — head encoded -> here is the one
+                # its own header cannot carry
+                park, serve, t_enc = item.stamps
+                metrics.add_keyed((_K_PARK, park), (_K_SERVE, serve),
+                                  (_K_SEND, time.perf_counter() - t_enc))
             if item.close_after:
                 self._poison = True
                 break
@@ -1223,7 +1271,7 @@ class _EvConn:
                     item.slice.release()
                     self._outq[0] = _BufItem(
                         [data], credited=item.credited, t0=item.t0,
-                        tenant=item.tenant)
+                        tenant=item.tenant, stamps=item.stamps)
                     return self._send_bufs(self._outq[0])
                 raise
             if n == 0:

@@ -98,6 +98,18 @@ supplier-side serve/pread work lands in the reduce-side fetch span's
 tree and ``scripts/trace_merge.py`` can stitch the processes' span
 files into one trace.
 
+**Supplier stage timing** (flagged, like the CRC): a DATA head sent in
+answer to a REQ that carried the trace tail — i.e. only while the
+reduce side's spans are on — also carries ``_FLAG_TIMING`` and, after
+the CRC block, two u32 microsecond DURATIONS: ``park`` (REQ decoded ->
+taken off the credit gates) and ``serve`` (-> head encoded: index
+lookup, slice plan or pread, pool hand-off). Durations, not
+timestamps: no clock is shared across processes. A REQ without the
+tail is answered with the pre-timing frame byte for byte, an ERR never
+carries the block, and the decoder accepts both shapes
+(``FetchResult.timing`` is None without it). A peer that sends the
+trace tail must therefore decode the block.
+
 Decoding is STRICT: a bad magic, an unknown version, an out-of-range
 type, a length over :data:`MAX_FRAME`, a short buffer or trailing
 garbage all raise :class:`TransportError` — the receiving side treats
@@ -230,6 +242,9 @@ _MAX_TYPE = 32
 _REQ = struct.Struct("!IQI")      # reduce_id, offset, chunk_size
 _DATA = struct.Struct("!QQQB")    # raw_length, part_length, offset, flags
 _CRC = struct.Struct("!I")
+_TIMING = struct.Struct("!II")    # park_us, serve_us (optional DATA
+                                  # block, _FLAG_TIMING — see docstring)
+_U32_MAX = 0xFFFFFFFF
 _SIZE_REQ = struct.Struct("!II")  # reduce_id, num maps
 _SIZE = struct.Struct("!q")       # total bytes, -1 = unknown
 _HELLO = struct.Struct("!IB")     # server generation, flags
@@ -292,6 +307,7 @@ STATS_SEC_ALL = STATS_SEC_TS | STATS_SEC_SLI | STATS_SEC_ANOMALY
 
 _FLAG_LAST = 0x01
 _FLAG_CRC = 0x02
+_FLAG_TIMING = 0x04
 
 # ERR frames carry the error's class name; the decoder re-raises the
 # same typed error on the reduce side so recovery paths (Segment retry,
@@ -351,18 +367,26 @@ def encode_request(req_id: int, req: ShuffleRequest,
 
 def encode_result_head(req_id: int, *, raw_length: int, part_length: int,
                        offset: int, last: bool, path: str,
-                       crc: Optional[int] = None, data_len: int) -> bytes:
+                       crc: Optional[int] = None, data_len: int,
+                       timing: Optional[tuple] = None) -> bytes:
     """Everything of a DATA frame BEFORE the chunk bytes — frame header
     plus the ACK fields — with the payload length accounting for
     ``data_len`` chunk bytes that the caller sends separately (the
     buffer-donating encode: ``sendmsg([head, chunk])`` scatter-gather,
     or ``head`` + ``os.sendfile`` when the chunk is fd-backed). The
-    chunk bytes never pass through an encode-side concatenation."""
+    chunk bytes never pass through an encode-side concatenation.
+    ``timing`` is the supplier's ``(park_us, serve_us)`` pair, given
+    ONLY in answer to a REQ that carried the trace tail (module
+    docstring); each saturates at the u32's 71 minutes."""
     flags = (_FLAG_LAST if last else 0) | \
-            (_FLAG_CRC if crc is not None else 0)
+            (_FLAG_CRC if crc is not None else 0) | \
+            (_FLAG_TIMING if timing is not None else 0)
     meta = _DATA.pack(raw_length, part_length, offset, flags)
     if crc is not None:
         meta += _CRC.pack(crc & 0xFFFFFFFF)
+    if timing is not None:
+        meta += _TIMING.pack(min(max(int(timing[0]), 0), _U32_MAX),
+                             min(max(int(timing[1]), 0), _U32_MAX))
     meta += _pack_str(path)
     return HEADER.pack(MAGIC, WIRE_VERSION, MSG_DATA, req_id,
                        len(meta) + data_len) + meta
@@ -372,7 +396,7 @@ def encode_result(req_id: int, res: FetchResult) -> bytes:
     return encode_result_head(
         req_id, raw_length=res.raw_length, part_length=res.part_length,
         offset=res.offset, last=res.last, path=res.path, crc=res.crc,
-        data_len=len(res.data)) + res.data
+        data_len=len(res.data), timing=res.timing) + res.data
 
 
 def encode_error(req_id: int, exc: BaseException) -> bytes:
@@ -659,7 +683,7 @@ def decode_request_ex(payload) -> tuple[ShuffleRequest, Optional[tuple]]:
 
 def _decode_result_meta(payload):
     """Parse a DATA payload's meta prefix in place -> (raw_length,
-    part_length, offset, last, crc, path, data_start)."""
+    part_length, offset, last, crc, timing, path, data_start)."""
     if len(payload) < _DATA.size:
         raise TransportError(f"truncated DATA frame ({len(payload)} B)")
     raw_length, part_length, offset, flags = _DATA.unpack_from(payload, 0)
@@ -671,18 +695,25 @@ def _decode_result_meta(payload):
                                  "but absent")
         (crc,) = _CRC.unpack_from(payload, off)
         off += _CRC.size
+    timing = None
+    if flags & _FLAG_TIMING:
+        if off + _TIMING.size > len(payload):
+            raise TransportError("truncated DATA frame: timing flagged "
+                                 "but absent")
+        timing = _TIMING.unpack_from(payload, off)
+        off += _TIMING.size
     path, off = _unpack_str(payload, off, "path")
     return (raw_length, part_length, offset, bool(flags & _FLAG_LAST),
-            crc, path, off)
+            crc, timing, path, off)
 
 
 def decode_result(payload) -> FetchResult:
     """Accepts bytes or a memoryview (meta fields are parsed in place;
     the single ``bytes()`` of the data region is the only copy)."""
-    raw_length, part_length, offset, last, crc, path, off = \
+    raw_length, part_length, offset, last, crc, timing, path, off = \
         _decode_result_meta(payload)
     return FetchResult(bytes(payload[off:]), raw_length, part_length,
-                       offset, path, last=last, crc=crc)
+                       offset, path, last=last, crc=crc, timing=timing)
 
 
 def decode_result_take(payload: bytearray) -> FetchResult:
@@ -693,11 +724,11 @@ def decode_result_take(payload: bytearray) -> FetchResult:
     Zero allocations, zero full-payload copies on the receive path;
     every downstream consumer (record cracking, CRC, decompress,
     ``carry + data`` concatenation) is buffer-agnostic."""
-    raw_length, part_length, offset, last, crc, path, off = \
+    raw_length, part_length, offset, last, crc, timing, path, off = \
         _decode_result_meta(payload)
     del payload[:off]  # one short memmove; the chunk stays in place
     return FetchResult(payload, raw_length, part_length, offset, path,
-                       last=last, crc=crc)
+                       last=last, crc=crc, timing=timing)
 
 
 def decode_error(payload: bytes) -> UdaError:

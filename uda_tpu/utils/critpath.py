@@ -61,9 +61,15 @@ SPAN_BUCKETS: Dict[str, str] = {
     # the MSG_JOB tenant registration is fetch-plane control traffic)
     "fetch": "fetch", "fetch.segment": "fetch", "net.fetch": "fetch",
     "net.size_probe": "fetch", "net.job_bind": "fetch",
+    # inside the completion upcall: a decoded frame queued for the one
+    # upcall thread, and the chunk's cracking
+    "net.dispatch.wait": "fetch", "fetch_crack": "fetch",
     # wait: blocked-on-memory / blocked-on-staging idle (hbm_admit: a
-    # task parked behind the live tasks' HBM reservations)
+    # task parked behind the live tasks' HBM reservations;
+    # fetch_feed_wait: the upcall blocked in feed() on staging's budget
+    # or queue — caused by staging, which outranks it while it runs)
     "wait_mem": "wait", "merge.wait": "wait", "hbm_admit": "wait",
+    "fetch_feed_wait": "wait",
     # decompress+pack: host staging compute (materialize, vint-decode,
     # pack, row build, run spooling)
     "overlap_pack": "decompress_pack", "pack": "decompress_pack",

@@ -87,6 +87,38 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                    "faults [labels: supplier]"),
     "fetch.deprioritized": ("counter", "schedule rotations past a boxed "
                                        "supplier"),
+    # -- counters: a chunk's fetch latency by stage (ISSUE 36). The
+    # four *_seconds are CHUNK-seconds: a window of fetches is in
+    # flight, so they sum past the wall; over fetch.chunks they say
+    # where a chunk's latency goes (net/client.py _account_chunk,
+    # _deliver) --------------------------------------------------------
+    "fetch.chunk.timed": ("counter", "DATA frames whose head carried "
+                                     "the supplier's (park, serve) "
+                                     "block — only in answer to a REQ "
+                                     "with the trace tail, so 0 s of "
+                                     "park is told from 'not reported'"),
+    "fetch.chunk.park_seconds": ("counter", "chunk-seconds a REQ sat "
+                                            "in the supplier behind "
+                                            "its credit gates, as the "
+                                            "DATA head reported"),
+    "fetch.chunk.serve_seconds": ("counter", "chunk-seconds of index "
+                                             "lookup, slice plan or "
+                                             "pread and pool hand-off "
+                                             "in the supplier, as the "
+                                             "DATA head reported"),
+    "fetch.chunk.wire_seconds": ("counter", "chunk-seconds posted -> "
+                                            "frame decoded less park "
+                                            "and serve: both loops, "
+                                            "both socket queues, the "
+                                            "supplier's send (the "
+                                            "whole remote time for an "
+                                            "untimed chunk; clamped "
+                                            "at 0)"),
+    "fetch.chunk.dispatch_wait_seconds": ("counter", "chunk-seconds a "
+                                          "decoded DATA frame waited "
+                                          "in the dispatch queue for "
+                                          "the process's one upcall "
+                                          "thread"),
     # -- counters: survivable shuffle (speculation / resume / coding) ----
     "fetch.speculated": ("counter", "straggler chunks that got a "
                                     "speculative duplicate fetch "
@@ -331,6 +363,32 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                   "byte path (CRC on, pread failpoint "
                                   "armed, zerocopy off, or sendfile "
                                   "fallback)"),
+    # the three below are added together, one locked update a DATA frame
+    # as its last byte is written (net/server.py _drain_locked): an ERR
+    # or an abandoned frame counts in none
+    "net.serve.park_seconds": ("counter", "supplier: seconds REQs sat "
+                                          "decoded behind the tenant "
+                                          "and connection credit gates "
+                                          "(_frame_done -> _start_req); "
+                                          "request-seconds, they "
+                                          "overlap"),
+    "net.serve.serve_seconds": ("counter", "supplier: seconds from "
+                                           "_start_req to the DATA head "
+                                           "encoded (index lookup, "
+                                           "slice plan or pread, pool "
+                                           "hand-off); request-seconds"),
+    "net.serve.send_seconds": ("counter", "supplier: seconds from the "
+                                          "DATA head encoded to the "
+                                          "frame's last byte written "
+                                          "(outbound queue + socket); "
+                                          "request-seconds"),
+    "net.dispatch.busy_seconds": ("counter", "seconds an event loop's "
+                                             "ONE upcall thread spent "
+                                             "inside dispatched calls "
+                                             "(serial: compares with a "
+                                             "wall) [labels: loop]"),
+    "net.dispatch.upcalls": ("counter", "calls the upcall thread ran "
+                                        "[labels: loop]"),
     "net.sendfile.bytes": ("counter", "chunk bytes that went disk->"
                                       "socket via os.sendfile without "
                                       "transiting the Python heap"),
@@ -722,7 +780,13 @@ SPAN_REGISTRY: Dict[str, str] = {
                      "reduce_task (merger/segment.py)",
     "net.fetch": "one chunk request on the wire, reduce side "
                  "(net/client.py); its (trace, span) ids ride the REQ "
-                 "frame",
+                 "frame; ends with the supplier's park_us / serve_us "
+                 "when the DATA head reported them",
+    "net.dispatch.wait": "a decoded DATA frame queued for the one "
+                         "upcall thread, child of its net.fetch "
+                         "(net/client.py _account_chunk -> _deliver); "
+                         "the span twin of "
+                         "fetch.chunk.dispatch_wait_seconds",
     "net.size_probe": "partition size probe over the wire "
                       "(net/client.py)",
     "net.serve": "one REQ served, supplier side (net/server.py); "
@@ -1071,6 +1135,36 @@ class Metrics:
         else:
             with self._lock:
                 self.counters[name] += value
+
+    @staticmethod
+    def series(name: str, **labels) -> tuple:
+        """The counter keys one ``add(name, ..., **labels)`` advances —
+        the total and, with labels, the series — built ONCE for a hot
+        path and fed to :meth:`add_keyed` (``add`` builds the series
+        key on every call). Call sites are held to the registry like
+        ``add``'s (UDA002)."""
+        return (name, _series_key(name, labels)) if labels else (name,)
+
+    @staticmethod
+    def timer_series(name: str) -> tuple:
+        """The counter key of timer ``name``, for a hot path that
+        stamps the phase itself while spans are off and hands the
+        seconds to :meth:`add_keyed` (what ``timer`` would have
+        written; with spans on, use ``timer``: it records the span)."""
+        return (name + "_time",)
+
+    def add_keyed(self, *updates) -> None:
+        """Several counter updates — ``(keys, value)`` pairs, ``keys``
+        from :meth:`series` / :meth:`timer_series` — under ONE
+        acquisition of the hub's lock: the per-chunk form of ``add``
+        (a chunk's fetch updates half a dozen counters, and each
+        acquisition is a Python-level call pair on a thread that
+        shares the interpreter with the whole reduce task)."""
+        with self._lock:
+            counters = self.counters
+            for keys, value in updates:
+                for key in keys:
+                    counters[key] += value
 
     # -- gauges -------------------------------------------------------------
 
