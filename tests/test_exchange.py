@@ -575,6 +575,12 @@ def test_fused_step_equals_the_scatter_then_sort_step(engine, p, case):
     # an engine that sorts them again
     assert "exchange.merge.runs" in grew
     assert grew["exchange.merge.runs"] == (p if engine == "lanes" else 0)
+    # merge passes whose kernel carried its split: the local sort's and
+    # the receive side's log2(p); 0, not nothing, on carry
+    from uda_tpu.ops.pallas_sort import runs_passes, sort_passes
+    assert grew["sort.passes.carried"] == (
+        sort_passes(len(words) // p) + runs_passes(p)
+        if engine == "lanes" else 0)
     want, want_nvalid, want_over, want_spl = scatter_then_sort_step(
         words, D._whole_keys(spl, _STEP_KEYS), mesh, AXIS, capacity,
         _STEP_KEYS, engine, interpret=engine == "lanes")

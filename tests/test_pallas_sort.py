@@ -102,6 +102,53 @@ def test_shape_validation():
                                3, tile=192, interpret=True)
 
 
+# -- the carried merge-path split (fast tier: 8 tiles of 128 lanes, three
+# passes, so pairs stored descending in the first two) -------------------
+
+def _sorted_lanes(x, k):
+    return x[:, np.lexsort(tuple(x[r] for r in reversed(range(k))))]
+
+
+_SORT_CASES = {
+    "uniform": lambda x, k: x,
+    "few_keys": lambda x, k: np.concatenate(
+        [x[:k] % 3, x[k:]]),
+    # the tie-break row alone decides every count
+    "all_keys_equal": lambda x, k: np.concatenate(
+        [np.full_like(x[:k], 7), x[k:]]),
+    "all_keys_max": lambda x, k: np.concatenate(
+        [np.full_like(x[:k], _INF), x[k:]]),
+    # every run wholly below / above its partner: the split jumps
+    "presorted": _sorted_lanes,
+    "reversed": lambda x, k: _sorted_lanes(x, k)[:, ::-1],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SORT_CASES))
+@pytest.mark.parametrize("rows,tb", [(8, 7), (32, 31)])
+@pytest.mark.parametrize("tiles", [2, 8])
+def test_sort_lanes_carried_split_is_the_stable_host_sort(tiles, rows, tb,
+                                                          case):
+    k = 3
+    rng = np.random.default_rng(tiles * rows + len(case))
+    x = rng.integers(0, 2**32, size=(rows, tiles * 128), dtype=np.uint32)
+    x = np.ascontiguousarray(_SORT_CASES[case](x, k))
+    out = np.asarray(pallas_sort.sort_lanes(x, k, tb_row=tb, tile=128,
+                                            interpret=True))
+    want, perm = _oracle(x, k)
+    keep = [r for r in range(rows) if r != tb]
+    np.testing.assert_array_equal(out[keep], want[keep])
+    np.testing.assert_array_equal(out[tb].astype(np.int64), perm)
+
+
+def test_pass_counts_are_the_loops_own():
+    assert pallas_sort.sort_passes(1 << 24) == 14      # 2^24 lanes, tile 1024
+    assert pallas_sort.sort_passes(1000) == 0          # one clamped tile
+    assert pallas_sort.sort_passes(1025) == 1
+    assert [pallas_sort.runs_passes(r) for r in (1, 2, 3, 4, 5, 8)] == [
+        0, 1, 2, 2, 3, 3]
+
+
 # -- merge_lanes_runs: the pipeline's merge-only entry (fast tier: a few
 # tiles of 128 lanes a case) --------------------------------------------
 
@@ -122,27 +169,41 @@ def _runs_matrix(counts, run_len, keys_of, seed):
     for k, c in enumerate(counts):
         lanes = slice(k * run_len, (k + 1) * run_len)
         keys = np.full((_KEYS, run_len), _INF, np.uint32)
-        real = keys_of(rng, c)
+        real = keys_of(rng, c, k)
         keys[:, :c] = real[:, np.lexsort(real[::-1])]
         x[:_KEYS, lanes] = keys
         x[_KEYS, lanes] = np.arange(run_len) >= c
     return x
 
 
-def _uniform_keys(rng, c):
+def _uniform_keys(rng, c, k):
     return rng.integers(0, 2**32, size=(_KEYS, c), dtype=np.uint32)
 
 
-def _few_keys(rng, c):      # ties inside a run and across run boundaries
+def _few_keys(rng, c, k):   # ties inside a run and across run boundaries
     return rng.integers(0, 3, size=(_KEYS, c), dtype=np.uint32)
 
 
-def _one_key(rng, c):
+def _one_key(rng, c, k):    # the tie-break row alone decides every count
     return np.full((_KEYS, c), 77, np.uint32)
 
 
-def _max_keys(rng, c):      # real all-0xFFFFFFFF keys beside the padding
+def _max_keys(rng, c, k):   # real all-0xFFFFFFFF keys beside the padding
     return np.full((_KEYS, c), _INF, np.uint32)
+
+
+def _run_below_run(rng, c, k):
+    # run k wholly below run k-1: a pair's split jumps 0 -> L at the
+    # tile where B' runs out, and the windows clamp at the run ends
+    keys = _uniform_keys(rng, c, k)
+    keys[0] = 1000 - k
+    return keys
+
+
+def _run_above_run(rng, c, k):
+    keys = _uniform_keys(rng, c, k)
+    keys[0] = k
+    return keys
 
 
 # run length (slots), how many of each run's slots are real, the keys
@@ -158,11 +219,18 @@ _RUN_CASES = {
     "all_keys_equal": (200, lambda k, L: (L, 90, 0, 199)[k % 4], _one_key),
     "real_max_keys_beside_padding": (200, lambda k, L: (60, L, 0, 7)[k % 4],
                                      _max_keys),
+    "each_run_below_the_last": (3 * _TILE, lambda k, L: L, _run_below_run),
+    "each_run_above_the_last": (3 * _TILE, lambda k, L: (L, L - 1)[k % 2],
+                                _run_above_run),
+    "one_tile_runs": (_TILE, lambda k, L: L, _few_keys),
+    "one_off_a_tile_multiple": (2 * _TILE + 1,
+                                lambda k, L: (L, L - 2, 1, L)[k % 4],
+                                _few_keys),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_RUN_CASES))
-@pytest.mark.parametrize("runs", [1, 2, 4, 8])
+@pytest.mark.parametrize("runs", [1, 2, 3, 4, 5, 8])
 def test_merge_lanes_runs_is_the_stable_host_sort(runs, case):
     run_len, count_of, keys_of = _RUN_CASES[case]
     counts = [count_of(k, run_len) for k in range(runs)]

@@ -12,13 +12,13 @@ Implementation: one merge PASS of the lanes bitonic pipeline
 sort would have left them — A ascending in lanes [0, L), B stored
 DESCENDING in lanes [L, 2L) (so the pair is bitonic as stored), with
 the arrival index in the tie-break row and +inf-key padding lanes on
-the ascending tail / descending front. ``_pass_splits`` +
-``_merge_pass`` then merge them like any other pass. This reuses the
-ONE merge kernel that is validated on real TPU hardware; the earlier
-row-matrix merge kernel variant was unloadable under Mosaic (minor-dim
-slices of a [tile, W] block violate the 128-lane tiling rule — the
-same layout problem that motivated the lanes design in the first
-place).
+the ascending tail / descending front. ``_merge_pass`` then merges
+them like any other pass, carrying the merge-path split from tile to
+tile inside the kernel. This reuses the ONE merge kernel that is
+validated on real TPU hardware; the earlier row-matrix merge kernel
+variant was unloadable under Mosaic (minor-dim slices of a [tile, W]
+block violate the 128-lane tiling rule — the same layout problem that
+motivated the lanes design in the first place).
 
 Rows travel as uint32[n, W] with the first ``num_keys`` columns the
 big-endian key words (the uda_tpu.ops.packing layout); W <= 31.
@@ -34,7 +34,7 @@ import numpy as np
 from jax import lax
 
 from uda_tpu.ops import pallas_sort
-from uda_tpu.ops.pallas_sort import _lex_lt, _merge_pass, _pass_splits
+from uda_tpu.ops.pallas_sort import _lex_lt, _merge_pass
 
 __all__ = ["merge_sorted_pair", "merge_splits"]
 
@@ -51,8 +51,9 @@ def merge_splits(a, b, tile: int, num_keys: int):
     the first d merged rows (merge-path diagonal intersection). Returns
     int32[num_tiles]. Vectorized binary search, 32 fixed iterations.
 
-    (Host-callable analysis utility; the kernel path computes its
-    windows with pallas_sort._pass_splits instead.)"""
+    (Host-callable analysis utility, and the tests' oracle of the
+    split the merge kernel carries from tile to tile: its i at tile t
+    is ``merge_splits(...)[t]``.)"""
     na, nb = a.shape[0], b.shape[0]
     num_tiles = (na + nb + tile - 1) // tile
     d = jnp.arange(num_tiles, dtype=jnp.int32) * tile
@@ -118,9 +119,7 @@ def _merge_sorted_pair_jit(a, b, num_keys: int, tile: int, interpret: bool):
     tb = pallas_sort.TB_ROW_DEFAULT
     L = _ceil_runs(na, nb, tile)
     x = _pack_bitonic_pair(a, b, wcols, pallas_sort.ROWS, tb, L)
-    splits = _pass_splits(x, jnp.int32(L), jnp.bool_(True), tile,
-                          num_keys, tb)
-    out = _merge_pass(x, splits, tile, num_keys, tb, interpret=interpret)
+    out = _merge_pass(x, L, True, tile, num_keys, tb, interpret=interpret)
     return out[:wcols, :na + nb].T
 
 

@@ -22,13 +22,19 @@ walk) built for how a TPU actually wants to touch memory:
   bitonic *as stored*, so no kernel ever reverses data.
 - **Merge passes** (`_merge_pass_kernel`): log2(n/T) passes; pass ℓ
   merges adjacent run pairs of length L into runs of 2L whose direction
-  again alternates (the final pass emits ascending). Per output tile, a
-  vectorized XLA binary search (merge-path) finds the pair diagonal;
-  the kernel DMAs one lane-ALIGNED superwindow per side, aligns with a
-  dynamic lane roll, masks out-of-window lanes to +inf positioned so
-  the concatenation stays bitonic (ascending A with +inf tail, then
-  +inf front on the stored-descending B window), and runs one
-  log2(2T)-stage bitonic merge network in the tile's output direction.
+  again alternates (the final pass emits ascending). The grid walks a
+  pair's output tiles in ascending rank order and CARRIES the pair's
+  merge-path split (records taken from A, from B) from tile to tile in
+  SMEM — (0, 0) at a pair's first tile, no search anywhere. Per tile the
+  kernel DMAs one lane-ALIGNED superwindow per side, aligns it with a
+  dynamic lane roll, and takes min(A_r, Bs_r) lane by lane (B as
+  stored, descending): the first stage of the bitonic merge of the two
+  windows, whose kept half — the tile's records, bitonic — goes through
+  the remaining log2(T) stages in the tile's output direction. The
+  count of lanes that kept A is what the tile consumed of A: it moves
+  the split, and the next tile's windows are in flight before those
+  stages run. A pair stored descending writes its tiles in reverse
+  position (the output index map), so no kernel ever reverses data.
 
 Stability: the tie-break row makes all sort keys distinct, so the
 (unstable) bitonic networks reproduce stable arrival order exactly.
@@ -59,7 +65,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ROWS", "sort_lanes", "merge_lanes_runs", "rows_to_lanes",
-           "lanes_to_rows", "keys8_sort_perm", "pad_pow2", "TB_ROW_DEFAULT"]
+           "lanes_to_rows", "keys8_sort_perm", "pad_pow2", "sort_passes",
+           "runs_passes", "TB_ROW_DEFAULT"]
 
 ROWS = 32               # sublane-padded row count of the lanes layout
 TB_ROW_DEFAULT = 31     # default tie-break row (last)
@@ -178,152 +185,119 @@ def _tile_sort(x, tile: int, num_keys: int, tb_row: int, alternate: bool,
     )(x)
 
 
-def _pass_splits(x, run_len, final, tile: int, num_keys: int, tb_row: int):
-    """Merge-path windows for one pass, in XLA.
+def _pass_windows(sc_ref, pair, i, j, *, tile: int, n: int):
+    """Scalar window arithmetic for one output tile: ``pair``'s runs are
+    A = lanes [pair * 2L, +L) ascending and B = the next L lanes stored
+    DESCENDING, read through its ascending view B'[m] = B[L-1-m]; the
+    tile wants A[i, i+tile) and B'[j, j+tile).
 
-    ``run_len`` (= L) and ``final`` may be TRACED scalars: every
-    pass-dependent quantity is computed here and handed to the kernel as
-    data, so ONE compiled kernel serves every pass (and the pass loop
-    can be a ``lax.fori_loop``) — the whole pipeline costs two Mosaic
-    kernel compiles regardless of n.
-
-    Rank bookkeeping: per output tile, d_eff is the pair-local diagonal
-    in ASCENDING rank space — for descending-output tiles the tile's
-    ranks are [2L - d_local - T, 2L - d_local), counted from the top —
-    and i0 is the number of A-run records among the first d_eff merged
-    records (vectorized merge-path binary search). B is the
-    stored-DESCENDING run read through its logical ascending view
-    B'[m] = B[L-1-m]; ties go to A (arrival order) which the strict
-    tie-break ordering decides naturally.
-
-    Returns int32[num_tiles, 8] rows
-    (a_blk, shift_a, thr_a, b_blk, shift_b, thr_b, out_asc, 0):
-    per side an aligned superwindow start (in lane-block units), the
-    non-negative cyclic lane shift in [0, win) that places the wanted
-    first record at lane 0, and the invalid-lane threshold
-    (A: lanes >= thr_a are past the run end; B: lanes < thr_b are below
-    B'[j0]); see _merge_pass_kernel for how they are applied.
-    """
-    rows, n = x.shape
-    L = jnp.asarray(run_len, jnp.int32)
-    final = jnp.asarray(final, jnp.bool_)
-    num_tiles = n // tile
+    Returns (a_blk, shift_a, thr_a, b_blk, shift_b, thr_b): per side an
+    aligned superwindow start (in lane-block units), the non-negative
+    cyclic lane shift in [0, win) that places the wanted first record at
+    lane 0, and the validity threshold (A: lanes >= thr_a are past the
+    run end; B: lanes < thr_b are below B'[j])."""
+    L = sc_ref[0]
     win = tile + _LANE
-    t = jnp.arange(num_tiles, dtype=jnp.int32)
-    pair = (t * tile) // (2 * L)
-    d_local = t * tile - pair * 2 * L
-    out_asc = final | ((pair % 2) == 0)
-    d_eff = jnp.where(out_asc, d_local, 2 * L - (d_local + tile))
+    last_blk = (n - win) // _LANE         # superwindows never leave x
     a_base = pair * 2 * L
     b_base = a_base + L
-    key_rows_idx = list(range(num_keys)) + [tb_row]
-
-    def key_at(global_idx):
-        return [x[r, global_idx] for r in key_rows_idx]
-
-    lo = jnp.maximum(0, d_eff - L)
-    hi = jnp.minimum(d_eff, L)
-    # under shard_map's strict vma typing the carry must ENTER the loop
-    # varying over the same manual axes it EXITS with: the body compares
-    # against x (device-varying), so (lo, hi) become varying after one
-    # iteration while their iota/run_len-derived inits are replicated.
-    # pcast the inits to x's vma (a no-op outside shard_map, where vma
-    # is empty) — this is what lets the distributed sort run the lanes
-    # engines with check_vma=True (see parallel/distributed._sort_step)
-    vma = tuple(sorted(jax.typeof(x).vma))
-    lo, hi = lax.pcast((lo, hi), vma, to="varying")
-
-    def body(_, carry):
-        lo, hi = carry
-        mid = (lo + hi + 1) // 2          # candidate: A-records taken
-        j = d_eff - mid                   # B'-records taken
-        a_idx = a_base + jnp.clip(mid - 1, 0, L - 1)
-        b_idx = b_base + jnp.clip(L - 1 - j, 0, L - 1)  # B'[j] stored lane
-        a_le_b = ~_lex_lt(key_at(b_idx), key_at(a_idx))
-        ok = (mid <= 0) | (j >= L) | a_le_b
-        lo = jnp.where(ok, mid, lo)
-        hi = jnp.where(ok, hi, mid - 1)
-        return lo, hi
-
-    bits = max(2, int(np.log2(n)) + 2)    # covers any L <= n/2
-    i0, _ = lax.fori_loop(0, bits, body, (lo, hi))
-    j0 = d_eff - i0
-
-    # ---- A: records [i0, i0+tile) of the ascending run ----
-    a_start = a_base + i0
-    a_align = jnp.minimum((a_start // _LANE) * _LANE, n - win)
-    roll_a = a_start - a_align
-    thr_a = L - i0                        # lanes >= thr_a: past run end
-    # ---- B: stored lanes holding B'[j0+tile-1] ... B'[j0] ----
-    # unclamped start b_base + L - j0 - tile undershoots b_base by
-    # inv = max(0, j0 + tile - L); read from the clamped start and roll
-    # RIGHT by inv so position r holds B'[j0 + tile - 1 - r] for r>=inv
-    # and the first inv lanes are masked (+inf front)
-    inv = jnp.maximum(0, j0 + tile - L)
-    b_clamp = b_base + jnp.maximum(0, L - j0 - tile)
-    b_align = jnp.minimum((b_clamp // _LANE) * _LANE, n - win)
-    roll_b = inv - (b_clamp - b_align)
-    # aligned starts ship as LANE-BLOCK indices; the kernel multiplies
-    # by _LANE so Mosaic can statically prove the HBM slice offset is
-    # lane-divisible (a raw traced offset fails its divisibility check).
+    # ---- A: records [i, i+tile) of the ascending run ----
+    a_start = a_base + i
+    a_blk = jnp.minimum(a_start // _LANE, last_blk)
+    roll_a = a_start - a_blk * _LANE
+    thr_a = L - i
+    # ---- B: stored lanes holding B'[j+tile-1] ... B'[j] ----
+    # unclamped start b_base + L - j - tile undershoots b_base by
+    # thr_b = max(0, j + tile - L); read from the clamped start and roll
+    # RIGHT by thr_b so position r holds B'[j + tile - 1 - r] for
+    # r >= thr_b and the first thr_b lanes are not B's
+    thr_b = jnp.maximum(0, j + tile - L)
+    b_clamp = b_base + jnp.maximum(0, L - j - tile)
+    b_blk = jnp.minimum(b_clamp // _LANE, last_blk)
+    roll_b = thr_b - (b_clamp - b_blk * _LANE)
     # Roll amounts are normalized to [0, win): hardware pltpu.roll
     # miscomputes NEGATIVE dynamic shifts (interpret mode is fine), so
-    # only non-negative cyclic shifts may reach the kernel.
-    shift_a = jnp.mod(-roll_a, win)
-    shift_b = jnp.mod(roll_b, win)
-    cols = [a_align // _LANE, shift_a, thr_a, b_align // _LANE, shift_b, inv,
-            out_asc.astype(jnp.int32), jnp.zeros_like(a_align)]
-    return jnp.stack([c.astype(jnp.int32) for c in cols], axis=1)
+    # only non-negative cyclic shifts may reach it. roll_a is in
+    # [0, win), roll_b in (-win, tile].
+    shift_a = jnp.where(roll_a == 0, 0, win - roll_a)
+    shift_b = jnp.where(roll_b < 0, roll_b + win, roll_b)
+    return a_blk, shift_a, thr_a, b_blk, shift_b, thr_b
 
 
-def _merge_pass_kernel(splits_ref, splits_nxt_ref, x_hbm, o_ref, a_bufs,
-                       b_bufs, sem_a, sem_b, *, tile, num_keys, tb_row,
-                       split_blk):
-    """One output tile of one merge pass (see _pass_splits for the rank
-    bookkeeping; every pass-dependent scalar arrives via splits_ref, so
-    this kernel compiles once and serves all log2(n/tile) passes).
+def _pair_ascending(sc_ref, pair):
+    """Whether ``pair``'s merged run is stored ascending: every pair of
+    the final pass, the even pairs of the others."""
+    return (sc_ref[1] != 0) | (pair % 2 == 0)
 
-    DMA double buffering: the windows for tile t+1 (whose aligned starts
-    arrive via splits_nxt_ref, the splits table shifted by one row) are
-    DMA'd into the other scratch slot WHILE tile t's merge network runs,
-    so HBM latency overlaps compute across sequential grid steps.
 
-    Window construction: each side DMAs a lane-aligned superwindow of
+def _out_tile(t, sc_ref):
+    """Output tile position of grid step ``t``. A pair's tiles are
+    computed in ASCENDING rank order whatever its output direction; a
+    pair stored descending holds its lowest ranks in its LAST tile, so
+    its steps write their tiles in reverse position."""
+    tpp = sc_ref[2]                       # tiles a pair
+    pair = t // tpp
+    return jnp.where(_pair_ascending(sc_ref, pair), t,
+                     (2 * pair + 1) * tpp - 1 - t)
+
+
+def _merge_pass_kernel(sc_ref, x_hbm, o_ref, a_bufs, b_bufs, sem_a, sem_b,
+                       st, *, tile, num_keys, tb_row):
+    """One output tile of one merge pass. Every pass-dependent scalar
+    arrives via ``sc_ref`` = (L, final, tiles a pair), so this kernel
+    compiles once and serves all log2(n/tile) passes.
+
+    The merge-path split is CARRIED, not searched: grid steps run in
+    order, a pair's tiles in ascending rank order, and ``st`` (SMEM
+    scratch: pair, tile-in-pair, i, j) holds how many records of A and
+    of B' the pair's earlier tiles consumed — (0, 0) at a pair's first
+    tile. The tile holds the ``tile`` smallest of A[i, i+tile) and
+    B'[j, j+tile). With the B window as stored (descending: lane r holds
+    Bs_r = B'[j + tile - 1 - r]) those are min(A_r, Bs_r), lane by lane
+    — the first compare-exchange stage of the bitonic merge of
+    [A ++ Bs], of which only this kept half is ever computed — and
+    because A rises and Bs falls the lanes that keep A are a prefix:
+    their count nA is what this tile consumes of A, tile - nA of B'.
+    A lane past A's run end takes Bs and a lane below B'[j] takes A
+    (every lane has a valid side: the runs' remainders sum to >= tile),
+    BEFORE any key is compared — a run's +inf padding lanes
+    (merge_lanes_runs) tie with each other in every row, and validity
+    first is what keeps i and j within L.
+
+    DMA double buffering: as soon as nA is known the windows of tile
+    t+1 are DMA'd into the other scratch slot, WHILE tile t's remaining
+    log2(tile) stages run, so HBM latency overlaps compute across
+    sequential grid steps. Each side DMAs a lane-aligned superwindow of
     tile+128 lanes (align floor-clamped so it never leaves the array),
     then one dynamic cyclic roll places the wanted first record at lane
-    0. Out-of-window lanes are masked to +inf *positionally* so the
-    concatenation stays bitonic:
-
-      [ A: ascending, +inf tail ] ++ [ B: +inf front, descending ]
-
-    (ascending -> +inf plateau -> descending = bitonic). The +inf lanes
-    always land in the discarded half of the merge: smallest-T taken
-    for ascending output, largest-T (positions [T, 2T) of the
-    descending-direction network) for descending output."""
-    rows = a_bufs.shape[1]
+    0 (_pass_windows)."""
     t = pl.program_id(0)
     nt = pl.num_programs(0)
-    s = t % split_blk                    # this tile's row in the block
     slot = t % 2
     win = tile + _LANE
+    windows = partial(_pass_windows, sc_ref, tile=tile, n=x_hbm.shape[1])
 
-    def issue(spl, slot):
-        a_cp = pltpu.make_async_copy(
-            x_hbm.at[:, pl.ds(spl[s, 0] * _LANE, win)], a_bufs.at[slot],
-            sem_a.at[slot])
-        b_cp = pltpu.make_async_copy(
-            x_hbm.at[:, pl.ds(spl[s, 3] * _LANE, win)], b_bufs.at[slot],
-            sem_b.at[slot])
-        a_cp.start()
-        b_cp.start()
+    def issue(a_blk, b_blk, slot):
+        # aligned starts are LANE-BLOCK indices times _LANE so Mosaic can
+        # statically prove the HBM slice offset is lane-divisible (a raw
+        # traced offset fails its divisibility check)
+        pltpu.make_async_copy(
+            x_hbm.at[:, pl.ds(a_blk * _LANE, win)], a_bufs.at[slot],
+            sem_a.at[slot]).start()
+        pltpu.make_async_copy(
+            x_hbm.at[:, pl.ds(b_blk * _LANE, win)], b_bufs.at[slot],
+            sem_b.at[slot]).start()
 
     @pl.when(t == 0)
     def _():
-        issue(splits_ref, 0)
+        for k in range(4):
+            st[k] = 0
+        a_blk, _, _, b_blk, _, _ = windows(0, 0, 0)
+        issue(a_blk, b_blk, 0)
 
-    @pl.when(t + 1 < nt)
-    def _():
-        issue(splits_nxt_ref, (t + 1) % 2)
+    pair, s, i, j = st[0], st[1], st[2], st[3]
+    _, shift_a, thr_a, _, shift_b, thr_b = windows(pair, i, j)
+    out_asc = _pair_ascending(sc_ref, pair)
 
     # wait for this tile's windows (issued at t-1, or just above for t=0)
     pltpu.make_async_copy(x_hbm.at[:, pl.ds(0, win)], a_bufs.at[slot],
@@ -331,68 +305,70 @@ def _merge_pass_kernel(splits_ref, splits_nxt_ref, x_hbm, o_ref, a_bufs,
     pltpu.make_async_copy(x_hbm.at[:, pl.ds(0, win)], b_bufs.at[slot],
                           sem_b.at[slot]).wait()
 
-    shift_a = splits_ref[s, 1]           # non-negative cyclic shifts only
-    thr_a = splits_ref[s, 2]
-    shift_b = splits_ref[s, 4]
-    thr_b = splits_ref[s, 5]
-    out_asc = splits_ref[s, 6] != 0
-
-    r_idx = lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-    rowi = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    is_key_row = (rowi < num_keys) | (rowi == tb_row)
-
     a_rows = pltpu.roll(a_bufs[slot], shift_a, 1)[:, :tile]
-    a_invalid = r_idx >= thr_a             # tail lanes past the run end
-    a_rows = jnp.where(is_key_row & a_invalid,
-                       jnp.broadcast_to(_INF, a_rows.shape), a_rows)
-
     b_rows = pltpu.roll(b_bufs[slot], shift_b, 1)[:, :tile]
-    b_invalid = r_idx < thr_b              # front lanes below B'[j0]
-    b_rows = jnp.where(is_key_row & b_invalid,
-                       jnp.broadcast_to(_INF, b_rows.shape), b_rows)
-
-    seq = jnp.concatenate([a_rows, b_rows], axis=1)
-    asc_mask = jnp.broadcast_to(out_asc, (1, 2 * tile))
     key_rows_idx = list(range(num_keys)) + [tb_row]
-    j = tile
-    while j >= 1:
-        seq = _cmp_exchange(seq, j, asc_mask, key_rows_idx)
-        j //= 2
-    o_ref[...] = jnp.where(out_asc, seq[:, :tile], seq[:, tile:])
+    r_idx = lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+    a_lt_b = _lex_lt([a_rows[r] for r in key_rows_idx],
+                     [b_rows[r] for r in key_rows_idx])[None, :]
+    take_a = (r_idx < thr_a) & ((r_idx < thr_b) | a_lt_b)
+    n_a = jnp.sum(take_a.astype(jnp.int32))
+
+    # carry the split to the next tile and start its windows
+    last = s + 1 == sc_ref[2]
+    pair, s = jnp.where(last, pair + 1, pair), jnp.where(last, 0, s + 1)
+    i, j = jnp.where(last, 0, i + n_a), jnp.where(last, 0, j + tile - n_a)
+    st[0], st[1], st[2], st[3] = pair, s, i, j
+
+    @pl.when(t + 1 < nt)
+    def _():
+        a_blk, _, _, b_blk, _, _ = windows(pair, i, j)
+        issue(a_blk, b_blk, 1 - slot)
+
+    x = jnp.where(take_a, a_rows, b_rows)  # bitonic: A's prefix, Bs' tail
+    asc_mask = jnp.broadcast_to(out_asc, (1, tile))
+    k = tile // 2
+    while k >= 1:
+        x = _cmp_exchange(x, k, asc_mask, key_rows_idx)
+        k //= 2
+    o_ref[...] = x
 
 
 @partial(jax.jit, static_argnames=("tile", "num_keys", "tb_row", "interpret"))
-def _merge_pass(x, splits, tile: int, num_keys: int, tb_row: int,
+def _merge_pass(x, run_len, final, tile: int, num_keys: int, tb_row: int,
                 interpret: bool = False):
+    """One merge pass: adjacent run pairs of length ``run_len`` (= L, a
+    multiple of ``tile``; A ascending, B stored descending) merge into
+    runs of 2L, stored ascending in even pairs and in every pair of the
+    ``final`` pass, descending in the others. ``run_len`` and ``final``
+    may be TRACED scalars, so ONE compiled kernel serves every pass
+    (and the pass loop can be a ``lax.fori_loop``) — the whole pipeline
+    costs two Mosaic kernel compiles regardless of n."""
     rows, n = x.shape
-    # The splits table is BLOCKED into SMEM a few rows per grid step: a
-    # whole-table scalar prefetch would put [num_tiles, 8] int32 in SMEM
-    # with the minor dim padded to 128 lanes — 4 MB at n=8M vs the 1 MB
-    # SMEM budget. An (8, 8) block is 256 bytes regardless of n (the
-    # lowering wants the sublane block dim divisible by 8 or equal to
-    # the array dim, hence 8 rows — the kernel picks its row by
-    # program_id % 8).
-    split_blk = min(8, n // tile)
-    # splits shifted by one row: step t reads tile t+1's aligned starts
-    # for the double-buffered prefetch (last row duplicated, never used)
-    splits_nxt = jnp.concatenate([splits[1:], splits[-1:]], axis=0)
-    blk = pl.BlockSpec((split_blk, 8), lambda t: (t // split_blk, 0),
-                       memory_space=pltpu.SMEM)
+    L = jnp.asarray(run_len, jnp.int32)
+    sc = jnp.stack([L, jnp.asarray(final, jnp.int32), 2 * L // tile])
     return pl.pallas_call(
         partial(_merge_pass_kernel, tile=tile, num_keys=num_keys,
-                tb_row=tb_row, split_blk=split_blk),
-        grid=(n // tile,),
-        in_specs=[blk, blk, pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((rows, tile), lambda t: (0, t)),
-        scratch_shapes=[
-            pltpu.VMEM((2, rows, tile + _LANE), jnp.uint32),
-            pltpu.VMEM((2, rows, tile + _LANE), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+                tb_row=tb_row),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((rows, tile),
+                                   lambda t, sc: (0, _out_tile(t, sc))),
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, tile + _LANE), jnp.uint32),
+                pltpu.VMEM((2, rows, tile + _LANE), jnp.uint32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((4,), jnp.int32),
+            ]),
+        # the carried split needs the grid's steps run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         out_shape=_uint32_struct((rows, n), x),
         interpret=interpret,
-    )(splits, splits_nxt, x)
+    )(sc, x)
 
 
 def pad_pow2(n: int, tile: int) -> tuple[int, int]:
@@ -402,6 +378,18 @@ def pad_pow2(n: int, tile: int) -> tuple[int, int]:
     (m % tile == 0 with m/tile a power of two). Returns (m, tile)."""
     m = max(_LANE, 1 << max(0, n - 1).bit_length())
     return m, min(tile, m)
+
+
+def sort_passes(n: int, tile: int = 1024) -> int:
+    """Merge passes ``sort_lanes`` runs over ``n`` lanes padded by
+    ``pad_pow2``."""
+    m, tile = pad_pow2(n, tile)
+    return (m // tile).bit_length() - 1
+
+
+def runs_passes(runs: int) -> int:
+    """Merge passes ``merge_lanes_runs`` runs over ``runs`` runs."""
+    return (runs - 1).bit_length()
 
 
 def keys8_sort_perm(keyrows, tile: int = 1024, interpret: bool = False):
@@ -452,7 +440,7 @@ def sort_lanes(x, num_keys: int, tb_row: int = TB_ROW_DEFAULT,
                          f"tile={tile}")
     if not 0 < num_keys <= tb_row < rows:
         raise ValueError(f"bad num_keys={num_keys} / tb_row={tb_row}")
-    levels = int(np.log2(n // tile))
+    levels = sort_passes(n, tile)
     x = _tile_sort(x, tile, num_keys, tb_row, alternate=levels > 0,
                    interpret=interpret)
     if levels == 0:
@@ -465,8 +453,7 @@ def sort_lanes(x, num_keys: int, tb_row: int = TB_ROW_DEFAULT,
     def body(lvl, x):
         run_len = jnp.int32(tile) << lvl
         final = lvl == levels - 1
-        splits = _pass_splits(x, run_len, final, tile, num_keys, tb_row)
-        return _merge_pass(x, splits, tile, num_keys, tb_row,
+        return _merge_pass(x, run_len, final, tile, num_keys, tb_row,
                            interpret=interpret)
 
     return lax.fori_loop(0, levels, body, x)
@@ -518,7 +505,8 @@ def merge_lanes_runs(x, run_len: int, num_keys: int,
         return x
     _, tile = pad_pow2(run_len, tile)
     blk = -(-run_len // tile) * tile
-    nblk = 1 << (runs - 1).bit_length()
+    levels = runs_passes(runs)
+    nblk = 1 << levels
     if (nblk, blk) != (runs, run_len):
         x = jnp.pad(x.reshape(rows, runs, run_len),
                     ((0, 0), (0, nblk - runs), (0, blk - run_len)),
@@ -526,12 +514,9 @@ def merge_lanes_runs(x, run_len: int, num_keys: int,
     x = jnp.concatenate(
         [jnp.flip(x[:, k * blk:(k + 1) * blk], axis=1) if k % 2
          else x[:, k * blk:(k + 1) * blk] for k in range(nblk)], axis=1)
-    levels = nblk.bit_length() - 1
 
     def body(lvl, x):   # sort_lanes' pass, run lengths blk, 2 blk, ...
-        splits = _pass_splits(x, jnp.int32(blk) << lvl, lvl == levels - 1,
-                              tile, num_keys, tb_row)
-        return _merge_pass(x, splits, tile, num_keys, tb_row,
-                           interpret=interpret)
+        return _merge_pass(x, jnp.int32(blk) << lvl, lvl == levels - 1,
+                           tile, num_keys, tb_row, interpret=interpret)
 
     return lax.fori_loop(0, levels, body, x)[:, :n]

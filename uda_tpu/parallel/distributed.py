@@ -232,6 +232,21 @@ def _merges_runs(payload_path: str) -> bool:
     return payload_path == "lanes"
 
 
+def _carried_passes(payload_path: str, n: int, p: int, capacity: int) -> int:
+    """Merge passes of a chip's two sort stages in the fused step — the
+    local sort of its ``n`` rows, the combine of the ``p`` runs of
+    ``capacity`` rows it receives — whose kernel carried its merge-path
+    split from tile to tile (ops/pallas_sort.py); 0 on the engine that
+    runs no such pass."""
+    from uda_tpu.ops import pallas_sort
+
+    if payload_path == "carry":
+        return 0
+    return pallas_sort.sort_passes(n) + (
+        pallas_sort.runs_passes(p) if _merges_runs(payload_path)
+        else pallas_sort.sort_passes(p * capacity))
+
+
 def _sort_valid_rows(flat, valid, num_keys, payload_path, interpret=False,
                      run_len=None):
     """Stable local sort of ``flat``'s rows by the first ``num_keys``
@@ -365,8 +380,9 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
     # grid-machinery dynamic_slice mixes replicated block indices with
     # varying operands — an emulator limitation, not a property of the
     # compiled kernel (minimal repro: scripts/repro_check_vma.py). The
-    # compiled path traces clean since the merge-pass fori_loop carry
-    # is pcast to the data's vma at init (ops/pallas_sort._pass_splits).
+    # compiled path traces clean: a merge pass is one pallas_call on
+    # the varying data and three replicated scalars, and its output
+    # carries the data's vma (ops/pallas_sort._uint32_struct).
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P()),
              out_specs=(P(axis), P(axis), P(axis), P(axis)),
              check_vma=not interpret)
@@ -421,7 +437,8 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     by the splitters, exchanges one window a destination and combines
     the P sorted runs it receives (``_sort_step``; counter
     ``exchange.merge.runs``: the runs a chip merged, P on the lanes
-    engine, 0 on an engine that sorts them again).
+    engine, 0 on an engine that sorts them again; counter
+    ``sort.passes.carried``: ``_carried_passes``).
 
     ``words``: uint32[N, W] records (rows sharded over ``axis``; the
     first ``num_keys`` columns are the big-endian key words).
@@ -495,6 +512,9 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     # not nothing, where the last stage sorted its buffer from scratch
     metrics.add("exchange.merge.runs",
                 p if _merges_runs(payload_path) else 0)
+    metrics.add("sort.passes.carried",
+                _carried_passes(payload_path, int(words.shape[0]) // p,
+                                p, capacity))
     metrics.add("exchange.fused.overflow_reruns", 0)
     res = DistributedSortResult(out, nvalid, overflow, totals, used,
                                 int(words.shape[0]))

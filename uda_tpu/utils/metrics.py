@@ -281,6 +281,13 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                        "source chip on the lanes engine; "
                                        "0 where the last stage sorted "
                                        "the receive buffer from scratch)"),
+    "sort.passes.carried": ("counter", "merge passes of a fused distributed "
+                                       "sort step's two sort stages (local "
+                                       "sort, receive-side combine) whose "
+                                       "kernel carried its merge-path "
+                                       "split from tile to tile "
+                                       "(ops/pallas_sort.py); 0 on the "
+                                       "engine that runs no such pass"),
     "exchange.fused.overflow_reruns": ("counter", "distributed sort steps "
                                                   "whose fused attempt "
                                                   "overflowed a credit "
