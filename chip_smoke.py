@@ -21,7 +21,12 @@ and exits 0 only if all of it happened on the TPU:
   on the ``ici:4`` and ``dcn:2,ici:2`` meshes, when the machine has
   four, and the lanes engine by name; then, on ``ici:4``, the same
   entry with ``splitters="sampled"`` on Zipf id keys that all share
-  their first word (records back, every one, in one total order).
+  their first word (records back, every one, in one total order). On
+  ``dcn:2,ici:2`` the step is the hierarchical round body, whose staged
+  buffers compile to 9,664 MB a chip at this size (2^22 records a chip)
+  where the flat step on ``ici:4`` compiles to 3,557 MB
+  (``memory_analysis()`` for a described v5e:2x2, PR 38): the benchmark
+  cell ``exchange_dcn2_ici2`` times it.
 
 The parent process never imports JAX: a chip belongs to one process at
 a time, so the phases run as sequential children that share one compile

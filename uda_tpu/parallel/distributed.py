@@ -189,22 +189,34 @@ class DistributedSortResult:
 
     def __init__(self, words: jax.Array, valid_counts: jax.Array,
                  send_overflow: jax.Array, totals=None, splitters=None,
-                 input_rows: int = 0):
+                 input_rows: int = 0, book_fabric=None):
         self.words = words              # [P*cap_total rows, W] sharded
         self.valid_counts = valid_counts  # [P] valid rows per device
         self.send_overflow = send_overflow  # [P] records dropped (0 = ok)
         # the whole keys [P-1, K] the step partitioned by (replicated):
         # shard d holds the keys in [splitters[d-1], splitters[d])
         self.splitters = splitters
-        # replicated int32[2], (records dropped, largest shard's valid
-        # rows): readable on EVERY process of a multi-host mesh (the
-        # per-device vectors are not addressable cross-process), and
-        # ONE readback for both
+        # replicated int32 vector, (records dropped, largest shard's
+        # valid rows) and, from a fused step on a pod mesh, every chip's
+        # recv_counts behind them: readable on EVERY process of a
+        # multi-host mesh (the per-device vectors are not addressable
+        # cross-process), and ONE readback for all of it
         self._totals = totals
         self._input_rows = input_rows   # n, the gauge's denominator
+        # what books the fused step's fabric from those recv_counts
+        # (_book_fused_fabric with the step's statics bound)
+        self._book_fabric = book_fabric
         self._read = False
 
     def overflow(self) -> int:
+        """Records the step dropped (0 = every bucket fit its window).
+        The first call is the readback of the replicated totals, and
+        what hangs on them happens there, once: the
+        ``exchange.shard.max_permille`` gauge and, for a fused step on
+        a pod mesh whose result is KEPT (nothing dropped; a rerun
+        through the rounds books its own windows), the step's fabric —
+        ``_book_fused_fabric``. A result whose totals are never read
+        books nothing."""
         if self._totals is None:
             return int(np.asarray(self.send_overflow).sum())
         if not self._read:
@@ -212,6 +224,8 @@ class DistributedSortResult:
             metrics.gauge("exchange.shard.max_permille",
                           1000.0 * int(self._totals[1])
                           / max(1, self._input_rows))
+            if self._book_fabric is not None and self._totals[0] == 0:
+                self._book_fabric(self._totals[2:])
         return int(self._totals[0])
 
     def check(self) -> None:
@@ -230,6 +244,31 @@ def _merges_runs(payload_path: str) -> bool:
     """Whether the engine combines sorted runs by merging them; the
     others sort them again (same answer: their sort is stable)."""
     return payload_path == "lanes"
+
+
+def _book_fused_fabric(recv_counts, topology, hierarchical: bool,
+                       capacity: int, wcols: int, itemsize: int) -> None:
+    """Book one kept fused step's fabric as ONE planned window, through
+    the rounds' own definitions: ``recv_counts`` — every chip's
+    ``recv_counts`` as the step gathered them, destination-major — is
+    the (source chip, destination chip) count matrix transposed, which
+    ``plan_rounds`` turns into intra-pod rows, staging hops, cross-pod
+    rows and pod-pair messages and ``record_window_metrics`` lands in
+    ``exchange.ici.bytes`` / ``exchange.dcn.bytes`` /
+    ``exchange.dcn.messages``. Beside them ``exchange.wire.bytes``: the
+    dense bytes of the step's collectives (``round_wire_bytes``)."""
+    from uda_tpu.parallel.exchange import round_wire_bytes
+    from uda_tpu.parallel.planner import plan_rounds, record_window_metrics
+
+    nd = topology.num_devices
+    counts = np.asarray(recv_counts).reshape(nd, nd).T      # [src, dst]
+    record_bytes = wcols * itemsize
+    for win in plan_rounds(counts, capacity, topology, record_bytes,
+                           hierarchical).windows:
+        record_window_metrics(win, record_bytes)
+    metrics.add("exchange.wire.bytes",
+                round_wire_bytes(topology, hierarchical, capacity, wcols,
+                                 itemsize))
 
 
 def _carried_passes(payload_path: str, n: int, p: int, capacity: int) -> int:
@@ -353,11 +392,11 @@ def _sort_valid_rows_lanes(flat, valid, num_keys, interpret, keys8=False,
 @partial(jax.jit, static_argnames=("mesh", "axis", "capacity", "num_keys",
                                    "payload_path", "interpret",
                                    "exchange_mode", "dcn_axis",
-                                   "ici_axis", "sample"))
+                                   "ici_axis", "sample", "pod_counts"))
 def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
                payload_path="carry", interpret=False,
                exchange_mode="flat", dcn_axis=None, ici_axis=None,
-               sample=False):
+               sample=False, pod_counts=False):
     """The fused step: sort, partition, exchange, combine. Each chip
     sorts its own rows (stable by input order), so they are in
     destination order with no permutation; the round body sends each
@@ -373,7 +412,11 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
     the unsorted ``words`` (``_sampled_splitters``) before anything else.
     Returns the sorted shards, their valid counts, the per-device
     overflow, the replicated ``(overflow, largest shard)`` pair and the
-    splitters the step partitioned by."""
+    splitters the step partitioned by. ``pod_counts`` (a mesh with a
+    pod structure; static, so a flat mesh's program is the one it
+    always was): every chip's ``recv_counts`` ride behind the pair,
+    ``P x P`` integers destination-major — the count matrix the host
+    books the step's fabric from (``_book_fused_fabric``)."""
     # check_vma is ON everywhere except interpret mode (which only the
     # Pallas engines on a CPU mesh ever set, _lanes_interpret): the
     # Pallas interpreter expands pallas_call into eval_jaxpr whose
@@ -384,7 +427,7 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
     # the varying data and three replicated scalars, and its output
     # carries the data's vma (ops/pallas_sort._uint32_struct).
     @partial(shard_map, mesh=mesh, in_specs=(P(axis), P()),
-             out_specs=(P(axis), P(axis), P(axis), P(axis)),
+             out_specs=(P(axis),) * (5 if pod_counts else 4),
              check_vma=not interpret)
     def _go(w, spl):
         from uda_tpu.parallel.exchange import run_round_body
@@ -418,12 +461,16 @@ def _sort_step(words, splitters, mesh, axis, capacity, num_keys,
         out = _sort_valid_rows(flat, valid, num_keys, payload_path,
                                interpret, run_len=capacity)
         nvalid = jnp.sum(recv_counts)
-        return out, nvalid[None], overflow[None], spl[None]
+        outs = out, nvalid[None], overflow[None], spl[None]
+        return (*outs, recv_counts[None]) if pod_counts else outs
 
-    out, nvalid, overflow, spl = _go(words, splitters[None])
+    out, nvalid, overflow, spl, *recv = _go(words, splitters[None])
     # replicated totals: host-readable on every process of a multi-host
     # mesh, where the per-device vectors are not addressable
     totals = jnp.stack([jnp.sum(overflow), jnp.max(nvalid)])
+    if pod_counts:
+        totals = _replicated(
+            jnp.concatenate([totals, recv[0].reshape(-1)]), mesh)
     return out, nvalid, overflow, totals, _replicated(spl[0], mesh)
 
 
@@ -438,7 +485,13 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     the P sorted runs it receives (``_sort_step``; counter
     ``exchange.merge.runs``: the runs a chip merged, P on the lanes
     engine, 0 on an engine that sorts them again; counter
-    ``sort.passes.carried``: ``_carried_passes``).
+    ``sort.passes.carried``: ``_carried_passes``). On a mesh with a pod
+    structure the step also books its fabric — ``exchange.ici.bytes``,
+    ``exchange.dcn.bytes``, ``exchange.dcn.messages`` by the round
+    planner's definitions, and ``exchange.wire.bytes`` — when its
+    result is kept and its totals are read
+    (``DistributedSortResult.overflow``; under ``multiround="auto"``
+    that read is made here).
 
     ``words``: uint32[N, W] records (rows sharded over ``axis``; the
     first ``num_keys`` columns are the big-endian key words).
@@ -505,7 +558,7 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     out, nvalid, overflow, totals, used = _sort_step(
         words, splitters_dev, mesh, axis, capacity, num_keys, payload_path,
         interpret=_lanes_interpret(payload_path, mesh), sample=sample,
-        **exchange_dispatch(topo, hier))
+        pod_counts=topo.hierarchical, **exchange_dispatch(topo, hier))
     if sample:
         _count_sample(int(words.shape[0]), p)
     # sorted runs a chip's receive side merged: one a source chip; 0,
@@ -516,8 +569,12 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
                 _carried_passes(payload_path, int(words.shape[0]) // p,
                                 p, capacity))
     metrics.add("exchange.fused.overflow_reruns", 0)
-    res = DistributedSortResult(out, nvalid, overflow, totals, used,
-                                int(words.shape[0]))
+    res = DistributedSortResult(
+        out, nvalid, overflow, totals, used, int(words.shape[0]),
+        partial(_book_fused_fabric, topology=topo, hierarchical=hier,
+                capacity=capacity, wcols=int(words.shape[1]),
+                itemsize=words.dtype.itemsize)
+        if topo.hierarchical else None)
     if multiround == "auto" and res.overflow() != 0:
         metrics.add("exchange.fused.overflow_reruns")
         return distributed_sort_multiround(words, used, mesh, axis,

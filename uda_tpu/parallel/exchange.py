@@ -99,7 +99,7 @@ from uda_tpu.utils.metrics import metrics
 
 __all__ = ["ShuffleLayout", "prepare_layout", "window_round_body",
            "hierarchical_round_body", "coded_round_body",
-           "run_round_body", "resolve_exchange_mode",
+           "run_round_body", "round_wire_bytes", "resolve_exchange_mode",
            "exchange_dispatch", "exchange_round",
            "execute_planned_window", "shuffle_exchange",
            "exchange_record_batches"]
@@ -374,38 +374,44 @@ def hierarchical_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
     (parallel/planner.py plan_rounds) rejects loudly.
     """
     # -- stage A (shared with the coded body): pod-local all_to_all
-    # (direct delivery / egress stage)
-    p, c, g, i, m, wcols, wex, intra_rows, cross = _staged_stage_a(
-        w, d, q, lo, dcn_axis, ici_axis, capacity)
+    # (direct delivery / egress stage). The four parts carry a
+    # jax.named_scope each, so a profile attributes their scatters and
+    # collectives by stage.
+    with jax.named_scope("exchange_stage_a"):
+        p, c, g, i, m, wcols, wex, intra_rows, cross = _staged_stage_a(
+            w, d, q, lo, dcn_axis, ici_axis, capacity)
     nd = p * c
 
     # -- stage B: ONE coalesced tile per pod pair over the DCN axis.
     # I am the egress chip of peer pods g' with (g + g') % c == i, i.e.
     # g' = ((i - g) mod c) + k*c for rank k — and by the same formula
     # the INGRESS chip for tiles arriving from those pods.
-    peers = ((i - g) % c) + jnp.arange(m) * c
-    tiles = jnp.swapaxes(cross, 0, 1).reshape(m, c * c * capacity, wex)
-    send_b = jnp.zeros((p + 1, c * c * capacity, wex), w.dtype)
-    send_b = send_b.at[jnp.where(peers < p, peers, p)].set(
-        tiles, mode="drop")
-    recv_b = lax.all_to_all(send_b[:p], dcn_axis, split_axis=0,
-                            concat_axis=0, tiled=False)
+    with jax.named_scope("exchange_stage_b"):
+        peers = ((i - g) % c) + jnp.arange(m) * c
+        tiles = jnp.swapaxes(cross, 0, 1).reshape(m, c * c * capacity, wex)
+        send_b = jnp.zeros((p + 1, c * c * capacity, wex), w.dtype)
+        send_b = send_b.at[jnp.where(peers < p, peers, p)].set(
+            tiles, mode="drop")
+        recv_b = lax.all_to_all(send_b[:p], dcn_axis, split_axis=0,
+                                concat_axis=0, tiled=False)
 
     # -- stage C: pod-local scatter of the arrived tiles (only the
     # blocks whose source pod I ingress for are populated; compact to
     # the m populated ranks before the all_to_all)
-    compact = jnp.take(recv_b, jnp.minimum(peers, p - 1), axis=0)
-    compact = jnp.where((peers < p)[:, None, None], compact, 0)
-    compact = compact.reshape(m, c, c, capacity, wex)
-    send_c = jnp.transpose(compact, (2, 0, 1, 3, 4)).reshape(
-        c, m * c * capacity, wex)
-    recv_c = lax.all_to_all(send_c, ici_axis, split_axis=0,
-                            concat_axis=0, tiled=False)
+    with jax.named_scope("exchange_stage_c"):
+        compact = jnp.take(recv_b, jnp.minimum(peers, p - 1), axis=0)
+        compact = jnp.where((peers < p)[:, None, None], compact, 0)
+        compact = compact.reshape(m, c, c, capacity, wex)
+        send_c = jnp.transpose(compact, (2, 0, 1, 3, 4)).reshape(
+            c, m * c * capacity, wex)
+        recv_c = lax.all_to_all(send_c, ici_axis, split_axis=0,
+                                concat_axis=0, tiled=False)
 
     # -- final assembly: tag - 1 IS the output row (shared)
-    arrived = jnp.concatenate([
-        intra_rows, recv_c.reshape(c * m * c * capacity, wex)])
-    return _tag_assemble(arrived, wcols, nd, capacity)
+    with jax.named_scope("exchange_assemble"):
+        arrived = jnp.concatenate([
+            intra_rows, recv_c.reshape(c * m * c * capacity, wex)])
+        return _tag_assemble(arrived, wcols, nd, capacity)
 
 
 def coded_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
@@ -495,6 +501,27 @@ def coded_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
     arrived = jnp.concatenate([
         intra_rows, mine.reshape(c * m * l_rows, wex)])
     return _tag_assemble(arrived, wcols, nd, capacity)
+
+
+def round_wire_bytes(topology: MeshTopology, hierarchical: bool,
+                     capacity: int, wcols: int, itemsize: int = 4) -> int:
+    """Dense bytes one round's record collectives carry, summed over
+    the chips: the static shapes of the ``all_to_all`` operands as the
+    round bodies build them — ``send`` ``[P, capacity, W]`` a chip on
+    the flat body; ``send_a[:, :rows_a]``, ``send_b[:p]`` and ``send_c``
+    on the staged one, every row with its tag word. Each chip's block
+    to itself is inside (it is in the operand). Over the record bytes
+    of the planner's ledger this is the padding the module's scope note
+    speaks of, as a number (counter ``exchange.wire.bytes``)."""
+    nd = topology.num_devices
+    if not hierarchical:
+        return nd * nd * capacity * wcols * itemsize
+    p, c = topology.num_pods, topology.pod_size
+    m = -(-p // c)                      # peer-pod slots per egress chip
+    rows = (c * (capacity + m * c * capacity)       # send_a[:, :rows_a]
+            + p * c * c * capacity                  # send_b[:p]
+            + c * m * c * capacity)                 # send_c
+    return nd * rows * (wcols + 1) * itemsize
 
 
 def run_round_body(w, d, q, lo, capacity: int, axis,

@@ -313,6 +313,17 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                          "exchange) vs coalesced pod "
                                          "pairs (hierarchical) [labels: "
                                          "pod (source pod)]"),
+    "exchange.wire.bytes": ("counter", "dense bytes of a fused "
+                                       "distributed sort step's record "
+                                       "collectives as compiled, over "
+                                       "all chips (the all_to_all "
+                                       "operands' static shapes, the "
+                                       "staged body's tag word and "
+                                       "unpopulated slots included: "
+                                       "exchange.round_wire_bytes); "
+                                       "booked on a mesh with a pod "
+                                       "structure, beside the step's "
+                                       "ici/dcn record bytes"),
     "exchange.dcn.coded.bytes": ("counter", "multicast-model DCN charge "
                                             "of coded windows: one "
                                             "L-row coded packet per "
