@@ -28,7 +28,8 @@ AXES = ("dcn", "ici")
 W = terasort.RECORD_WORDS
 RECORD_BYTES = 4 * W
 BOOKED = ("exchange.dcn.bytes", "exchange.dcn.messages",
-          "exchange.ici.bytes", "exchange.wire.bytes")
+          "exchange.ici.bytes", "exchange.wire.bytes",
+          "exchange.staged.block_copies")
 
 
 def _pod_mesh():
@@ -95,16 +96,19 @@ def _reference_fabric(words: np.ndarray, hierarchical: bool,
     if hierarchical:
         # send_a: [c, cap + c * cap], send_b: [p, c * c * cap],
         # send_c: [c, c * cap] rows a chip (one peer-pod slot an egress
-        # chip at p = c = 2), every row 26 words and its tag
+        # chip at p = c = 2), every row its 26 words (no tag word since
+        # PR 39: the staged body places whole windows)
         rows = (CHIPS_A_POD * (1 + CHIPS_A_POD) + PODS * CHIPS_A_POD ** 2
                 + CHIPS_A_POD ** 2) * capacity
-        wire = P * rows * (W + 1) * 4
+        wire = P * rows * W * 4
     else:
         wire = P * P * capacity * W * 4
     return {"exchange.dcn.bytes": dcn_rows * RECORD_BYTES,
             "exchange.dcn.messages": len(pairs),
             "exchange.ici.bytes": ici_rows * RECORD_BYTES,
-            "exchange.wire.bytes": wire}
+            "exchange.wire.bytes": wire,
+            # P windows placed in send_a + P blocks delivered, a chip
+            "exchange.staged.block_copies": 2 * P if hierarchical else 0}
 
 
 def _booked() -> dict:
@@ -169,7 +173,8 @@ def test_a_flat_mesh_books_no_dcn():
     _assert_shards(res, words)
     assert metrics.get("exchange.dcn.bytes") == 0
     assert metrics.get("exchange.dcn.messages") == 0
-    assert not [k for k in metrics.snapshot() if "dcn" in k or "wire" in k]
+    assert not [k for k in metrics.snapshot()
+                if "dcn" in k or "wire" in k or "staged" in k]
 
 
 def test_an_overflowed_attempt_rerun_through_the_rounds_is_booked_once():
@@ -187,6 +192,7 @@ def test_an_overflowed_attempt_rerun_through_the_rounds_is_booked_once():
     assert metrics.get("exchange.dcn.bytes") == want["exchange.dcn.bytes"]
     assert metrics.get("exchange.ici.bytes") == want["exchange.ici.bytes"]
     assert metrics.get("exchange.wire.bytes") == 0
+    assert metrics.get("exchange.staged.block_copies") == 0
     rounds = metrics.get("exchange.rounds")
     assert rounds >= 3
     # a pod pair's transfer a window it has rows in: the tail windows
@@ -216,6 +222,30 @@ def test_booking_waits_for_the_totals_and_for_a_result_that_is_kept(
         res.check()
         res.check()                         # the second read books nothing
         assert _booked() == _reference_fabric(words, True, capacity)
+
+
+@pytest.mark.parametrize("case", ("hierarchical", "flat_on_the_pod_mesh",
+                                  "flat_mesh", "dropped_rows"))
+def test_block_copies_are_booked_where_the_staged_body_delivered(case):
+    # 2 * P a chip a step on the hierarchical body, 0 (the key is
+    # there) when the flat body ran on the pod mesh, nothing at all on
+    # a flat mesh, nothing for a step whose result is not kept
+    key = "exchange.staged.block_copies"
+    n, steps = P * 256, 2
+    words = _records("uniform", n, seed=43)
+    capacity = 24 if case == "dropped_rows" else 2 * n // (P * P)
+    mesh, axis = ((make_mesh(P, "ici"), "ici") if case == "flat_mesh"
+                  else (_pod_mesh(), AXES))
+    mode = "flat" if case == "flat_on_the_pod_mesh" else "auto"
+    metrics.reset()
+    for _ in range(steps):
+        res = distributed_sort_step(words, uniform_splitters(P), mesh, axis,
+                                    capacity=capacity, num_keys=3,
+                                    exchange_mode=mode, multiround="never")
+        assert (res.overflow() > 0) == (case == "dropped_rows")
+    want = {"hierarchical": steps * 2 * P, "flat_on_the_pod_mesh": 0}
+    assert metrics.get(key) == want.get(case, 0)
+    assert (key in metrics.snapshot()) == (case in want)
 
 
 # -- what the program says of itself, against the traced program -------------

@@ -256,8 +256,11 @@ def _book_fused_fabric(recv_counts, topology, hierarchical: bool,
     rows and pod-pair messages and ``record_window_metrics`` lands in
     ``exchange.ici.bytes`` / ``exchange.dcn.bytes`` /
     ``exchange.dcn.messages``. Beside them ``exchange.wire.bytes``: the
-    dense bytes of the step's collectives (``round_wire_bytes``)."""
-    from uda_tpu.parallel.exchange import round_wire_bytes
+    dense bytes of the step's collectives (``round_wire_bytes``), and
+    ``exchange.staged.block_copies``: the hierarchical body's block
+    copies a chip (``staged_block_copies``; 0 on the flat body)."""
+    from uda_tpu.parallel.exchange import (round_wire_bytes,
+                                           staged_block_copies)
     from uda_tpu.parallel.planner import plan_rounds, record_window_metrics
 
     nd = topology.num_devices
@@ -269,6 +272,8 @@ def _book_fused_fabric(recv_counts, topology, hierarchical: bool,
     metrics.add("exchange.wire.bytes",
                 round_wire_bytes(topology, hierarchical, capacity, wcols,
                                  itemsize))
+    metrics.add("exchange.staged.block_copies",
+                staged_block_copies(topology, hierarchical))
 
 
 def _carried_passes(payload_path: str, n: int, p: int, capacity: int) -> int:
@@ -513,7 +518,7 @@ def distributed_sort_step(words, splitters, mesh: Mesh, axis: str,
     ``exchange_mode``: fabric dispatch for multi-pod meshes —
     ``"auto"`` (default) runs the two-stage hierarchical round body
     (pod-local all_to_all, ONE coalesced DCN tile per pod pair, pod-
-    local delivery scatter — parallel/exchange.py) whenever the mesh
+    local delivery — parallel/exchange.py) whenever the mesh
     has a DCN-tagged outer axis with >1 pod of >1 chip; ``"flat"``
     forces the single-stage body (the A/B baseline, where XLA routes
     one global all_to_all per axis); ``"hierarchical"`` demands a pod

@@ -28,11 +28,17 @@ over the ICI axis, staging every record's cross-pod hop onto the ONE
 designated egress chip of its (pod, peer-pod) pair, moves one
 coalesced tile per pod pair over the DCN axis — O(p^2) messages, the
 reference's per-QP aggregation win (RDMAServer.cc chunked server
-pool) — and delivers with a second pod-local scatter. Same window
+pool) — and delivers with a second pod-local all_to_all. Same window
 semantics, same delivery contract, byte-identical output; the host
 planner (parallel/planner.py) proves the per-round message reduction
 and accounts the RECORD bytes each tier carries (identical to flat on
 the DCN by construction — the same rows cross pods either way).
+The staged body moves rows in BLOCKS, never by row address (PR 39):
+every caller hands the round bodies rows in destination order, so a
+(destination, window) is one contiguous slice, placed whole in each
+staging buffer and delivered whole — 2*P block copies a chip where two
+row scatters stood (counter ``exchange.staged.block_copies``) — and
+rows travel as their W words, the senders' counts beside them.
 
 Coded multicast stage B (``mode="coded"``, Coded TeraSort
 arXiv:1702.04850): when the host plan says a window's pod pairs are
@@ -46,9 +52,12 @@ of disjoint per-destination blocks; stage C broadcasts the arrived
 chunks pod-locally (``lax.all_gather`` over ICI — the cheap fabric
 pays for the expensive one, the Coded TeraSort trade) and every
 member decodes its OWN block locally with the inverse row of its chip
-index. Delivery tags ride through encode/decode untouched, so the
-post-decode scatter reproduces the exact flat (peer row-block, slot)
-layout — byte-identity vs the flat oracle stays gated by
+index. The coded body ALONE tags its rows (``src_device * capacity +
+slot + 1`` in a W+1-th word, handed to the shared block-placed stage
+A): its compaction moves a block's rows off their slots, so only a tag
+can place them again. The tags ride through encode/decode untouched,
+and the post-decode scatter reproduces the exact flat (peer row-block,
+slot) layout — byte-identity vs the flat oracle stays gated by
 construction. Windows the plan declines (skew, single-destination
 pairs, 1-pod meshes) ride the plain coalesced tile with zero coded
 overhead, and a decode failure (failpoint site ``exchange.decode``)
@@ -99,7 +108,8 @@ from uda_tpu.utils.metrics import metrics
 
 __all__ = ["ShuffleLayout", "prepare_layout", "window_round_body",
            "hierarchical_round_body", "coded_round_body",
-           "run_round_body", "round_wire_bytes", "resolve_exchange_mode",
+           "run_round_body", "round_wire_bytes", "staged_block_copies",
+           "resolve_exchange_mode",
            "exchange_dispatch", "exchange_round",
            "execute_planned_window", "shuffle_exchange",
            "exchange_record_batches"]
@@ -237,6 +247,31 @@ def prepare_layout(words: jax.Array, dest: jax.Array, mesh: Mesh,
                          topo, hier, coded)
 
 
+def _window_reader(w, d, lo, p: int, capacity: int):
+    """What both round bodies read their send blocks through. On rows
+    in destination order (``window_round_body``'s precondition),
+    destination k's window ``[lo, lo + capacity)`` is one
+    ``dynamic_slice`` of the rows, the rows past the bucket's end zeroed
+    in the same pass. Returns ``(send_counts, window)``: the valid rows
+    of each of the ``p`` windows and ``window(k)``, destination k's
+    ``[capacity, W]`` block."""
+    counts = jnp.bincount(d, length=p).astype(jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    send_counts = jnp.clip(counts - lo, 0, capacity)
+    # a window may run past the last row (the last bucket's always can,
+    # and capacity >= n whenever P <= 2), where dynamic_slice would
+    # clamp its start and shift the rows: the slices read w padded with
+    # zeros (fused into each slice by XLA, no padded copy exists)
+    wpad = jnp.pad(w, ((0, capacity), (0, 0)))
+    slot = jnp.arange(capacity, dtype=jnp.int32)[:, None]
+
+    def window(k: int):
+        rows = lax.dynamic_slice_in_dim(wpad, starts[k] + lo, capacity)
+        return jnp.where(slot < send_counts[k], rows, 0)
+
+    return send_counts, window
+
+
 def window_round_body(w, d, q, lo, axis: str, capacity: int):
     """One windowed exchange round, for use INSIDE a shard_map body (the
     single definition of the round wire protocol — exchange_round and
@@ -249,12 +284,12 @@ def window_round_body(w, d, q, lo, axis: str, capacity: int):
     destination k's window ``[lo, lo + capacity)`` IS the contiguous
     rows ``starts[k] + lo ...`` of ``w``, so the send buffer is P
     window copies — one ``dynamic_slice`` a destination, the rows past
-    the bucket's end zeroed in the same pass — and ``q``, implied by
-    the order, is not read. Why slices: a ``[n, W]`` row matrix is
-    stored long-dimension-minor on the chip, so scattering rows to
-    ``(d, q - lo)`` is W lane scatters n long — a quarter of the fused
-    step at 2^24 rows a chip (PERF.md §6, PR 33). That scatter is the
-    reference tests/test_exchange.py holds this body to.
+    the bucket's end zeroed in the same pass (``_window_reader``) — and
+    ``q``, implied by the order, is not read. Why slices: a ``[n, W]``
+    row matrix is stored long-dimension-minor on the chip, so scattering
+    rows to ``(d, q - lo)`` is W lane scatters n long — a quarter of the
+    fused step at 2^24 rows a chip (PERF.md §6, PR 33). That scatter is
+    the reference tests/test_exchange.py holds this body to.
 
     Returns ``(flat, recv_counts)``: the local [P*capacity, W] delivery
     (row block i = peer i's contribution, zeros past its count) and
@@ -262,23 +297,12 @@ def window_round_body(w, d, q, lo, axis: str, capacity: int):
     """
     p = lax.psum(1, axis)
     wcols = w.shape[1]
-    counts = jnp.bincount(d, length=p).astype(jnp.int32)
-    starts = jnp.cumsum(counts) - counts
-    send_counts = jnp.clip(counts - lo, 0, capacity)
-    # a window may run past the last row (the last bucket's always can,
-    # and capacity >= n whenever P <= 2), where dynamic_slice would
-    # clamp its start and shift the rows: the slices read w padded with
-    # zeros (fused into each slice by XLA, no padded copy exists)
-    wpad = jnp.pad(w, ((0, capacity), (0, 0)))
-    slot = jnp.arange(capacity, dtype=jnp.int32)[:, None]
+    send_counts, window = _window_reader(w, d, lo, p, capacity)
     # updates in place, not jnp.stack: the compiler's memory_analysis()
     # of the fused step is 134 MB a chip lower this way (v5e:2x2)
     send = jnp.zeros((p, capacity, wcols), w.dtype)
     for k in range(p):
-        window = lax.dynamic_slice_in_dim(wpad, starts[k] + lo, capacity)
-        send = lax.dynamic_update_slice(
-            send, jnp.where(slot < send_counts[k], window, 0)[None],
-            (k, 0, 0))
+        send = lax.dynamic_update_slice(send, window(k)[None], (k, 0, 0))
     recv = lax.all_to_all(send, axis, split_axis=0,
                           concat_axis=0, tiled=False)
     recv_counts = lax.all_to_all(send_counts[:, None], axis,
@@ -287,51 +311,123 @@ def window_round_body(w, d, q, lo, axis: str, capacity: int):
     return recv.reshape(p * capacity, wcols), recv_counts
 
 
-def _staged_stage_a(w, d, q, lo, dcn_axis: str, ici_axis: str,
-                    capacity: int):
-    """The staged bodies' shared prologue + stage A (pod-local
-    all_to_all: intra-pod records straight to their final chip,
-    cross-pod records onto the pair's egress chip, every row tagged
-    ``src_device * capacity + slot + 1``). ONE definition for the
-    hierarchical and coded bodies — the staging row formula, the
-    trash-row trick and the tag discipline can never diverge between
-    them. Returns ``(p, c, g, i, m, wcols, wex, intra_rows, cross)``
-    with ``cross`` shaped [src chip, peer-pod rank, dst chip, slot,
-    word]."""
-    p = lax.psum(1, dcn_axis)           # pods
-    c = lax.psum(1, ici_axis)           # chips per pod
-    g = lax.axis_index(dcn_axis)        # my pod
-    i = lax.axis_index(ici_axis)        # my chip
-    m = -(-p // c)                      # peer-pod slots per egress chip
-    wcols = w.shape[1]
-    in_round = (q >= lo) & (q < lo + capacity)
-    slot = q - lo
-    tag = ((g * c + i) * capacity + slot + 1).astype(w.dtype)
-    ext = jnp.concatenate([w, tag[:, None]], axis=1)
-    wex = wcols + 1
-    dpod = d // c
-    dchip = d % c
-    intra = dpod == g
-    rows_a = capacity + m * c * capacity
-    blk = jnp.where(intra, dchip, (g + dpod) % c)
-    row = jnp.where(intra, slot,
-                    capacity + (dpod // c) * (c * capacity)
-                    + dchip * capacity + slot)
-    row = jnp.where(in_round, row, rows_a)      # trash row, sliced off
-    send_a = jnp.zeros((c, rows_a + 1, wex), w.dtype)
-    send_a = send_a.at[blk, row].set(ext, mode="drop")
-    recv_a = lax.all_to_all(send_a[:, :rows_a], ici_axis, split_axis=0,
+def _pod_coords(dcn_axis: str, ici_axis: str):
+    """``(p, c, g, i, m)`` of the staged bodies: pods, chips a pod (both
+    static), this chip's pod and chip index (traced) and ``m``, the
+    peer-pod slots an egress chip holds (``ceil(p / c)``: the pairs
+    ``(g, g')`` rotate over the chips by ``(g + g') % c``)."""
+    p = lax.psum(1, dcn_axis)
+    c = lax.psum(1, ici_axis)
+    return (p, c, lax.axis_index(dcn_axis), lax.axis_index(ici_axis),
+            -(-p // c))
+
+
+def _stage_a(block, rows: int, wcols: int, dtype, coords, ici_axis: str):
+    """Stage A of the staged bodies (pod-local all_to_all: an intra-pod
+    block straight to its final chip, a cross-pod block onto its pod
+    pair's egress chip), generic in what a block is: ``block(k)`` is the
+    ``[rows, wcols]`` block this chip holds for destination device k —
+    a window of record rows, of tagged rows (the coded body), or the
+    window's one count. Every block is placed WHOLE in ``send_a[c, 1 +
+    m*c, rows, wcols]``: destination ``(dpod, dchip)`` at ``[dchip, 0]``
+    when ``dpod`` is this chip's pod, else at ``[(g + dpod) % c, 1 +
+    (dpod // c)*c + dchip]`` — static but for the chip's own pod ``g``,
+    so one ``dynamic_update_slice`` a destination. Peer-pod slots with
+    ``dpod >= p`` stay zero. Returns ``(intra, cross)``: ``intra[s]``
+    the block pod mate ``s`` sent this chip, ``cross`` shaped [src chip,
+    peer-pod rank, dst chip, row, word].
+
+    Here and down to the delivery a block keeps its two dimensions: the
+    stages permute, select and slice whole blocks by their leading
+    indices and never fold ``rows`` into another dimension (on the chip
+    that is a re-tiling of the whole buffer, PERF.md §6, PR 39)."""
+    p, c, g, _, m = coords
+    send_a = jnp.zeros((c, 1 + m * c, rows, wcols), dtype)
+    for k in range(p * c):
+        dpod, dchip = divmod(k, c)
+        intra = dpod == g
+        at = (jnp.where(intra, dchip, (g + dpod) % c),
+              jnp.where(intra, 0, 1 + (dpod // c) * c + dchip), 0, 0)
+        send_a = lax.dynamic_update_slice(send_a, block(k)[None, None], at)
+    recv_a = lax.all_to_all(send_a, ici_axis, split_axis=0,
                             concat_axis=0, tiled=False)
-    intra_rows = recv_a[:, :capacity].reshape(c * capacity, wex)
-    cross = recv_a[:, capacity:].reshape(c, m, c, capacity, wex)
-    return p, c, g, i, m, wcols, wex, intra_rows, cross
+    return recv_a[:, 0], recv_a[:, 1:].reshape(c, m, c, rows, wcols)
+
+
+def _stage_b(tiles, coords, dcn_axis: str):
+    """Stage B: ONE coalesced tile a pod pair over the DCN axis.
+    ``tiles[k]`` is what this chip's pod staged on it for the peer pod
+    of rank k (stage A's ``cross`` with the rank in front, or the coded
+    body's coded chunks). The chip is the egress chip of the peer pods
+    g' with ``(g + g') % c == i``, rank ``g' // c``: its tile for every
+    other pod is zeros. Returns ``recv_b[g']``, the tile pod g' sent
+    here."""
+    p, c, g, i, m = coords
+    mine = (g + jnp.arange(m * c)) % c == i
+    send_b = jnp.where(mine.reshape(-1, *[1] * (tiles.ndim - 1)),
+                       jnp.repeat(tiles, c, axis=0), 0)[:p]
+    return lax.all_to_all(send_b, dcn_axis, split_axis=0,
+                          concat_axis=0, tiled=False)
+
+
+def _ingress_tiles(recv_b, coords):
+    """The arrived tiles this chip is the INGRESS chip of: by stage B's
+    formula those of the pods g' = ``((i - g) mod c) + k*c`` (rank k),
+    the only populated blocks of ``recv_b`` — compacted to the m ranks,
+    a rank past the last pod zeros. One ``dynamic_slice`` a rank, not a
+    ``take``: the chip's compiler unrolls a gather of slices this size
+    into hundreds of pieces (PERF.md §6, PR 39)."""
+    p, c, g, i, m = coords
+    first = (i - g) % c
+    return jnp.stack([
+        jnp.where(first + k * c < p,
+                  lax.dynamic_index_in_dim(
+                      recv_b, jnp.minimum(first + k * c, p - 1), 0, False), 0)
+        for k in range(m)])
+
+
+def _route_blocks(block, rows: int, wcols: int, dtype, coords,
+                  dcn_axis: str, ici_axis: str):
+    """One kind of block — a window of rows, or its count — through the
+    hierarchical body's three stages to its delivery: ``[P, rows,
+    wcols]``, block k what source device k's ``block(me)`` held. The
+    four parts carry a jax.named_scope each, so a profile attributes
+    their copies and collectives by stage."""
+    p, c, g, _, _ = coords
+    with jax.named_scope("exchange_stage_a"):
+        intra, cross = _stage_a(block, rows, wcols, dtype, coords, ici_axis)
+    with jax.named_scope("exchange_stage_b"):
+        recv_b = _stage_b(jnp.swapaxes(cross, 0, 1), coords, dcn_axis)
+    with jax.named_scope("exchange_stage_c"):
+        # the arrived tiles are [rank, src chip, dst chip, row, word]:
+        # destination chip in front, and recv_c is [ingress chip, rank,
+        # src chip, row, word]
+        send_c = jnp.transpose(_ingress_tiles(recv_b, coords),
+                               (2, 0, 1, 3, 4))
+        recv_c = lax.all_to_all(send_c, ici_axis, split_axis=0,
+                                concat_axis=0, tiled=False)
+    with jax.named_scope("exchange_assemble"):
+        # source device (g', s)'s block is intra[s] when g' is this
+        # chip's pod, else recv_c[(g + g') % c, g' // c, s]: P block
+        # copies, one traced block index each
+        out = jnp.zeros((p * c, rows, wcols), dtype)
+        for k in range(p * c):
+            g2, s = divmod(k, c)
+            far = lax.dynamic_slice(recv_c, ((g + g2) % c, g2 // c, s, 0, 0),
+                                    (1, 1, 1, rows, wcols))
+            out = lax.dynamic_update_slice(
+                out, jnp.where(g2 == g, intra[s][None],
+                               far.reshape(1, rows, wcols)), (k, 0, 0))
+        return out
 
 
 def _tag_assemble(arrived, wcols, nd, capacity: int):
-    """The staged bodies' shared delivery: tag - 1 IS the output row
-    of the flat ``[P*capacity, W]`` layout (0 marks an empty slot),
-    recv_counts from the tags' source devices. Shared so the
-    byte-identity contract has exactly one assembly definition."""
+    """The coded body's delivery: tag - 1 IS the output row of the flat
+    ``[P*capacity, W]`` layout (0 marks an empty slot), recv_counts from
+    the tags' source devices. The coded body alone needs it: after its
+    compaction a block's rows are no longer at their slots, so only a
+    tag can place them (the plain staged rows never leave theirs:
+    ``_route_blocks``)."""
     atag = arrived[:, wcols].astype(jnp.int32)
     valid = atag > 0
     idx = jnp.where(valid, atag - 1, nd * capacity)
@@ -347,71 +443,44 @@ def _tag_assemble(arrived, wcols, nd, capacity: int):
 def hierarchical_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
                             capacity: int):
     """The two-stage (pod-local + coalesced DCN) round body, for use
-    INSIDE a shard_map over BOTH mesh axes. Same window semantics and
-    same delivery contract as :func:`window_round_body` — callers
-    cannot tell which body ran except through the fabric accounting:
+    INSIDE a shard_map over BOTH mesh axes. Same window semantics, same
+    precondition (rows in destination order) and same delivery contract
+    as :func:`window_round_body` — callers cannot tell which body ran
+    except through the fabric accounting:
 
-    - **stage A (ICI all_to_all):** records are re-bucketed by
-      destination POD; an intra-pod record goes straight to its final
-      chip, a cross-pod record to the ONE designated egress chip of its
-      (pod, peer-pod) pair (``MeshTopology.egress_chip`` =
-      ``(g + g') % c``, rotating pairs across chips);
+    - **stage A (ICI all_to_all):** the P destination windows are read
+      as ``window_round_body`` reads them and re-bucketed by destination
+      POD; an intra-pod window goes straight to its final chip, a
+      cross-pod window to the ONE designated egress chip of its (pod,
+      peer-pod) pair (``MeshTopology.egress_chip`` = ``(g + g') % c``,
+      rotating pairs across chips);
     - **stage B (DCN all_to_all):** each populated egress chip moves
       ONE coalesced tile per peer pod — O(p^2) DCN messages per round
       instead of the flat body's O((p*c)^2) device pairs;
-    - **stage C (ICI all_to_all):** the ingress chip scatters arrived
-      rows to their final chips.
+    - **stage C (ICI all_to_all):** the ingress chip hands the arrived
+      windows to their final chips.
 
-    Delivery slots are carried, not recomputed: every staged row rides
-    with a ``tag`` column (``src_device * capacity + in_window_slot +
-    1``; 0 marks an empty staging slot), and the final scatter places
-    row ``tag - 1`` of the ``[P*capacity, W]`` output — exactly the
-    (peer row-block, slot) layout of the flat body, so the output is
-    byte-identical by construction, not by sort order luck. The tag is
-    computed and decoded in int32, capping ``P * capacity`` at
-    2^31 - 1 — a bound the [P*capacity, W] delivery buffer hits in HBM
-    long before the tag does, and which the host planner
-    (parallel/planner.py plan_rounds) rejects loudly.
+    The body moves rows in BLOCKS, never by row address: a window is
+    placed whole in each staging buffer, so it arrives whole, its rows
+    at their slots and zeros past its count, at a place the sender's and
+    the receiver's mesh coordinates alone decide — the ``[P*capacity,
+    W]`` delivery is P block copies and byte for byte the flat body's.
+    Rows travel as their W words; ``recv_counts`` is the senders'
+    ``send_counts`` carried as int32 along the rows' own route (the same
+    placement at one integer a block, through the same three axes), as
+    the flat body sends its counts beside its rows. Why blocks: tagging
+    every row and scattering it twice by address was 86 % of the step on
+    the chip (PERF.md §6, PR 39). That body is the reference
+    tests/test_exchange_staged.py holds this one to.
     """
-    # -- stage A (shared with the coded body): pod-local all_to_all
-    # (direct delivery / egress stage). The four parts carry a
-    # jax.named_scope each, so a profile attributes their scatters and
-    # collectives by stage.
-    with jax.named_scope("exchange_stage_a"):
-        p, c, g, i, m, wcols, wex, intra_rows, cross = _staged_stage_a(
-            w, d, q, lo, dcn_axis, ici_axis, capacity)
-    nd = p * c
-
-    # -- stage B: ONE coalesced tile per pod pair over the DCN axis.
-    # I am the egress chip of peer pods g' with (g + g') % c == i, i.e.
-    # g' = ((i - g) mod c) + k*c for rank k — and by the same formula
-    # the INGRESS chip for tiles arriving from those pods.
-    with jax.named_scope("exchange_stage_b"):
-        peers = ((i - g) % c) + jnp.arange(m) * c
-        tiles = jnp.swapaxes(cross, 0, 1).reshape(m, c * c * capacity, wex)
-        send_b = jnp.zeros((p + 1, c * c * capacity, wex), w.dtype)
-        send_b = send_b.at[jnp.where(peers < p, peers, p)].set(
-            tiles, mode="drop")
-        recv_b = lax.all_to_all(send_b[:p], dcn_axis, split_axis=0,
-                                concat_axis=0, tiled=False)
-
-    # -- stage C: pod-local scatter of the arrived tiles (only the
-    # blocks whose source pod I ingress for are populated; compact to
-    # the m populated ranks before the all_to_all)
-    with jax.named_scope("exchange_stage_c"):
-        compact = jnp.take(recv_b, jnp.minimum(peers, p - 1), axis=0)
-        compact = jnp.where((peers < p)[:, None, None], compact, 0)
-        compact = compact.reshape(m, c, c, capacity, wex)
-        send_c = jnp.transpose(compact, (2, 0, 1, 3, 4)).reshape(
-            c, m * c * capacity, wex)
-        recv_c = lax.all_to_all(send_c, ici_axis, split_axis=0,
-                                concat_axis=0, tiled=False)
-
-    # -- final assembly: tag - 1 IS the output row (shared)
-    with jax.named_scope("exchange_assemble"):
-        arrived = jnp.concatenate([
-            intra_rows, recv_c.reshape(c * m * c * capacity, wex)])
-        return _tag_assemble(arrived, wcols, nd, capacity)
+    coords = _pod_coords(dcn_axis, ici_axis)
+    nd, wcols = coords[0] * coords[1], w.shape[1]
+    send_counts, window = _window_reader(w, d, lo, nd, capacity)
+    flat = _route_blocks(window, capacity, wcols, w.dtype, coords,
+                         dcn_axis, ici_axis)
+    recv_counts = _route_blocks(lambda k: send_counts[k].reshape(1, 1),
+                                1, 1, jnp.int32, coords, dcn_axis, ici_axis)
+    return flat.reshape(nd * capacity, wcols), recv_counts.reshape(nd)
 
 
 def coded_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
@@ -421,8 +490,9 @@ def coded_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
     GF(2^8)-coded chunks instead of disjoint per-destination blocks
     (the Coded TeraSort multicast phase, arXiv:1702.04850):
 
-    - **stage A** is byte-for-byte the hierarchical staging (cross-pod
-      rows onto the pair's egress chip, tags riding along);
+    - **stage A** is the hierarchical staging (``_stage_a``: whole
+      windows, cross-pod ones onto the pair's egress chip) on rows that
+      carry a tag word — this body alone tags, because of the next step;
     - **encode:** the egress chip COMPACTS each destination chip's
       rows to the front of an ``l_rows``-row block (``l_rows`` is the
       host plan's padded chunk length — the plan guarantees every
@@ -440,19 +510,30 @@ def coded_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
       row of its chip index (``gfjax.gf_decode_row``, traced row).
 
     Tags ride INSIDE the coded words (the GF action is exact), so the
-    final tag-indexed scatter reproduces the flat layout precisely —
-    byte-identity by construction, the same contract as the plain
-    staged body. ``l_rows`` must be positive and cover the biggest
-    per-(pair, destination-chip) in-window block; the host plan
-    (parallel/planner.py) guarantees both before dispatching here.
+    final tag-indexed scatter (``_tag_assemble``) reproduces the flat
+    layout precisely — byte-identity by construction, the same contract
+    as the plain staged body, whose rows never leave their slots and
+    need no tag. The tag is computed and decoded in int32, capping
+    ``P * capacity`` at 2^31 - 1, which the host planner
+    (parallel/planner.py plan_rounds) rejects loudly. ``l_rows`` must
+    be positive and cover the biggest per-(pair, destination-chip)
+    in-window block; the host plan guarantees both before dispatching
+    here.
     """
     from uda_tpu.coding.gfjax import (coded_matrices, gf_decode_row,
                                       gf_matmul_words)
 
-    # -- stage A: the SHARED hierarchical staging (_staged_stage_a)
-    p, c, g, i, m, wcols, wex, intra_rows, cross = _staged_stage_a(
-        w, d, q, lo, dcn_axis, ici_axis, capacity)
-    nd = p * c
+    # -- stage A: the SHARED staging (_stage_a), on rows that carry
+    # their tag: src_device * capacity + in-window slot + 1 (a window's
+    # rows past its count are zeroed whole, tag and all: 0 = empty)
+    coords = p, c, g, i, m = _pod_coords(dcn_axis, ici_axis)
+    nd, wcols = p * c, w.shape[1]
+    wex = wcols + 1
+    tag = ((g * c + i) * capacity + (q - lo) + 1).astype(w.dtype)
+    _, window = _window_reader(jnp.concatenate([w, tag[:, None]], axis=1),
+                               d, lo, nd, capacity)
+    intra, cross = _stage_a(window, capacity, wex, w.dtype, coords,
+                            ici_axis)
     # [src chip, peer-pod rank, dst chip, slot, word] -> destination-
     # block view [peer slot, dst chip, (src chip, slot), word]
     blocks_full = jnp.transpose(cross, (1, 2, 0, 3, 4)).reshape(
@@ -476,15 +557,9 @@ def coded_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
     coded = gf_matmul_words(enc, jnp.swapaxes(blocks, 0, 1))
     tiles = jnp.swapaxes(coded, 0, 1).reshape(m, c * l_rows, wex)
 
-    # -- stage B: one coded tile per pod pair over the DCN axis
-    peers = ((i - g) % c) + jnp.arange(m) * c
-    send_b = jnp.zeros((p + 1, c * l_rows, wex), w.dtype)
-    send_b = send_b.at[jnp.where(peers < p, peers, p)].set(
-        tiles, mode="drop")
-    recv_b = lax.all_to_all(send_b[:p], dcn_axis, split_axis=0,
-                            concat_axis=0, tiled=False)
-    compact = jnp.take(recv_b, jnp.minimum(peers, p - 1), axis=0)
-    compact = jnp.where((peers < p)[:, None, None], compact, 0)
+    # -- stage B (shared): one coded tile per pod pair over the DCN
+    # axis, the arrived ones compacted to the ranks I ingress for
+    compact = _ingress_tiles(_stage_b(tiles, coords, dcn_axis), coords)
 
     # -- stage C: pod-local broadcast of the arrived coded tiles —
     # every member needs the full chunk set to decode its block
@@ -499,7 +574,8 @@ def coded_round_body(w, d, q, lo, dcn_axis: str, ici_axis: str,
 
     # -- final assembly: tag - 1 IS the output row (shared)
     arrived = jnp.concatenate([
-        intra_rows, mine.reshape(c * m * l_rows, wex)])
+        intra.reshape(c * capacity, wex),
+        mine.reshape(c * m * l_rows, wex)])
     return _tag_assemble(arrived, wcols, nd, capacity)
 
 
@@ -508,8 +584,9 @@ def round_wire_bytes(topology: MeshTopology, hierarchical: bool,
     """Dense bytes one round's record collectives carry, summed over
     the chips: the static shapes of the ``all_to_all`` operands as the
     round bodies build them — ``send`` ``[P, capacity, W]`` a chip on
-    the flat body; ``send_a[:, :rows_a]``, ``send_b[:p]`` and ``send_c``
-    on the staged one, every row with its tag word. Each chip's block
+    the flat body; ``send_a``, ``send_b`` and ``send_c`` on the
+    hierarchical one, W words a row (the coded body's tagged rows are
+    not counted here: the fused step never runs it). Each chip's block
     to itself is inside (it is in the operand). Over the record bytes
     of the planner's ledger this is the padding the module's scope note
     speaks of, as a number (counter ``exchange.wire.bytes``)."""
@@ -518,10 +595,18 @@ def round_wire_bytes(topology: MeshTopology, hierarchical: bool,
         return nd * nd * capacity * wcols * itemsize
     p, c = topology.num_pods, topology.pod_size
     m = -(-p // c)                      # peer-pod slots per egress chip
-    rows = (c * (capacity + m * c * capacity)       # send_a[:, :rows_a]
-            + p * c * c * capacity                  # send_b[:p]
+    rows = (c * (capacity + m * c * capacity)       # send_a
+            + p * c * c * capacity                  # send_b
             + c * m * c * capacity)                 # send_c
-    return nd * rows * (wcols + 1) * itemsize
+    return nd * rows * wcols * itemsize
+
+
+def staged_block_copies(topology: MeshTopology, hierarchical: bool) -> int:
+    """Block copies a chip a round standing where the hierarchical
+    body's two row scatters stood: P windows placed in ``send_a``, P
+    blocks delivered (counter ``exchange.staged.block_copies``); 0 on
+    the flat body."""
+    return 2 * topology.num_devices if hierarchical else 0
 
 
 def run_round_body(w, d, q, lo, capacity: int, axis,

@@ -234,8 +234,9 @@ def plan_rounds(counts, capacity: int,
     n = counts.shape[0] if counts.ndim == 2 else 0
     coded = bool(coded) and bool(hierarchical) and topology is not None
     if hierarchical and n * capacity >= 1 << 31:
-        # the staged body's delivery tag (src_device*capacity + slot)
-        # is computed in int32 on device — past this it wraps and rows
+        # the coded staged body's delivery tag (src_device*capacity +
+        # slot; the plain staged body carries none since PR 39) is
+        # computed in int32 on device — past this it wraps and rows
         # silently misdeliver (the buffer is unbuildable long before,
         # but fail loudly, not by physics)
         raise ValueError(f"hierarchical exchange tag overflow: "

@@ -318,12 +318,23 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                        "collectives as compiled, over "
                                        "all chips (the all_to_all "
                                        "operands' static shapes, the "
-                                       "staged body's tag word and "
-                                       "unpopulated slots included: "
+                                       "staged body's unpopulated "
+                                       "slots included: "
                                        "exchange.round_wire_bytes); "
                                        "booked on a mesh with a pod "
                                        "structure, beside the step's "
                                        "ici/dcn record bytes"),
+    "exchange.staged.block_copies": ("counter", "block copies a chip a "
+                                                "kept fused step made "
+                                                "where the hierarchical "
+                                                "body's two row scatters "
+                                                "stood: P windows into "
+                                                "the stage-A buffer + P "
+                                                "delivered blocks; 0 "
+                                                "when the flat body ran "
+                                                "on the pod mesh; "
+                                                "booked beside "
+                                                "exchange.wire.bytes"),
     "exchange.dcn.coded.bytes": ("counter", "multicast-model DCN charge "
                                             "of coded windows: one "
                                             "L-row coded packet per "
