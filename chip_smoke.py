@@ -61,15 +61,18 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 DEADLINE_S = 1150       # the contract allows 1200 s, compilation included
 NO_ACCELERATOR = 3      # child exit code: JAX did not find the platform
 JOB = "smoke"
+JOB_TEXT = "smoketext"
 
 # what each phase runs at: the real sizes, and the CPU rehearsal's. The
 # two fixed configurations — a cut some limit forces is made HERE and
 # reported as one
 SIZES = {
     "chip": {"records": 10_500_000, "maps": 64, "sort_log2": 23,
-             "engine_log2": 20, "per_chip_log2": 22},
+             "engine_log2": 20, "per_chip_log2": 22,
+             "text_records": 4_000, "text_maps": 8},
     "rehearsal": {"records": 2_000, "maps": 4, "sort_log2": 12,
-                  "engine_log2": 11, "per_chip_log2": 10},
+                  "engine_log2": 11, "per_chip_log2": 10,
+                  "text_records": 4_000, "text_maps": 8},
 }
 MESHES = ("ici:4", "dcn:2,ici:2")
 
@@ -274,79 +277,125 @@ def phase_a(args, sizes: dict) -> dict:
     supplier.do_command(form_cmd(Cmd.INIT, []))
     if supplier.failed or supplier.net_server() is None:
         raise SmokeFailure(f"supplier did not start: {sup_cb.failure!r}")
-    red_cb = _ReducerCallable(supplier.net_server().port)
-    reducer = UdaBridge()
+
+    def reduce_task(job: str, ids: list, key_class: str) -> tuple:
+        """One NetMerger task against the supplier: ``(callable, merge
+        manager, wall)``. A failure_in_uda is a smoke failure."""
+        red_cb = _ReducerCallable(supplier.net_server().port)
+        reducer = UdaBridge()
+        t0 = time.perf_counter()
+        reducer.start(True, [], red_cb)
+        try:
+            # reference-layout INIT (reducer.cc:56-133): num_maps, job,
+            # reduce id, lpq size, buffer B, min buffer B, key class,
+            # codec, codec block, shuffle memory B — the defaults' own
+            # values
+            reducer.do_command(form_cmd(Cmd.INIT, [
+                str(len(ids)), job, "0", "0", str(1 << 20), str(16 << 10),
+                key_class, "0", "0", str(1 << 30)]))
+            mm = reducer._mm   # outlives reduce_exit(), for the counters
+            for mid in ids:
+                reducer.do_command(form_cmd(Cmd.FETCH,
+                                            ["127.0.0.1", job, mid, "0"]))
+            reducer.do_command(form_cmd(Cmd.FINAL, []))
+        finally:
+            reducer.reduce_exit()  # joins the merge thread
+            wall_s = time.perf_counter() - t0
+        failure = red_cb.failure or sup_cb.failure
+        if failure is not None or reducer.failed or supplier.failed:
+            cause = "".join(traceback.format_exception(failure)) if failure \
+                else "bridge went inert without reporting a cause"
+            raise SmokeFailure(f"failure_in_uda — the bridge asked for the "
+                               f"vanilla fallback. Root cause:\n{cause}")
+        return red_cb, mm, wall_s
+
     metrics.enable_spans()     # counts the merge.device_put spans below
-    t0 = time.perf_counter()
-    reducer.start(True, [], red_cb)
     try:
-        # reference-layout INIT (reducer.cc:56-133): num_maps, job,
-        # reduce id, lpq size, buffer B, min buffer B, key class, codec,
-        # codec block, shuffle memory B — the defaults' own values
-        reducer.do_command(form_cmd(Cmd.INIT, [
-            str(maps), JOB, "0", "0", str(1 << 20), str(16 << 10),
-            "uda.tpu.RawBytes", "0", "0", str(1 << 30)]))
-        mm = reducer._mm       # outlives reduce_exit(), for the counters
-        for mid in map_ids:
-            reducer.do_command(form_cmd(Cmd.FETCH,
-                                        ["127.0.0.1", JOB, mid, "0"]))
-        reducer.do_command(form_cmd(Cmd.FINAL, []))
+        red_cb, mm, wall_s = reduce_task(JOB, map_ids, "uda.tpu.RawBytes")
+        om = mm._active_overlap
+        engine = merge_ops.resolve_run_engine("auto")
+        obs = {
+            "device": device, "records": records, "maps": maps,
+            "partition_bytes": records * 100, "setup_s": round(setup_s, 3),
+            "wall_s": round(wall_s, 3), "run_engine": engine,
+            "interpret": om.interpret,
+            "forest_merges": om.stats["device_merges"],
+            "device_put_spans": sum(s["name"] == "merge.device_put"
+                                    for s in metrics.spans),
+            "merge_records": int(metrics.get("merge.records")),
+            "budget_rerouted": int(metrics.get("budget.rerouted")),
+            "fallback_signals": int(metrics.get("fallback.signals")),
+            "fetch_retries": int(metrics.get("fetch.retries")),
+            "emitted_bytes": len(red_cb.out), "compile_cache": cache,
+        }
+        want = {"merge_records": records, "budget_rerouted": 0,
+                "fallback_signals": 0, "emitted_bytes": records * 102 + 2}
+        if not args.rehearse_cpu:
+            stats = jax.devices()[0].memory_stats()
+            key_width = int(Config().get("uda.tpu.key.width"))
+            obs["hbm"] = {
+                "peak_bytes_in_use": stats["peak_bytes_in_use"],
+                "bytes_limit": stats["bytes_limit"],
+                "device_bytes_estimate": device_bytes_estimate(records * 100,
+                                                               key_width),
+                "staged_bytes_per_record": _staged_bytes_per_record(
+                    key_width // 4 + merge_ops.ROW_EXTRA_COLS)}
+            want.update(run_engine="pallas", interpret=False,
+                        device_put_spans=maps)
+            if om.stats["device_merges"] <= 0:
+                raise SmokeFailure(f"no forest merge ran on the device: {obs}")
+        wrong = {k: (obs[k], v) for k, v in want.items() if obs[k] != v}
+        if wrong:
+            raise SmokeFailure(f"observed != expected: {wrong}; all: {obs}")
+
+        # correctness, outside every timed region: byte for byte
+        got = np.frombuffer(red_cb.out, np.uint8)
+        ref = _host_reference(root, map_ids)
+        if got[-2:].tobytes() != b"\xff\xff":
+            raise SmokeFailure("stream does not end in the IFile EOF marker")
+        if not np.array_equal(got[:-2], ref):
+            bad = int(np.flatnonzero(got[:-2] != ref)[0])
+            raise SmokeFailure(f"stream differs from the host reference at "
+                               f"byte {bad} (record {bad // 102})")
+        obs["byte_identical"] = True
+        obs["text_task"] = _text_task(args, sizes, root, reduce_task)
     finally:
-        reducer.reduce_exit()  # joins the merge thread
-        wall_s = time.perf_counter() - t0
         supplier.do_command(form_cmd(Cmd.EXIT, []))
-    failure = red_cb.failure or sup_cb.failure
-    if failure is not None or reducer.failed or supplier.failed:
-        cause = "".join(traceback.format_exception(failure)) if failure \
-            else "bridge went inert without reporting a cause"
-        raise SmokeFailure(f"failure_in_uda — the bridge asked for the "
-                           f"vanilla fallback. Root cause:\n{cause}")
+    return obs
 
-    om = mm._active_overlap
-    engine = merge_ops.resolve_run_engine("auto")
-    obs = {
-        "device": device, "records": records, "maps": maps,
-        "partition_bytes": records * 100, "setup_s": round(setup_s, 3),
-        "wall_s": round(wall_s, 3), "run_engine": engine,
-        "interpret": om.interpret,
-        "forest_merges": om.stats["device_merges"],
-        "device_put_spans": sum(s["name"] == "merge.device_put"
-                                for s in metrics.spans),
-        "merge_records": int(metrics.get("merge.records")),
-        "budget_rerouted": int(metrics.get("budget.rerouted")),
-        "fallback_signals": int(metrics.get("fallback.signals")),
-        "fetch_retries": int(metrics.get("fetch.retries")),
-        "emitted_bytes": len(red_cb.out), "compile_cache": cache,
-    }
-    want = {"merge_records": records, "budget_rerouted": 0,
-            "fallback_signals": 0, "emitted_bytes": records * 102 + 2}
-    if not args.rehearse_cpu:
-        stats = jax.devices()[0].memory_stats()
-        key_width = int(Config().get("uda.tpu.key.width"))
-        obs["hbm"] = {
-            "peak_bytes_in_use": stats["peak_bytes_in_use"],
-            "bytes_limit": stats["bytes_limit"],
-            "device_bytes_estimate": device_bytes_estimate(records * 100,
-                                                           key_width),
-            "staged_bytes_per_record": _staged_bytes_per_record(
-                key_width // 4 + merge_ops.ROW_EXTRA_COLS)}
-        want.update(run_engine="pallas", interpret=False,
-                    device_put_spans=maps)
-        if om.stats["device_merges"] <= 0:
-            raise SmokeFailure(f"no forest merge ran on the device: {obs}")
-    wrong = {k: (obs[k], v) for k, v in want.items() if obs[k] != v}
+
+def _text_task(args, sizes: dict, root: str, reduce_task) -> dict:
+    """A few thousand ``<word, posting>`` records under the comparator
+    the benchmark cell ``reduce_invindex`` uses
+    (``org.apache.hadoop.io.Text``), some words longer than the carried
+    width: the task leaves the forest for the overflow fallback, and its
+    stream is the benchmark's plain Text reference's, byte for byte."""
+    import numpy as np
+
+    from benchmark.gen import invindex_mofs
+    from benchmark.reference import host_sort_text
+    from uda_tpu.utils.metrics import metrics
+
+    part = invindex_mofs.generate(root, JOB_TEXT, args.seed,
+                                  sizes["text_records"], sizes["text_maps"])
+    counted = ("merge.overflow.fallbacks", "merge.overflow.keys")
+    before = [metrics.get(k) for k in counted]
+    red_cb, _, wall_s = reduce_task(JOB_TEXT, part.map_ids,
+                                    "org.apache.hadoop.io.Text")
+    fallbacks, oversize = (int(metrics.get(k) - b)
+                           for k, b in zip(counted, before))
+    obs = {"records": part.records, "maps": len(part.map_ids),
+           "wall_s": round(wall_s, 3), "emitted_bytes": len(red_cb.out),
+           "overflow_fallbacks": fallbacks, "oversize_keys": oversize}
+    if fallbacks != 1 or oversize < 1:
+        raise SmokeFailure(f"the Text task met no key longer than the "
+                           f"carried width: {obs}")
+    wrong = host_sort_text.compare(
+        np.frombuffer(red_cb.out, np.uint8),
+        host_sort_text.sorted_stream(root, JOB_TEXT, part.map_ids))
     if wrong:
-        raise SmokeFailure(f"observed != expected: {wrong}; all: {obs}")
-
-    # correctness, outside every timed region: byte for byte
-    got = np.frombuffer(red_cb.out, np.uint8)
-    ref = _host_reference(root, map_ids)
-    if got[-2:].tobytes() != b"\xff\xff":
-        raise SmokeFailure("stream does not end in the IFile EOF marker")
-    if not np.array_equal(got[:-2], ref):
-        bad = int(np.flatnonzero(got[:-2] != ref)[0])
-        raise SmokeFailure(f"stream differs from the host reference at "
-                           f"byte {bad} (record {bad // 102})")
+        raise SmokeFailure(f"Text task: stream differs from the host "
+                           f"reference: {wrong}")
     obs["byte_identical"] = True
     return obs
 

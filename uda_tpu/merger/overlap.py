@@ -81,7 +81,17 @@ by overflow *rank*, which is only meaningful computed across ALL records
 the forest detects oversize keys at staging and ``finish()`` falls back
 to the global device re-sort (merge_batches) — correctness never
 depends on the fast path applying. TeraSort-shaped keys (10 B <= width)
-always stay on the fast path.
+always stay on the fast path. A text job never does: one word longer
+than the carried width (0.26 % of an inverted index's postings are)
+latches the whole task, staging stops at that segment, and the task
+pays a concatenation, a pack with host-side ranks, one device sort and
+a take over the whole partition — 14.75 of a 16.3 s task with the chip
+idle 99.3 % in the benchmark cell ``reduce_invindex`` (PERF.md §5).
+``merge.overflow.fallbacks`` counts such tasks, ``merge.overflow.keys``
+the keys that forced them, and the ``overflow_resort`` timer (around
+``ops.merge.merge_batches``' ``overflow_concat``, ``pack`` with
+``overflow_rank`` inside it, ``device_sort``, ``overflow_take``) what
+they paid.
 """
 
 from __future__ import annotations
@@ -345,9 +355,12 @@ class OverlappedMerger:
         metrics.add("merge.host_merges", 0)
         metrics.add("stage.native_segments", 0)
         metrics.add("merge.device_groups", 0)
+        metrics.add("merge.overflow.fallbacks", 0)
+        metrics.add("merge.overflow.keys", 0)
         for timer in ("merge_host_batch", "merge_group_flush",
                       "merge_group_join", "run_spool", "fetch_crack",
-                      "fetch_feed_wait"):
+                      "fetch_feed_wait", "overflow_resort",
+                      "overflow_rank"):
             metrics.declare_timer(timer)
         self.pipeline = bool(pipeline)
         self._consumer_thread: Optional[threading.Thread] = None
@@ -1245,9 +1258,21 @@ class OverlappedMerger:
             acc = self._merge(acc, nxt)
         return acc
 
-    def _warn_overflow(self, fallback: str) -> None:
+    def _note_overflow_fallback(self, fallback: str) -> None:
+        """A task's merge leaves the forest because of oversize keys:
+        counted once a task, whichever fallback it takes."""
+        metrics.add("merge.overflow.fallbacks")
         log.warn(f"overlap fast path disabled (oversize keys); "
                  f"falling back to {fallback}")
+
+    def _overflow_resort(self, batches: Sequence[RecordBatch]) -> RecordBatch:
+        """The in-memory routes' overflow fallback: the whole partition
+        re-sorted by ``ops.merge.merge_batches``, under a timer of its
+        own so that a trace says what of the ``merge`` seconds it is."""
+        self._note_overflow_fallback("global device re-sort")
+        with metrics.timer("overflow_resort"):
+            return merge_ops.merge_batches(batches, self.key_type,
+                                           self.width)
 
     def _check_accounting(self, acc: Optional[_Run], total: int) -> bool:
         """Lost-records guard shared by every finish variant. Returns
@@ -1274,9 +1299,7 @@ class OverlappedMerger:
         try:
             self._drain()
             if self._overflow:
-                self._warn_overflow("global device re-sort")
-                return merge_ops.merge_batches(batches, self.key_type,
-                                               self.width)
+                return self._overflow_resort(batches)
             cat = RecordBatch.concat(list(batches))
             acc = self._merge_leftovers()
             if not self._check_accounting(acc, cat.num_records):
@@ -1308,9 +1331,7 @@ class OverlappedMerger:
                 self._drain()
                 merged = None
                 if self._overflow:
-                    self._warn_overflow("global device re-sort")
-                    merged = merge_ops.merge_batches(batches, self.key_type,
-                                                     self.width)
+                    merged = self._overflow_resort(batches)
                 else:
                     total = sum(b.num_records for b in batches)
                     acc = self._merge_leftovers()
@@ -1376,7 +1397,7 @@ class OverlappedMerger:
                 # fallback is a comparator-level k-way merge over the
                 # run FILES — bounded memory, like the hybrid RPQ
                 if self._overflow:
-                    self._warn_overflow("k-way merge over run files")
+                    self._note_overflow_fallback("k-way merge over run files")
                 else:
                     log.info("streaming without device runs: k-way merge "
                              "over run files (no device forest)")

@@ -5,6 +5,14 @@ handful of reducers receive most of the data — the skew case the
 reference handled with its backlog/credit machinery (reference
 src/DataNet/RDMAComm.cc:707-752) and that the TPU exchange handles with
 multi-round windowing (uda_tpu.parallel.exchange).
+
+On the chip this job is measured through the benchmark configuration
+``benchmark/configs/invindex_text.json`` (cell ``reduce_invindex``): one
+reduce task's partition of this module's ``<text_key(term), (doc, pos)>``
+records, 16.4 M of them with no combiner, on the served reduce path.
+Its generator draws a Zipf vocabulary of 2^20 lowercase words of 5-48
+bytes (``benchmark/gen/invindex_mofs.py``) where ``zipf_corpus`` here
+has 1,000 nine-byte terms; the key and value layout is this module's.
 """
 
 from __future__ import annotations

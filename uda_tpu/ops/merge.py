@@ -359,10 +359,16 @@ def merge_batches(batches: Sequence[RecordBatch], kt: KeyType,
 
     Overflow ranks are computed across the *concatenation* so they are
     globally consistent (see merge_runs caveat in uda_tpu.ops.sort).
+    This is also where a task with keys longer than ``width`` ends up
+    (merger.overlap's overflow fallback), so each stage has a timer of
+    its own: ``overflow_concat``, ``pack`` (``overflow_rank`` inside
+    it), ``device_sort``, ``overflow_take``.
     """
-    cat = RecordBatch.concat(list(batches))
+    with metrics.timer("overflow_concat"):
+        cat = RecordBatch.concat(list(batches))
     order = sorted_batch_order(cat, kt, width)
-    return cat.take(order)
+    with metrics.timer("overflow_take"):
+        return cat.take(order)
 
 
 def merge_batches_host(batches: Sequence[RecordBatch], kt: KeyType) -> RecordBatch:

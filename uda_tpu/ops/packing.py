@@ -28,6 +28,7 @@ import numpy as np
 from uda_tpu.utils.comparators import KeyType
 from uda_tpu.utils.errors import MergeError
 from uda_tpu.utils.ifile import RecordBatch
+from uda_tpu.utils.metrics import metrics
 
 __all__ = ["PackedKeys", "content_spans", "pack_keys", "overflow_ranks",
            "pack_fixed_payload", "unpack_fixed_payload"]
@@ -131,7 +132,8 @@ def pack_keys(batch: RecordBatch, kt: KeyType, width: int) -> PackedKeys:
     if kt.name in ("int_numeric", "long_numeric"):
         raw[:, 0] ^= 0x80  # sign-bit flip: memcmp order == numeric order
     words = _bytes_to_words(raw)
-    ranks = overflow_ranks(batch, raw, off, ln, width)
+    with metrics.timer("overflow_rank"):
+        ranks = overflow_ranks(batch, raw, off, ln, width)
     return PackedKeys(words, ln.astype(np.int32), ranks)
 
 
@@ -153,6 +155,7 @@ def overflow_ranks(batch: RecordBatch, prefixes: np.ndarray,
     over = np.nonzero(content_len > width)[0]
     if over.size == 0:
         return ranks
+    metrics.add("merge.overflow.keys", over.size)
 
     def content(i: int) -> bytes:
         o, l = int(content_off[i]), int(content_len[i])

@@ -73,7 +73,7 @@ SPAN_BUCKETS: Dict[str, str] = {
     # decompress+pack: host staging compute (materialize, vint-decode,
     # pack, row build, run spooling)
     "overlap_pack": "decompress_pack", "pack": "decompress_pack",
-    "run_spool": "decompress_pack",
+    "overflow_rank": "decompress_pack", "run_spool": "decompress_pack",
     # device-put: host->device transfer + buffer-recycle wait
     "overlap_stage": "device_put", "merge.device_put": "device_put",
     # merge: device/host merge + sort compute (merge_host_batch runs
@@ -85,6 +85,9 @@ SPAN_BUCKETS: Dict[str, str] = {
     "merge_group_join": "merge",
     "device_sort": "merge", "lpq_spill": "merge", "lpq_phase": "merge",
     "rpq_phase": "merge",
+    # the overflow fallback's global re-sort and its host stages
+    "overflow_resort": "merge", "overflow_concat": "merge",
+    "overflow_take": "merge",
     # emit: the reduce side's output path after the forest is merged —
     # slab read-back, record gather, framing, block staging, and the
     # consumer up-call (the reference's trio has no emit term)

@@ -237,6 +237,25 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                        "and read back to a host row run "
                                        "(merger/overlap.py:_flush_group); "
                                        "0 for a task the chip holds whole"),
+    "merge.overflow.fallbacks": ("counter", "tasks whose merge left "
+                                            "the run forest because a "
+                                            "key was longer than the "
+                                            "carried width: the global "
+                                            "re-sort (ops.merge."
+                                            "merge_batches, timer "
+                                            "overflow_resort) or, "
+                                            "streaming, the k-way merge "
+                                            "over run files (merger/"
+                                            "overlap.py)"),
+    "merge.overflow.keys": ("counter", "keys whose content exceeds the "
+                                       "carried width, counted where "
+                                       "they are ranked (ops/packing.py "
+                                       "overflow_ranks): once a task, "
+                                       "over the whole partition, in "
+                                       "the global re-sort; the numpy "
+                                       "staging passes (no native "
+                                       "library) also rank the segment "
+                                       "that latched the fallback"),
     "spool.bytes": ("counter", "bytes spooled to sorted run files "
                                "(streaming online mode)"),
     # -- counters: staging pipeline (merger/overlap stage pool) ----------
