@@ -3,9 +3,13 @@ an inverted index's ``<word, posting>`` records, no combiner) through
 bridge INIT / FETCH / FINAL with ``org.apache.hadoop.io.Text`` as the
 key class and every flag at its default, held to the benchmark's plain
 reference ``benchmark/reference/host_sort_text.py``: on the run forest
-when every word fits the carried width, on the overflow fallback
-(``merger/overlap.py``; counters ``merge.overflow.fallbacks`` and
-``merge.overflow.keys``, timer ``overflow_resort``) when one does not."""
+whether or not every word fits the carried width — the words that do
+not are staged by their first 16 bytes and their equal-prefix blocks
+re-ordered at emit (``merger/overlap.py``; counters
+``merge.overflow.keys`` and ``merge.oversize.blocks``, timer
+``oversize_fixup``) — and on the overflow fallback
+(``merge.overflow.fallbacks``, timer ``overflow_resort``) only with a
+run store, the streaming route."""
 
 import os
 import struct
@@ -173,28 +177,29 @@ def _maps_an_empty_map(rng):
     return [_words(rng, 120), [], _words(rng, 80), []]
 
 
-# case -> (maps, oversize keys in the partition)
+# case -> (maps, oversize keys in the partition, equal-prefix blocks
+# of two or more of them)
 CASES = {
-    "every_key_within_16_bytes": (_maps_within_width, 0),
+    "every_key_within_16_bytes": (_maps_within_width, 0, 0),
     "one_oversize_key_in_the_last_map": (
-        _maps_one_oversize_in_the_last_map, 1),
+        _maps_one_oversize_in_the_last_map, 1, 0),
     "oversize_keys_sharing_their_first_16_bytes": (
-        _maps_oversize_sharing_their_first_16_bytes, 8),
+        _maps_oversize_sharing_their_first_16_bytes, 8, 1),
     "content_of_exactly_16_and_of_17_bytes": (
-        _maps_exactly_16_and_17_bytes, 3),
-    "a_beside_a_nul": (_maps_a_beside_a_nul, 0),
+        _maps_exactly_16_and_17_bytes, 3, 1),
+    "a_beside_a_nul": (_maps_a_beside_a_nul, 0, 0),
     "a_term_that_is_a_prefix_of_another": (
-        _maps_a_term_that_is_a_prefix_of_another, 4),
+        _maps_a_term_that_is_a_prefix_of_another, 4, 1),
     "thousands_of_equal_keys_across_maps": (
-        _maps_thousands_of_equal_keys, 0),
-    "an_empty_map": (_maps_an_empty_map, 0),
+        _maps_thousands_of_equal_keys, 0, 0),
+    "an_empty_map": (_maps_an_empty_map, 0, 0),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_text_task_through_the_bridge_equals_the_plain_reference(tmp_path,
                                                                  case):
-    build, oversize = CASES[case]
+    build, oversize, blocks = CASES[case]
     maps = build(np.random.default_rng(41))
     ids = _write_maps(str(tmp_path), maps)
     assert sum(len(w) > 16 for words in maps for w in words) == oversize
@@ -202,21 +207,23 @@ def test_text_task_through_the_bridge_equals_the_plain_reference(tmp_path,
     ref = host_sort_text.sorted_stream(str(tmp_path), JOB, ids)
     assert ref.starts.size == sum(len(words) for words in maps)
     assert host_sort_text.compare(stream, ref) is None
-    # the route: the forest when every word fits the carried width, the
-    # overflow fallback (once a task) when one does not
-    assert metrics.get("merge.overflow.fallbacks") == (1 if oversize else 0)
+    # the route: the forest, oversize words or not — every segment
+    # staged by the native pass, no fallback, the keys counted as they
+    # are staged and their blocks as the emit re-orders them
+    assert metrics.get("merge.overflow.fallbacks") == 0
     assert metrics.get("merge.overflow.keys") == oversize
+    assert metrics.get("merge.oversize.blocks") == blocks
+    assert metrics.get("merge.records") == ref.starts.size
+    assert metrics.get("stage.native_segments") == sum(
+        1 for words in maps if words)
     counters = metrics.snapshot()
+    assert counters["overflow_resort_time"] == 0
+    assert counters["overflow_rank_time"] == 0
+    assert "overflow_concat_time" not in counters
     if oversize:
-        assert counters["overflow_resort_time"] > 0
-        assert counters["overflow_resort_time"] >= sum(
-            counters[t + "_time"] for t in (
-                "overflow_concat", "pack", "device_sort", "overflow_take"))
-        assert counters["pack_time"] >= counters["overflow_rank_time"] > 0
+        assert counters["oversize_fixup_time"] > 0
     else:
-        assert counters["overflow_resort_time"] == 0
-        assert "overflow_concat_time" not in counters
-        assert metrics.get("merge.records") == ref.starts.size
+        assert counters["oversize_fixup_time"] == 0
 
 
 def test_equal_keys_keep_map_order_then_row_order(tmp_path):

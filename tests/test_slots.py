@@ -16,7 +16,9 @@ from uda_tpu.bridge.protocol import Cmd, form_cmd
 from uda_tpu.merger import LocalFetchClient, MergeManager
 from uda_tpu.mofserver import DataEngine, DirIndexResolver, read_index_file
 from uda_tpu.utils import comparators, critpath
-from uda_tpu.utils.budget import HbmLedger, MemoryBudget, hbm_ledger
+from uda_tpu.utils.budget import (HbmLedger, MemoryBudget,
+                                  device_bytes_estimate, hbm_ledger,
+                                  merge_temp_bytes_estimate)
 from uda_tpu.utils.config import Config
 from uda_tpu.utils.errors import MergeError, UdaError
 from uda_tpu.utils.failpoints import failpoints
@@ -356,6 +358,116 @@ def test_a_task_too_large_alone_is_sized_into_groups_and_waits_its_turn():
     assert metrics.get("budget.rerouted") == 1
     hold.release()
     _books_are_empty()
+
+
+@pytest.mark.parametrize("record_bytes, grows",
+                         ((20.0, True), (100.0, False), (102.0, False)))
+def test_the_rebook_grows_a_hold_for_20_byte_records_never_for_100(
+        record_bytes, grows):
+    """Admission reckons 100 bytes a record; staging tells the ledger
+    what they are. Smaller records are more rows: the hold grows to the
+    model's figure for them. Records of 100 bytes or more (TeraSort's
+    are 102 on disk) leave the books alone."""
+    budget = _budget(256)
+    est, segments = 2 * MB, 8
+    hold, _ = budget.admit_device(est, segments=segments)
+    rows, temps = hold.nbytes, hold.temp_bytes
+    assert budget.rebook_device(hold, est, segments, record_bytes) is grows
+    if grows:
+        assert hold.nbytes == device_bytes_estimate(est, 16, record_bytes)
+        assert hold.nbytes == 192 * (est // 20) > 4 * rows  # 5 x the forest
+        assert hold.temp_bytes == merge_temp_bytes_estimate(
+            est, segments, record_bytes) > temps
+        # grow-only: the same figure again, or a larger record, moves nothing
+        assert not budget.rebook_device(hold, est, segments, record_bytes)
+        assert not budget.rebook_device(hold, est, segments, 50.0)
+        assert budget.rebook_device(hold, est, segments, 10.0)
+        assert hold.nbytes == 192 * (est // 10)
+    else:
+        assert (hold.nbytes, hold.temp_bytes) == (rows, temps)
+    assert metrics.get("budget.hbm.rebooked") == (2 if grows else 0)
+    assert hbm_ledger.reserved_bytes == hold.nbytes + hold.temp_bytes \
+        == metrics.get_gauge("budget.hbm.reserved")
+    hold.release()
+    assert not budget.rebook_device(hold, est, segments, 5.0)   # released
+    _books_are_empty()
+
+
+def test_the_rebook_never_waits_even_past_the_budget():
+    """Two live tasks both grow beyond what the chip's budget holds:
+    neither waits (a wait could deadlock them; their rows are on the
+    way whatever the books say) — the task that asks next does."""
+    budget = _budget(44)
+    est = 2 * MB      # 4.03 MB of rows + 16 MB of temporaries each
+    a, _ = budget.admit_device(est)
+    b, _ = budget.admit_device(est)
+    t0 = time.monotonic()
+    assert budget.rebook_device(a, est, None, 20.0)
+    assert budget.rebook_device(b, est, None, 20.0)
+    assert time.monotonic() - t0 < 0.5
+    assert metrics.get("budget.waited") == 0
+    assert metrics.get("hbm_admit_time") < 0.1
+    # 2 x 20.1 MB of rows + 64 MB of temporaries, booked once
+    assert hbm_ledger.reserved_bytes == 2 * a.nbytes + a.temp_bytes \
+        > budget.hbm_budget_bytes
+    assert metrics.gauge_peaks_snapshot()["budget.hbm.reserved"] \
+        == hbm_ledger.reserved_bytes
+    admitted = threading.Event()
+    third: list = []
+
+    def late() -> None:
+        third.append(budget.admit_device(64 * 1024)[0])
+        admitted.set()
+
+    t = threading.Thread(target=late)
+    t.start()
+    assert not admitted.wait(0.3)     # the books are over the budget
+    a.release()
+    b.release()
+    assert admitted.wait(10)
+    t.join()
+    third[0].release()
+    _books_are_empty()
+
+
+@pytest.mark.parametrize("val_bytes, rebooked", ((8, 1), (90, 0)))
+def test_a_task_of_small_records_rebooks_once_through_the_manager(
+        tmp_path, val_bytes, rebooked):
+    """Through ``MergeManager.run``: staging's first segment tells the
+    ledger the record size (10-byte keys: 20 or 102 bytes a frame); the
+    hold of the 20-byte task is five times the rows by the time it
+    emits, the TeraSort-shaped task's is what admission booked."""
+    expected = make_mof_tree(str(tmp_path), "jobR", 4, 1, 200, seed=5,
+                             val_bytes=val_bytes)
+    engine = DataEngine(DirIndexResolver(str(tmp_path)))
+    try:
+        cfg = Config({"uda.tpu.hbm.budget.mb": 64,
+                      "uda.tpu.host.budget.mb": 1024})
+        mm = MergeManager(LocalFetchClient(engine), KT, cfg)
+        est = mm.client.estimate_partition_bytes("jobR",
+                                                 map_ids("jobR", 4), 0)
+        assert est == 4 * (200 * (12 + val_bytes) + 2)
+        seen: list = []
+        blocks: list = []
+
+        def consumer(block) -> None:
+            seen.append(hbm_ledger.reserved_bytes)
+            blocks.append(bytes(block))
+
+        mm.run("jobR", map_ids("jobR", 4), 0, consumer)
+        assert list(IFileReader(io.BytesIO(b"".join(blocks)))) \
+            == sorted(expected[0])
+        assert metrics.get("budget.hbm.rebooked") == rebooked
+        assert metrics.get("budget.waited") == 0
+        booked = sum(mm.budget().device_need(est, 4))
+        if rebooked:
+            assert seen[-1] == device_bytes_estimate(est, 16, 20.0) \
+                + merge_temp_bytes_estimate(est, 4, 20.0) > booked
+        else:
+            assert seen[-1] == booked
+        _books_are_empty()
+    finally:
+        engine.stop()
 
 
 def test_default_approach_reserves_and_reroutes_over_a_small_budget(tmp_path):

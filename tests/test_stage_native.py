@@ -3,8 +3,9 @@ behind ``ops.merge.stage_run_rows``): key gather, big-endian words, the
 composite-key row fill, the (words, len) order check and — for a segment
 that is not presorted — the stable sort, where the numpy path takes
 ``pack_keys`` + ``run_row_order`` + ``fill_run_rows``. The numpy path is
-the reference: same rows byte for byte, same overflow test, same byte
-sum, for every key type, order, size and key length; it is what runs
+the reference: same rows byte for byte — a key longer than the width
+gets its row too, on both paths — same longest key, same byte sum, for
+every key type, order, size and key length; it is what runs
 when ``uda.tpu.use.native`` is off or the library is absent. A task
 staged natively counts each segment in ``stage.native_segments`` and
 emits the numpy-path task's stream; every exit sends the row lease home."""
@@ -103,11 +104,9 @@ def _batch(kind: str, contents, seed: int = 0):
 
 def _numpy_rows(batch, kt, width: int, cap: int):
     """The reference: the three numpy passes, into a fresh matrix."""
-    packed = packing.pack_keys(batch, kt, width)
+    packed = packing.pack_keys(batch, kt, width, ranks=False)
     longest = int(np.max(packed.key_lens, initial=0))
     nbytes = int(batch.key_len.sum() + batch.val_len.sum())
-    if longest > width:
-        return None, (False, longest, nbytes)
     order = merge_ops.run_row_order(packed)
     rows = np.zeros((cap, width // 4 + merge_ops.ROW_EXTRA_COLS), np.uint32)
     merge_ops.fill_run_rows(rows, packed, order, SEG)
@@ -133,14 +132,41 @@ def test_native_rows_equal_the_numpy_rows(kind, relation, order, n, cap):
                    np.uint32)
     got = merge_ops.stage_run_rows(rows, batch, kt, width, SEG)
     assert metrics.get("stage.native_segments") == 1
-    if relation == "longer" and n:
-        # the overflow latch's operand, same as numpy's; rows are dropped
-        assert got[1:] == want[1:] and got[1] > width
-        return
     assert got == want
     assert rows.tobytes() == want_rows.tobytes()
-    if order == "presorted" or n < 2:
+    if relation == "longer" and n and kind not in NUMERIC_BYTES:
+        # an oversize key's row: its first ``width`` bytes, its whole
+        # length; the caller counts such keys, staging does not
+        kw = width // 4
+        assert got[1] == int(rows[:n, kw].max()) > width
+        assert metrics.get("merge.overflow.keys") == 0
+    elif order == "presorted" or n < 2:
         assert got[0]
+
+
+@pytest.mark.parametrize("use_native", (True, False), ids=("native", "numpy"))
+def test_an_oversize_segment_is_staged_in_words_length_row_order(use_native):
+    """Inside a block of oversize keys with equal words the staged
+    order is (length, row) — not the comparator's, which the emit
+    restores — and both paths fill the same rows."""
+    set_native_enabled(use_native)
+    stem = b"s" * WIDTH
+    contents = [b"a", stem + b"ab", stem + b"b" * 9, stem + b"za", stem,
+                stem + b"ab"]                 # comparator-sorted but for...
+    contents.sort()
+    batch = _batch("raw", contents)
+    rows = np.empty((8, WIDTH // 4 + 3), np.uint32)
+    presorted, longest, _ = merge_ops.stage_run_rows(rows, batch, RAW, WIDTH,
+                                                     SEG)
+    assert not presorted and longest == WIDTH + 9
+    kw = WIDTH // 4
+    # a, the stem (within the width), then the block by (length, row)
+    assert rows[:6, kw].tolist() == [1, WIDTH, WIDTH + 2, WIDTH + 2,
+                                     WIDTH + 2, WIDTH + 9]
+    assert rows[:6, kw + 2].tolist() == [0, 1, 2, 3, 5, 4]
+    assert (rows[1:6, :kw] == rows[1, :kw]).all()
+    assert (rows[6:] == merge_ops.PAD_WORD).all()
+    assert metrics.get("stage.native_segments") == int(use_native)
 
 
 @pytest.mark.parametrize("use_native", (True, False), ids=("native", "numpy"))
@@ -277,8 +303,10 @@ def test_a_task_that_fell_back_reads_zero_not_nothing():
 
 @pytest.mark.parametrize("streaming", (False, True),
                          ids=("in_memory", "streaming"))
-def test_an_oversize_key_latches_overflow_as_the_numpy_path_does(
+def test_a_task_with_oversize_keys_emits_what_the_numpy_path_does(
         streaming, tmp_path, monkeypatch):
+    """In memory the oversize segment's rows go to the forest on both
+    paths; with a run store both latch the k-way fallback."""
     batches = [_batch("raw", _contents("raw", "shorter", "presorted", 30,
                                        seed=i)) for i in range(6)]
     batches[3] = _batch("raw", _contents("raw", "longer", "unsorted", 30,
@@ -288,9 +316,15 @@ def test_an_oversize_key_latches_overflow_as_the_numpy_path_does(
         return (RunStore([str(tmp_path)], tag=tag) if streaming else None)
 
     got = _task_bytes(batches, RAW, store("native"))
+    counted = [metrics.get("merge.overflow." + c)
+               for c in ("keys", "fallbacks")]
+    assert counted[0] > 0 and counted[1] == int(streaming)
+    metrics.reset()
     want = _task_bytes(batches, RAW, store("numpy"), numpy_path=True,
                        monkeypatch=monkeypatch)
-    assert got == want
+    assert got == want == _oracle_bytes(batches, RAW)
+    assert counted == [metrics.get("merge.overflow." + c)
+                       for c in ("keys", "fallbacks")]
 
 
 # -- every exit returns the row lease --------------------------------------------
@@ -336,8 +370,10 @@ def test_a_raising_native_pass_releases_its_lease(monkeypatch):
 @pytest.mark.parametrize("how", ("overflow", "no_device_runs", "spool_raises"))
 def test_rows_the_forest_does_not_take_go_back_to_the_pool(monkeypatch, how,
                                                            tmp_path):
-    """The lease is taken before the pass: a segment whose keys overflow,
-    a spool-only task and a failing spool all hand it back."""
+    """Every lease goes home: a task whose keys overflow keeps its rows
+    on the forest (the leases return as runs merge away and at the
+    finish; the pool is whole after the emit), a spool-only task and a
+    failing spool hand theirs back from staging."""
     store = None
     if how != "overflow":
         store = RunStore([str(tmp_path)], tag=how)
@@ -356,7 +392,10 @@ def test_rows_the_forest_does_not_take_go_back_to_the_pool(monkeypatch, how,
     if how == "overflow":
         om.emit_stream(batches, FramedEmitter(1 << 14),
                        lambda blk: out.write(bytes(blk)))
-        assert om.stats["overflow"]
+        assert om.stats["oversize"] and not om.stats["overflow"]
+        assert om.stats["staged_runs"] == 5 and om.stats["device_merges"] == 4
+        assert out.getvalue() == _oracle_bytes(batches, RAW)
+        assert metrics.get("merge.overflow.fallbacks") == 0
     elif how == "no_device_runs":
         om.finish_streaming(FramedEmitter(1 << 14),
                             lambda blk: out.write(bytes(blk)),

@@ -1049,10 +1049,17 @@ class MergeManager:
             flightrec.record("admission", decision=reroute.decision,
                              cause=reroute.cause, rejected=False,
                              estimate=est)
+        rebook = None
+        if est is not None and reroute is None:
+            # the hold was sized for RECORD_BYTES_DEFAULT-byte records;
+            # staging says what they are (a grouped task's hold is the
+            # group's, a task of unknown size has the whole budget)
+            rebook = functools.partial(self.budget().rebook_device, hold,
+                                       est, len(map_ids))
         with hold:
             return self._run_overlapped(job_id, map_ids, reduce_id,
                                         consumer, streaming, group_rows,
-                                        cap_budget)
+                                        cap_budget, rebook)
 
     def _admit_poll(self) -> bool:
         """Polled by the HBM ledger while this task waits: whether the
@@ -1066,11 +1073,15 @@ class MergeManager:
                         reduce_id: int,
                         consumer: Callable[[memoryview], None],
                         streaming: bool, group_rows: int,
-                        cap_budget: Optional[MemoryBudget]) -> int:
+                        cap_budget: Optional[MemoryBudget],
+                        rebook: Optional[Callable[[float], bool]] = None
+                        ) -> int:
         """The overlapped fetch/merge route (streaming or in-memory),
         run while the task holds its reservation of the chip's HBM;
         ``group_rows`` > 0: the reservation holds a group of that many
-        rows of run capacity, not the task (streaming only)."""
+        rows of run capacity, not the task (streaming only).
+        ``rebook`` grows the reservation once staging has seen the
+        record size (``MemoryBudget.rebook_device``)."""
         from uda_tpu.merger.overlap import OverlappedMerger
 
         store = None
@@ -1129,7 +1140,8 @@ class MergeManager:
                 self.cfg, self.window, self.chunk_size,
                 budget=cap_budget),
             on_spool=((lambda i: ckpt.maybe_save(collect))
-                      if ckpt is not None else None))
+                      if ckpt is not None else None),
+            on_record_bytes=rebook)
         self._active_overlap = om  # observability (tests/diagnostics)
         adopted: set = set()
         preload: dict = {}

@@ -75,23 +75,41 @@ randomized fetch schedule is nondeterministic. Pipelined and serial
 staging are byte-identical by construction for the same reason: forest
 insertion order never decides anything.
 
-Overflow fallback: keys whose content exceeds the carried width compare
-by overflow *rank*, which is only meaningful computed across ALL records
-(ops.packing.overflow_ranks). Rather than serialize rank computation,
-the forest detects oversize keys at staging and ``finish()`` falls back
-to the global device re-sort (merge_batches) — correctness never
-depends on the fast path applying. TeraSort-shaped keys (10 B <= width)
-always stay on the fast path. A text job never does: one word longer
-than the carried width (0.26 % of an inverted index's postings are)
-latches the whole task, staging stops at that segment, and the task
-pays a concatenation, a pack with host-side ranks, one device sort and
-a take over the whole partition — 14.75 of a 16.3 s task with the chip
-idle 99.3 % in the benchmark cell ``reduce_invindex`` (PERF.md §5).
-``merge.overflow.fallbacks`` counts such tasks, ``merge.overflow.keys``
-the keys that forced them, and the ``overflow_resort`` timer (around
-``ops.merge.merge_batches``' ``overflow_concat``, ``pack`` with
-``overflow_rank`` inside it, ``device_sort``, ``overflow_take``) what
-they paid.
+Oversize keys: a key whose content exceeds the carried width is staged
+like any other — its first ``width`` bytes as words, its whole content
+length, segment, row (``ops.merge.stage_run_rows``) — and merges on the
+forest under the same total row order. That order is the comparator's
+everywhere but inside a BLOCK: the oversize keys (length > width) of
+one set of key words, contiguous in the merged order because keys of
+those words that fit the width sort before them (a key equal to the
+block's ``width``-byte stem is a proper prefix of every member and
+already comes first). The emit re-orders each block's (segment, row)
+pairs by whole content — memcmp, shorter first, ties by (segment, row):
+the comparator's order and the stable order of the reference —
+before it gathers a slab (``_fix_oversize_blocks``); a block that
+straddles a read-back slab is held back until it closes. The scan runs
+only in a task that staged an oversize key (``_oversize``, a one-way
+flag): 0.3 % of an inverted index's postings are such keys, in blocks
+of tens, and the fix-up is hundredths of a second where the fallback
+below was 14 of the task's 16 (benchmark cell ``reduce_invindex``,
+PERF.md §5). ``merge.overflow.keys`` counts the oversize keys as they
+are staged, ``merge.oversize.blocks`` the blocks of two or more rows
+re-ordered, the ``oversize_fixup`` timer the scan and the re-order.
+
+Overflow fallback, for what the forest cannot order: a key type whose
+``compare`` is its own (``not uses_default_bytewise``: the prefix says
+nothing about its order) latches ``_overflow`` at its first oversize
+key; staging stops and ``finish()`` / ``emit_stream()`` fall back to
+the global device re-sort with host-side ranks
+(``ops.merge.merge_batches``: a concatenation, a pack, one device sort
+and a take over the whole partition). The streaming route (a run
+store) latches too, for every key type: its oversize segments spool in
+full-comparator order and ``finish_streaming`` merges the run FILES
+k-way. ``merge.overflow.fallbacks`` counts such tasks and the
+``overflow_resort`` timer (around ``merge_batches``'
+``overflow_concat``, ``pack`` with ``overflow_rank`` inside it,
+``device_sort``, ``overflow_take``) what the in-memory ones paid.
+Correctness never depends on the fast path applying.
 """
 
 from __future__ import annotations
@@ -110,7 +128,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from uda_tpu.ops import merge as merge_ops
-from uda_tpu.utils.budget import FOREST_FACTOR
+from uda_tpu.utils.budget import FOREST_FACTOR, RECORD_BYTES_DEFAULT
 from uda_tpu.utils.comparators import KeyType, uses_default_bytewise
 from uda_tpu.utils.errors import MergeError
 from uda_tpu.utils.ifile import EOF_MARKER, RecordBatch
@@ -256,7 +274,7 @@ class OverlappedMerger:
                  run_store=None, max_pending: int = 0, stagers: int = 0,
                  device_runs: bool = True, pipeline: bool = False,
                  inflight_bytes: int = 0, on_spool=None,
-                 group_rows: int = 0):
+                 group_rows: int = 0, on_record_bytes=None):
         self.key_type = key_type
         self.width = width
         # run-spool boundary hook (merger/checkpoint.py): called with the
@@ -264,6 +282,16 @@ class OverlappedMerger:
         # natural crash-consistent snapshot trigger. Contract: the hook
         # never raises (TaskCheckpoint.maybe_save catches internally).
         self._on_spool = on_spool
+        # admission reckoned the partition's rows from its bytes at
+        # RECORD_BYTES_DEFAULT a record; staging sees the records. The
+        # hook (MemoryBudget.rebook_device through MergeManager; never
+        # waits) is told the framed bytes a record so far, whenever
+        # they project more rows than the last figure did (the three
+        # counters below: under _state_lock, _observe_records).
+        self._on_record_bytes = on_record_bytes
+        self._seen_records = 0
+        self._seen_bytes = 0
+        self._booked_record_bytes = float(RECORD_BYTES_DEFAULT)
         # device_runs=False (streaming mode only): the caller wants no
         # run staged to the device — segments still spool to sorted run
         # files and finish_streaming() merges the run FILES with the
@@ -307,13 +335,19 @@ class OverlappedMerger:
         # udarace: lockfree=_q,_staged_q - queue.Queue is internally
         # locked; cross-thread put/get rides the Queue's own mutex
         self._q: "queue.Queue" = queue.Queue(maxsize=max_pending)
-        # udarace: lockfree=_aborted,_overflow - one-way bool latches
-        # (GIL-atomic store; readers may lag one item, by design)
+        # udarace: lockfree=_aborted,_overflow,_oversize - one-way bool
+        # latches (GIL-atomic store; readers may lag one item, by design)
         self._aborted = False
         self._forest: dict[int, _Run] = {}   # capacity -> run
         self._forest_lock = threading.Lock()
         self._state_lock = threading.Lock()  # counters/overflow flag
         self._overflow = False
+        # oversize keys stay on the forest, their blocks fixed up at
+        # emit (module docstring), where the row order decides all but
+        # that: the in-memory route under the stock bytewise compare
+        self._forest_orders_oversize = (run_store is None
+                                        and uses_default_bytewise(key_type))
+        self._oversize = False            # such a key was staged
         # udarace: lockfree=_error - first-error latch: a lagging racer
         # overwrites with its own exception, either surfaces at finish()
         self._error: Optional[Exception] = None
@@ -357,10 +391,11 @@ class OverlappedMerger:
         metrics.add("merge.device_groups", 0)
         metrics.add("merge.overflow.fallbacks", 0)
         metrics.add("merge.overflow.keys", 0)
+        metrics.add("merge.oversize.blocks", 0)
         for timer in ("merge_host_batch", "merge_group_flush",
                       "merge_group_join", "run_spool", "fetch_crack",
                       "fetch_feed_wait", "overflow_resort",
-                      "overflow_rank"):
+                      "overflow_rank", "oversize_fixup"):
             metrics.declare_timer(timer)
         self.pipeline = bool(pipeline)
         self._consumer_thread: Optional[threading.Thread] = None
@@ -687,10 +722,11 @@ class OverlappedMerger:
             return
         rows, lease, _, longest, _ = self._stage_rows(seg_index, batch)
         if longest > self.width:
-            # oversize keys: same posture as _prepare — disable the fast
-            # path; finish_streaming's comparator k-way file merge (which
-            # reads this adopted run file) is the correctness fallback
-            self._overflow = True
+            # oversize keys: same posture as _prepare — on a streaming
+            # resume the fast path is disabled and finish_streaming's
+            # comparator k-way file merge (which reads this adopted run
+            # file) is the correctness fallback
+            self._keep_oversize(rows, n)
         with self._state_lock:
             self._staged += 1
         metrics.add("merge.records", n)
@@ -744,11 +780,10 @@ class OverlappedMerger:
         kept = False  # whether the forest takes the rows (and the lease)
         try:
             metrics.add("stage.bytes", nbytes)
-            if longest > self.width:
-                # rank-bearing keys: cross-run rank consistency needs
-                # the global view; disable the fast path (see module
-                # docstring)
-                self._overflow = True
+            self._observe_records(n, nbytes)
+            if longest > self.width and not self._keep_oversize(rows, n):
+                # the forest cannot order these keys: the fast path is
+                # disabled (see module docstring)
                 if not streaming:
                     return None
                 # streaming keeps spooling: this run is ordered by the
@@ -790,6 +825,44 @@ class OverlappedMerger:
         finally:
             if not kept:
                 self._release_rows(lease)
+
+    def _observe_records(self, n: int, nbytes: int) -> None:
+        """Tell the admission hook the task's record size once staging
+        knows it, and again only if later segments bring it down by
+        more than a thirty-second: a frame is its key and value bytes
+        and two length VInts, of a byte each at least. TeraSort's 102
+        never pass the model's 100, so such a task never calls."""
+        if self._on_record_bytes is None:
+            return
+        with self._state_lock:
+            self._seen_records += n
+            self._seen_bytes += nbytes + 2 * n
+            observed = self._seen_bytes / self._seen_records
+            if observed * 32 >= self._booked_record_bytes * 31:
+                return
+            self._booked_record_bytes = observed
+        self._on_record_bytes(observed)
+
+    def _keep_oversize(self, rows: np.ndarray, n: int) -> bool:
+        """A staged segment holds a key longer than the carried width.
+        True: its rows stay on the forest and the emit fixes the
+        equal-prefix blocks up (the task flag ``_oversize``). False:
+        the task latches the overflow fallback — a key type with a
+        ``compare`` of its own, or the streaming route. Counts the
+        keys in ``merge.overflow.keys``, except where the in-memory
+        fallback will (``packing.overflow_ranks``, as it ranks the
+        whole partition)."""
+        keep = self._forest_orders_oversize
+        if keep:
+            self._oversize = True
+        else:
+            self._overflow = True
+            if self.run_store is None:
+                return False
+        kw = rows.shape[1] - merge_ops.ROW_EXTRA_COLS
+        metrics.add("merge.overflow.keys",
+                    int(np.count_nonzero(rows[:n, kw] > self.width)))
+        return keep
 
     def _overflow_order(self, batch: RecordBatch, n: int) -> np.ndarray:
         """Full-comparator sort order for an oversize-key run. Default
@@ -1123,6 +1196,7 @@ class OverlappedMerger:
         return {"device_merges": self._merges, "staged_runs": self._staged,
                 "device_groups": self._groups,
                 "pending": pending, "overflow": self._overflow,
+                "oversize": self._oversize,
                 "pipeline": self.pipeline,
                 "inflight_bytes": self._inflight}
 
@@ -1291,6 +1365,75 @@ class OverlappedMerger:
                 f"(segments fed != segments finished?)")
         return True
 
+    # -- oversize blocks (the task staged a key longer than the width) ------
+
+    @staticmethod
+    def _row_pairs(rows: np.ndarray) -> np.ndarray:
+        """The (segment, row) columns of merged rows: a view."""
+        return rows[:, -2:]
+
+    def _fix_oversize_blocks(self, rows: np.ndarray,
+                             batches: Sequence[RecordBatch],
+                             last: bool) -> tuple:
+        """The (segment, row) pairs of merged ``rows`` in the
+        comparator's order: every block of oversize keys with equal key
+        words (module docstring) re-ordered by whole content, the rest
+        as the rows have them. The rows are sorted by (words, length,
+        segment, row) and the sort below is stable, so equal contents
+        keep (segment, row) order. Returns ``(pairs, done)``: a block
+        that reaches the end of ``rows`` may go on in the next slab, so
+        unless these are the ``last`` rows it is left alone and
+        ``done`` is where it starts — ``pairs[:done]`` is final, the
+        caller brings ``rows[done:]`` back in front of the next slab."""
+        kw = rows.shape[1] - merge_ops.ROW_EXTRA_COLS
+        pairs = self._row_pairs(rows)
+        over = np.flatnonzero(rows[:, kw] > self.width)
+        if over.size == 0:
+            return pairs, len(rows)
+        words = rows[over, :kw]
+        first = np.ones(over.size, bool)    # a block starts at this row
+        first[1:] = ((over[1:] != over[:-1] + 1)
+                     | np.any(words[1:] != words[:-1], axis=1))
+        at = np.flatnonzero(first)
+        starts = over[at]
+        stops = np.append(over[at[1:] - 1], over[-1]) + 1
+        done = len(rows)
+        if not last and stops[-1] == done:
+            done = int(starts[-1])
+            starts, stops = starts[:-1], stops[:-1]
+        blocks = [(a, b) for a, b in zip(starts.tolist(), stops.tolist())
+                  if b - a > 1]
+        if blocks:
+            content = self.key_type.content
+            # the slab itself stays as read; a whole-row copy is a
+            # memcpy, where the two strided columns alone cost twice it
+            pairs = self._row_pairs(np.array(rows))
+            for a, b in blocks:
+                member = pairs[a:b].tolist()
+                keys = [content(batches[s].key(r)) for s, r in member]
+                order = sorted(range(b - a), key=keys.__getitem__)
+                pairs[a:b] = [member[i] for i in order]
+            metrics.add("merge.oversize.blocks", len(blocks))
+        return pairs, done
+
+    def _oversize_fixed(self, slabs, batches: Sequence[RecordBatch]):
+        """``emit_stream``'s read-back slabs as (segment, row) pairs
+        with the oversize blocks fixed up; a slab's open trailing block
+        is held back and fixed as one with the slab that closes it."""
+        held = None
+        for rows in slabs:
+            with metrics.timer("oversize_fixup"):
+                if held is not None:
+                    rows = np.concatenate([held, rows])
+                pairs, done = self._fix_oversize_blocks(rows, batches, False)
+                held = rows[done:] if done < len(rows) else None
+            if done:
+                yield pairs[:done]
+        if held is not None:
+            with metrics.timer("oversize_fixup"):
+                pairs, _ = self._fix_oversize_blocks(held, batches, True)
+            yield pairs
+
     def finish(self, batches: Sequence[RecordBatch]) -> RecordBatch:
         """Drain, merge the leftover forest, and materialize the sorted
         batch. ``batches`` must be ALL segments' batches in original
@@ -1305,9 +1448,13 @@ class OverlappedMerger:
             if not self._check_accounting(acc, cat.num_records):
                 return cat  # all segments legitimately empty
             rows = np.asarray(acc.rows)[:acc.valid]
-            kw = rows.shape[1] - 3
-            seg_col = rows[:, kw + 1].astype(np.int64)
-            row_col = rows[:, kw + 2].astype(np.int64)
+            if self._oversize:
+                with metrics.timer("oversize_fixup"):
+                    pairs, _ = self._fix_oversize_blocks(rows, batches, True)
+            else:
+                pairs = self._row_pairs(rows)
+            seg_col = pairs[:, 0].astype(np.int64)
+            row_col = pairs[:, 1].astype(np.int64)
             sizes = np.asarray([b.num_records for b in batches], np.int64)
             offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]])
             perm = offsets[seg_col] + row_col
@@ -1339,16 +1486,18 @@ class OverlappedMerger:
                 return emitter.emit_batch(merged, consumer)
             if not self._check_accounting(acc, total):
                 return emitter.emit_framed(iter([EOF_MARKER]), consumer)
-            kw = int(acc.rows.shape[1]) - 3
             table = stream_mod.segment_table(batches)
+            slabs = stream_mod.iter_row_slabs(acc.rows, acc.valid)
+            slab_pairs = (self._oversize_fixed(slabs, batches)
+                          if self._oversize else map(self._row_pairs, slabs))
 
             def pieces():
                 from uda_tpu import native
 
-                for rows in stream_mod.iter_row_slabs(acc.rows, acc.valid):
+                for pairs in slab_pairs:
                     with metrics.timer("emit_gather"):
                         sub = stream_mod.slab_batch(
-                            batches, rows[:, kw + 1], rows[:, kw + 2], table)
+                            batches, pairs[:, 0], pairs[:, 1], table)
                     with metrics.timer("emit_frame"):
                         piece = native.frame_batch(sub, write_eof=False)
                     yield piece

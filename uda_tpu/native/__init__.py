@@ -619,10 +619,14 @@ def stage_segment_native(batch: RecordBatch, kt, width: int, seg_index: int,
     ``PAD_WORD``. Returns ``(presorted, longest content length,
     key + value bytes)``: whether the segment arrived in (words, len)
     order (if not, the rows were sorted and their row-index column is
-    the stable order vector), the overflow test's operand, and
-    ``stage.bytes``. A key longer than ``width`` is reported, not
-    ranked: the caller leaves the fast path as it always has and the
-    rows are dropped. A key type outside ``_KWAY_MODES`` packs its
+    the stable order vector), the oversize test's operand, and
+    ``stage.bytes``. A key longer than ``width`` is reported
+    (``longest``) and gets its row like any other — its first ``width``
+    bytes as words, its whole content length — so a segment with
+    several of equal words is not in (words, len) order where their
+    lengths fall, and is sorted here; the caller keeps the rows on the
+    forest and restores the comparator's order inside such a block at
+    emit, or takes its fallback (merger/overlap.py). A key type outside ``_KWAY_MODES`` packs its
     serialized bytes, as ``packing.content_spans`` does. A malformed
     key raises MergeError. Returns None when the library isn't
     available."""

@@ -408,9 +408,14 @@ void uda_runs_close(void* h) {
 // sign bit flipped), then content length, segment index and row index —
 // the composite-key row of ops.merge.fill_run_rows — written straight
 // into the row matrix, with the (words, length) order check and the
-// sums staging needs taken on the way. The numpy path (pack_keys,
-// run_row_order, fill_run_rows) is the fallback and the reference this
-// is parity-tested against (tests/test_stage_native.py).
+// sums staging needs taken on the way. A key longer than the carried
+// width gets the same row — its first 4 * key_words bytes, its whole
+// content length — and the caller keeps it: among keys of equal words
+// the row order is then (length, row), which the emit of
+// merger/overlap.py turns into the comparator's order a block at a
+// time. The numpy path (pack_keys, run_row_order, fill_run_rows) is
+// the fallback and the reference this is parity-tested against
+// (tests/test_stage_native.py), oversize keys included.
 
 // Rows not in (words, length) order: sort them whole. The last column
 // is the row index, so rows are totally ordered and the result is the

@@ -301,20 +301,22 @@ def stage_run_rows(rows: np.ndarray, batch: RecordBatch, kt: KeyType,
     ``run_row_order``, ``fill_run_rows``, the fallback and the plain
     reference, same bytes — gives it up ~50 times, and a pool of
     workers staging small segments then spends its time handing the
-    lock around. A segment with a key longer than ``width`` leaves
-    ``rows`` unspecified on either path: such keys order by rank, which
-    a run alone cannot compute."""
+    lock around. A key longer than ``width`` gets a row like any other
+    on either path — its first ``width`` bytes as words, its whole
+    content length — and the rows are sorted by (words, length, row):
+    inside a block of oversize keys with equal words that is not the
+    comparator's order, which the caller restores at emit
+    (merger/overlap.py) or replaces by a fallback; ``presorted`` says
+    nothing about such a block."""
     if native_enabled():
         staged = native.stage_segment_native(batch, kt, width, seg_index,
                                              rows)
         if staged is not None:
             metrics.add("stage.native_segments")
             return staged
-    packed = packing.pack_keys(batch, kt, width)
+    packed = packing.pack_keys(batch, kt, width, ranks=False)
     nbytes = int(batch.key_len.sum() + batch.val_len.sum())
     longest = int(np.max(packed.key_lens, initial=0))
-    if longest > width:
-        return False, longest, nbytes
     order = run_row_order(packed)
     fill_run_rows(rows, packed, order, seg_index)
     return order is None, longest, nbytes

@@ -119,8 +119,13 @@ def _words_to_bytes(words: np.ndarray) -> np.ndarray:
     return raw
 
 
-def pack_keys(batch: RecordBatch, kt: KeyType, width: int) -> PackedKeys:
-    """Pack normalized key prefixes into big-endian uint32 lane columns."""
+def pack_keys(batch: RecordBatch, kt: KeyType, width: int,
+              ranks: bool = True) -> PackedKeys:
+    """Pack normalized key prefixes into big-endian uint32 lane columns.
+    ``ranks=False`` leaves the rank column zero and the oversize keys
+    uncounted: a staged run's rows carry no rank (the run forest orders
+    oversize keys by (prefix, length) and fixes their blocks up at emit,
+    merger/overlap.py), and its caller counts the keys itself."""
     if width % 4 != 0 or width <= 0:
         raise MergeError(f"key width must be a positive multiple of 4, got {width}")
     n = batch.num_records
@@ -132,9 +137,11 @@ def pack_keys(batch: RecordBatch, kt: KeyType, width: int) -> PackedKeys:
     if kt.name in ("int_numeric", "long_numeric"):
         raw[:, 0] ^= 0x80  # sign-bit flip: memcmp order == numeric order
     words = _bytes_to_words(raw)
+    if not ranks:
+        return PackedKeys(words, ln.astype(np.int32), np.zeros(n, np.int32))
     with metrics.timer("overflow_rank"):
-        ranks = overflow_ranks(batch, raw, off, ln, width)
-    return PackedKeys(words, ln.astype(np.int32), ranks)
+        rank_col = overflow_ranks(batch, raw, off, ln, width)
+    return PackedKeys(words, ln.astype(np.int32), rank_col)
 
 
 def overflow_ranks(batch: RecordBatch, prefixes: np.ndarray,

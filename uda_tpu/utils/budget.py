@@ -71,7 +71,7 @@ __all__ = ["MemoryBudget", "Admission", "HbmLedger", "HbmHold",
            "merge_temp_bytes_estimate", "group_capacity_rows",
            "stage_inflight_cap", "ROW_OVERHEAD_WORDS",
            "HBM_ROW_ALIGN_WORDS", "RUN_PAD_FACTOR", "FOREST_FACTOR",
-           "MERGE_TEMP_ROW_BYTES",
+           "MERGE_TEMP_ROW_BYTES", "RECORD_BYTES_DEFAULT",
            "WORKING_SET_FACTOR", "HBM_RESERVE_FRACTION",
            "PLATFORM_HBM_MB", "STAGE_INFLIGHT_FLOOR_MB"]
 
@@ -157,6 +157,20 @@ def stage_inflight_cap(cfg, window: int, chunk_size: int,
 # padded to a power of two and so is every merge's output, so the
 # largest merge of a task writes the power of two at or above the sum
 # of its runs' capacities (merge_temp_bytes_estimate).
+#
+# The model is told bytes, not records: it takes RECORD_BYTES_DEFAULT
+# a record, TeraSort's, and a partition of smaller records holds that
+# many more rows than it books — a 20-byte posting five times. So the
+# booking is corrected as soon as somebody knows: staging tells the
+# ledger the framed bytes a record it has seen
+# (OverlappedMerger._observe_records -> MemoryBudget.rebook_device),
+# and a hold sized for fewer rows GROWS to the model's figure for the
+# observed size — grow-only, once or twice a task, never waiting (the
+# rows are on their way whatever the books say, and two tasks that
+# both waited to grow could wait for each other); the tasks that ask
+# after it see the true figure. A task of 100-byte records or larger
+# never grows. What this cannot do is re-route: a text partition the
+# chip cannot hold whole was admitted whole (PERF.md §7).
 ROW_OVERHEAD_WORDS = 3        # length, segment index, row index columns
 HBM_ROW_ALIGN_WORDS = 8       # a row's columns as the device stores them
 RUN_PAD_FACTOR = 2.0          # power-of-two run capacity, at worst
@@ -244,7 +258,7 @@ def _pow2_at_least(n: int) -> int:
 
 
 def device_bytes_estimate(partition_bytes: int, key_width: int,
-                          record_bytes: int = RECORD_BYTES_DEFAULT) -> int:
+                          record_bytes: float = RECORD_BYTES_DEFAULT) -> int:
     """Device-resident bytes of ROWS the merge would hold for a
     partition of ``partition_bytes`` on-disk bytes: the larger of the
     run forest and the sort ladder (see the model above) — what
@@ -255,7 +269,7 @@ def device_bytes_estimate(partition_bytes: int, key_width: int,
     (:func:`merge_temp_bytes_estimate`)."""
     if partition_bytes <= 0:
         return 0
-    records = max(1, partition_bytes // max(1, record_bytes))
+    records = max(1, int(partition_bytes // max(1, record_bytes)))
     forest = (records * _row_bytes(key_width) * RUN_PAD_FACTOR
               * FOREST_FACTOR)
     ladder = partition_bytes * SORT_LADDER_RATIO * WORKING_SET_FACTOR
@@ -264,7 +278,7 @@ def device_bytes_estimate(partition_bytes: int, key_width: int,
 
 def merge_temp_bytes_estimate(partition_bytes: int,
                               segments: Optional[int] = None,
-                              record_bytes: int = RECORD_BYTES_DEFAULT
+                              record_bytes: float = RECORD_BYTES_DEFAULT
                               ) -> int:
     """Temporaries of the largest merge program a task of
     ``partition_bytes`` in ``segments`` equal runs dispatches (see the
@@ -274,7 +288,7 @@ def merge_temp_bytes_estimate(partition_bytes: int,
     are taken at their worst padding (``RUN_PAD_FACTOR``)."""
     if partition_bytes <= 0:
         return 0
-    records = max(1, partition_bytes // max(1, record_bytes))
+    records = max(1, int(partition_bytes // max(1, record_bytes)))
     if segments and segments > 0:
         capacity = segments * _pow2_at_least(-(-records // segments))
     else:
@@ -314,9 +328,18 @@ class HbmHold:
         self.temp_bytes = temp_bytes      # merge temporaries: the max
 
     def release(self) -> None:
-        ledger, self._ledger = self._ledger, None
+        ledger = self._ledger
         if ledger is not None:
-            ledger._release(self.nbytes, self.temp_bytes)
+            ledger._release(self)
+
+    def grow(self, nbytes: int, temp_bytes: int) -> bool:
+        """Raise the reservation to ``nbytes`` of rows and
+        ``temp_bytes`` of merge temporaries where it holds less: what
+        the task turned out to need once its records were seen
+        (:meth:`MemoryBudget.rebook_device`). Grow-only and never
+        waits; False when nothing grew or the hold is released."""
+        ledger = self._ledger
+        return ledger is not None and ledger._grow(self, nbytes, temp_bytes)
 
     def __enter__(self) -> "HbmHold":
         return self
@@ -426,11 +449,40 @@ class HbmLedger:
             "budget.hbm.reserved", grew)
         return HbmHold(self, nbytes, temp_bytes)
 
-    def _release(self, nbytes: int, temp_bytes: int) -> None:
+    def _grow(self, hold: HbmHold, nbytes: int, temp_bytes: int) -> bool:
+        """Book a live task's larger need at once (HbmHold.grow): the
+        rows are on their way to the chip whatever the books say, and
+        a wait here could deadlock two tasks that both grow. What is
+        booked may pass the budget; the tasks that ask after it wait
+        for releases as they always do."""
         with self._cv:
+            if hold._ledger is not self:
+                return False        # released meanwhile (_release)
+            nbytes = max(hold.nbytes, int(nbytes))
+            temp_bytes = max(hold.temp_bytes, int(temp_bytes))
+            if (nbytes, temp_bytes) == (hold.nbytes, hold.temp_bytes):
+                return False
             before = self._booked()
-            self._reserved -= nbytes
-            self._temps.remove(temp_bytes)
+            self._reserved += nbytes - hold.nbytes
+            self._temps.remove(hold.temp_bytes)
+            self._temps.append(temp_bytes)
+            hold.nbytes, hold.temp_bytes = nbytes, temp_bytes
+            grew = self._booked() - before
+        metrics.add("budget.hbm.rebooked")
+        # rides the hold like reserve()'s: its _release() takes it back
+        metrics.gauge_add(  # udalint: disable=UDA101
+            "budget.hbm.reserved", grew)
+        return True
+
+    def _release(self, hold: HbmHold) -> None:
+        with self._cv:
+            if hold._ledger is not self:
+                return              # released already
+            # under the lock: a grow() in flight lands before or not at all
+            hold._ledger = None
+            before = self._booked()
+            self._reserved -= hold.nbytes
+            self._temps.remove(hold.temp_bytes)
             self._holders -= 1
             shrank = before - self._booked()
             self._cv.notify_all()
@@ -547,12 +599,15 @@ class MemoryBudget:
         return device_bytes_estimate(partition_bytes, self.key_width)
 
     def device_need(self, partition_bytes: int,
-                    segments: Optional[int] = None) -> tuple:
+                    segments: Optional[int] = None,
+                    record_bytes: float = RECORD_BYTES_DEFAULT) -> tuple:
         """``(rows, temporaries)`` bytes the chip must have free to
         hold the task whole: its rows and the temporaries of its
         largest merge program."""
-        return (self.device_bytes(partition_bytes),
-                merge_temp_bytes_estimate(partition_bytes, segments))
+        return (device_bytes_estimate(partition_bytes, self.key_width,
+                                      record_bytes),
+                merge_temp_bytes_estimate(partition_bytes, segments,
+                                          record_bytes))
 
     def group_reservation(self) -> tuple:
         """``(group_rows, rows, temporaries)``: the largest device group
@@ -735,6 +790,25 @@ class MemoryBudget:
                 self._record(reroute, "budget.rerouted")
             dev, temps = group_bytes, group_temps
         return hbm_ledger.reserve(dev, hbm, stopped, temps), reroute
+
+    def rebook_device(self, hold: HbmHold, estimate_bytes: int,
+                      segments: Optional[int], record_bytes: float) -> bool:
+        """Staging has seen the task's records: ``record_bytes`` framed
+        bytes each, where :meth:`admit_device` reckoned with
+        ``RECORD_BYTES_DEFAULT``. Grow ``hold`` to the device need of
+        that many more rows (the model's, for the observed record
+        size); a task of larger records keeps what it has. Never
+        waits, and never re-routes: a task admitted whole whose rows
+        turn out not to fit the chip beside the live ones is beyond
+        this (PERF.md §7). Counted in ``budget.hbm.rebooked``."""
+        grew = hold.grow(*self.device_need(estimate_bytes, segments,
+                                           record_bytes))
+        if grew:
+            log.info(f"HBM ledger: records of {record_bytes:.1f} B, not "
+                     f"{RECORD_BYTES_DEFAULT}: the task's reservation "
+                     f"grows to {hold.nbytes} B of rows and "
+                     f"{hold.temp_bytes} B of merge temporaries")
+        return grew
 
     # -- bookkeeping --------------------------------------------------------
 

@@ -93,6 +93,9 @@ SPAN_BUCKETS: Dict[str, str] = {
     # consumer up-call (the reference's trio has no emit term)
     "emit": "emit", "emit_readback": "emit", "emit_gather": "emit",
     "emit_frame": "emit", "emit_deliver": "emit",
+    # a text task's oversize blocks re-ordered between a slab's
+    # read-back and its gather (merger/overlap.py)
+    "oversize_fixup": "emit",
     # serve: supplier-side reads
     "net.serve": "serve", "engine.pread": "serve",
     "engine.read_batch": "serve", "supplier_read": "serve",

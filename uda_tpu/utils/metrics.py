@@ -175,6 +175,14 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                    "window)"),
     "budget.rejected": ("counter", "tasks refused before allocation "
                                    "(hard ceiling / unfittable INIT)"),
+    "budget.hbm.rebooked": ("counter", "reservations of the chip-wide "
+                                       "HBM ledger grown after "
+                                       "admission: staging saw records "
+                                       "smaller than the model's 100 "
+                                       "bytes, so more rows than were "
+                                       "booked (utils/budget.py "
+                                       "MemoryBudget.rebook_device; "
+                                       "grow-only, never waits)"),
     "budget.waited": ("counter", "reduce tasks that had to wait for the "
                                  "chip-wide HBM ledger because the live "
                                  "tasks' reservations left no room "
@@ -238,24 +246,35 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                        "(merger/overlap.py:_flush_group); "
                                        "0 for a task the chip holds whole"),
     "merge.overflow.fallbacks": ("counter", "tasks whose merge left "
-                                            "the run forest because a "
-                                            "key was longer than the "
-                                            "carried width: the global "
-                                            "re-sort (ops.merge."
-                                            "merge_batches, timer "
+                                            "the run forest because of "
+                                            "a key longer than the "
+                                            "carried width — a key type "
+                                            "with a compare of its own, "
+                                            "or the streaming route: "
+                                            "the global re-sort (ops."
+                                            "merge.merge_batches, timer "
                                             "overflow_resort) or, "
                                             "streaming, the k-way merge "
                                             "over run files (merger/"
-                                            "overlap.py)"),
+                                            "overlap.py); 0 for a task "
+                                            "whose oversize keys stay "
+                                            "on the forest"),
     "merge.overflow.keys": ("counter", "keys whose content exceeds the "
-                                       "carried width, counted where "
-                                       "they are ranked (ops/packing.py "
-                                       "overflow_ranks): once a task, "
-                                       "over the whole partition, in "
-                                       "the global re-sort; the numpy "
-                                       "staging passes (no native "
-                                       "library) also rank the segment "
-                                       "that latched the fallback"),
+                                       "carried width: counted a "
+                                       "segment at a time as they are "
+                                       "staged (merger/overlap.py:"
+                                       "_keep_oversize) or, by a task "
+                                       "that takes the global re-sort, "
+                                       "once over the whole partition "
+                                       "where they are ranked (ops/"
+                                       "packing.py overflow_ranks)"),
+    "merge.oversize.blocks": ("counter", "blocks of two or more oversize "
+                                         "keys with equal carried words "
+                                         "that the emit re-ordered by "
+                                         "whole content (merger/overlap."
+                                         "py:_fix_oversize_blocks, timer "
+                                         "oversize_fixup); 0 for a task "
+                                         "without oversize keys"),
     "spool.bytes": ("counter", "bytes spooled to sorted run files "
                                "(streaming online mode)"),
     # -- counters: staging pipeline (merger/overlap stage pool) ----------
