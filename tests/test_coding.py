@@ -693,7 +693,7 @@ def test_completion_warm_restart_resumes_from_offset_ledger(tmp_path):
             srv2.stop()
         router.stop()
         eng.stop()
-    assert seg.num_records == len(expected[0])
+    assert seg.record_batch().num_records == len(expected[0])
     assert metrics.get("fetch.resumed") >= 1
     assert metrics.get("fetch.resumed.bytes") > 0  # bytes NOT refetched
     assert metrics.get("net.handoff.persisted") >= 1
@@ -728,7 +728,7 @@ def test_remote_pread_error_resumes_mid_partition(tmp_path):
         srv.stop()
         router.stop()
         eng.stop()
-    assert seg.num_records == len(expected[0])
+    assert seg.record_batch().num_records == len(expected[0])
     assert metrics.get("fetch.resumed") >= 1
     assert metrics.get("fetch.resumed.bytes") > 0  # ground held
 

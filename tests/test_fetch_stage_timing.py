@@ -178,6 +178,21 @@ def _remote_seconds() -> float:
     return h["sum"] / 1e3
 
 
+def test_one_chunk_segments_are_cracked_on_the_upcall_thread(supplier):
+    """A segment that arrives whole in its first chunk is cracked where
+    it lands: a crack a chunk, inside the upcall thread's busy seconds,
+    none deferred."""
+    metrics.enable_stats()
+    assert _shuffle(supplier, {"mapred.rdma.buf.size": 1024})
+    chunks = metrics.get("fetch.chunks")
+    assert chunks == 3              # one chunk a segment
+    assert metrics.get("fetch.crack.deferred_segments") == 0
+    assert 0.0 < metrics.get("fetch_crack_time") \
+        <= metrics.get("net.dispatch.busy_seconds", loop=CLIENT_LOOP)
+    cracks = [s for s in metrics.spans if s["name"] == "fetch_crack"]
+    assert len(cracks) == chunks
+
+
 def test_spans_on_every_chunk_is_timed_and_the_stages_add_up(supplier):
     metrics.enable_stats()          # histograms + spans
     assert _shuffle(supplier)
@@ -199,8 +214,9 @@ def test_spans_on_every_chunk_is_timed_and_the_stages_add_up(supplier):
         < stages["fetch.chunk.serve_seconds"] + 1e-6 * (chunks + 1)
     assert metrics.get("fetch_crack_time") > 0.0
     assert metrics.get("net.dispatch.upcalls", loop=CLIENT_LOOP) >= chunks
-    assert metrics.get("fetch_crack_time") \
-        <= metrics.get("net.dispatch.busy_seconds", loop=CLIENT_LOOP)
+    # several chunks a segment: every crack was deferred to the thread
+    # that materialized the segment, one crack a segment
+    assert metrics.get("fetch.crack.deferred_segments") == 3
 
     # the spans: all in the task's trace, under the right parents
     spans = list(metrics.spans)
@@ -218,7 +234,7 @@ def test_spans_on_every_chunk_is_timed_and_the_stages_add_up(supplier):
     assert {s["parent"] for s in waits} <= fetch_ids
     segment_ids = {s["id"] for s in by_name["fetch.segment"]}
     cracks = by_name["fetch_crack"]
-    assert len(cracks) == chunks
+    assert len(cracks) == 3
     assert {s["parent"] for s in cracks} <= segment_ids
     assert {s["trace"] for s in waits + cracks} == {root["trace"]}
 

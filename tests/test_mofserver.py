@@ -187,7 +187,7 @@ def test_chained_fetches_under_delay_failpoint_no_deadlock(tmp_path):
     finally:
         engine.stop()
     assert all(s.ready for s in out["segs"])
-    assert sum(s.num_records for s in out["segs"]) == 240
+    assert sum(s.record_batch().num_records for s in out["segs"]) == 240
 
 
 @pytest.mark.faults

@@ -1164,9 +1164,9 @@ class MergeManager:
             # up, but re-persisting keeps the walk short)
             ckpt.maybe_save(collect, force=True)
         try:
-            # feed the Segment itself: record_batch() (a full concat of
-            # the segment's chunks) then runs on the merge thread, not
-            # on the transport's completion thread
+            # feed the Segment itself: record_batch() (the join and the
+            # one crack of a deferred segment's chunks) then runs on a
+            # stage worker, not on the transport's completion thread
             segments = self.fetch_all(job_id, map_ids, reduce_id,
                                       on_segment=om.feed,
                                       skip=adopted, preload=preload)
@@ -1190,17 +1190,30 @@ class MergeManager:
         if streaming:
             out = om.finish_streaming(
                 self.emitter, consumer,
-                expected_records=(sum(s.num_records for s in segments
-                                      if s is not None)
-                                  + adopted_records))
+                # asked after the drain: a deferred segment counts its
+                # records where a stage worker cracks it, and one that
+                # no worker was handed is cracked for the count
+                expected_records=lambda: (
+                    sum(s.fetched_records() for s in segments
+                        if s is not None)
+                    + adopted_records))
             if ckpt is not None:
                 # the emitted output is the durable artifact now; a
                 # retained checkpoint would resume a FINISHED task
                 ckpt.discard()
                 self._ckpt = None
             return out
-        return om.emit_stream([s.record_batch() for s in segments],
-                              self.emitter, consumer)
+        try:
+            return om.emit_stream(segments, self.emitter, consumer)
+        finally:
+            # the stream is out (or the task failed): drop the
+            # partition's bytes now. A finished task's segments sit in
+            # reference cycles (their callbacks), full collections are
+            # rare in a process this size, and what lingers until one —
+            # a partition a task, more tasks a minute now that they are
+            # shorter — is memory the node's live tasks are refused
+            for seg in segments:
+                seg.release()
 
     def stop(self) -> None:
         self._stop.set()

@@ -121,7 +121,7 @@ import queue
 import threading
 import time
 from collections import deque
-from typing import Optional, Sequence
+from typing import Callable, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -392,6 +392,7 @@ class OverlappedMerger:
         metrics.add("merge.overflow.fallbacks", 0)
         metrics.add("merge.overflow.keys", 0)
         metrics.add("merge.oversize.blocks", 0)
+        metrics.add("fetch.crack.deferred_segments", 0)
         for timer in ("merge_host_batch", "merge_group_flush",
                       "merge_group_join", "run_spool", "fetch_crack",
                       "fetch_feed_wait", "overflow_resort",
@@ -455,7 +456,10 @@ class OverlappedMerger:
         """Stage one completed segment's records (safe to call from a
         transport completion thread). ``source`` is either a RecordBatch
         or an object with a ``record_batch()`` method (a Segment) —
-        materialization happens on a stage thread. This call BLOCKS when
+        materialization happens on a stage thread, and for a Segment
+        whose crack was deferred that is where its bytes are cracked
+        (Segment.record_batch), so a corrupt stream fails the task from
+        there, through ``_error`` at finish(). This call BLOCKS when
         staging lags — on the bounded queue (streaming mode) and on the
         in-flight bytes budget (``uda.tpu.stage.inflight.mb``) — which
         is the intended backpressure: the transport thread holds off
@@ -759,17 +763,25 @@ class OverlappedMerger:
         if lease is not None:
             self._buf_pool.release(lease)
 
+    @staticmethod
+    def _batch_of(source) -> RecordBatch:
+        """A fed source's records: itself, or a Segment's
+        ``record_batch()`` (whose first call cracks a deferred
+        segment, on this thread)."""
+        return (source if isinstance(source, RecordBatch)
+                else source.record_batch())
+
     def _prepare(self, seg_index: int, source,
                  fed_t: float) -> Optional[_StagedRun]:
-        """The host half of staging: materialize (the decompress tail
-        runs here for Segment sources), pack, per-run sort, spool.
+        """The host half of staging: materialize (a Segment whose
+        crack was deferred joins and cracks its chunks here, under its
+        own ``fetch_crack`` timer), pack, per-run sort, spool.
         Returns the device-bound staged run, or None when nothing needs
         the forest (empty segment, spool-only modes, overflow)."""
         streaming = self.run_store is not None
         if self._overflow and not streaming:
             return None  # fast path already disabled; finish() re-sorts
-        batch = (source if isinstance(source, RecordBatch)
-                 else source.record_batch())
+        batch = self._batch_of(source)
         n = batch.num_records
         if n == 0:
             if streaming:
@@ -1462,20 +1474,25 @@ class OverlappedMerger:
         finally:
             self._finish_cleanup(acc)
 
-    def emit_stream(self, batches: Sequence[RecordBatch], emitter,
-                    consumer) -> int:
+    def emit_stream(self, sources: Sequence, emitter, consumer) -> int:
         """In-memory streaming emission: the same result bytes as
         ``emitter.emit_batch(self.finish(batches))`` but without ever
         concatenating the shuffle — each output slab's bytes are
         gathered straight from the per-segment batches and framed
         natively, so transient host memory is one slab (the reference's
-        staging-loop memory model over memory-resident segments)."""
+        staging-loop memory model over memory-resident segments).
+        ``sources`` are what was fed, in segment-index order: batches,
+        or Segments — asked for their batch only once staging has
+        drained, so that the stage workers crack the deferred ones and
+        a corrupt one fails the task through ``_error``, not from this
+        thread with the workers still live."""
         from uda_tpu.merger import streaming as stream_mod
 
         acc = None
         try:
             with metrics.timer("merge"):
                 self._drain()
+                batches = [self._batch_of(s) for s in sources]
                 merged = None
                 if self._overflow:
                     merged = self._overflow_resort(batches)
@@ -1510,12 +1527,16 @@ class OverlappedMerger:
             self._finish_cleanup(acc)
 
     def finish_streaming(self, emitter, consumer,
-                         expected_records: Optional[int] = None) -> int:
+                         expected_records: Union[int, Callable[[], int],
+                                                 None] = None) -> int:
         """Streaming-mode finish: drain staging, then emit the merged
         stream straight from the sorted run files — the permutation-
         driven k-way interleave (uda_tpu.merger.streaming). Host memory
         is one slab + one read buffer per run; no shuffle-sized
-        allocation exists on this path. Cleans up the run store."""
+        allocation exists on this path. Cleans up the run store.
+        ``expected_records`` is the count the runs must add up to, or a
+        callable that gives it, called once staging has drained (a
+        Segment whose crack was deferred knows its records only then)."""
         from uda_tpu import native
         from uda_tpu.merger import streaming as stream_mod
         from uda_tpu.utils.ifile import iter_file_records, native_enabled
@@ -1534,6 +1555,8 @@ class OverlappedMerger:
                     acc = (self._join_groups() if self._group_rows
                            else self._merge_leftovers())
             total = store.total_records
+            if callable(expected_records):
+                expected_records = expected_records()
             if expected_records is not None and total != expected_records:
                 raise MergeError(
                     f"staged {total} of {expected_records} records")

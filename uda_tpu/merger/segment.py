@@ -7,7 +7,11 @@ chunk boundaries. The reference does this with double-buffered RDMA
 fetches and a cond-wait ``switch_mem`` that ``join``s the split record
 into ``temp_kv`` (StreamRW.cc:462-590); here the same contract is a
 *carry buffer*: each chunk is columnar-cracked up to its last complete
-record and the partial tail is prepended to the next chunk.
+record and the partial tail is prepended to the next chunk. A segment
+whose first chunk is not its last skips the per-chunk work: its chunks
+are kept as they come and the partition is cracked once, whole, by
+whoever first materializes it (``Segment.record_batch``: a stage worker
+of the overlapped merger).
 
 ``InputClient`` is the transport abstraction of reference
 src/Merger/InputClient.h:30-56 (``start_fetch_req``/``comp_fetch_req``):
@@ -24,12 +28,14 @@ import time
 import zlib
 from typing import Optional
 
+import numpy as np
+
 from uda_tpu.mofserver.data_engine import DataEngine, FetchResult, ShuffleRequest
 from uda_tpu.utils.errors import (MergeError, StorageError, TenantError,
                                   TransportError, attribute_supplier)
 from uda_tpu.utils.failpoints import failpoint
 from uda_tpu.utils.flightrec import flightrec
-from uda_tpu.utils.ifile import RecordBatch, crack_partial
+from uda_tpu.utils.ifile import RecordBatch, crack, crack_partial
 from uda_tpu.utils.locks import TrackedLock
 from uda_tpu.utils.logging import get_logger
 from uda_tpu.utils.metrics import metrics
@@ -455,9 +461,14 @@ class Segment:
 
     Drives ``chunk_size``-byte fetches at increasing offsets until
     ``raw_length`` bytes have arrived (the reference's send_request /
-    switch_mem loop, StreamRW.cc:462-590). Completed chunks are cracked
-    into RecordBatches immediately so bytes can be packed/shipped to
-    device while later chunks are still in flight.
+    switch_mem loop, StreamRW.cc:462-590). A segment that arrives whole
+    in its first chunk is cracked where it lands, on the thread that
+    delivered it. One that does not is *deferred*: the completion thread
+    (the process's one upcall thread, shared by every live task) only
+    keeps each chunk, and ``record_batch()`` joins and cracks them once
+    — one copy and one native call where carry + per-chunk crack +
+    concat were two copies and a call a chunk. The first chunk decides,
+    by what it is: a one-chunk segment has nothing to save.
 
     Survivable-shuffle ladder (ISSUE 8; every rung shares the task's
     :class:`~uda_tpu.merger.recovery.RecoveryLedger`):
@@ -474,7 +485,8 @@ class Segment:
     - **resume** (``uda.tpu.fetch.resume``): a transport-level retry
       against a resumable source (InputClient.resume_ok — warm
       supplier restart, immutable MOFs) keeps the offset ledger
-      (batches + carry + next offset) and continues mid-partition
+      (batches + carry, or the kept chunks, + next offset) and
+      continues mid-partition
       instead of refetching from zero; the first resumed chunk's
       ``raw_length`` must match the pre-fault identity or the segment
       falls back to a full restart;
@@ -512,7 +524,10 @@ class Segment:
         self.resume_enabled = bool(resume)
         self.stripe = stripe  # StripeContext when k-of-n coding is on
         self.batches: list[RecordBatch] = []
-        self.num_records = 0  # monotone fetch-side record count
+        # records cracked so far: monotone as chunks land on the eager
+        # path; 0 on a deferred segment until record_batch() (or
+        # fetched_records()) has cracked it
+        self.num_records = 0
         self.raw_length: Optional[int] = None
         self.on_done = None  # callback fired once when fetch finishes
         self.on_fault = None  # callback fired on EVERY transport fault
@@ -522,6 +537,9 @@ class Segment:
         self._issue_t0 = 0.0
         self._released = False
         self._carry = b""
+        # deferred crack: the fetched chunks as they came, cracked once
+        # by record_batch(); None while the segment cracks eagerly
+        self._raw: Optional[list] = None
         self._next_offset = 0
         self._retries_left = max(0, self.policy.retries)
         self._deadline: Optional[float] = None
@@ -983,6 +1001,7 @@ class Segment:
                         self.batches = []
                         self.num_records = 0
                         self._carry = b""
+                        self._raw = None
                         self._next_offset = 0
                         self._crc_refetched.clear()
                         self._resume_check = False
@@ -1111,7 +1130,13 @@ class Segment:
         # with spans off two stamps, and its counter rides the chunk's
         # one locked update below
         with self._lock:
-            if metrics.record_spans:
+            if self._raw is not None or not (res.is_last
+                                             or self._next_offset):
+                # the first chunk is not the last, or the segment is
+                # deferred already: keep the bytes, crack nothing here
+                crack_s = None
+                last = self._keep(res)
+            elif metrics.record_spans:
                 crack_s = None
                 with metrics.use_span(self.trace_span), \
                         metrics.timer("fetch_crack"):
@@ -1137,6 +1162,17 @@ class Segment:
                             supplier=self.supplier, **tenant)
             metrics.observe("fetch.chunk.bytes", nbytes, **tenant)
         return last
+
+    def _keep(self, res: FetchResult) -> bool:
+        """self._lock held: keep a deferred segment's chunk as it came
+        (no copy, no crack); record_batch() cracks the lot."""
+        if self._raw is None:
+            self._raw = []
+        self.raw_length = res.raw_length
+        if len(res.data):
+            self._raw.append(res.data)
+        self._next_offset = res.offset + len(res.data)
+        return res.is_last
 
     def _absorb(self, res: FetchResult) -> bool:
         """self._lock held: crack the chunk onto the carried tail."""
@@ -1173,6 +1209,7 @@ class Segment:
             self.batches = []
             self.num_records = 0
             self._carry = b""
+            self._raw = None
             self._next_offset = 0
             self._resume_check = False
             self._issue_t0 = time.perf_counter()
@@ -1273,26 +1310,63 @@ class Segment:
 
     def record_batch(self) -> RecordBatch:
         """All records of the partition as one batch (fetch must be
-        done). The concat is cached: callers on different threads (the
+        done). A deferred segment is cracked here, by the first caller
+        (a stage worker of the overlapped merger; the merge thread on
+        the serial routes), and corrupt framing raises its StorageError
+        here. The batch is cached: callers on different threads (the
         overlap staging thread, then the finish pass) pay for it once."""
         self.wait()
         with self._lock:
             if self._released:
                 raise MergeError(
                     f"segment {self.map_id} bytes were released "
-                    f"(streaming mode spooled them to a sorted run)")
+                    f"(spooled to a sorted run, or the stream is out)")
+            if self._raw is not None:
+                self._crack_kept()
             if len(self.batches) == 1:
                 return self.batches[0]
             cat = RecordBatch.concat(self.batches)
             self.batches = [cat]
             return cat
 
+    def _crack_kept(self) -> None:
+        """self._lock held: the deferred crack — join the kept chunks
+        (the one copy) and crack the partition in one call. The
+        fetch_crack timer, under the segment's own span (ended already,
+        still the parent: a stage worker's ambient span must not adopt
+        it) as the eager crack is. The chunks go only once the batch
+        stands, so a corrupt stream raises for every caller."""
+        with metrics.use_span(self.trace_span), \
+                metrics.timer("fetch_crack"):
+            views = [np.frombuffer(c, np.uint8) for c in self._raw]
+            # no bytes at all: the legitimately empty partition of
+            # _absorb, no records and no EOF marker
+            batch = crack(np.concatenate(views)) if views else None
+        if batch is not None and batch.num_records:
+            self.batches = [batch]
+            self.num_records = batch.num_records
+        self._raw = None
+        metrics.add("fetch.crack.deferred_segments")
+
+    def fetched_records(self) -> int:
+        """The fetch side's count of a finished segment's records, for
+        a caller that holds what was staged to what was fetched. A
+        deferred segment that nobody materialized is cracked for it, so
+        that fetched and never staged reads as records missing, not as
+        none fetched."""
+        with self._lock:
+            if self._raw is not None:
+                self._crack_kept()
+            return self.num_records
+
     def release(self) -> None:
         """Drop the fetched bytes (streaming online mode: the sorted run
-        file is now the source of truth; ``num_records`` survives for
-        accounting). record_batch() raises after this."""
+        file is now the source of truth; the in-memory route: the
+        stream is out; ``num_records`` survives for accounting).
+        record_batch() raises after this."""
         with self._lock:
             self.batches = []
+            self._raw = None
             self._released = True
 
     # -- checkpoint (merger/checkpoint.py) ----------------------------------
@@ -1306,20 +1380,29 @@ class Segment:
         has fetched nothing yet (a fresh fetch costs the same).
 
         Crash-consistent by construction: state is copied under the
-        segment lock (batches are immutable once appended and
-        ``_next_offset`` advances in the same critical section as the
-        append, so the copy is internally consistent); the re-framing
-        runs outside the lock."""
+        segment lock (batches and kept chunks are immutable once
+        appended and ``_next_offset`` advances in the same critical
+        section as the append, so the copy is internally consistent);
+        the re-framing runs outside the lock. A deferred segment cracks
+        what it holds here, to find where its last whole record ends:
+        its bytes are the framed records and the carry tail already."""
         with self._lock:
             if self._done.is_set() or self._released \
                     or self._next_offset <= 0:
                 return None
             batches = list(self.batches)
             carry = self._carry
+            raw = None if self._raw is None else list(self._raw)
             state = {"next_offset": self._next_offset,
                      "raw_length": self.raw_length,
                      "num_records": self.num_records,
                      "carry_len": len(carry)}
+        if raw is not None:
+            data = b"".join(raw)
+            batch, consumed, _ = crack_partial(data, expect_eof=False)
+            state.update(num_records=batch.num_records,
+                         carry_len=len(data) - consumed, data=data)
+            return state
         from uda_tpu import native
 
         framed = b"".join(native.frame_batch(b, write_eof=False)

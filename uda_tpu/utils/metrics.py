@@ -119,6 +119,16 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                           "in the dispatch queue for "
                                           "the process's one upcall "
                                           "thread"),
+    "fetch.crack.deferred_segments": ("counter", "segments whose crack "
+                                      "was deferred: the first chunk was "
+                                      "not the last, so the chunks were "
+                                      "kept as they came and joined and "
+                                      "cracked once by the thread that "
+                                      "materialized the segment (merger/"
+                                      "segment.py:Segment.record_batch, "
+                                      "timer fetch_crack); 0 for a task "
+                                      "whose segments each arrive in "
+                                      "one chunk"),
     # -- counters: survivable shuffle (speculation / resume / coding) ----
     "fetch.speculated": ("counter", "straggler chunks that got a "
                                     "speculative duplicate fetch "
