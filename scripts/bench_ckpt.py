@@ -145,7 +145,7 @@ def _spool_once(batches, tmp: str, ckpt_on: bool,
         store = RunStore([tmp], tag="ckbenchAB_off")
         on_spool = None
     om = OverlappedMerger(kt, 16, engine="host", run_store=store,
-                          pipeline=True, on_spool=on_spool)
+                          on_spool=on_spool)
     total = sum(b.num_records for b in batches)
     sink = {"n": 0}
     t0 = time.monotonic()

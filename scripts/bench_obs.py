@@ -102,8 +102,7 @@ def _spool_once(batches, tmp: str, armed: bool, interval: float) -> dict:
 
         timeseries.sample = timed_sample  # instance attr, dropped below
     store = RunStore([tmp], tag=f"obsbench_{'on' if armed else 'off'}")
-    om = OverlappedMerger(kt, 16, engine="host", run_store=store,
-                          pipeline=True)
+    om = OverlappedMerger(kt, 16, engine="host", run_store=store)
     total = sum(b.num_records for b in batches)
     sink = {"n": 0}
     t0 = time.monotonic()

@@ -168,32 +168,14 @@ echo "-- hierarchical + coded exchange bench (quick)" | tee -a "$ART/ci.log"
 python scripts/exchange_bench.py --quick \
   --out "$ART/exchange_bench.json" 2>&1 | tee -a "$ART/ci.log" | tail -5
 
-# Staging-pipeline gate, quick mode: the pipelined stage pool must be
-# BYTE-IDENTICAL to the serial staging twin across sorted/shuffled
-# input, spool mode and a compressed end-to-end run (exit 3 on any
-# divergence), and the time-accounting point must partition the task
-# wall (buckets + idle == wall within 5%). Runs under UDA_TPU_STATS=1
-# (the span layer critpath needs) + UDA_TPU_PROFILE (the sampling
-# profiler rides the same run — its overhead is inside the reported
-# numbers, which is the honest configuration perfwatch trends).
-# Throughput is reported, not gated, in quick mode — the 64x64 MB
-# speedup gate rides the full run's BENCH_PIPELINE_r*.json.
-echo "-- staging pipeline A/B + time accounting (quick)" | tee -a "$ART/ci.log"
-env JAX_PLATFORMS=cpu \
-  UDA_TPU_STATS=1 UDA_TPU_PROFILE=47 \
-  python scripts/bench_pipeline.py --quick \
-  --out "$ART/bench_pipeline.json" 2>&1 | tee -a "$ART/ci.log" | tail -2
-
 # perfwatch gate: the fresh quick point (throughput trends +
-# correctness booleans + the time-accounting block) against the
-# committed PERF_TRAJECTORY.json. The band is generous — shared CI
+# correctness booleans) against the committed
+# PERF_TRAJECTORY.json. The band is generous — shared CI
 # hosts gate direction-of-change, not absolute MB/s; quick-mode
 # throughputs are recorded as trend data and the hard gates are the
 # correctness/identity metrics (per-entry tol 0). Exit 1 = a shipped
 # perf regression, which is a build failure.
 echo "-- perfwatch perf-regression gate" | tee -a "$ART/ci.log"
-python scripts/perfwatch.py --check "$ART/bench_pipeline.json" \
-  --tolerance 0.6 2>&1 | tee -a "$ART/ci.log" | tail -3
 python scripts/perfwatch.py --check "$ART/bench_io.json" \
   --tolerance 0.6 2>&1 | tee -a "$ART/ci.log" | tail -3
 python scripts/perfwatch.py --check "$ART/bench_tenant.json" \

@@ -130,7 +130,7 @@ def pool_seconds(task, kind: str, workers: int) -> float:
 def task_seconds(task) -> float:
     """Wall seconds for a host-engine merger with four stage workers to
     take every segment and carry it into its forest."""
-    om = OverlappedMerger(KT, WIDTH, engine="host", stagers=4, pipeline=True)
+    om = OverlappedMerger(KT, WIDTH, engine="host", stagers=4)
     try:
         t0 = time.perf_counter()
         for seg, source in enumerate(task):

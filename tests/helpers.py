@@ -1,6 +1,7 @@
-"""Shared test helpers: synthetic map-output generation.
+"""Shared test helpers: synthetic map-output generation, and the
+framed bytes of a merged partition (the merger's emit and its oracle).
 
-Builds the on-disk layout the supplier serves (``<root>/<job>/<map>/
+``make_mof_tree`` builds the on-disk layout the supplier serves (``<root>/<job>/<map>/
 file.out[.index]``) the way a Hadoop mapper would: per-map records
 partitioned by reducer, each partition sorted and IFile-framed, index
 triples pointing into the concatenated MOF.
@@ -14,7 +15,9 @@ from typing import Callable
 
 import numpy as np
 
+from uda_tpu.merger.emitter import FramedEmitter
 from uda_tpu.mofserver.index import write_index_file
+from uda_tpu.ops import merge as merge_ops
 from uda_tpu.utils.ifile import IFileWriter
 
 
@@ -62,3 +65,28 @@ def make_mof_tree(root: str, job_id: str, num_maps: int, num_reducers: int,
 
 def map_ids(job_id: str, num_maps: int) -> list[str]:
     return [f"attempt_{job_id}_m_{m:06d}_0" for m in range(num_maps)]
+
+
+def framed_bytes(batch) -> bytes:
+    """A sorted batch as the emitter frames it (the block size cuts the
+    stream into consumer calls; it does not change the bytes)."""
+    out = io.BytesIO()
+    FramedEmitter(1 << 14).emit_batch(batch,
+                                      lambda blk: out.write(bytes(blk)))
+    return out.getvalue()
+
+
+def host_sort_bytes(batches, kt) -> bytes:
+    """The oracle: one comparator sort of the concatenation on the
+    host (stable: equal keys keep (segment, row) order), framed."""
+    return framed_bytes(merge_ops.merge_batches_host(batches, kt))
+
+
+def emit_stream_bytes(om, batches) -> bytes:
+    """An in-memory ``OverlappedMerger``'s whole output: ``emit_stream``
+    (drain, leftover merge, slab-wise gather and framing) into a
+    buffer. Compare it with ``framed_bytes`` of an oracle's batch."""
+    out = io.BytesIO()
+    om.emit_stream(batches, FramedEmitter(1 << 14),
+                   lambda blk: out.write(bytes(blk)))
+    return out.getvalue()

@@ -70,3 +70,21 @@ def test_flag_inventory_complete():
         "mapred.rdma.developer.mode",
     ]:
         assert key in FLAGS, key
+
+
+@pytest.mark.parametrize("name", ("uda.tpu.stage.pipeline",
+                                  "uda.tpu.online.stagers",
+                                  "uda.tpu.merge.overlap",
+                                  "uda.tpu.merge.two_phase"))
+def test_the_flags_of_the_deleted_merge_routes_are_gone(name):
+    """PR 45: one staging architecture and no plain fetch-all route —
+    the flags that chose are not declared and not documented."""
+    import os
+
+    assert name not in FLAGS
+    with pytest.raises(ConfigError):
+        Config().get(name)
+    readme = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "README.md")
+    with open(readme, encoding="utf-8") as f:
+        assert name not in f.read()

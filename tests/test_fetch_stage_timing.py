@@ -322,7 +322,7 @@ def _feed_two(inflight_bytes: int, gated: bool) -> OverlappedMerger:
     is held until the second feed is blocked on it (the deterministic
     form of 'staging lags')."""
     kt = comparators.get_key_type("uda.tpu.RawBytes")
-    om = OverlappedMerger(kt, 16, engine="host", pipeline=True,
+    om = OverlappedMerger(kt, 16, engine="host",
                           inflight_bytes=inflight_bytes)
     batches = [_big_batch(s, 700_000) for s in (1, 2)]
     gate = threading.Event()

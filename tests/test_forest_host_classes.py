@@ -16,6 +16,7 @@ import time
 import numpy as np
 import pytest
 
+from tests.helpers import emit_stream_bytes
 from uda_tpu.merger import overlap
 from uda_tpu.merger.emitter import FramedEmitter
 from uda_tpu.merger.overlap import OverlappedMerger
@@ -32,8 +33,10 @@ KT = comparators.get_key_type("uda.tpu.RawBytes")
 WIDTH = 16
 ROW_BYTES = 4 * (WIDTH // 4 + merge_ops.ROW_EXTRA_COLS)
 SMALL_DEVICE_CLASS = 1024
-PIPELINE = pytest.mark.parametrize("pipeline", (False, True),
-                                   ids=("serial", "pipeline"))
+# the stage pool's width: one worker hands its runs to the consumer in
+# feed order, three in whatever order they finish — the stream and the
+# counts below must not depend on it
+POOL = pytest.mark.parametrize("workers", (1, 3), ids=("pool1", "pool3"))
 
 
 def _batch(seed: int, n: int, presorted: bool = False):
@@ -57,26 +60,26 @@ def _oracle_bytes(batches) -> bytes:
     return out.getvalue()
 
 
-def _merger(pipeline: bool, store=None) -> OverlappedMerger:
+def _merger(workers: int = 3, store=None) -> OverlappedMerger:
     return OverlappedMerger(KT, WIDTH, engine="pallas", run_store=store,
-                            stagers=3 if pipeline else 1, pipeline=pipeline,
-                            inflight_bytes=8 << 20)
+                            stagers=workers, inflight_bytes=8 << 20)
 
 
-def _stream_bytes(batches, pipeline: bool, store=None, order=None) -> bytes:
-    om = _merger(pipeline, store)
+def _stream_bytes(batches, workers: int = 3, store=None, order=None) -> bytes:
+    om = _merger(workers, store)
     for i in (order if order is not None else range(len(batches))):
         om.feed(i, batches[i])
-    out = io.BytesIO()
-    emitter = FramedEmitter(1 << 14)
-    if store is not None:
-        om.finish_streaming(emitter, lambda blk: out.write(bytes(blk)),
+    if store is None:
+        got = emit_stream_bytes(om, batches)
+    else:
+        out = io.BytesIO()
+        om.finish_streaming(FramedEmitter(1 << 14),
+                            lambda blk: out.write(bytes(blk)),
                             expected_records=sum(b.num_records
                                                  for b in batches))
-    else:
-        om.emit_stream(batches, emitter, lambda blk: out.write(bytes(blk)))
+        got = out.getvalue()
     assert metrics.get_gauge("stage.inflight.bytes") == 0
-    return out.getvalue()
+    return got
 
 
 def _forest_counts(sizes, threshold: int, native: bool = True):
@@ -110,13 +113,13 @@ def _counts():
 
 # -- every run small: the whole forest is the host's --------------------------
 
-@PIPELINE
+@POOL
 @pytest.mark.parametrize("fanin", (1, 3, 65, 200))
-def test_small_fanins_merge_on_the_host_and_transfer_once(fanin, pipeline):
+def test_small_fanins_merge_on_the_host_and_transfer_once(fanin, workers):
     sizes = [4 + (7 * i) % 23 for i in range(fanin)]
     batches = _batches(sizes, seed=fanin)
     order = list(np.random.default_rng(fanin).permutation(fanin))
-    assert _stream_bytes(batches, pipeline, order=order) \
+    assert _stream_bytes(batches, workers, order=order) \
         == _oracle_bytes(batches)
     # one run ever reaches the device, at finish, and nothing merges there
     assert _counts() == (1, fanin - 1)
@@ -127,7 +130,7 @@ def test_small_fanins_merge_on_the_host_and_transfer_once(fanin, pipeline):
 
 def test_all_segments_empty_put_nothing_on_the_device():
     batches = _batches([0, 0, 0])
-    assert _stream_bytes(batches, True) == _oracle_bytes(batches)
+    assert _stream_bytes(batches) == _oracle_bytes(batches)
     assert _counts() == (0, 0)
 
 
@@ -136,33 +139,33 @@ def test_all_segments_empty_put_nothing_on_the_device():
 MIXED = (300, 700, 0, 280, 650, 310, 290, 1500, 5, 0, 260, 270, 520)
 
 
-@PIPELINE
+@POOL
 @pytest.mark.parametrize("streaming", (False, True),
                          ids=("in_memory", "streaming"))
 def test_mixed_size_classes_keep_the_stream_and_the_counts(
-        monkeypatch, tmp_path, streaming, pipeline):
+        monkeypatch, tmp_path, streaming, workers):
     monkeypatch.setattr(overlap, "DEVICE_MIN_BUCKET", SMALL_DEVICE_CLASS)
     batches = _batches(MIXED, seed=9)
     store = RunStore([str(tmp_path)], tag="hostclass") if streaming else None
-    assert _stream_bytes(batches, pipeline, store) == _oracle_bytes(batches)
+    assert _stream_bytes(batches, workers, store) == _oracle_bytes(batches)
     want = _forest_counts(MIXED, SMALL_DEVICE_CLASS)
-    # serial staging feeds the forest in segment order; the pool's
+    # one worker feeds the forest in segment order; three workers'
     # completion order varies, and with it which carries happen — the
     # totals it must respect do not
     runs, host = _counts()
     staged = sum(n > 0 for n in MIXED)
-    if not pipeline:
+    if workers == 1:
         assert (runs, host) == want == (8, 3)
     assert 4 <= runs < staged and 0 < host < staged
     assert host + runs >= staged    # a host merge saves at most one transfer
 
 
-@PIPELINE
-def test_device_class_segments_never_merge_on_the_host(monkeypatch, pipeline):
+@POOL
+def test_device_class_segments_never_merge_on_the_host(monkeypatch, workers):
     monkeypatch.setattr(overlap, "DEVICE_MIN_BUCKET", SMALL_DEVICE_CLASS)
     sizes = (600, 900, 1024, 513, 1100)
     batches = _batches(sizes, seed=4)
-    assert _stream_bytes(batches, pipeline) == _oracle_bytes(batches)
+    assert _stream_bytes(batches, workers) == _oracle_bytes(batches)
     assert _counts() == (len(sizes), 0)
     # present and zero: what the benchmark's counter reader tells from a
     # program that has no host classes
@@ -175,7 +178,7 @@ def test_without_the_native_merge_every_run_goes_to_the_device(monkeypatch):
     monkeypatch.setattr(merge_ops, "resolve_native_rows_merge", lambda: None)
     sizes = (40, 0, 25, 33)
     batches = _batches(sizes, seed=2)
-    assert _stream_bytes(batches, True) == _oracle_bytes(batches)
+    assert _stream_bytes(batches) == _oracle_bytes(batches)
     assert _counts() == (3, 0)
     assert _counts() == _forest_counts(sizes, overlap.DEVICE_MIN_BUCKET,
                                        native=False)
@@ -185,22 +188,19 @@ def test_adopted_runs_join_the_host_classes():
     """The checkpoint-resume route: runs a previous attempt spooled are
     adopted, the rest fed; same forest, same stream."""
     batches = [_batch(50 + i, 20 + i, presorted=True) for i in range(5)]
-    om = _merger(pipeline=True)
+    om = _merger()
     for i in (0, 1, 2):
         om.adopt_run(i, batches[i])
     for i in (3, 4):
         om.feed(i, batches[i])
-    out = io.BytesIO()
-    om.emit_stream(batches, FramedEmitter(1 << 14),
-                   lambda blk: out.write(bytes(blk)))
-    assert out.getvalue() == _oracle_bytes(batches)
+    assert emit_stream_bytes(om, batches) == _oracle_bytes(batches)
     assert _counts() == (1, 4)
 
 
 def test_host_carries_are_timed_inside_the_merge_timer():
     metrics.enable_spans()
     batches = _batches([30] * 8, seed=6)
-    assert _stream_bytes(batches, True) == _oracle_bytes(batches)
+    assert _stream_bytes(batches) == _oracle_bytes(batches)
     spans = {s["id"]: s for s in metrics.spans}
     inner = [s for s in spans.values() if s["name"] == "merge_host_batch"]
     assert len(inner) == 7 == metrics.get("merge.host_merges")
@@ -220,7 +220,7 @@ def test_device_rows_stay_within_the_reservation(monkeypatch):
     monkeypatch.setattr(overlap, "DEVICE_MIN_BUCKET", SMALL_DEVICE_CLASS)
     sizes = [300] * 9 + [700, 1500]
     batches = _batches(sizes, seed=12)
-    om = _merger(pipeline=False)
+    om = _merger(workers=1)       # the runs reach the forest in feed order
     held_over = []
     insert = om._insert
 
@@ -236,8 +236,7 @@ def test_device_rows_stay_within_the_reservation(monkeypatch):
     om._insert = watched
     for i, b in enumerate(batches):
         om.feed(i, b)
-    got = om.finish(batches)
-    assert got.num_records == sum(sizes)
+    assert emit_stream_bytes(om, batches) == _oracle_bytes(batches)
     assert not held_over
     # 9 runs of class 512 -> four promoted at class 1,024 and one at
     # finish (padded to 512); the two large ones as staged
@@ -255,7 +254,7 @@ def _pooled_merger(monkeypatch) -> OverlappedMerger:
     leases on the books."""
     monkeypatch.setattr(resledger, "enabled", True)
     monkeypatch.setattr(resledger, "leak_reports", [])
-    om = _merger(pipeline=True)
+    om = _merger()
     om._buf_pool = merge_ops.RowBufferPool()
     return om
 
@@ -300,7 +299,7 @@ def test_stage_error_mid_batch_returns_every_lease(monkeypatch):
     for i, b in enumerate(batches):
         om.feed(i, Broken() if i == 4 else b)
     with pytest.raises(MergeError, match="unreadable"):
-        om.finish(batches)
+        emit_stream_bytes(om, batches)
     _books_whole(om)
 
 
@@ -325,7 +324,7 @@ def test_failed_host_merge_returns_both_inputs_leases(monkeypatch, fanin,
     for i, b in enumerate(batches):
         om.feed(i, b)
     with pytest.raises(MergeError, match="native merge broke"):
-        om.finish(batches)
+        emit_stream_bytes(om, batches)
     assert len(calls) == failing_call
     _books_whole(om)
 

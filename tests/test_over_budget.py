@@ -321,10 +321,9 @@ def _oracle(batches, kt) -> bytes:
 
 
 def _grouped_stream(tmp_path, batches, kt, engine: str, group_rows: int,
-                    pipeline: bool = True, width: int = WIDTH):
+                    width: int = WIDTH):
     om = OverlappedMerger(kt, width, engine=engine,
-                          run_store=RunStore(str(tmp_path)),
-                          stagers=3 if pipeline else 2, pipeline=pipeline,
+                          run_store=RunStore(str(tmp_path)), stagers=3,
                           inflight_bytes=8 << 20, group_rows=group_rows)
     for i, b in enumerate(batches):
         om.feed(i, b)
@@ -357,8 +356,7 @@ def test_interpreted_pallas_groups_with_host_classes(tmp_path, monkeypatch):
     at the capacity they will have there."""
     monkeypatch.setattr(overlap, "DEVICE_MIN_BUCKET", 1024)
     batches = [_batch("raw", 300, 13 * i) for i in range(6)]
-    om, got = _grouped_stream(tmp_path, batches, RAW, "pallas", 2048,
-                              pipeline=False)
+    om, got = _grouped_stream(tmp_path, batches, RAW, "pallas", 2048)
     assert got == _oracle(batches, RAW)
     assert om.stats["device_groups"] == 2       # four runs of 512, then two
     assert metrics.get("merge.host_merges") == 3
@@ -414,7 +412,7 @@ def test_an_overflow_key_still_takes_the_kway_files_path(tmp_path):
 
 def test_abort_drops_the_groups(tmp_path):
     om = OverlappedMerger(RAW, WIDTH, engine="host",
-                          run_store=RunStore(str(tmp_path)), pipeline=True,
+                          run_store=RunStore(str(tmp_path)),
                           group_rows=1024)
     for i in range(5):
         om.feed(i, _batch("raw", 300, i))

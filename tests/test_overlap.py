@@ -9,9 +9,9 @@ import threading
 import numpy as np
 import pytest
 
-from tests.helpers import make_mof_tree, map_ids
+from tests.helpers import (emit_stream_bytes, framed_bytes, host_sort_bytes,
+                           make_mof_tree, map_ids)
 from uda_tpu.merger import LocalFetchClient, MergeManager
-from uda_tpu.merger.emitter import FramedEmitter
 from uda_tpu.merger.overlap import OverlappedMerger
 from uda_tpu.mofserver import DataEngine, DirIndexResolver
 from uda_tpu.ops import merge as merge_ops
@@ -42,9 +42,8 @@ def test_overlap_matches_global_resort():
     # (segment, row) order, not completion order
     for i in (3, 0, 4, 1, 2):
         om.feed(i, batches[i])
-    got = om.finish(batches)
     want = merge_ops.merge_batches(batches, kt, 16)
-    assert list(got.iter_records()) == list(want.iter_records())
+    assert emit_stream_bytes(om, batches) == framed_bytes(want)
     assert om.stats["device_merges"] >= 1
     assert not om.stats["overflow"]
 
@@ -65,9 +64,8 @@ def test_overlap_pallas_engine_matches_host(monkeypatch):
     for i, b in enumerate(batches):
         om_p.feed(i, b)
         om_h.feed(i, b)
-    got_p = om_p.finish(batches)
-    got_h = om_h.finish(batches)
-    assert list(got_p.iter_records()) == list(got_h.iter_records())
+    got_p = emit_stream_bytes(om_p, batches)
+    assert got_p == emit_stream_bytes(om_h, batches) and len(got_p) > 0
     assert om_p.stats["device_merges"] >= 1
 
 
@@ -82,9 +80,7 @@ def test_overlap_oversize_keys_stay_on_the_forest():
     om = OverlappedMerger(kt, width=16)
     om.feed(0, b0)
     om.feed(1, b1)
-    got = om.finish([b0, b1])
-    want = merge_ops.merge_batches_host([b0, b1], kt)
-    assert list(got.iter_records()) == list(want.iter_records())
+    assert emit_stream_bytes(om, [b0, b1]) == host_sort_bytes([b0, b1], kt)
     assert om.stats["oversize"] and not om.stats["overflow"]
     assert om.stats["device_merges"] >= 1
     assert metrics.get("merge.overflow.fallbacks") == 0
@@ -94,24 +90,6 @@ def test_overlap_oversize_keys_stay_on_the_forest():
 
 
 STEM = b"abcdefghijklmnop"                      # 16 bytes: the carried width
-
-
-def _emit_bytes(om, batches) -> bytes:
-    out = io.BytesIO()
-    om.emit_stream(batches, FramedEmitter(1 << 12),
-                   lambda blk: out.write(bytes(blk)))
-    return out.getvalue()
-
-
-def _framed(batch) -> bytes:
-    out = io.BytesIO()
-    FramedEmitter(1 << 12).emit_batch(batch,
-                                      lambda blk: out.write(bytes(blk)))
-    return out.getvalue()
-
-
-def _host_bytes(batches, kt) -> bytes:
-    return _framed(merge_ops.merge_batches_host(batches, kt))
 
 
 def _values_in_order(stream: bytes) -> list:
@@ -133,8 +111,8 @@ def test_a_block_with_a_prefix_equal_keys_across_maps_and_the_stem():
     om = OverlappedMerger(kt, width=16)
     for i, b in enumerate(batches):
         om.feed(i, b)
-    got = _emit_bytes(om, batches)
-    assert got == _host_bytes(batches, kt)
+    got = emit_stream_bytes(om, batches)
+    assert got == host_sort_bytes(batches, kt)
     keys = [k for k, _ in IFileReader(io.BytesIO(got))]
     assert keys == [STEM[:9], STEM, STEM + b"l", STEM + b"long",
                     STEM + b"long", STEM + b"long", STEM + b"longer",
@@ -172,7 +150,7 @@ def test_a_block_that_straddles_a_read_back_slab_is_fixed_as_one(
     om = OverlappedMerger(kt, width=16)
     for i, b in enumerate(batches):
         om.feed(i, b)
-    assert _emit_bytes(om, batches) == _host_bytes(batches, kt)
+    assert emit_stream_bytes(om, batches) == host_sort_bytes(batches, kt)
     assert metrics.get("merge.overflow.keys") == 18
     # the fifteen STEM + "x" keys are one block whatever the slab; the
     # three OTHERSTEM keys another
@@ -207,22 +185,22 @@ def test_random_keys_with_shared_stems_on_both_engines(monkeypatch, engine):
                         overlap.MIN_RUN_CAPACITY)
     kt = comparators.get_key_type("uda.tpu.RawBytes")
     batches = _shared_stem_batches(seed=42)
-    want = _host_bytes(batches, kt)
+    want = host_sort_bytes(batches, kt)
     om = OverlappedMerger(kt, width=16, engine=engine)
     for i in (2, 0, 3, 1):
         om.feed(i, batches[i])
-    assert _emit_bytes(om, batches) == want
+    assert emit_stream_bytes(om, batches) == want
     assert om.stats["oversize"] and om.stats["device_merges"] == 3
     oversize = sum(int((b.key_len > 16).sum()) for b in batches)
     assert oversize > 50
     assert metrics.get("merge.overflow.keys") == oversize
     assert metrics.get("merge.oversize.blocks") >= 3
     assert metrics.get("merge.overflow.fallbacks") == 0
-    # finish() takes the same fix-up over the whole run at once
-    om = OverlappedMerger(kt, width=16, engine="host", pipeline=True)
+    # fed in map order this time: the same bytes
+    om = OverlappedMerger(kt, width=16, engine="host")
     for i, b in enumerate(batches):
         om.feed(i, b)
-    assert _framed(om.finish(batches)) == want
+    assert emit_stream_bytes(om, batches) == want
 
 
 def test_a_key_type_with_its_own_compare_still_takes_the_fallback():
@@ -242,11 +220,11 @@ def test_a_key_type_with_its_own_compare_still_takes_the_fallback():
     om = OverlappedMerger(kt, width=16)
     for i, b in enumerate(batches):
         om.feed(i, b)
-    got = om.finish(batches)
+    got = emit_stream_bytes(om, batches)
     # the fallback's order is the bytewise one (ops.merge.merge_batches
     # ranks by content), as before this route existed
     want = merge_ops.merge_batches(batches, kt, 16)
-    assert list(got.iter_records()) == list(want.iter_records())
+    assert got == framed_bytes(want)
     assert om.stats["overflow"] and not om.stats["oversize"]
     assert metrics.get("merge.overflow.fallbacks") == 1
     assert metrics.get("merge.overflow.keys") == 4   # ranked twice: got, want
@@ -261,9 +239,12 @@ def test_overlap_empty_and_single_segment():
     om = OverlappedMerger(kt, width=16)
     om.feed(0, empty)
     om.feed(1, one)
-    got = om.finish([empty, one])
     want = merge_ops.merge_batches([empty, one], kt, 16)
-    assert list(got.iter_records()) == list(want.iter_records())
+    assert emit_stream_bytes(om, [empty, one]) == framed_bytes(want)
+    # nothing fed at all: the emit is the bare end-of-stream marker
+    om = OverlappedMerger(kt, width=16)
+    om.feed(0, empty)
+    assert emit_stream_bytes(om, [empty]) == framed_bytes(empty)
 
 
 def test_merge_work_happens_before_last_fetch(tmp_path):
@@ -329,18 +310,3 @@ def test_merge_work_happens_before_last_fetch(tmp_path):
 def _overlap_stats(mm):
     om = getattr(mm, "_active_overlap", None)
     return om.stats if om is not None else {"device_merges": 0}
-
-
-def test_online_merge_with_overlap_disabled_still_works(tmp_path):
-    make_mof_tree(str(tmp_path), "jobN", 4, 1, 25, seed=13)
-    engine = DataEngine(DirIndexResolver(str(tmp_path)))
-    cfg = Config({"uda.tpu.merge.overlap": False})
-    try:
-        mm = MergeManager(LocalFetchClient(engine), "uda.tpu.RawBytes", cfg)
-        blocks = []
-        mm.run("jobN", map_ids("jobN", 4), 0,
-               lambda b: blocks.append(bytes(b)))
-        got = list(IFileReader(io.BytesIO(b"".join(blocks))))
-        assert len(got) == 100
-    finally:
-        engine.stop()

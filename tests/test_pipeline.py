@@ -1,6 +1,6 @@
 """Staged fetch->decompress->pack->stage pipeline (ISSUE 9): the
-bounded stage pool + merge consumer must be byte-identical to the
-serial staging twin on every engine/compression/spool combination,
+bounded stage pool + merge consumer must be byte-identical to a sort
+of the whole partition on every engine/compression/spool combination,
 drain cleanly (no leaked in-flight budget bytes) when a fault lands
 mid-pipeline, and bound in-flight bytes under a slow consumer."""
 
@@ -11,7 +11,8 @@ import time
 import numpy as np
 import pytest
 
-from tests.helpers import make_mof_tree, map_ids
+from tests.helpers import (emit_stream_bytes, framed_bytes, host_sort_bytes,
+                           make_mof_tree, map_ids)
 from uda_tpu.compress import DecompressingClient, get_codec
 from uda_tpu.merger import LocalFetchClient, MergeManager
 from uda_tpu.merger.emitter import FramedEmitter
@@ -25,7 +26,7 @@ from uda_tpu.utils.budget import STAGE_INFLIGHT_FLOOR_MB, stage_inflight_cap
 from uda_tpu.utils.config import Config
 from uda_tpu.utils.errors import FallbackSignal
 from uda_tpu.utils.failpoints import failpoints
-from uda_tpu.utils.ifile import IFileReader, RecordBatch, crack, write_records
+from uda_tpu.utils.ifile import crack, write_records
 from uda_tpu.utils.metrics import metrics
 
 KT = "uda.tpu.RawBytes"
@@ -44,54 +45,52 @@ def _rand_recs(seed, n, dup_every=5, key_bytes=6):
     return recs
 
 
-def _finish_bytes(batches, pipeline, engine="host", spool=False,
-                  tmp=None, stagers=2):
+def _pool_bytes(batches, engine="host", spool=False, tmp=None):
+    """The stage pool's output for ``batches``: through a run store and
+    ``finish_streaming`` (``spool``), or in memory."""
     store = RunStore([str(tmp)], tag="pipetest") if spool else None
     kt = comparators.get_key_type(KT)
     om = OverlappedMerger(kt, 16, engine=engine, run_store=store,
-                          stagers=stagers if pipeline else 1,
-                          pipeline=pipeline, inflight_bytes=8 << 20)
-    out = io.BytesIO()
+                          stagers=2, inflight_bytes=8 << 20)
     for i, b in enumerate(batches):
         om.feed(i, b)
-    emitter = FramedEmitter(1 << 14)
-    if spool:
-        om.finish_streaming(
-            emitter, lambda blk: out.write(bytes(blk)),
-            expected_records=sum(b.num_records for b in batches))
-    else:
-        om.emit_stream(batches, emitter, lambda blk: out.write(bytes(blk)))
+    if not spool:
+        return emit_stream_bytes(om, batches)
+    out = io.BytesIO()
+    om.finish_streaming(
+        FramedEmitter(1 << 14), lambda blk: out.write(bytes(blk)),
+        expected_records=sum(b.num_records for b in batches))
     return out.getvalue()
 
 
-# -- byte-identity: pipelined vs serial staging ------------------------------
+def _sort_bytes(batches):
+    return host_sort_bytes(batches, comparators.get_key_type(KT))
+
+
+# -- byte-identity: the stage pool vs a sort of the whole partition ----------
 
 def test_pipeline_identity_host_engine():
     batches = [_batch(_rand_recs(s, 60 + 11 * s)) for s in range(7)]
-    a = _finish_bytes(batches, pipeline=False)
-    b = _finish_bytes(batches, pipeline=True)
-    assert a == b and len(a) > 0
+    got = _pool_bytes(batches)
+    assert got == _sort_bytes(batches) and len(got) > 0
 
 
 def test_pipeline_identity_out_of_order_feed():
-    # completion order never decides anything: feed in a scrambled
-    # order on BOTH paths, results stay identical to in-order serial
+    # completion order never decides anything: fed in a scrambled
+    # order, the result stays the stable sort's
     batches = [_batch(_rand_recs(40 + s, 50)) for s in range(6)]
     kt = comparators.get_key_type(KT)
     want = merge_ops.merge_batches(batches, kt, 16)
-    om = OverlappedMerger(kt, 16, engine="host", pipeline=True, stagers=3)
+    om = OverlappedMerger(kt, 16, engine="host", stagers=3)
     for i in (4, 0, 5, 2, 1, 3):
         om.feed(i, batches[i])
-    got = om.finish(batches)
-    assert list(got.iter_records()) == list(want.iter_records())
-    assert om.stats["pipeline"]
+    assert emit_stream_bytes(om, batches) == framed_bytes(want)
 
 
 def test_pipeline_identity_spool(tmp_path):
     batches = [_batch(_rand_recs(s, 80)) for s in range(5)]
-    a = _finish_bytes(batches, False, spool=True, tmp=tmp_path)
-    b = _finish_bytes(batches, True, spool=True, tmp=tmp_path)
-    assert a == b and len(a) > 0
+    got = _pool_bytes(batches, spool=True, tmp=tmp_path)
+    assert got == _sort_bytes(batches) and len(got) > 0
 
 
 @pytest.mark.slow
@@ -101,33 +100,34 @@ def test_pipeline_identity_pallas_engine(monkeypatch):
     monkeypatch.setattr(overlap, "DEVICE_MIN_BUCKET",
                         overlap.MIN_RUN_CAPACITY)
     batches = [_batch(_rand_recs(70 + s, 30)) for s in range(4)]
-    a = _finish_bytes(batches, pipeline=False, engine="pallas")
-    b = _finish_bytes(batches, pipeline=True, engine="pallas")
-    assert a == b and len(a) > 0
+    got = _pool_bytes(batches, engine="pallas")
+    assert got == _sort_bytes(batches) and len(got) > 0
 
 
 def test_pipeline_identity_overflow_keys():
-    # oversize keys disable the fast path on both paths identically
+    # keys longer than the carried width, equal in all of it: the
+    # comparator's order whichever worker staged which segment
     pre = b"Q" * 17
     batches = [_batch([(pre + b"z", b"v0"), (b"a", b"v1")]),
                _batch([(pre + b"b", b"v2"), (b"c", b"v3")])]
-    a = _finish_bytes(batches, pipeline=False)
-    b = _finish_bytes(batches, pipeline=True)
-    assert a == b and len(a) > 0
+    got = _pool_bytes(batches)
+    assert got == _sort_bytes(batches) and len(got) > 0
 
 
-def _compressed_run(tmp_path, cfg_extra):
+def test_pipeline_identity_compressed_e2e(tmp_path):
+    # a whole task over zlib map outputs in 8 KB chunks, against a host
+    # sort of the records the maps wrote
     codec = get_codec("zlib")
     rng = np.random.default_rng(11)
     job = "jobPC"
-    writer = MOFWriter(str(tmp_path / f"c{len(cfg_extra)}"), job,
-                       codec=codec)
+    writer = MOFWriter(str(tmp_path), job, codec=codec)
+    written = []
     for m in range(4):
         recs = sorted((rng.bytes(8), rng.bytes(24)) for _ in range(120))
         writer.write(f"attempt_{job}_m_{m:06d}_0", [recs])
-    cfg = Config({"mapred.rdma.buf.size": 8, **cfg_extra})
-    engine = DataEngine(DirIndexResolver(str(tmp_path /
-                                             f"c{len(cfg_extra)}")), cfg)
+        written.extend(recs)
+    cfg = Config({"mapred.rdma.buf.size": 8, "uda.tpu.stage.pool": 2})
+    engine = DataEngine(DirIndexResolver(str(tmp_path)), cfg)
     try:
         mm = MergeManager(DecompressingClient(LocalFetchClient(engine),
                                               codec), KT, cfg)
@@ -135,14 +135,10 @@ def _compressed_run(tmp_path, cfg_extra):
         mm.run(job, writer.map_ids, 0, lambda b: blocks.append(bytes(b)))
     finally:
         engine.stop()
-    return b"".join(blocks)
-
-
-def test_pipeline_identity_compressed_e2e(tmp_path):
-    a = _compressed_run(tmp_path, {"uda.tpu.stage.pipeline": False})
-    b = _compressed_run(tmp_path, {"uda.tpu.stage.pipeline": True,
-                                   "uda.tpu.stage.pool": 2})
-    assert a == b and len(a) > 0
+    # stable by key: equal keys keep (map, row) order
+    want = sorted(written, key=lambda kv: kv[0])
+    assert b"".join(blocks) == framed_bytes(_batch(want))
+    assert len(mm._active_overlap._workers) == 2
 
 
 # -- merge-path split + buffer pool (the pipeline's merge half) --------------
@@ -205,44 +201,26 @@ def test_row_buffer_pool_reuses_and_bounds():
     assert len(pool._free) == pool.MAX_FREE
 
 
-# -- two-phase device sort + engine routing ----------------------------------
+# -- one staging architecture -------------------------------------------------
 
-def test_two_phase_matches_resort():
-    kt = comparators.get_key_type(KT)
-    batches = [_batch(_rand_recs(s, 45 + 9 * s)) for s in range(6)]
-    want = merge_ops.merge_batches(batches, kt, 16)
-    got = merge_ops.merge_batches_two_phase(batches, kt, 16, engine="host")
-    assert list(got.iter_records()) == list(want.iter_records())
+def test_a_bare_merger_runs_the_pool_and_abort_joins_it():
+    """No argument selects the staging: a merger built with the key
+    type and the width alone starts the auto-width stage pool and the
+    one merge consumer, and ``abort()`` joins every one of them."""
+    from uda_tpu.merger import overlap
 
-
-def test_two_phase_overflow_falls_back():
-    kt = comparators.get_key_type(KT)
-    pre = b"W" * 20
-    batches = [_batch([(pre + b"x", b"1"), (b"k", b"2")]),
-               _batch([(pre + b"a", b"3")])]
-    want = merge_ops.merge_batches(batches, kt, 16)
-    got = merge_ops.merge_batches_two_phase(batches, kt, 16, engine="host")
-    assert list(got.iter_records()) == list(want.iter_records())
-
-
-def test_two_phase_empty_and_single():
-    kt = comparators.get_key_type(KT)
-    empty = RecordBatch.concat([])
-    one = _batch(_rand_recs(3, 12))
-    got = merge_ops.merge_batches_two_phase([empty, one], kt, 16,
-                                            engine="host")
-    want = merge_ops.merge_batches([empty, one], kt, 16)
-    assert list(got.iter_records()) == list(want.iter_records())
-
-
-def test_resolve_merge_mode_routing():
-    assert merge_ops.resolve_merge_mode("off", 8) == "resort"
-    assert merge_ops.resolve_merge_mode("on", 8) == "two_phase"
-    assert merge_ops.resolve_merge_mode("on", 1) == "resort"  # nothing to merge
-    # auto on the CPU backend keeps the single lexsort-shaped re-sort
-    assert merge_ops.resolve_merge_mode("auto", 8) == "resort"
-    with pytest.raises(Exception):
-        merge_ops.resolve_merge_mode("sideways", 2)
+    om = OverlappedMerger(comparators.get_key_type(KT), 16)
+    try:
+        names = sorted(t.name for t in om._threads)
+        width = overlap._auto_width()
+        assert names == sorted([f"uda-stage-w{i}" for i in range(width)]
+                               + ["uda-overlap-merge"])
+        assert all(t.is_alive() for t in om._threads)
+        om.feed(0, _batch(_rand_recs(1, 30)))
+    finally:
+        om.abort()
+    assert not any(t.is_alive() for t in om._threads)
+    assert om._inflight == 0 and om.stats["pending"] == 0
 
 
 def test_feed_racing_abort_releases_charge():
@@ -252,7 +230,7 @@ def test_feed_racing_abort_releases_charge():
     # Forced deterministically by completing abort() inside _charge.
     kt = comparators.get_key_type(KT)
     b = _batch(_rand_recs(50, 10))
-    om = OverlappedMerger(kt, 16, pipeline=True, inflight_bytes=1 << 20)
+    om = OverlappedMerger(kt, 16, inflight_bytes=1 << 20)
     orig_charge = om._charge
 
     def charge_then_abort(source):
@@ -333,8 +311,7 @@ def test_pipeline_pread_fault_drains_clean(tmp_path):
     stage pool drains and the in-flight byte gauge returns to zero."""
     make_mof_tree(str(tmp_path), "jobPF", 6, 1, 40, seed=3)
     engine = DataEngine(DirIndexResolver(str(tmp_path)))
-    cfg = Config({"uda.tpu.stage.pipeline": True,
-                  "uda.tpu.stage.pool": 2,
+    cfg = Config({"uda.tpu.stage.pool": 2,
                   "uda.tpu.fetch.retries": 0})
     mm = MergeManager(LocalFetchClient(engine), KT, cfg)
     try:
@@ -363,8 +340,7 @@ def test_pipeline_decompress_fault_drains_clean(tmp_path):
     for m in range(3):
         recs = sorted((rng.bytes(8), rng.bytes(24)) for _ in range(100))
         writer.write(f"attempt_{job}_m_{m:06d}_0", [recs])
-    cfg = Config({"uda.tpu.stage.pipeline": True,
-                  "uda.tpu.fetch.retries": 0})
+    cfg = Config({"uda.tpu.fetch.retries": 0})
     engine = DataEngine(DirIndexResolver(str(tmp_path)), cfg)
     mm = MergeManager(DecompressingClient(LocalFetchClient(engine), codec),
                       KT, cfg)
@@ -398,7 +374,7 @@ def test_pipeline_backpressure_bounds_inflight(monkeypatch):
         real_insert(self, run)
 
     monkeypatch.setattr(OverlappedMerger, "_insert", slow_insert)
-    om = OverlappedMerger(kt, 16, engine="host", pipeline=True, stagers=2,
+    om = OverlappedMerger(kt, 16, engine="host", stagers=2,
                           inflight_bytes=cap)
     peak = {"v": 0}
     done = threading.Event()
@@ -413,21 +389,21 @@ def test_pipeline_backpressure_bounds_inflight(monkeypatch):
     before = metrics.get("stage.backpressure_events")
     for i, b in enumerate(batches):
         om.feed(i, b)  # blocks past the cap — that IS the test
-    got = om.finish(batches)
+    got = emit_stream_bytes(om, batches)
     done.set()
     w.join(timeout=5)
     assert peak["v"] <= cap
     assert metrics.get("stage.backpressure_events") > before
     assert om._inflight == 0
     want = merge_ops.merge_batches(batches, kt, 16)
-    assert list(got.iter_records()) == list(want.iter_records())
+    assert got == framed_bytes(want)
 
 
 def test_pipeline_abort_releases_blocked_feed():
     kt = comparators.get_key_type(KT)
     batches = [_batch(_rand_recs(s, 120)) for s in range(4)]
     one = OverlappedMerger._source_bytes(batches[0])
-    om = OverlappedMerger(kt, 16, engine="host", pipeline=True, stagers=1,
+    om = OverlappedMerger(kt, 16, engine="host", stagers=1,
                           inflight_bytes=int(1.5 * one))
     # wedge the consumer (abort-responsive) so charges stay held
     hold = threading.Event()

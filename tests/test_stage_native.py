@@ -12,10 +12,12 @@ emits the numpy-path task's stream; every exit sends the row lease home."""
 
 import io
 import struct
+import time
 
 import numpy as np
 import pytest
 
+from tests.helpers import emit_stream_bytes
 from uda_tpu import native
 from uda_tpu.merger.emitter import FramedEmitter
 from uda_tpu.merger.overlap import OverlappedMerger
@@ -240,19 +242,20 @@ def _task_bytes(batches, kt, store=None, numpy_path=False,
         monkeypatch.setattr(native, "stage_segment_native",
                             lambda *a, **k: None)
     om = OverlappedMerger(kt, WIDTH, engine="host", run_store=store,
-                          stagers=4, pipeline=True, inflight_bytes=8 << 20)
+                          stagers=4, inflight_bytes=8 << 20)
     for i in np.random.default_rng(1).permutation(len(batches)):
         om.feed(int(i), batches[int(i)])
-    out = io.BytesIO()
-    emitter = FramedEmitter(1 << 14)
-    if store is not None:
-        om.finish_streaming(emitter, lambda blk: out.write(bytes(blk)),
+    if store is None:
+        got = emit_stream_bytes(om, batches)
+    else:
+        out = io.BytesIO()
+        om.finish_streaming(FramedEmitter(1 << 14),
+                            lambda blk: out.write(bytes(blk)),
                             expected_records=sum(b.num_records
                                                  for b in batches))
-    else:
-        om.emit_stream(batches, emitter, lambda blk: out.write(bytes(blk)))
+        got = out.getvalue()
     assert metrics.get_gauge("stage.inflight.bytes") == 0
-    return out.getvalue()
+    return got
 
 
 def _oracle_bytes(batches, kt) -> bytes:
@@ -293,10 +296,10 @@ def test_a_task_of_many_small_segments_stages_every_one_natively(
 
 def test_a_task_that_fell_back_reads_zero_not_nothing():
     set_native_enabled(False)
-    om = OverlappedMerger(RAW, WIDTH, engine="host", pipeline=True)
+    om = OverlappedMerger(RAW, WIDTH, engine="host")
     batch = _batch("raw", [b"a", b"b"])
     om.feed(0, batch)
-    om.finish([batch])
+    assert emit_stream_bytes(om, [batch]) == _oracle_bytes([batch], RAW)
     assert "stage.native_segments" in metrics.snapshot()
     assert metrics.get("stage.native_segments") == 0
 
@@ -332,7 +335,7 @@ def test_a_task_with_oversize_keys_emits_what_the_numpy_path_does(
 def _pooled_merger(monkeypatch, **kwargs) -> OverlappedMerger:
     monkeypatch.setattr(resledger, "enabled", True)
     monkeypatch.setattr(resledger, "leak_reports", [])
-    om = OverlappedMerger(RAW, WIDTH, engine="host", stagers=3, pipeline=True,
+    om = OverlappedMerger(RAW, WIDTH, engine="host", stagers=3,
                           inflight_bytes=8 << 20, **kwargs)
     assert om._buf_pool is not None
     return om
@@ -363,43 +366,57 @@ def test_a_raising_native_pass_releases_its_lease(monkeypatch):
     for i, b in enumerate(batches):
         om.feed(i, b)
     with pytest.raises(MergeError, match="native pass broke"):
-        om.finish(batches)
+        emit_stream_bytes(om, batches)
     _books_whole(om)
 
 
-@pytest.mark.parametrize("how", ("overflow", "no_device_runs", "spool_raises"))
+@pytest.mark.parametrize("how", ("overflow", "streaming_overflow",
+                                 "spool_raises"))
 def test_rows_the_forest_does_not_take_go_back_to_the_pool(monkeypatch, how,
                                                            tmp_path):
-    """Every lease goes home: a task whose keys overflow keeps its rows
-    on the forest (the leases return as runs merge away and at the
-    finish; the pool is whole after the emit), a spool-only task and a
-    failing spool hand theirs back from staging."""
+    """Every lease goes home: an in-memory task whose keys overflow keeps
+    its rows on the forest (the leases return as runs merge away and at
+    the finish; the pool is whole after the emit); a streaming task
+    latches the k-way merge over its run files at the first oversize
+    key and every later segment only spools, and a failing spool hands
+    its rows back from staging too."""
     store = None
     if how != "overflow":
         store = RunStore([str(tmp_path)], tag=how)
-    om = _pooled_merger(monkeypatch, run_store=store,
-                        device_runs=how != "no_device_runs")
+    om = _pooled_merger(monkeypatch, run_store=store)
     if how == "spool_raises":
         def full(*a, **k):
             raise MergeError("the spool disk is full")
         monkeypatch.setattr(store, "write_run", full)
-    relation = "longer" if how == "overflow" else "shorter"
-    batches = [_batch("raw", _contents("raw", relation, "unsorted", 25,
+    # streaming_overflow: the first segment alone has keys longer than
+    # the width, and is staged before the rest are fed
+    relations = ["longer" if how == "overflow"
+                 or (how == "streaming_overflow" and i == 0) else "shorter"
+                 for i in range(5)]
+    batches = [_batch("raw", _contents("raw", relations[i], "unsorted", 25,
                                        seed=i)) for i in range(5)]
     for i, b in enumerate(batches):
         om.feed(i, b)
+        if how == "streaming_overflow" and i == 0:
+            deadline = time.monotonic() + 10
+            while not om.stats["staged_runs"]:
+                assert time.monotonic() < deadline
+                time.sleep(0.005)
+            assert om.stats["overflow"]
     out = io.BytesIO()
     if how == "overflow":
-        om.emit_stream(batches, FramedEmitter(1 << 14),
-                       lambda blk: out.write(bytes(blk)))
+        assert emit_stream_bytes(om, batches) == _oracle_bytes(batches, RAW)
         assert om.stats["oversize"] and not om.stats["overflow"]
         assert om.stats["staged_runs"] == 5 and om.stats["device_merges"] == 4
-        assert out.getvalue() == _oracle_bytes(batches, RAW)
         assert metrics.get("merge.overflow.fallbacks") == 0
-    elif how == "no_device_runs":
+    elif how == "streaming_overflow":
         om.finish_streaming(FramedEmitter(1 << 14),
                             lambda blk: out.write(bytes(blk)),
                             expected_records=125)
+        assert out.getvalue() == _oracle_bytes(batches, RAW)
+        # spooled, every one; nothing reached the forest
+        assert om.stats["staged_runs"] == 5 and om.stats["device_merges"] == 0
+        assert metrics.get("merge.overflow.fallbacks") == 1
     else:
         with pytest.raises(MergeError, match="disk is full"):
             om.finish_streaming(FramedEmitter(1 << 14),
@@ -415,9 +432,6 @@ def test_adopt_run_stages_through_the_same_pass(monkeypatch):
         om.adopt_run(i, batches[i])
     for i in (2, 3):
         om.feed(i, batches[i])
-    out = io.BytesIO()
-    om.emit_stream(batches, FramedEmitter(1 << 14),
-                   lambda blk: out.write(bytes(blk)))
+    assert emit_stream_bytes(om, batches) == _oracle_bytes(batches, RAW)
     assert metrics.get("stage.native_segments") == 4
-    assert out.getvalue() == _oracle_bytes(batches, RAW)
     _books_whole(om)

@@ -263,13 +263,13 @@ def test_backpressure_bounded_queue(tmp_path):
     kt = comparators.get_key_type("uda.tpu.RawBytes")
     store = RunStore(str(tmp_path))
     om = OverlappedMerger(kt, 16, run_store=store, max_pending=2)
-    orig_stage = om._stage
+    orig_prepare = om._prepare
 
-    def slow_stage(i, src, fed_t):
+    def slow_prepare(i, src, fed_t):
         time.sleep(0.02)
-        orig_stage(i, src, fed_t)
+        return orig_prepare(i, src, fed_t)
 
-    om._stage = slow_stage
+    om._prepare = slow_prepare
     batches = [crack(write_records(sorted(
         (bytes([s, i]), bytes([i])) for i in range(20))))
         for s in range(12)]
@@ -291,11 +291,11 @@ def test_backpressure_bounded_queue(tmp_path):
 
 
 def test_staging_pool_parity(tmp_path):
-    # 4 stager threads must produce byte-identical output (forest
-    # carries serialize under the lock; insertion order may differ but
+    # 4 stage workers must produce byte-identical output (the forest
+    # carries in the one consumer; insertion order may differ but
     # the composite key is total, so the merged rows are identical)
     a = _merge_once(tmp_path, True, num_maps=9, records_per_map=150,
-                    extra_cfg={"uda.tpu.online.stagers": 4})
+                    extra_cfg={"uda.tpu.stage.pool": 4})
     b = _merge_once(tmp_path, False, num_maps=9, records_per_map=150)
     assert a == b
 
@@ -320,7 +320,7 @@ def test_staging_pool_stress_parity(tmp_path):
     # adversarial pool schedule: 64 segments of random sizes (empty,
     # tiny, big, oversize-key mix) staged by 4 workers with random
     # per-stage delays must produce byte-identical output to the
-    # single-threaded run — the forest-carry and run-store locking
+    # one-worker run — the forest-carry and run-store locking
     # under real interleaving
     import random as _random
     import time
@@ -339,19 +339,19 @@ def test_staging_pool_stress_parity(tmp_path):
         batches.append(crack(write_records(recs)))
     kt = comparators.get_key_type("uda.tpu.RawBytes")
     outs = {}
-    for stagers in (0, 4):
+    for stagers in (1, 4):
         store = RunStore(str(tmp_path), tag=f"stress{stagers}")
         om = OverlappedMerger(kt, 16, run_store=store, max_pending=8,
                               stagers=stagers)
-        if stagers:
-            orig = om._stage
+        if stagers > 1:
+            orig = om._prepare
             delay = _random.Random(7)
 
-            def jitter_stage(i, src, fed_t, _orig=orig, _d=delay):
+            def jitter_prepare(i, src, fed_t, _orig=orig, _d=delay):
                 time.sleep(_d.random() * 0.004)
-                _orig(i, src, fed_t)
+                return _orig(i, src, fed_t)
 
-            om._stage = jitter_stage
+            om._prepare = jitter_prepare
         for s, b in enumerate(batches):
             om.feed(s, b)
         blocks = []
@@ -360,17 +360,21 @@ def test_staging_pool_stress_parity(tmp_path):
             emitter, lambda mv: blocks.append(bytes(mv)),
             expected_records=sum(b.num_records for b in batches))
         outs[stagers] = b"".join(blocks)
-    assert outs[0] == outs[4]
+    assert outs[1] == outs[4]
 
 
 def test_abort_with_full_queue_does_not_deadlock(tmp_path):
     kt = comparators.get_key_type("uda.tpu.RawBytes")
     store = RunStore(str(tmp_path))
-    om = OverlappedMerger(kt, 16, run_store=store, max_pending=1)
-    # wedge the stager so the queue stays full
+    om = OverlappedMerger(kt, 16, run_store=store, max_pending=1, stagers=1)
+    # wedge the one stage worker so the queue stays full
     import threading
     gate = threading.Event()
-    om._stage = lambda i, src, fed_t: gate.wait(5)
+
+    def wedged(i, src, fed_t):
+        gate.wait(5)        # and stages nothing
+
+    om._prepare = wedged
     b = crack(write_records([(b"k", b"v")]))
     om.feed(0, b)
     om.feed(1, b)

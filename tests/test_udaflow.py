@@ -770,8 +770,7 @@ def test_resledger_midpipeline_fault_and_seeded_leak(tmp_path, monkeypatch):
 
     make_mof_tree(str(tmp_path), "jobRL", 6, 1, 40, seed=11)
     engine = DataEngine(DirIndexResolver(str(tmp_path)))
-    cfg = Config({"uda.tpu.stage.pipeline": True,
-                  "uda.tpu.stage.pool": 2,
+    cfg = Config({"uda.tpu.stage.pool": 2,
                   "uda.tpu.fetch.retries": 0})
     mm = MergeManager(LocalFetchClient(engine), KT, cfg)
     try:

@@ -90,8 +90,6 @@ THREAD_ROOTS: Tuple[ThreadRoot, ...] = (
                "_worker_loop", "overlap stage pool workers"),
     ThreadRoot("merge.overlap.consumer", "merger/overlap.py",
                "_consumer_loop", "overlap stage consumer thread"),
-    ThreadRoot("merge.overlap.feeder", "merger/overlap.py", "_loop",
-               "overlap feeder thread"),
     ThreadRoot("bridge.merge", "bridge/bridge.py", "_merge_main",
                "bridge-side merge thread"),
     # -- daemons ---------------------------------------------------------

@@ -614,30 +614,14 @@ class MergeManager:
     # -- merge phase --------------------------------------------------------
 
     def merge_segments(self, segments: Sequence[Segment]) -> RecordBatch:
-        """Device-merge all fetched segments into one sorted batch.
-        Routed by ``uda.tpu.merge.two_phase``: the two-phase device sort
-        (per-run partial sort + HBM-resident merge tree) or the
-        whole-shuffle re-sort — byte-identical either way."""
+        """Device-merge all fetched segments into one sorted batch (the
+        hybrid route's LPQ merge): one device sort of the
+        concatenation."""
         batches = [s.record_batch() for s in segments]
         metrics.add("merge.records", sum(b.num_records for b in batches))
-        mode = merge_ops.resolve_merge_mode(
-            str(self.cfg.get("uda.tpu.merge.two_phase")), len(batches))
         with metrics.timer("merge"):
-            if mode == "two_phase":
-                return merge_ops.merge_batches_two_phase(
-                    batches, self.key_type, self.key_width)
             return merge_ops.merge_batches(batches, self.key_type,
                                            self.key_width)
-
-    def emit_framed(self, merged: RecordBatch,
-                    consumer: Callable[[memoryview], None]) -> int:
-        """Stream the sorted batch to ``consumer`` in IFile-framed blocks
-        of at most the staging-buffer size (the dataFromUda contract:
-        each call hands one filled KV block whose memory is only valid
-        during the call, reference UdaPlugin.java:368-402). Framing runs
-        through the native bulk framer when built (emit_batch). Returns
-        total bytes emitted."""
-        return self.emitter.emit_batch(merged, consumer)
 
     def run(self, job_id: str, map_ids: Sequence, reduce_id: int,
             consumer: Callable[[memoryview], None]) -> int:
@@ -963,12 +947,10 @@ class MergeManager:
             # pick via mapred.netmerger.merge.approach), now budget-
             # aware (uda_tpu.utils.budget): the transport's size
             # estimate routes through MemoryBudget.route —
-            #   in budget + small -> hybrid LPQ/RPQ (fastest at
-            #     small/mid scale: 1.05 GB: 102 s vs streaming 192 s);
-            #   in budget + large -> streaming online (wins at scale
-            #     with O(window) host memory: 10.24 GB: 579 s vs 866 s
-            #     at a third of the RSS) — REGRESSION_cpu_
-            #     x{,x}large_r05.json;
+            #   in budget + small -> hybrid LPQ/RPQ;
+            #   in budget + large -> streaming online (O(window) host
+            #     memory); crossover not measured on the chip (ROADMAP
+            #     D2c);
             #   over the HBM budget -> streaming, merged on the device
             #     in budget-sized groups (never an OOM); over the host
             #     budget -> streaming;
@@ -1011,10 +993,6 @@ class MergeManager:
         if approach == 2:
             from uda_tpu.merger.hybrid import run_hybrid
             return run_hybrid(self, job_id, map_ids, reduce_id, consumer)
-        if not streaming and not self.cfg.get("uda.tpu.merge.overlap"):
-            segments = self.fetch_all(job_id, map_ids, reduce_id)
-            merged = self.merge_segments(segments)
-            return self.emit_framed(merged, consumer)
         # the overlapped route builds the device row forest. The chip
         # is shared with every other live reduce task of this process
         # (a node's reduce slots): reserve this task's device need in
@@ -1119,13 +1097,6 @@ class MergeManager:
             else:
                 store = RunStore(spill_dirs(self.cfg),
                                  tag=f"{job_id}.r{reduce_id}")
-        # staged pipeline (uda.tpu.stage.pipeline, default on): stage
-        # pool + merge consumer with an in-flight byte budget; off =
-        # the serial stage loop (the A/B twin). Pool width:
-        # uda.tpu.stage.pool, else the legacy stagers knob, else auto.
-        pipelined = bool(self.cfg.get("uda.tpu.stage.pipeline"))
-        pool = int(self.cfg.get("uda.tpu.stage.pool"))
-        stagers = int(self.cfg.get("uda.tpu.online.stagers"))
         if ckpt is not None:
             mids = [m[1] if isinstance(m, tuple) else m for m in map_ids]
             collect = functools.partial(self._ckpt_state, job_id,
@@ -1133,9 +1104,8 @@ class MergeManager:
         om = OverlappedMerger(
             self.key_type, self.key_width, run_store=store,
             max_pending=self.window if streaming else 0,
-            stagers=pool if (pipelined and pool > 0) else stagers,
+            stagers=int(self.cfg.get("uda.tpu.stage.pool")),
             group_rows=group_rows,
-            pipeline=pipelined,
             inflight_bytes=stage_inflight_cap(
                 self.cfg, self.window, self.chunk_size,
                 budget=cap_budget),

@@ -27,8 +27,9 @@ VPU) but a **log-structured run forest**:
   the run's size, so a 1,024-map task puts tens of runs on the device,
   not 1,024. Rows are totally ordered (every column is key), so the
   merged run is the same bytes whichever engine merged a class;
-- ``finish()`` merges the O(log k) leftover runs, largest-capacity
-  last, and gathers the final byte permutation on host;
+- the finish (``emit_stream`` in memory, ``finish_streaming`` over a
+  run store) merges the O(log k) leftover runs, largest-capacity last,
+  and gathers the output a read-back slab at a time on the host;
 - a partition the chip cannot hold whole (``group_rows``, streaming
   mode: what admission reserved holds one GROUP of that many rows of
   run capacity, utils/budget.py) is merged on the device a group at a
@@ -40,10 +41,8 @@ VPU) but a **log-structured run forest**:
   by (segment, row) — so the joined order is the order one forest
   would have given, whichever group a record went through.
 
-**Staging pipeline** (``pipeline=True``, the deployment default via
-``uda.tpu.stage.pipeline``): staging is a true fetch→decompress→pack→
-stage pipeline instead of one stage-a-whole-segment-at-a-time loop. A
-bounded pool of stage workers runs the host-side work — segment
+**Staging pipeline**: fetch→decompress→pack→stage. A bounded pool of
+stage workers (``uda.tpu.stage.pool``) runs the host-side work — segment
 materialization (which includes the decompress tail and any pure-Python
 LZO blocks), vint-decode/pack, row-matrix build on reusable
 pre-allocated host buffers, run spooling — concurrently across
@@ -55,10 +54,7 @@ recycle after a transfer completes, and the finish drain). In-flight
 bytes are budgeted (``uda.tpu.stage.inflight.mb``): ``feed()`` blocks
 while fed-but-unmerged bytes would exceed the cap, which is the same
 credit-flow backpressure posture the bounded queue gives streaming mode
-(the reference's RDMA credit flow, MergeManager.cc:47-63). The serial
-path (``pipeline=False``) is kept verbatim as the correctness twin the
-A/B bench and the byte-identity tests diff against
-(scripts/bench_pipeline.py).
+(the reference's RDMA credit flow, MergeManager.cc:47-63).
 
 ``merge.wait_ms`` measures how long the merge waited for each run to
 become mergeable: feed()-to-staged latency (queue wait + decompress +
@@ -71,9 +67,9 @@ Stability contract (identical to ops.merge.merge_batches): the device
 rows carry (key words, content length, segment index, row index) as the
 composite sort key, so equal comparator keys order by original (segment,
 row) arrival — independent of fetch COMPLETION order, which under a
-randomized fetch schedule is nondeterministic. Pipelined and serial
-staging are byte-identical by construction for the same reason: forest
-insertion order never decides anything.
+randomized fetch schedule is nondeterministic. The order in which the
+pool's workers hand their runs to the consumer decides nothing either,
+for the same reason: forest insertion order never decides anything.
 
 Oversize keys: a key whose content exceeds the carried width is staged
 like any other — its first ``width`` bytes as words, its whole content
@@ -99,7 +95,7 @@ re-ordered, the ``oversize_fixup`` timer the scan and the re-order.
 Overflow fallback, for what the forest cannot order: a key type whose
 ``compare`` is its own (``not uses_default_bytewise``: the prefix says
 nothing about its order) latches ``_overflow`` at its first oversize
-key; staging stops and ``finish()`` / ``emit_stream()`` fall back to
+key; staging stops and ``emit_stream()`` falls back to
 the global device re-sort with host-side ranks
 (``ops.merge.merge_batches``: a concatenation, a pack, one device sort
 and a take over the whole partition). The streaming route (a run
@@ -188,9 +184,8 @@ class _Run:
     ``bucket`` is the binary-counter size class: staging assigns
     next_pow2(valid), each merge doubles it — so every record passes
     through at most log2(k) merges regardless of engine. ``lease`` is
-    the pool-owned host buffer backing a host run's ``rows`` (pipeline
-    mode), recycled when this run merges into a larger one or moves to
-    the device.
+    the pool-owned host buffer backing a host run's ``rows``, recycled
+    when this run merges into a larger one or moves to the device.
     """
 
     __slots__ = ("rows", "valid", "bucket", "lease")
@@ -230,8 +225,8 @@ class _StagedRun:
 
 # Reusable pre-allocated host row buffers (ops.merge.RowBufferPool).
 # Pallas engine: stage workers lease, the merge consumer recycles once
-# the jax.device_put transfer completes. Host runs (pipeline mode: the
-# host engine's, and the pallas engine's small classes): staged runs
+# the jax.device_put transfer completes. Host runs (the host engine's,
+# and the pallas engine's small classes): staged runs
 # AND merge outputs lease, each buffer recycled when its run merges
 # into a larger one — killing the per-merge large-alloc page-fault
 # churn that would otherwise dominate k*log2(k) merge traffic on this
@@ -262,17 +257,14 @@ class OverlappedMerger:
     emulation compiles an unrolled grid per shape), or "auto" (host on
     CPU, pallas elsewhere).
 
-    ``pipeline`` selects the staging architecture: False = the serial
-    stage-then-merge loop (one thread per ``stagers``, the r8 behavior
-    and the A/B baseline); True = the bounded stage pool + single merge
-    consumer (see module docstring). ``inflight_bytes`` > 0 bounds the
-    fed-but-unmerged bytes in either mode (feed() blocks — the
-    credit-flow backpressure).
+    Staging is a bounded pool of ``stagers`` stage workers (0 = a few,
+    ``_auto_width()``) and one merge consumer (see module docstring).
+    ``inflight_bytes`` > 0 bounds the fed-but-unmerged bytes (feed()
+    blocks — the credit-flow backpressure).
     """
 
     def __init__(self, key_type: KeyType, width: int, engine: str = "auto",
                  run_store=None, max_pending: int = 0, stagers: int = 0,
-                 device_runs: bool = True, pipeline: bool = False,
                  inflight_bytes: int = 0, on_spool=None,
                  group_rows: int = 0, on_record_bytes=None):
         self.key_type = key_type
@@ -292,19 +284,6 @@ class OverlappedMerger:
         self._seen_records = 0
         self._seen_bytes = 0
         self._booked_record_bytes = float(RECORD_BYTES_DEFAULT)
-        # device_runs=False (streaming mode only): the caller wants no
-        # run staged to the device — segments still spool to sorted run
-        # files and finish_streaming() merges the run FILES with the
-        # bounded k-way path instead of the device forest. Run files
-        # are written in (words, len) row order, which equals
-        # comparator order for within-width keys, so the k-way merge is
-        # correct on both the fast path and the overflow path.
-        # MergeManager never asks for it: an over-budget task merges on
-        # the device in groups (below).
-        self.device_runs = bool(device_runs)
-        if not self.device_runs and run_store is None:
-            raise MergeError("device_runs=False requires streaming mode "
-                             "(a run store)")
         # group_rows > 0 (streaming mode only): the forest may hold
         # that many rows of run capacity at a time (module docstring);
         # _group_held counts what it holds, _group_runs are the host
@@ -349,7 +328,7 @@ class OverlappedMerger:
                                         and uses_default_bytewise(key_type))
         self._oversize = False            # such a key was staged
         # udarace: lockfree=_error - first-error latch: a lagging racer
-        # overwrites with its own exception, either surfaces at finish()
+        # overwrites with its own exception, either surfaces at the finish
         self._error: Optional[Exception] = None
         self._merges = 0
         self._staged = 0
@@ -359,7 +338,7 @@ class OverlappedMerger:
         # udarace: lockfree=_device_pending - confined to carries
         # udarace: lockfree=_device_pending_bytes - confined to carries:
         # touched only inside _insert's _forest_lock (_merge,
-        # _merge_rows, _await_device_room run under it) or by finish's
+        # _merge_rows, _await_device_room run under it) or by the finish's
         # _merge_leftovers after _drain() has joined every stage thread
         self._device_staged_bytes = 0    # every run staged so far
         self._device_pending: deque = deque()   # (merge output, bytes)
@@ -398,55 +377,38 @@ class OverlappedMerger:
                       "fetch_feed_wait", "overflow_resort",
                       "overflow_rank", "oversize_fixup"):
             metrics.declare_timer(timer)
-        self.pipeline = bool(pipeline)
-        self._consumer_thread: Optional[threading.Thread] = None
-        if self.pipeline:
-            # bounded stage pool + single merge consumer. Pool width:
-            # explicit ``stagers`` wins; auto = a few workers (staging
-            # is numpy-heavy and releases the GIL, so width ~ cores).
-            nworkers = stagers if stagers > 0 else _auto_width()
-            # staged-run queue is bounded: a slow device consumer
-            # backpressures the workers (and, through the in-flight
-            # budget, the transports feeding feed())
-            self._staged_q: "queue.Queue" = queue.Queue(maxsize=nworkers + 2)
-            # host-buffer reuse where ownership hands off cleanly:
-            # pallas = rows are COPIED to the device (recycle after the
-            # transfer; interpret-mode device_put may alias numpy memory,
-            # so it owns its arrays), host+native = staged runs AND
-            # merge outputs lease (recycle when a run merges away), and
-            # large host merges split across threads at merge-path
-            # partition points — the merge half of the pipeline uses
-            # the cores the stage half leaves idle
-            self._buf_pool = None
-            self._merge_parts = 1
-            if self.engine == "pallas" and not self.interpret:
-                self._buf_pool = _RowBufferPool()
-            elif (self.engine == "host"
-                  and self._native_rows_merge is not None):
-                self._buf_pool = _RowBufferPool()
-                self._merge_parts = _auto_width()
-            self._workers = [
-                threading.Thread(target=self._worker_loop, daemon=True,
-                                 name=f"uda-stage-w{i}")
-                for i in range(nworkers)]
-            self._consumer_thread = threading.Thread(
-                target=self._consumer_loop, daemon=True,
-                name="uda-overlap-merge")
-            self._threads = self._workers + [self._consumer_thread]
-        else:
-            # serial staging (uda.tpu.online.stagers): pack+sort+spool
-            # of DIFFERENT segments parallelize; forest carries
-            # serialize under _forest_lock (the merge chain itself is
-            # one run at a time anyway). One thread when unset — the r4
-            # behavior.
-            self._staged_q = None
-            self._buf_pool = None
-            self._merge_parts = 1
-            self._workers = [
-                threading.Thread(target=self._loop, daemon=True,
-                                 name=f"uda-overlap-merge-{i}")
-                for i in range(max(1, stagers))]
-            self._threads = list(self._workers)
+        # bounded stage pool + single merge consumer. Pool width:
+        # explicit ``stagers`` wins; auto = a few workers (staging
+        # is numpy-heavy and releases the GIL, so width ~ cores).
+        nworkers = stagers if stagers > 0 else _auto_width()
+        # staged-run queue is bounded: a slow device consumer
+        # backpressures the workers (and, through the in-flight
+        # budget, the transports feeding feed())
+        self._staged_q: "queue.Queue" = queue.Queue(maxsize=nworkers + 2)
+        # host-buffer reuse where ownership hands off cleanly:
+        # pallas = rows are COPIED to the device (recycle after the
+        # transfer; interpret-mode device_put may alias numpy memory,
+        # so it owns its arrays), host+native = staged runs AND
+        # merge outputs lease (recycle when a run merges away), and
+        # large host merges split across threads at merge-path
+        # partition points — the merge half of the pipeline uses
+        # the cores the stage half leaves idle
+        self._buf_pool = None
+        self._merge_parts = 1
+        if self.engine == "pallas" and not self.interpret:
+            self._buf_pool = _RowBufferPool()
+        elif (self.engine == "host"
+              and self._native_rows_merge is not None):
+            self._buf_pool = _RowBufferPool()
+            self._merge_parts = _auto_width()
+        self._workers = [
+            threading.Thread(target=self._worker_loop, daemon=True,
+                             name=f"uda-stage-w{i}")
+            for i in range(nworkers)]
+        self._consumer_thread = threading.Thread(
+            target=self._consumer_loop, daemon=True,
+            name="uda-overlap-merge")
+        self._threads = self._workers + [self._consumer_thread]
         for t in self._threads:
             t.start()
 
@@ -459,7 +421,7 @@ class OverlappedMerger:
         materialization happens on a stage thread, and for a Segment
         whose crack was deferred that is where its bytes are cracked
         (Segment.record_batch), so a corrupt stream fails the task from
-        there, through ``_error`` at finish(). This call BLOCKS when
+        there, through ``_error`` at the finish. This call BLOCKS when
         staging lags — on the bounded queue (streaming mode) and on the
         in-flight bytes budget (``uda.tpu.stage.inflight.mb``) — which
         is the intended backpressure: the transport thread holds off
@@ -560,38 +522,7 @@ class OverlappedMerger:
             self._inflight_cv.notify_all()
         metrics.gauge_add("stage.inflight.bytes", -charge)
 
-    # -- serial merge thread (pipeline=False; the A/B baseline) -------------
-
-    def _loop(self) -> None:
-        with metrics.use_span(self._parent_span):
-            while True:
-                try:
-                    item = self._q.get(timeout=0.25)
-                except queue.Empty:
-                    if self._aborted:
-                        return  # abort() without a reachable poison pill
-                    continue
-                if item is None:
-                    return
-                seg_index, source, fed_t, charge = item
-                if self._error is not None or self._aborted:
-                    self._release_charge(charge)
-                    continue  # drain; finish() will surface the error
-                try:
-                    self._stage(seg_index, source, fed_t)
-                except Exception as e:  # surfaced at finish()
-                    self._error = e
-                finally:
-                    self._release_charge(charge)
-
-    def _stage(self, seg_index: int, source, fed_t: float) -> None:
-        staged = self._prepare(seg_index, source, fed_t)
-        if staged is None:
-            return
-        self._observe_wait(fed_t)
-        self._consume_run(staged)
-
-    # -- pipelined staging (pipeline=True) -----------------------------------
+    # -- staging: the stage pool and the merge consumer --------------------
 
     def _worker_loop(self) -> None:
         """Stage worker: decompress/materialize + pack + row build +
@@ -613,7 +544,7 @@ class OverlappedMerger:
                     continue
                 try:
                     staged = self._prepare(seg_index, source, fed_t)
-                except Exception as e:  # surfaced at finish()
+                except Exception as e:  # surfaced at the finish
                     self._error = e
                     self._release_charge(charge)
                     continue
@@ -636,8 +567,7 @@ class OverlappedMerger:
         """The merge loop as a consumer of staged runs: device_put of
         the next run is dispatched while the previous run's merges are
         still executing (async dispatch); the forest carry serializes
-        here, which also makes _forest_lock uncontended in pipeline
-        mode."""
+        here, which also makes _forest_lock uncontended."""
         with metrics.use_span(self._parent_span):
             # merge.wait spans: the consumer's blocked-on-staging time
             # as a first-class trace lane (the span twin of the
@@ -664,7 +594,7 @@ class OverlappedMerger:
                     self._observe_wait(staged.fed_t)
                     self._consume_run(staged)
                     metrics.add("merge.pipeline.runs")
-                except Exception as e:  # surfaced at finish()
+                except Exception as e:  # surfaced at the finish
                     self._error = e
                     self._recycle(staged)
                 finally:
@@ -734,7 +664,7 @@ class OverlappedMerger:
         with self._state_lock:
             self._staged += 1
         metrics.add("merge.records", n)
-        if self._overflow or not self.device_runs:
+        if self._overflow:
             self._release_rows(lease)
             return
         self._consume_run(_StagedRun(seg_index, rows, n, lease,
@@ -777,10 +707,10 @@ class OverlappedMerger:
         crack was deferred joins and cracks its chunks here, under its
         own ``fetch_crack`` timer), pack, per-run sort, spool.
         Returns the device-bound staged run, or None when nothing needs
-        the forest (empty segment, spool-only modes, overflow)."""
+        the forest (empty segment, overflow)."""
         streaming = self.run_store is not None
         if self._overflow and not streaming:
-            return None  # fast path already disabled; finish() re-sorts
+            return None  # fast path already disabled; the emit re-sorts
         batch = self._batch_of(source)
         n = batch.num_records
         if n == 0:
@@ -829,7 +759,7 @@ class OverlappedMerger:
             with self._state_lock:
                 self._staged += 1
             metrics.add("merge.records", n)
-            if self._overflow or not self.device_runs:
+            if self._overflow:
                 self._observe_wait(fed_t)
                 return None  # forest output won't be consumed; runs suffice
             kept = True
@@ -1039,8 +969,8 @@ class OverlappedMerger:
 
     def _host_rows(self, rows: int, cols: int):
         """A host row buffer and its pool lease. No pool in this mode
-        (serial staging, interpret-mode pallas): a fresh array that
-        nobody reuses, lease None."""
+        (interpret-mode pallas, the host engine without the native
+        merge): a fresh array that nobody reuses, lease None."""
         if self._buf_pool is None:
             return np.empty((rows, cols), np.uint32), None
         buf = self._buf_pool.lease(rows, cols)
@@ -1065,8 +995,9 @@ class OverlappedMerger:
 
     def _insert(self, run: _Run) -> None:
         # binary-counter carry: equal size classes merge immediately.
-        # The lock serializes carries across the staging pool (pack/
-        # sort/spool of other segments proceed concurrently).
+        # Carries run on the one merge consumer (and in adopt_run, before
+        # any feed) while the stage workers pack/sort/spool other
+        # segments; the lock keeps them and the finish's reads apart.
         with self._forest_lock:
             if not run.on_host:
                 self._device_staged_bytes += int(run.rows.nbytes)
@@ -1101,13 +1032,13 @@ class OverlappedMerger:
         return _Run(merged, a.valid + b.valid, bucket, lease)
 
     def _merge_rows(self, a: _Run, b: _Run):
-        """One pairwise run merge. Host engine in pipeline mode merges
+        """One pairwise run merge. The host engine with a pool merges
         into a pool-leased output buffer (no per-merge large-alloc
         page faults) and splits large merges across threads at
         merge-path partition points (the native call releases the GIL);
         the inputs' leases recycle immediately. The pallas engine's
-        host classes: _merge_rows_host. Every other engine/mode keeps
-        the plain merge_row_pair path."""
+        host classes: _merge_rows_host. The rest takes the plain
+        merge_row_pair path."""
         if self.engine == "host" and self._buf_pool is not None:
             total = a.valid + b.valid
             out = self._buf_pool.lease(total, int(a.rows.shape[1]))
@@ -1202,14 +1133,11 @@ class OverlappedMerger:
     def stats(self) -> dict:
         """Counters for observability/tests: merges that have completed
         and segments staged so far (both monotone)."""
-        pending = self._q.qsize()
-        if self._staged_q is not None:
-            pending += self._staged_q.qsize()
+        pending = self._q.qsize() + self._staged_q.qsize()
         return {"device_merges": self._merges, "staged_runs": self._staged,
                 "device_groups": self._groups,
                 "pending": pending, "overflow": self._overflow,
                 "oversize": self._oversize,
-                "pipeline": self.pipeline,
                 "inflight_bytes": self._inflight}
 
     def _reap_input_queue(self) -> None:
@@ -1231,8 +1159,6 @@ class OverlappedMerger:
         abort/error drain never leaks in-flight bytes (the gauge must
         return to zero)."""
         self._reap_input_queue()
-        if self._staged_q is None:
-            return
         while True:
             try:
                 staged = self._staged_q.get_nowait()
@@ -1247,9 +1173,8 @@ class OverlappedMerger:
             self._q.put(None)
         for t in self._workers:
             t.join()
-        if self._consumer_thread is not None:
-            self._staged_q.put(None)
-            self._consumer_thread.join()
+        self._staged_q.put(None)
+        self._consumer_thread.join()
         # error paths drop their items without consuming them
         self._reap_pending()
         if self._error is not None:
@@ -1446,37 +1371,9 @@ class OverlappedMerger:
                 pairs, _ = self._fix_oversize_blocks(held, batches, True)
             yield pairs
 
-    def finish(self, batches: Sequence[RecordBatch]) -> RecordBatch:
-        """Drain, merge the leftover forest, and materialize the sorted
-        batch. ``batches`` must be ALL segments' batches in original
-        segment-index order (the indices fed to :meth:`feed`)."""
-        acc = None
-        try:
-            self._drain()
-            if self._overflow:
-                return self._overflow_resort(batches)
-            cat = RecordBatch.concat(list(batches))
-            acc = self._merge_leftovers()
-            if not self._check_accounting(acc, cat.num_records):
-                return cat  # all segments legitimately empty
-            rows = np.asarray(acc.rows)[:acc.valid]
-            if self._oversize:
-                with metrics.timer("oversize_fixup"):
-                    pairs, _ = self._fix_oversize_blocks(rows, batches, True)
-            else:
-                pairs = self._row_pairs(rows)
-            seg_col = pairs[:, 0].astype(np.int64)
-            row_col = pairs[:, 1].astype(np.int64)
-            sizes = np.asarray([b.num_records for b in batches], np.int64)
-            offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]])
-            perm = offsets[seg_col] + row_col
-            return cat.take(perm)
-        finally:
-            self._finish_cleanup(acc)
-
     def emit_stream(self, sources: Sequence, emitter, consumer) -> int:
-        """In-memory streaming emission: the same result bytes as
-        ``emitter.emit_batch(self.finish(batches))`` but without ever
+        """In-memory streaming emission: drain staging, merge the
+        leftover forest and emit the merged stream without ever
         concatenating the shuffle — each output slab's bytes are
         gathered straight from the per-segment batches and framed
         natively, so transient host memory is one slab (the reference's
@@ -1550,7 +1447,7 @@ class OverlappedMerger:
                 self._drain()
                 # read the latch only now: a segment still being staged
                 # when finish was called may be the one that sets it
-                no_forest = self._overflow or not self.device_runs
+                no_forest = self._overflow
                 if not no_forest:
                     acc = (self._join_groups() if self._group_rows
                            else self._merge_leftovers())
@@ -1568,11 +1465,7 @@ class OverlappedMerger:
                 # runs by (words, len) == comparator order), so the
                 # fallback is a comparator-level k-way merge over the
                 # run FILES — bounded memory, like the hybrid RPQ
-                if self._overflow:
-                    self._note_overflow_fallback("k-way merge over run files")
-                else:
-                    log.info("streaming without device runs: k-way merge "
-                             "over run files (no device forest)")
+                self._note_overflow_fallback("k-way merge over run files")
                 paths = [store.run_path(s) for s in sorted(store.counts)]
                 if (native_enabled() and native.kway_supported(self.key_type)
                         and native.build()):
@@ -1616,11 +1509,10 @@ class OverlappedMerger:
             self._q.put_nowait(None)  # best effort: wake one instantly
         except queue.Full:
             pass
-        if self._staged_q is not None:
-            try:
-                self._staged_q.put_nowait(None)
-            except queue.Full:
-                pass
+        try:
+            self._staged_q.put_nowait(None)
+        except queue.Full:
+            pass
         with self._inflight_cv:
             self._inflight_cv.notify_all()  # wake budget-blocked feeds
         deadline = 10.0

@@ -12,18 +12,13 @@ correctness oracle the device path is diffed against, and (b) as the
 actual merge path when no accelerator is present — mirroring the
 reference's fallback-to-vanilla philosophy (SURVEY §5) inside the engine.
 
-``merge_batches_two_phase`` is the TopSort-shaped alternative
-(arXiv:2205.07991: structure the sorter around HBM bandwidth, not
-compute): instead of re-sorting the concatenation of k sorted runs —
-O(n log n) compare-exchange over the whole shuffle — each run is
+The run-row helpers here (``stage_run_rows``, ``merge_row_pair``,
+``RowBufferPool``, ...) build and merge the sorted runs of the
+overlapped merger's forest (uda_tpu.merger.overlap): each run is
 partially sorted on its own (usually just the monotonicity check: Hadoop
-map outputs arrive comparator-sorted) and the runs then fold through an
-HBM-resident pairwise merge tree (the O(n log k) merge-path kernel /
-native linear merge), so every record moves through at most log2(k)
-merges and the gather-bound small-batch regime never pays a whole-
-shuffle re-sort. The row-building helpers here are shared with the
-overlapped merger (uda_tpu.merger.overlap), which is the same merge
-tree fed online.
+map outputs arrive comparator-sorted) and the runs fold through a
+pairwise merge tree (the O(n log k) merge-path kernel / native linear
+merge), so every record moves through at most log2(k) merges.
 """
 
 from __future__ import annotations
@@ -52,7 +47,6 @@ def _buf_key(flat: np.ndarray) -> int:
 
 __all__ = ["merge_batches", "merge_batches_host", "merge_iter_host",
            "merge_record_streams", "sorted_batch_order",
-           "merge_batches_two_phase", "resolve_merge_mode",
            "resolve_run_engine", "resolve_native_rows_merge",
            "lex_cols_sorted", "run_row_order", "fill_run_rows",
            "stage_run_rows",
@@ -60,7 +54,7 @@ __all__ = ["merge_batches", "merge_batches_host", "merge_iter_host",
            "RowBufferPool", "next_run_capacity", "pad_rows_to",
            "PAD_WORD", "MIN_RUN_CAPACITY", "ROW_EXTRA_COLS"]
 
-# -- shared run-row machinery (the overlap forest + two-phase merge) --------
+# -- run-row machinery (the overlap forest) ---------------------------------
 
 # Padding word for device runs: all-0xFFFFFFFF rows sort strictly after
 # every real row (a real row's length column is a content length < 2^31),
@@ -433,126 +427,15 @@ def merge_iter_host(batches: Sequence[RecordBatch],
     return merge_record_streams([b.iter_records() for b in batches], kt)
 
 
-# -- two-phase device sort ---------------------------------------------------
-
-def resolve_merge_mode(mode: str, num_batches: int) -> str:
-    """Batch-count/backend-aware routing between the whole-shuffle
-    re-sort ("resort") and the two-phase partial-sort + HBM merge tree
-    ("two_phase"). "auto" takes two-phase on real accelerators (the
-    re-sort's final permutation gather was the small-batch bottleneck
-    in the take-ramp probe of 2026-07-31 on a backend that no longer
-    exists — git history; not measured on this machine) and
-    keeps the re-sort on the XLA CPU backend, where one lexsort-shaped
-    sort beats Python-orchestrated pairwise folds. Resolution is EAGER,
-    never inside a jitted trace."""
-    if mode not in ("auto", "on", "off"):
-        from uda_tpu.utils.errors import MergeError
-
-        raise MergeError(f"unknown merge two-phase mode {mode!r}")
-    if num_batches < 2:
-        return "resort"
-    if mode == "on":
-        return "two_phase"
-    if mode == "off":
-        return "resort"
-    return "two_phase" if jax.default_backend() == "tpu" else "resort"
-
-
-def merge_batches_two_phase(batches: Sequence[RecordBatch], kt: KeyType,
-                            width: int, engine: str = "auto",
-                            interpret: Optional[bool] = None) -> RecordBatch:
-    """Two-phase merge of k segments: per-run partial sort (usually just
-    the monotonicity check) + pairwise HBM-resident merge tree, instead
-    of re-sorting the concatenation (see module docstring).
-
-    Byte-identical to :func:`merge_batches` by construction: the rows
-    carry (words, len, segment, row) as a total composite key, so equal
-    comparator keys order by original (segment, row) arrival — exactly
-    the stable-sort contract. Overflow keys (content wider than the
-    carried width) need a globally consistent rank column, which only
-    the concatenation view can provide — those fall back to
-    :func:`merge_batches` (correctness never depends on the fast path
-    applying)."""
-    # the concatenation is only needed for the final take — defer it so
-    # the fallback paths (which concat inside merge_batches) never hold
-    # two transient copies of a multi-GB shuffle
-    if sum(b.num_records for b in batches) == 0 or len(batches) < 2:
-        return merge_batches(batches, kt, width)
-    engine = resolve_run_engine(engine)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    native_merge = resolve_native_rows_merge() if engine == "host" else None
-    runs: list[tuple] = []  # (rows, valid) per non-empty segment
-    kw = width // 4
-    for seg_index, b in enumerate(batches):
-        n = b.num_records
-        if n == 0:
-            continue
-        packed = packing.pack_keys(b, kt, width)
-        if int(np.max(packed.key_lens, initial=0)) > width:
-            return merge_batches(batches, kt, width)  # overflow fallback
-        cap = next_run_capacity(n) if engine == "pallas" else n
-        rows = np.empty((cap, kw + ROW_EXTRA_COLS), np.uint32)
-        fill_run_rows(rows, packed, run_row_order(packed), seg_index)
-        if engine == "pallas":
-            rows = jax.device_put(rows)
-        runs.append((rows, n))
-    if not runs:  # unreachable given the record-count early-out; guard
-        return merge_batches(batches, kt, width)
-    metrics.add("merge.pipeline.two_phase")
-    rows, valid = _fold_runs(runs, engine, interpret, native_merge)
-    rows = np.asarray(rows)[:valid]
-    seg_col = rows[:, kw + 1].astype(np.int64)
-    row_col = rows[:, kw + 2].astype(np.int64)
-    sizes = np.asarray([b.num_records for b in batches], np.int64)
-    offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]])
-    cat = RecordBatch.concat(list(batches))
-    return cat.take(offsets[seg_col] + row_col)
-
-
 def pad_rows_to(rows, capacity: int):
     """Pad a device run up to ``capacity`` rows with PAD_WORD rows.
     Padding rows sort strictly last, so the validity prefix is
     preserved; capacities stay powers of two, keeping pallas kernel
-    shapes in the O(log) compiled set. The ONE implementation of the
-    pad-up invariant — shared by :func:`_fold_runs` and the overlap
-    forest's leftover merge (merger.overlap), which encode the same
-    binary-counter fold over different run carriers."""
+    shapes in the O(log) compiled set (the overlap forest's leftover
+    merge, merger.overlap)."""
     cur = int(rows.shape[0])
     if cur >= capacity:
         return rows
     pad = np.full((capacity - cur, int(rows.shape[1])), PAD_WORD,
                   np.uint32)
     return jax.numpy.concatenate([rows, jax.device_put(pad)], axis=0)
-
-
-def _fold_runs(runs: list, engine: str, interpret: bool, native_merge):
-    """Binary-counter fold of sorted (rows, valid) runs: equal
-    capacity classes merge immediately, leftovers merge smallest-first
-    (pallas runs pad the smaller operand up to the larger capacity —
-    :func:`pad_rows_to`). Same fold shape as the overlap forest's
-    _insert/_merge_leftovers (merger.overlap), which carries _Run
-    objects with locks and pool leases instead of bare (rows, valid)
-    tuples — a semantic change here must land there too."""
-    forest: dict[int, tuple] = {}  # bucket -> (rows, valid)
-    for rows, valid in runs:
-        bucket = next_run_capacity(valid)
-        while bucket in forest:
-            o_rows, o_valid = forest.pop(bucket)
-            rows = merge_row_pair(o_rows, rows, o_valid, valid, engine,
-                                  interpret, native_merge)
-            valid += o_valid
-            bucket *= 2
-        forest[bucket] = (rows, valid)
-    acc_rows, acc_valid = None, 0
-    for bucket in sorted(forest):
-        rows, valid = forest[bucket]
-        if acc_rows is None:
-            acc_rows, acc_valid = rows, valid
-            continue
-        if engine == "pallas" and acc_rows.shape[0] < rows.shape[0]:
-            acc_rows = pad_rows_to(acc_rows, int(rows.shape[0]))
-        acc_rows = merge_row_pair(acc_rows, rows, acc_valid, valid, engine,
-                                  interpret, native_merge)
-        acc_valid += valid
-    return acc_rows, acc_valid

@@ -101,9 +101,6 @@ _FLAG_LIST = [
          "reference's 1000-chunk server pool)"),
     Flag("uda.tpu.use.native", True, bool,
          "use the C++ native codec/reader library when built"),
-    Flag("uda.tpu.merge.overlap", True, bool,
-         "overlap device merge with fetching (the network-levitated "
-         "property); off = merge once after all fetches complete"),
     Flag("uda.tpu.spill.dirs", "", str,
          "comma-separated local dirs for LPQ spill files (round-robin, "
          "like the reference's local-dir rotation); empty = system tmp"),
@@ -113,23 +110,12 @@ _FLAG_LIST = [
          "memory to the fetch window (the reference's 1 MB staging-loop "
          "memory model, StreamRW.cc:151-225); off = keep every segment "
          "host-resident through emission"),
-    Flag("uda.tpu.online.stagers", 0, int,
-         "overlap staging worker threads (pack+sort+spool per segment); "
-         "0 = single merge thread (serial mode; with "
-         "uda.tpu.stage.pipeline this is superseded by uda.tpu.stage.pool)"),
     # --- staged fetch->decompress->pack->stage pipeline (merger/overlap) ---
-    Flag("uda.tpu.stage.pipeline", True, bool,
-         "pipelined staging: a bounded stage-worker pool (decompress + "
-         "vint-decode/pack + row build + spool, concurrent across "
-         "segments, reusable pre-allocated host buffers) feeds ONE "
-         "merge consumer that overlaps jax.device_put of the next run "
-         "with the device merge of the current one. off = the serial "
-         "stage-one-segment-at-a-time loop (the byte-identical "
-         "correctness twin, scripts/bench_pipeline.py A/Bs the two)"),
     Flag("uda.tpu.stage.pool", 0, int,
-         "stage-pipeline worker count; 0 = auto (a few workers, "
-         "~min(4, cores) — staging is numpy-heavy and releases the "
-         "GIL). Ignored when uda.tpu.stage.pipeline is off"),
+         "stage-pipeline worker count (decompress + vint-decode/pack + "
+         "row build + spool, concurrent across segments, feeding ONE "
+         "merge consumer); 0 = auto (a few workers, ~min(4, cores) — "
+         "staging is numpy-heavy and releases the GIL)"),
     Flag("uda.tpu.stage.inflight.mb", 0, int,
          "in-flight staging budget in MB: bytes fed to the overlap "
          "merger but not yet merged/spooled; feed() blocks past it "
@@ -137,12 +123,6 @@ _FLAG_LIST = [
          "stage.backpressure_events). 0 = auto: max(256 MB, 2x the "
          "fetch window), capped to half the host budget when one is "
          "already built (utils.budget.stage_inflight_cap)"),
-    Flag("uda.tpu.merge.two_phase", "auto", str,
-         "non-overlapped merge routing: 'on' = two-phase device sort "
-         "(per-run partial sort + HBM-resident pairwise merge tree, "
-         "ops.merge.merge_batches_two_phase), 'off' = whole-shuffle "
-         "re-sort of the concatenation, 'auto' = two-phase on TPU "
-         "backends / re-sort on CPU. Byte-identical either way"),
     # --- failure-domain knobs (failpoints + retrying fetch path) ---
     Flag("mapred.rdma.fetch.retry.backoff.ms", 0, int,
          "base exponential backoff between fetch retries in ms, doubling "

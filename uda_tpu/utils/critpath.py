@@ -3,7 +3,7 @@
 The reference accounts every reduce task into exactly three buckets —
 ``total_wait_mem_time`` / ``total_fetch_time`` / ``total_merge_time``
 (reducer.h:80-90) — which PR 2 mirrored as counter aliases. After the
-evloop data plane, the staging pipeline and the two-phase merge, three
+evloop data plane, the staging pipeline and the run forest, three
 numbers cannot say which STAGE owns the wall-clock: fetch overlaps
 decompress overlaps device merges, so the timer sums legitimately
 exceed the wall. This module answers the real question over the

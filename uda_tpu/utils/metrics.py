@@ -309,12 +309,6 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                        "pipeline's merge consumer "
                                        "(device_put overlapped with "
                                        "the previous run's merges)"),
-    "merge.pipeline.two_phase": ("counter", "non-overlapped merges "
-                                            "routed to the two-phase "
-                                            "device sort (partial "
-                                            "sort + HBM merge tree) "
-                                            "instead of the "
-                                            "concatenation re-sort"),
     "exchange.rounds": ("counter", "all-to-all exchange rounds executed"),
     "exchange.sample.keys": ("counter", "whole keys a distributed sort "
                                         "step sampled from its own input, "

@@ -2,7 +2,7 @@
 
 The metrics layer counts WHAT happened (bytes, chunks, retries) and the
 tracer records WHEN each phase ran; after the evloop data plane, the
-staging pipeline and the two-phase merge, neither says which *code*
+staging pipeline and the run forest, neither says which *code*
 burns the time inside a phase. This module is the missing layer: one
 daemon thread walks ``sys._current_frames()`` at ``uda.tpu.profile.hz``
 (``UDA_TPU_PROFILE=<hz>`` env; 0 = off) and attributes every thread's
