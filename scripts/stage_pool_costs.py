@@ -18,8 +18,10 @@ comparator uda.tpu.RawBytes, width 16 as the reduce cells run). Segment
 kinds: ``presorted`` (every Hadoop map output), ``unsorted`` (the same
 records in arrival order: an exchange bucket, a foreign writer) and
 ``compressed`` (presorted, but the worker first zlib-inflates the framed
-bytes and cracks them, as the decompress tail of a compressed fetch
-does). The one-worker time is the work; what four workers take over a
+bytes and cracks them: the probe's model of an inflate on the stage
+pool, NOT what the served path does — there ``DecompressingClient``
+inflates on the thread that completes the fetch, ``PERF.md`` section 7,
+"What ``reduce_invindex_compressed`` opens"). The one-worker time is the work; what four workers take over a
 quarter of it is the convoy on the interpreter lock.
 
     chiprun -- python3 scripts/stage_pool_costs.py

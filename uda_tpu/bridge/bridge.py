@@ -495,8 +495,12 @@ class UdaBridge:
         if comp_alg and comp_alg not in ("0", "null", "None"):
             self.cfg.set("mapred.compress.map.output", True)
             self.cfg.set("mapred.map.output.compression.codec", comp_alg)
-            if comp_block:
-                self.cfg.set("io.compression.codec.lzo.buffersize",
+            # under the codec's own key (DefaultCodec has none: its
+            # blocks are io.file.buffer.size, which nothing here reads)
+            family = next((f for f in ("snappy", "lzo")
+                           if f in comp_alg.lower()), None)
+            if comp_block and family:
+                self.cfg.set(f"io.compression.codec.{family}.buffersize",
                              comp_block)
         num_dirs = int(params[10]) if len(params) > 10 else 0
         return params[11:11 + num_dirs]

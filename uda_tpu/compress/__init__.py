@@ -25,6 +25,7 @@ import ctypes
 import ctypes.util
 import struct
 import threading
+import time
 import zlib
 from typing import Callable, Dict, Optional
 
@@ -42,6 +43,13 @@ __all__ = ["Codec", "get_codec", "register_codec", "compress_block_stream",
 log = get_logger()
 
 BLOCK_HEADER = struct.Struct(">II")  # (uncompressed_len, compressed_len)
+
+# one completion's counters go in with ONE locked update (add_keyed)
+_K_INFLATE = metrics.timer_series("fetch_inflate")
+_K_BYTES = metrics.series("decompress.bytes")
+_K_BLOCKS = metrics.series("decompress.blocks")
+_K_WIRE = metrics.series("decompress.wire_bytes")
+_K_CARRY = metrics.series("decompress.carry_bytes")
 
 
 class Codec:
@@ -164,10 +172,10 @@ def compress_block_stream(data: bytes, codec: Codec,
     return bytes(out)
 
 
-def decompress_block_stream(data: bytes, codec: Codec) -> bytes:
-    """Inverse of compress_block_stream (whole-buffer convenience)."""
+def _inflate_whole(data: bytes, codec: Codec) -> tuple:
+    """-> (uncompressed bytes, blocks) of a whole block stream."""
     out = bytearray()
-    pos = 0
+    pos = blocks = 0
     while pos < len(data):
         if pos + BLOCK_HEADER.size > len(data):
             raise CompressionError("truncated block header")
@@ -177,7 +185,28 @@ def decompress_block_stream(data: bytes, codec: Codec) -> bytes:
             raise CompressionError("truncated block body")
         out += codec.decompress(bytes(data[pos:pos + comp_len]), raw_len)
         pos += comp_len
-    return bytes(out)
+        blocks += 1
+    return bytes(out), blocks
+
+
+def decompress_block_stream(data: bytes, codec: Codec) -> bytes:
+    """Inverse of compress_block_stream (whole-buffer convenience)."""
+    return _inflate_whole(data, codec)[0]
+
+
+def _timed_inflate(span, fn, *args) -> tuple:
+    """``fn(*args)`` inside the fetch_inflate timer -> (its result, the
+    seconds the caller still has to book). With spans on it is
+    metrics.timer under ``span`` — a span outside the task's trace is
+    one critpath never sees — which books its own seconds; with spans
+    off two stamps, and the seconds ride the caller's one locked
+    counter update."""
+    if metrics.record_spans:
+        with metrics.use_span(span), metrics.timer("fetch_inflate"):
+            return fn(*args), 0.0
+    t0 = time.perf_counter()
+    out = fn(*args)
+    return out, time.perf_counter() - t0
 
 
 class _StreamState:
@@ -225,6 +254,12 @@ class DecompressingClient(InputClient):
         self.comp_chunk_size = comp_chunk_size
         self._streams: dict[tuple, _StreamState] = {}
         self._lock = threading.Lock()
+        # a compressed task that inflated nothing reads 0, where a
+        # program without these counters reads nothing
+        metrics.add_keyed((_K_BYTES, 0.0), (_K_BLOCKS, 0.0), (_K_WIRE, 0.0),
+                          (_K_CARRY, 0.0))
+        metrics.add("decompress.fetches", 0)
+        metrics.declare_timer("fetch_inflate")
 
     def estimate_partition_bytes(self, job_id: str, map_ids,
                                  reduce_id: int):
@@ -256,12 +291,16 @@ class DecompressingClient(InputClient):
         byte-agnostic contract): delegate to the inner transport and
         decompress the rebuilt partition on the way up, delivering the
         same uncompressed domain a fetched stream would."""
+        span = metrics.current_span()   # the recovering segment's
+
         def _done(res) -> None:
             if not isinstance(res, Exception):
                 try:
-                    out = decompress_block_stream(bytes(res.data),
-                                                  self.codec)
-                    metrics.add("decompress.bytes", len(out))
+                    (out, blocks), secs = _timed_inflate(
+                        span, _inflate_whole, bytes(res.data), self.codec)
+                    metrics.add_keyed(
+                        (_K_INFLATE, secs), (_K_BYTES, len(out)),
+                        (_K_BLOCKS, blocks), (_K_WIRE, len(res.data)))
                     res = FetchResult(out, len(out), res.part_length,
                                       0, res.path, last=True)
                 except Exception as e:  # noqa: BLE001 - a corrupt
@@ -304,6 +343,11 @@ class DecompressingClient(InputClient):
                                    comp_offset,
                                    self.comp_chunk_size or req.chunk_size,
                                    host=req.host)
+        # the fetching segment's span (it issues under it): the parent
+        # of this completion's fetch_inflate span, since the completion
+        # thread has no ambient context
+        span = metrics.current_span()
+        metrics.add("decompress.fetches")
 
         def _done(res) -> None:
             # decide + mutate under st.mu, deliver after releasing it
@@ -339,7 +383,7 @@ class DecompressingClient(InputClient):
                             f"{req.map_id}:{res.offset}")
                     else:
                         try:
-                            res = self._ingest(key, st, req, res)
+                            res = self._ingest(key, st, req, res, span)
                         except Exception as e:  # noqa: BLE001 - to segment
                             with self._lock:
                                 self._streams.pop(key, None)
@@ -349,12 +393,27 @@ class DecompressingClient(InputClient):
         self.inner.start_fetch(inner_req, _done)
 
     def _ingest(self, key, st: _StreamState, req: ShuffleRequest,
-                res: FetchResult) -> FetchResult:
+                res: FetchResult, span) -> FetchResult:
+        """st.mu held: one inner completion inflated, under the
+        fetch_inflate timer as Segment._ingest's crack is under
+        fetch_crack — its seconds and the completion's counters in one
+        locked update."""
+        wire = len(res.data)
+        (out, blocks), secs = _timed_inflate(span, self._inflate, key, st,
+                                             req, res)
+        metrics.add_keyed((_K_INFLATE, secs), (_K_BYTES, len(out.data)),
+                          (_K_BLOCKS, blocks), (_K_WIRE, wire),
+                          (_K_CARRY, len(st.carry)))
+        return out
+
+    def _inflate(self, key, st: _StreamState, req: ShuffleRequest,
+                 res: FetchResult) -> tuple:
+        """-> (the uncompressed-domain FetchResult, blocks inflated)."""
         st.part_length = res.part_length
         st.comp_offset = res.offset + len(res.data)
         data = st.carry + res.data
         out = bytearray()
-        pos = 0
+        pos = blocks = 0
         while pos + BLOCK_HEADER.size <= len(data):
             raw_len, comp_len = BLOCK_HEADER.unpack_from(data, pos)
             if pos + BLOCK_HEADER.size + comp_len > len(data):
@@ -368,9 +427,8 @@ class DecompressingClient(InputClient):
                       key=f"{req.map_id}@{res.offset}")
             out += self.codec.decompress(body, raw_len)
             pos += BLOCK_HEADER.size + comp_len
+            blocks += 1
         st.carry = bytes(data[pos:])
-        if out:
-            metrics.add("decompress.bytes", len(out))
         comp_done = st.comp_offset >= (st.part_length or 0)
         if comp_done and st.carry:
             raise CompressionError(
@@ -384,7 +442,7 @@ class DecompressingClient(InputClient):
             with self._lock:
                 self._streams.pop(key, None)
         return FetchResult(bytes(out), raw_length, res.part_length,
-                           offset, res.path, last=comp_done)
+                           offset, res.path, last=comp_done), blocks
 
     def stop(self) -> None:
         self.inner.stop()

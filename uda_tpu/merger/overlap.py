@@ -41,10 +41,12 @@ VPU) but a **log-structured run forest**:
   by (segment, row) — so the joined order is the order one forest
   would have given, whichever group a record went through.
 
-**Staging pipeline**: fetch→decompress→pack→stage. A bounded pool of
+**Staging pipeline**: fetch→pack→stage. A bounded pool of
 stage workers (``uda.tpu.stage.pool``) runs the host-side work — segment
-materialization (which includes the decompress tail and any pure-Python
-LZO blocks), vint-decode/pack, row-matrix build on reusable
+materialization (the join and crack of a segment whose chunks were kept;
+a compressed segment's blocks are inflated before it gets here, by
+``compress.DecompressingClient`` on the thread that completes each
+inner fetch), vint-decode/pack, row-matrix build on reusable
 pre-allocated host buffers, run spooling — concurrently across
 DIFFERENT segments, while ONE merge consumer drains the staged-run
 queue: it dispatches ``jax.device_put`` of the next run while the
@@ -57,7 +59,7 @@ credit-flow backpressure posture the bounded queue gives streaming mode
 (the reference's RDMA credit flow, MergeManager.cc:47-63).
 
 ``merge.wait_ms`` measures how long the merge waited for each run to
-become mergeable: feed()-to-staged latency (queue wait + decompress +
+become mergeable: feed()-to-staged latency (queue wait + materialize +
 pack + spool). Its complement is the ``feed()`` backpressure block
 (``stage.backpressure_events``) — together they say whether the device
 is starved by the host (high wait) or the host is throttled by the
@@ -525,7 +527,7 @@ class OverlappedMerger:
     # -- staging: the stage pool and the merge consumer --------------------
 
     def _worker_loop(self) -> None:
-        """Stage worker: decompress/materialize + pack + row build +
+        """Stage worker: materialize + pack + row build +
         spool for ONE segment at a time, concurrently across workers;
         finished runs queue for the merge consumer."""
         with metrics.use_span(self._parent_span):
@@ -617,8 +619,8 @@ class OverlappedMerger:
     @staticmethod
     def _observe_wait(fed_t: float) -> None:
         # merge-wait: how long the merge waited for this run to become
-        # mergeable after its segment was fed (queue wait + decompress
-        # tail + pack + spool). Its complement is the feed()
+        # mergeable after its segment was fed (queue wait + materialize
+        # + pack + spool). Its complement is the feed()
         # backpressure block (stage.backpressure_events): high wait =
         # the device is starved by the host, backpressure = the host is
         # throttled by the device.

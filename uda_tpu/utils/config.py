@@ -110,9 +110,9 @@ _FLAG_LIST = [
          "memory to the fetch window (the reference's 1 MB staging-loop "
          "memory model, StreamRW.cc:151-225); off = keep every segment "
          "host-resident through emission"),
-    # --- staged fetch->decompress->pack->stage pipeline (merger/overlap) ---
+    # --- staged fetch->pack->stage pipeline (merger/overlap) ---
     Flag("uda.tpu.stage.pool", 0, int,
-         "stage-pipeline worker count (decompress + vint-decode/pack + "
+         "stage-pipeline worker count (materialize + vint-decode/pack + "
          "row build + spool, concurrent across segments, feeding ONE "
          "merge consumer); 0 = auto (a few workers, ~min(4, cores) — "
          "staging is numpy-heavy and releases the GIL)"),

@@ -62,8 +62,10 @@ SPAN_BUCKETS: Dict[str, str] = {
     "fetch": "fetch", "fetch.segment": "fetch", "net.fetch": "fetch",
     "net.size_probe": "fetch", "net.job_bind": "fetch",
     # inside the completion upcall: a decoded frame queued for the one
-    # upcall thread, and the chunk's cracking
-    "net.dispatch.wait": "fetch", "fetch_crack": "fetch",
+    # upcall thread, a compressed chunk's inflate (compress/) and the
+    # chunk's cracking
+    "net.dispatch.wait": "fetch", "fetch_inflate": "fetch",
+    "fetch_crack": "fetch",
     # wait: blocked-on-memory / blocked-on-staging idle (hbm_admit: a
     # task parked behind the live tasks' HBM reservations;
     # fetch_feed_wait: the upcall blocked in feed() on staging's budget

@@ -409,6 +409,27 @@ METRICS_REGISTRY: Dict[str, tuple] = {
                                         "default)"),
     "decompress.bytes": ("counter", "uncompressed bytes produced by the "
                                     "decompressing fetch client"),
+    "decompress.blocks": ("counter", "compressed blocks the "
+                                     "decompressing fetch client inflated "
+                                     "(one codec call each; timer "
+                                     "fetch_inflate holds their seconds)"),
+    "decompress.wire_bytes": ("counter", "compressed bytes the "
+                                         "decompressing fetch client took "
+                                         "from its inner transport (block "
+                                         "headers included): the "
+                                         "partition's part_length once its "
+                                         "stream ends"),
+    "decompress.fetches": ("counter", "compressed-domain inner fetches "
+                                      "the decompressing fetch client "
+                                      "issued (each the compressed "
+                                      "sub-buffer's size, mapred.rdma."
+                                      "compression.buffer.ratio of the "
+                                      "buffer)"),
+    "decompress.carry_bytes": ("counter", "bytes of a partial trailing "
+                                          "block the decompressing fetch "
+                                          "client carried to the next "
+                                          "inner fetch (copied again "
+                                          "there)"),
     # -- counters: network data plane (uda_tpu/net/) ---------------------
     "net.accepts": ("counter", "connections accepted by the shuffle "
                                "server"),
@@ -685,7 +706,7 @@ METRICS_REGISTRY: Dict[str, tuple] = {
     "merge.wait_ms": ("histogram", "how long the merge waited for a "
                                    "run to become mergeable after its "
                                    "segment was fed (queue wait + "
-                                   "decompress tail + pack + spool) — "
+                                   "materialize + pack + spool) — "
                                    "the device-starvation signal; its "
                                    "complement is the feed() "
                                    "backpressure block "
